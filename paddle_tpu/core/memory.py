@@ -140,17 +140,4 @@ def compiled_memory_stats(compiled: Any) -> Dict[str, int]:
         "generated_code_size_in_bytes",
     ):
         out[k] = int(getattr(ma, k, 0))
-    if not hasattr(ma, "peak_memory_in_bytes"):
-        # jax 0.4.37's CompiledMemoryStats predates the PJRT peak field;
-        # arguments + outputs + temps are simultaneously live at the peak of
-        # one program execution — minus aliased bytes, where a donated input's
-        # buffer IS the output (counting both would overstate peak by the
-        # whole donated KV pool on the engine's decode step).
-        out["peak_memory_in_bytes"] = max(
-            out["argument_size_in_bytes"]
-            + out["output_size_in_bytes"]
-            + out["temp_size_in_bytes"]
-            - out["alias_size_in_bytes"],
-            0,
-        )
     return out

@@ -36,22 +36,12 @@ __all__ = [
 ]
 
 
-def _lax_axis_size(axis: str):
-    """``jax.lax.axis_size`` with a jax<0.5 fallback: ``psum(1, axis)``
-    constant-folds to the same static size (and raises the same ``NameError``
-    for an unbound axis name)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis)
-
-
 def _axis_in_trace(axis: Optional[str]) -> bool:
     """True when `axis` is a bound shard_map/pmap axis in the current trace."""
     if axis is None:
         return False
     try:
-        _lax_axis_size(axis)
+        jax.lax.axis_size(axis)
         return True
     except NameError:
         return False
@@ -101,8 +91,8 @@ def _merged_spec(t: Any, dim: Optional[int], axis: str) -> PartitionSpec:
         spec = list(current.spec) + [None] * (ndim - len(current.spec))
         for i, e in enumerate(spec):
             if not isinstance(e, (str, tuple, list)):
-                # None, or jax<0.5's UNCONSTRAINED singleton (not iterable):
-                # neither pins this dim to a mesh axis — nothing to preserve
+                # None or UNCONSTRAINED: neither pins this dim to a mesh
+                # axis — nothing to preserve
                 continue
             kept = tuple(a for a in ((e,) if isinstance(e, str) else tuple(e)) if a != axis)
             entries[i] = kept[0] if len(kept) == 1 else (kept or None)
@@ -200,7 +190,7 @@ def _c_split_op(x: Any, *, axis: str) -> Any:
     # keep own chunk of last dim; bwd = all_gather
     @jax.custom_vjp
     def f(v):
-        world = _lax_axis_size(axis)
+        world = jax.lax.axis_size(axis)
         if v.shape[-1] % world != 0:
             raise ValueError(
                 f"_c_split: last dim {v.shape[-1]} not divisible by mp world size {world}"

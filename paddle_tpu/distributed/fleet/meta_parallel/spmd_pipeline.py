@@ -24,27 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover - jax<0.6 fallback
-    import inspect
-
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    _SM_PARAMS = inspect.signature(_experimental_shard_map).parameters
-
-    def shard_map(f, mesh=None, **kw):  # type: ignore[misc]
-        """New-API ``jax.shard_map`` surface over the experimental one:
-        ``axis_names={...}`` becomes its complement in ``auto=``, and
-        ``check_vma=`` maps back to its old name ``check_rep=``."""
-        if "axis_names" in kw and "axis_names" not in _SM_PARAMS:
-            axis_names = kw.pop("axis_names")
-            auto = frozenset(getattr(mesh, "axis_names", ())) - frozenset(axis_names)
-            if auto:
-                kw["auto"] = auto
-        if "check_vma" in kw and "check_vma" not in _SM_PARAMS:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _experimental_shard_map(f, mesh=mesh, **kw)
-
 __all__ = [
     "pipeline",
     "pipeline_interleaved",
@@ -181,7 +160,7 @@ def _build_pipeline_callable(
     # stage compute — specs may only mention `axis_name`. Partial-manual
     # shard_map only lowers inside a jit scope, so wrap the call (a no-op
     # nesting when the caller is already tracing).
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_fn,
         mesh=jmesh,
         in_specs=(param_specs, mb_spec),
@@ -305,7 +284,7 @@ def _build_interleaved_callable(
         )
         return outputs
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_fn,
         mesh=jmesh,
         in_specs=(param_specs, mb_spec),
@@ -591,7 +570,7 @@ def _build_zero_bubble_callable(stage_fn, jmesh, axis_name, S, V, M, param_treed
         return dparams, dmb
 
     mapped_fwd = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_fwd,
             mesh=jmesh,
             in_specs=(param_specs, mb_spec),
@@ -601,7 +580,7 @@ def _build_zero_bubble_callable(stage_fn, jmesh, axis_name, S, V, M, param_treed
         )
     )
     mapped_bwd = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_bwd,
             mesh=jmesh,
             in_specs=(param_specs, save_spec, mb_spec),

@@ -24,7 +24,6 @@ from paddle_tpu.core.dispatch import defop
 from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import (
     _axis_in_trace,
     _get_mp_env,
-    _lax_axis_size,
 )
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn.layer.layers import Layer
@@ -56,7 +55,7 @@ def _scatter_op(x: Any, *, axis: str) -> Any:
     # fwd: keep own seq chunk; bwd: all-gather seq (GatherOp's forward)
     @jax.custom_vjp
     def f(v):
-        world = _lax_axis_size(axis)
+        world = jax.lax.axis_size(axis)
         _check_divisible(v.shape[_SEQ_DIM], world, "ScatterOp")
         idx = jax.lax.axis_index(axis)
         d = v.shape[_SEQ_DIM] // world

@@ -60,7 +60,13 @@ def _parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
 
 
 def _child_env(args: argparse.Namespace, local_rank: int) -> Dict[str, str]:
+    from paddle_tpu.core.compile_cache import compile_cache_dir
+
     env = dict(os.environ)
+    # workers share one persistent compile cache (theirs to read: the
+    # launcher itself never touches the jax backend — a chip belongs to one
+    # process, and it must be the worker)
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
     world = args.nnodes * args.nproc_per_node
     global_rank = args.rank * args.nproc_per_node + local_rank
     env["PADDLE_TRAINER_ID"] = str(global_rank)

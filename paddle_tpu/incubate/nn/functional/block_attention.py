@@ -64,7 +64,6 @@ def _tp_sharded_flash_chunk(
     shard split itself is testable off-TPU."""
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.distributed.fleet.meta_parallel.spmd_pipeline import shard_map
     from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
     in_specs = [
@@ -87,7 +86,7 @@ def _tp_sharded_flash_chunk(
             interpret=interpret, k_scale=ks_l, v_scale=vs_l,
         )
 
-    return shard_map(
+    return jax.shard_map(
         _shard_chunk_attend,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -115,7 +114,6 @@ def _tp_sharded_flash_chunk_fused(
     while q/caches (and scale planes) split over the head partition."""
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.distributed.fleet.meta_parallel.spmd_pipeline import shard_map
     from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused
 
     in_specs = [
@@ -141,7 +139,7 @@ def _tp_sharded_flash_chunk_fused(
             scale=scale, interpret=interpret, k_scale=ks_l, v_scale=vs_l,
         )
 
-    return shard_map(
+    return jax.shard_map(
         _shard_chunk_attend,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -710,52 +708,37 @@ def block_multihead_chunk_attention(
             return out, key_cache, value_cache, key_scale, value_scale
         return out, key_cache, value_cache
 
-    if pallas_enabled("use_pallas_paged_attention"):
+    if pallas_enabled("use_pallas_paged_attention", shard_mapped=True):
         # ragged mixed prefill/decode kernel: one grid walks each sequence's
         # physical blocks once, serving its decode row and its prompt-chunk
-        # rows alike; applicability is probed host-side at trace time (a
-        # Mosaic error inside the jitted step is uncatchable at run time).
-        # Under a tensor-parallel mesh the kernel runs shard_mapped over the
-        # head partition, so the probe uses the PER-SHARD geometry.
-        from paddle_tpu.kernels.paged_attention import (
-            chunk_lowering_supported,
-            paged_flash_chunk,
-        )
+        # rows alike. The kernel is REQUIRED to compile on TPU
+        # (tests/test_tpu_aot_compile.py): only a trace-time failure degrades
+        # to the XLA path below. Under a tensor-parallel mesh the kernel runs
+        # shard_mapped over the head partition.
+        from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
-        nb, hkv_c, bs, d_c = key_cache.shape
         tp_mesh = _current_tp_mesh()
-        ntp = tp_mesh.shape["tp"] if tp_mesh is not None else 1
-        if chunk_lowering_supported(
-            b, c, hq // ntp, hkv_c // ntp, d_c, nb, bs,
-            block_tables.shape[1], str(q.dtype),
-            kv_dtype=str(key_cache.dtype) if quantized else "",
-        ):
-            try:
-                if quantized:
-                    # injected dequant failure degrades THIS dispatch to the
-                    # XLA fallback below (counted), never the engine's
-                    # recovery path — the except arm swallows it
-                    _fault_point("quant.dequant")
-                if tp_mesh is not None:
-                    out = _tp_sharded_flash_chunk(
-                        q, key_cache, value_cache, block_tables,
-                        seq_lens, attend_q, scale, tp_mesh,
-                        k_scale=key_scale, v_scale=value_scale,
-                    )
-                else:
-                    out = paged_flash_chunk(
-                        q, key_cache, value_cache, block_tables,
-                        seq_lens, attend_q, scale=scale,
-                        k_scale=key_scale, v_scale=value_scale,
-                    )
-                return _ret(out)
-            except Exception as exc:  # noqa: BLE001 - XLA fallback below
-                warn_fallback("paged_flash_chunk", exc)
-        else:
-            warn_fallback(
-                "paged_flash_chunk",
-                RuntimeError("Mosaic lowering unsupported for geometry"),
-            )
+        try:
+            if quantized:
+                # injected dequant failure degrades THIS dispatch to the
+                # XLA fallback below (counted), never the engine's
+                # recovery path — the except arm swallows it
+                _fault_point("quant.dequant")
+            if tp_mesh is not None:
+                out = _tp_sharded_flash_chunk(
+                    q, key_cache, value_cache, block_tables,
+                    seq_lens, attend_q, scale, tp_mesh,
+                    k_scale=key_scale, v_scale=value_scale,
+                )
+            else:
+                out = paged_flash_chunk(
+                    q, key_cache, value_cache, block_tables,
+                    seq_lens, attend_q, scale=scale,
+                    k_scale=key_scale, v_scale=value_scale,
+                )
+            return _ret(out)
+        except Exception as exc:  # noqa: BLE001 - XLA fallback below
+            warn_fallback("paged_flash_chunk", exc)
     out = _gather_chunk_attend(
         q, key_cache, value_cache, block_tables, seq_lens, attend_q, scale,
         k_scale=key_scale, v_scale=value_scale,
@@ -820,45 +803,30 @@ def block_multihead_chunk_attention_fused(
             return out, key_cache, value_cache, key_scale, value_scale
         return out, key_cache, value_cache
 
-    if pallas_enabled("use_pallas_paged_attention"):
-        from paddle_tpu.kernels.paged_attention import (
-            chunk_fused_lowering_supported,
-            paged_flash_chunk_fused,
-        )
+    if pallas_enabled("use_pallas_paged_attention", shard_mapped=True):
+        from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused
 
-        nb, hkv_c, bs, d_c = key_cache.shape
         tp_mesh = _current_tp_mesh()
-        ntp = tp_mesh.shape["tp"] if tp_mesh is not None else 1
         cos3 = cos.reshape(b, c, d)
         sin3 = sin.reshape(b, c, d)
-        if chunk_fused_lowering_supported(
-            b, c, hq // ntp, hkv_c // ntp, d_c, nb, bs,
-            block_tables.shape[1], str(q.dtype),
-            kv_dtype=str(key_cache.dtype) if quantized else "",
-        ):
-            try:
-                if quantized:
-                    _fault_point("quant.dequant")
-                if tp_mesh is not None:
-                    out = _tp_sharded_flash_chunk_fused(
-                        q, cos3, sin3, key_cache, value_cache, block_tables,
-                        seq_lens, attend_q, scale, tp_mesh,
-                        k_scale=key_scale, v_scale=value_scale,
-                    )
-                else:
-                    out = paged_flash_chunk_fused(
-                        q, cos3, sin3, key_cache, value_cache, block_tables,
-                        seq_lens, attend_q, scale=scale,
-                        k_scale=key_scale, v_scale=value_scale,
-                    )
-                return _ret(out)
-            except Exception as exc:  # noqa: BLE001 - XLA fallback below
-                warn_fallback("paged_flash_chunk_fused", exc)
-        else:
-            warn_fallback(
-                "paged_flash_chunk_fused",
-                RuntimeError("Mosaic lowering unsupported for geometry"),
-            )
+        try:
+            if quantized:
+                _fault_point("quant.dequant")
+            if tp_mesh is not None:
+                out = _tp_sharded_flash_chunk_fused(
+                    q, cos3, sin3, key_cache, value_cache, block_tables,
+                    seq_lens, attend_q, scale, tp_mesh,
+                    k_scale=key_scale, v_scale=value_scale,
+                )
+            else:
+                out = paged_flash_chunk_fused(
+                    q, cos3, sin3, key_cache, value_cache, block_tables,
+                    seq_lens, attend_q, scale=scale,
+                    k_scale=key_scale, v_scale=value_scale,
+                )
+            return _ret(out)
+        except Exception as exc:  # noqa: BLE001 - XLA fallback below
+            warn_fallback("paged_flash_chunk_fused", exc)
     # lockstep fallback: the SAME rope composition the unfused path applies,
     # then the shared dense-gather attention
     q = _rope_apply_xla(q, sin, cos, True)
@@ -920,36 +888,22 @@ def block_multihead_attention(
 
     if pallas_enabled("use_pallas_paged_attention"):
         # block-table flash-decode kernel: streams only this sequence's
-        # physical blocks HBM -> VMEM (no dense [B, MBS*BS, H, D] gather).
-        # Applicability is checked with a cached host-side lowering probe
-        # BEFORE the kernel is baked into the trace — a Mosaic error inside
-        # a jitted decode step could not be caught here at run time.
-        from paddle_tpu.kernels.paged_attention import (
-            lowering_supported,
-            paged_flash_decode,
-        )
+        # physical blocks HBM -> VMEM (no dense [B, MBS*BS, H, D] gather);
+        # only a trace-time failure degrades to the XLA path below
+        from paddle_tpu.kernels.paged_attention import paged_flash_decode
 
-        nb, hkv_c, bs, d_c = key_cache.shape
-        if lowering_supported(
-            b, hq, hkv_c, d_c, nb, bs, block_tables.shape[1], str(q.dtype),
-            kv_dtype=str(key_cache.dtype) if quantized else "",
-        ):
-            try:
-                if quantized:
-                    _fault_point("quant.dequant")
-                out = paged_flash_decode(
-                    q[:, 0], key_cache, value_cache, block_tables,
-                    attend_lens,  # kernel masks pos < len INCLUDING this token
-                    scale=scale,
-                    k_scale=key_scale, v_scale=value_scale,
-                )
-                return _ret(out[:, None])
-            except Exception as exc:  # noqa: BLE001 - XLA fallback below
-                warn_fallback("paged_flash_decode", exc)
-        else:
-            warn_fallback(
-                "paged_flash_decode", RuntimeError("Mosaic lowering unsupported for geometry")
+        try:
+            if quantized:
+                _fault_point("quant.dequant")
+            out = paged_flash_decode(
+                q[:, 0], key_cache, value_cache, block_tables,
+                attend_lens,  # kernel masks pos < len INCLUDING this token
+                scale=scale,
+                k_scale=key_scale, v_scale=value_scale,
             )
+            return _ret(out[:, None])
+        except Exception as exc:  # noqa: BLE001 - XLA fallback below
+            warn_fallback("paged_flash_decode", exc)
     # the decode step IS the C == 1 chunk: one new row per sequence whose
     # causal limit is seq_lens + 1 (attend_lens), masked slots exact zeros
     out = _gather_chunk_attend(
@@ -1015,39 +969,25 @@ def block_multihead_attention_fused(
         return out, key_cache, value_cache
 
     if pallas_enabled("use_pallas_paged_attention"):
-        # rope-fused flash-decode kernel; same cached host-side lowering
-        # probe contract as the unfused decode dispatch above — a Mosaic
-        # error inside the jitted decode step is uncatchable at run time
-        from paddle_tpu.kernels.paged_attention import (
-            decode_fused_lowering_supported,
-            paged_flash_decode_fused,
-        )
+        # rope-fused flash-decode kernel; same contract as the unfused
+        # decode dispatch above
+        from paddle_tpu.kernels.paged_attention import paged_flash_decode_fused
 
-        nb, hkv_c, bs, d_c = key_cache.shape
         cos3 = cos.reshape(b, 1, d)
         sin3 = sin.reshape(b, 1, d)
-        if decode_fused_lowering_supported(
-            b, hq, hkv_c, d_c, nb, bs, block_tables.shape[1], str(q.dtype),
-            kv_dtype=str(key_cache.dtype) if quantized else "",
-        ):
-            try:
-                if quantized:
-                    _fault_point("quant.dequant")
-                out = paged_flash_decode_fused(
-                    q[:, 0], cos3, sin3, key_cache, value_cache,
-                    block_tables,
-                    attend_lens,  # kernel masks pos < len INCLUDING this token
-                    scale=scale,
-                    k_scale=key_scale, v_scale=value_scale,
-                )
-                return _ret(out[:, None])
-            except Exception as exc:  # noqa: BLE001 - XLA fallback below
-                warn_fallback("paged_flash_decode_fused", exc)
-        else:
-            warn_fallback(
-                "paged_flash_decode_fused",
-                RuntimeError("Mosaic lowering unsupported for geometry"),
+        try:
+            if quantized:
+                _fault_point("quant.dequant")
+            out = paged_flash_decode_fused(
+                q[:, 0], cos3, sin3, key_cache, value_cache,
+                block_tables,
+                attend_lens,  # kernel masks pos < len INCLUDING this token
+                scale=scale,
+                k_scale=key_scale, v_scale=value_scale,
             )
+            return _ret(out[:, None])
+        except Exception as exc:  # noqa: BLE001 - XLA fallback below
+            warn_fallback("paged_flash_decode_fused", exc)
     # lockstep fallback: the SAME rope composition the unfused path applies,
     # then the shared dense-gather attention (C == 1 chunk)
     q = _rope_apply_xla(q, sin, cos, True)

@@ -24,7 +24,12 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-__all__ = ["greedy_token_match", "max_logit_error", "quality_delta"]
+__all__ = [
+    "engine_first_step_logits",
+    "greedy_token_match",
+    "max_logit_error",
+    "quality_delta",
+]
 
 
 def _run_engine(
@@ -94,6 +99,69 @@ def max_logit_error(
         got = np.asarray(q_model(ids).numpy(), np.float32)
         worst = max(worst, float(np.max(np.abs(ref - got))))
     return worst
+
+
+def engine_first_step_logits(engine: Any, prompt: Any) -> np.ndarray:
+    """fp32 logits ``[len(prompt), V]`` of ``engine``'s step math on its first
+    prompt chunk: the same paged 6-tuple past, ``[max_slots, prefill_chunk]``
+    token block and (under tp) armed shard group ``_step_impl`` traces, on an
+    empty scratch pool of one sequence's blocks — the step itself only hands
+    back argmaxes. Compared against the dense ``model(ids)`` forward this
+    isolates the paged kernels from everything host-side. bf16 KV only;
+    ``prompt`` is cut to one chunk."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.nn.layer.layers import bind_param_arrays
+
+    if engine.kv_cache_dtype != "bf16":
+        raise ValueError("engine_first_step_logits compares the bf16 KV plane only")
+    slots, chunk = engine.max_slots, engine.prefill_chunk
+    ids = np.asarray(prompt, np.int32)[:chunk]
+    n = len(ids)
+    mbs = engine.max_blocks_per_seq
+    toks = np.zeros((slots, chunk), np.int32)
+    toks[0, :n] = ids
+    tables = np.zeros((slots, mbs), np.int32)
+    tables[0] = np.arange(mbs)
+    q_lens = np.zeros((slots,), np.int32)
+    q_lens[0] = n
+    active = np.zeros((slots,), bool)
+    active[0] = True
+    shape = (mbs,) + tuple(engine._cache_shape[1:])
+    named, model = engine._named, engine.model
+
+    def step(arrays, toks, tables, lens, q_lens, active):
+        with bind_param_arrays(named, arrays), paddle_tpu.no_grad():
+            pkv = [
+                (
+                    Tensor(jnp.zeros(shape, engine._cache_dtype)),
+                    Tensor(jnp.zeros(shape, engine._cache_dtype)),
+                    Tensor(tables), Tensor(lens), Tensor(active), Tensor(q_lens),
+                )
+                for _ in range(engine._num_layers)
+            ]
+            logits, _ = model(
+                Tensor(toks), past_key_values=pkv, use_cache=True,
+                cache_position=Tensor(lens),
+            )
+        return logits._data[0].astype(jnp.float32)
+
+    tp_ctx = (
+        engine._tp_ctx(engine._tp_mesh)
+        if engine._tp_mesh is not None
+        else contextlib.nullcontext()
+    )
+    with tp_ctx:
+        out = jax.jit(step)(
+            [p._data for _, p in named], jnp.asarray(toks), jnp.asarray(tables),
+            jnp.zeros((slots,), jnp.int32), jnp.asarray(q_lens), jnp.asarray(active),
+        )
+    return np.asarray(out)[:n]
 
 
 def quality_delta(

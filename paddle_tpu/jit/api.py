@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.core import autograd as _ag
 from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.kernels.select import gspmd_trace
 from paddle_tpu.observability.recompile import (
     CAUSE_FIRST_CALL,
     CAUSE_MODE_FLIP,
@@ -274,10 +275,19 @@ class StaticFunction:
 
             self._cache[key] = jax.jit(staged, donate_argnums=(0, 1))
 
+        # the Python body runs inside this call whenever jax traces (this
+        # cache's misses, and jit's own re-traces, e.g. once the optimizer
+        # state exists): tell the kernel dispatch whether GSPMD will
+        # partition the trace
+        spans_devices = any(
+            len(getattr(getattr(a, "sharding", None), "device_set", ())) > 1
+            for a in state_arrays + in_arrays
+        )
         try:
-            out_arrays, new_state, new_opt, new_rng = self._cache[key](
-                state_arrays, opt_states, rng_key, in_arrays
-            )
+            with gspmd_trace(spans_devices):
+                out_arrays, new_state, new_opt, new_rng = self._cache[key](
+                    state_arrays, opt_states, rng_key, in_arrays
+                )
         except _TRACE_BREAK_ERRORS as exc:
             self._cache.pop(key, None)
             if self._full_graph:

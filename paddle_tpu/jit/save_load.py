@@ -18,7 +18,6 @@ import pickle
 from typing import Any, Callable, List, Optional, Sequence
 
 import jax
-import jax.export  # noqa: F401  (jax 0.4.x: not re-exported by `import jax`)
 import jax.numpy as jnp
 import numpy as np
 

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.kernels.select import _CompilerParams
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -176,7 +175,7 @@ def _run_fwd(q, k, v, idx, *, sq, sk, scale, causal, blk_q, blk_k, interpret):
         grid=grid,
         # every (batch, head, q-block) cell is independent — Mosaic may split
         # them across TensorCores (megacore on v4/v5p)
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         in_specs=in_specs,
@@ -334,7 +333,7 @@ def _run_bwd(q, k, v, idx, g, out, lse, *, sq, sk, scale, causal, blk_q, blk_k, 
     dq = pl.pallas_call(
         dq_kernel,
         grid=(b, h, sq_pad // blk_q),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         in_specs=dq_specs,
@@ -379,7 +378,7 @@ def _run_bwd(q, k, v, idx, g, out, lse, *, sq, sk, scale, causal, blk_q, blk_k, 
     dk_h, dv_h = pl.pallas_call(
         dkv_kernel,
         grid=(b, h, sk_pad // blk_k),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         in_specs=dkv_specs,

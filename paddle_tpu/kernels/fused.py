@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.kernels.select import _CompilerParams
 
 __all__ = [
     "fused_rms_norm_pallas",
@@ -109,7 +108,7 @@ def _make_rms(rows, h, eps, blk_rows, interpret):
             functools.partial(_rms_fwd_kernel, eps=eps),
             grid=grid,
             # independent row blocks: megacore-splittable
-            compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
             in_specs=[
                 pl.BlockSpec((1, blk_rows, h), lambda i: (0, i, 0)),
                 pl.BlockSpec((h,), lambda i: (0,)),
@@ -141,7 +140,7 @@ def _make_rms(rows, h, eps, blk_rows, interpret):
             grid=grid,
             # dw accumulates across the grid in one output block: the grid
             # MUST run sequentially ("arbitrary"), never be split
-            compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
             in_specs=[
                 pl.BlockSpec((1, blk_rows, h), lambda i: (0, i, 0)),
                 pl.BlockSpec((h,), lambda i: (0,)),
@@ -246,7 +245,7 @@ def _make_rope_runner(bh, s, d, interpret):
             kernel,
             grid=grid,
             # independent (batch*head) cells
-            compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
             in_specs=in_specs,
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((bh, 1, s, d), xh.dtype),
@@ -440,7 +439,7 @@ def _rms_res_fwd_call(x2, res2, w, eps, blk, interpret):
     return pl.pallas_call(
         functools.partial(_rms_res_fwd_kernel, eps=eps),
         grid=(rows // blk,),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         in_specs=[spec, spec, pl.BlockSpec((h,), lambda i: (0,))],
         out_specs=[spec, spec],
         out_shape=[
@@ -458,7 +457,7 @@ def _rms_res_adjoint_call(g2, r2, w, eps, blk, interpret):
         functools.partial(_rms_res_bwd_kernel, eps=eps),
         grid=(rows // blk,),
         # dw accumulates across the grid: sequential, never megacore-split
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         in_specs=[spec, pl.BlockSpec((h,), lambda i: (0,)), spec],
         out_specs=[spec, pl.BlockSpec((1, h), lambda i: (0, 0))],
         out_shape=[
@@ -476,7 +475,7 @@ def _ln_res_fwd_call(x2, res2, w, b, eps, blk, interpret):
     return pl.pallas_call(
         functools.partial(_ln_res_fwd_kernel, eps=eps),
         grid=(rows // blk,),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         in_specs=[spec, spec, wspec, wspec],
         out_specs=[spec, spec],
         out_shape=[
@@ -493,7 +492,7 @@ def _ln_res_adjoint_call(g2, r2, w, eps, blk, interpret):
     return pl.pallas_call(
         functools.partial(_ln_res_bwd_kernel, eps=eps),
         grid=(rows // blk,),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         in_specs=[spec, pl.BlockSpec((h,), lambda i: (0,)), spec],
         out_specs=[
             spec,
@@ -615,18 +614,32 @@ def layer_norm_residual_adjoint_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _embed_rms_kernel(ids_ref, row_ref, w_ref, emb_ref, y_ref, *, eps):
+# rows per block on both sides of the gather: a (1, H) block over [V, H] or
+# [N, H] breaks the TPU rule that a block's last two dims are multiples of
+# (8, 128) or the full dims; 16 is the bf16 sublane tile and legal for f32
+_EMBED_GROUP = 16
+
+
+def _embed_rms_kernel(ids_ref, rows_ref, w_ref, emb_ref, y_ref, *, eps):
     # ids_ref is the scalar-prefetched token vector that already steered this
-    # grid cell's row_ref block onto the right embedding row — the gather IS
-    # the BlockSpec index map, so the dense [N, V] one-hot / XLA gather
-    # round-trip never materializes. One cell = one token row.
-    row = row_ref[...]  # [1, H] embedding row, table dtype
-    emb_ref[...] = row.astype(emb_ref.dtype)
-    xf = row.astype(jnp.float32)
+    # grid cell's rows_ref block onto the aligned row group holding the
+    # token's embedding row — the gather IS the BlockSpec index map, so the
+    # dense [N, V] one-hot / XLA gather round-trip never materializes. One
+    # cell = one token; _EMBED_GROUP consecutive cells share an output block.
+    i = pl.program_id(0)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (_EMBED_GROUP, 1), 0)
+    group = rows_ref[...].astype(jnp.float32)  # [G, H]
+    # exact row select: every other term of the sum is 0
+    xf = jnp.sum(
+        jnp.where(sub == ids_ref[i] % _EMBED_GROUP, group, 0.0), axis=0, keepdims=True
+    )
     w = w_ref[...].astype(jnp.float32)
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
     # same op order as _rms_fwd_kernel (bitwise-matched vs the unfused path)
-    y_ref[...] = (xf * jax.lax.rsqrt(ms + eps) * w[None, :]).astype(y_ref.dtype)
+    y = xf * jax.lax.rsqrt(ms + eps) * w[None, :]
+    mine = sub == i % _EMBED_GROUP  # this token's row of the shared out block
+    emb_ref[...] = jnp.where(mine, xf.astype(emb_ref.dtype), emb_ref[...])
+    y_ref[...] = jnp.where(mine, y.astype(y_ref.dtype), y_ref[...])
 
 
 def fused_embed_rms_norm_pallas(
@@ -639,31 +652,37 @@ def fused_embed_rms_norm_pallas(
     """Chunk-step entry fusion: token-id gather + embedding row load + the
     first decoder layer's pre-attention RMSNorm in ONE dispatch. The
     scalar-prefetched ids steer the BlockSpec index map (the same trick the
-    paged-attention block table plays), so each grid cell streams exactly its
-    token's [1, H] row HBM -> VMEM and writes the raw embedding (the layer
-    loop's residual stream) plus its normed form. Returns ``(emb, y)``, both
-    ``[B, C, H]`` in the table dtype. Inference-only (the serving step) —
-    there is no backward; training embeds through the regular op."""
+    paged-attention block table plays), so each grid cell streams the
+    aligned ``[_EMBED_GROUP, H]`` row group around its token HBM -> VMEM and
+    writes the raw embedding (the layer loop's residual stream) plus its
+    normed form. Returns ``(emb, y)``, both ``[B, C, H]`` in the table dtype.
+    Inference-only (the serving step) — there is no backward; training
+    embeds through the regular op."""
     b, c = ids.shape
     v, h = table.shape
     n = b * c
+    n_pad = -(-n // _EMBED_GROUP) * _EMBED_GROUP
     flat = jnp.clip(ids.reshape(n).astype(jnp.int32), 0, v - 1)
-    row_spec = pl.BlockSpec((1, h), lambda i, ids: (ids[i], 0))
-    out_spec = pl.BlockSpec((1, h), lambda i, ids: (i, 0))
+    if n_pad > n:
+        flat = jnp.pad(flat, (0, n_pad - n))
+    rows_spec = pl.BlockSpec(
+        (_EMBED_GROUP, h), lambda i, ids: (ids[i] // _EMBED_GROUP, 0)
+    )
+    out_spec = pl.BlockSpec((_EMBED_GROUP, h), lambda i, ids: (i // _EMBED_GROUP, 0))
     emb, y = pl.pallas_call(
         functools.partial(_embed_rms_kernel, eps=float(epsilon)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n,),
-            in_specs=[row_spec, pl.BlockSpec((h,), lambda i, ids: (0,))],
+            grid=(n_pad,),
+            in_specs=[rows_spec, pl.BlockSpec((h,), lambda i, ids: (0,))],
             out_specs=[out_spec, out_spec],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((n, h), table.dtype),
-            jax.ShapeDtypeStruct((n, h), table.dtype),
+            jax.ShapeDtypeStruct((n_pad, h), table.dtype),
+            jax.ShapeDtypeStruct((n_pad, h), table.dtype),
         ],
-        # token cells are independent: megacore-splittable
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        # consecutive cells revisit one output block: must run in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(flat, table, weight)
-    return emb.reshape(b, c, h), y.reshape(b, c, h)
+    return emb[:n].reshape(b, c, h), y[:n].reshape(b, c, h)

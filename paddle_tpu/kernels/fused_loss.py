@@ -46,8 +46,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.kernels.select import _CompilerParams, pallas_enabled, warn_fallback
+from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
 
 __all__ = ["fused_linear_cross_entropy"]
 
@@ -383,7 +384,7 @@ def _make_pallas_core(
             grid=(nr, nv),
             # row blocks are independent (megacore-splittable); the vocab dim
             # accumulates the online softmax state and MUST run sequentially
-            compiler_params=_CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
             in_specs=[row_spec, w_spec, col_spec],
             out_specs=[col_spec, col_spec, col_spec],
             out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.float32)] * 3,
@@ -399,7 +400,7 @@ def _make_pallas_core(
         dx = pl.pallas_call(
             functools.partial(_flxent_dx_kernel, **kw),
             grid=(nr, nv),
-            compiler_params=_CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
             in_specs=[row_spec, w_spec, col_spec, col_spec, col_spec],
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((n_pad, h), jnp.float32),
@@ -416,7 +417,7 @@ def _make_pallas_core(
         dw = pl.pallas_call(
             functools.partial(_flxent_dw_kernel, **kw),
             grid=(nv, nr),
-            compiler_params=_CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
             in_specs=[
                 pl.BlockSpec((blk_rows, h), lambda j, i: (i, 0)),
                 pl.BlockSpec((blk_v, h), lambda j, i: (j, 0))
@@ -480,7 +481,7 @@ def _make_pallas_quant_fwd(n_pad, v, vp, h, blk_rows, blk_v, vocab_major, interp
                 quantized=True,
             ),
             grid=(nr, nv),
-            compiler_params=_CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
             in_specs=[row_spec, w_spec, col_spec, s_spec],
             out_specs=[col_spec, col_spec, col_spec],
             out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.float32)] * 3,
@@ -523,7 +524,6 @@ def _pallas_quant_path(
 
 
 def _default_block(h: int, itemsize: int) -> Tuple[int, int]:
-    # dW kernel VMEM budget: x + w blocks (native dtype) + fp32 dw block;
     # larger hidden sizes need smaller blocks — pick the largest tier that
     # fits the same budget the autotune candidate filter enforces
     for cfg in ((512, 512), (256, 256), (128, 128)):
@@ -533,13 +533,18 @@ def _default_block(h: int, itemsize: int) -> Tuple[int, int]:
 
 
 def _vmem_ok(blk_rows: int, blk_v: int, h: int, itemsize: int) -> bool:
-    resident = (
+    """Whether the fattest backward kernel fits the chip's default 16 MiB
+    scoped-VMEM limit, counted as the TPU compiler counts it: the Pallas
+    pipeline double-buffers EVERY blocked operand — both inputs and the fp32
+    output accumulator (dW ``[blk_v, H]`` / dX ``[blk_rows, H]``) — and the
+    block's logits and their softmax copy live beside them."""
+    buffers = 2 * (
         blk_rows * h * itemsize  # x block
         + blk_v * h * itemsize  # w block
-        + blk_rows * blk_v * 4  # logits
-        + blk_v * h * 4  # fp32 dw accumulator (the fattest kernel's extra)
+        + max(blk_rows, blk_v) * h * 4  # fp32 dw / dx accumulator
     )
-    return resident <= 12 * 1024 * 1024
+    temporaries = 2 * blk_rows * blk_v * 4  # logits + (softmax - onehot)
+    return buffers + temporaries <= 16 * 1024 * 1024
 
 
 def _autotune_fused_loss(n, v, h, dtype, vocab_major, interpret):

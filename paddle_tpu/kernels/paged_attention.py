@@ -26,14 +26,11 @@ import functools
 from typing import Optional
 
 import jax
-import jax.export  # noqa: F401  (jax 0.4.x: not re-exported by `import jax`)
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-from paddle_tpu.kernels.select import _CompilerParams
 
 
 def _dequant_tile(k_ref, v_ref, ks_ref, vs_ref):
@@ -113,39 +110,6 @@ def _decode_kernel(
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.lru_cache(maxsize=64)
-def lowering_supported(b: int, hq: int, hkv: int, d: int, nb: int, bs: int, mbs: int,
-                       dtype: str, kv_dtype: str = "") -> bool:
-    """Static Mosaic-lowering probe, cached per geometry. A lowering error
-    inside a captured (jitted) decode step is uncatchable at run time — this
-    check runs host-side at TRACE time so the caller can route to the XLA
-    path instead (same rule as the bench preflight). ``kv_dtype`` names the
-    cache storage dtype when it differs from ``dtype`` (the quantized path);
-    empty = cache stores ``dtype``, the historical geometry."""
-    import numpy as np
-
-    q = jax.ShapeDtypeStruct((b, hq, d), np.dtype(dtype))
-    kc = jax.ShapeDtypeStruct((nb, hkv, bs, d), np.dtype(kv_dtype or dtype))
-    tb = jax.ShapeDtypeStruct((b, mbs), np.int32)
-    ln = jax.ShapeDtypeStruct((b,), np.int32)
-    try:
-        if kv_dtype:
-            sc = jax.ShapeDtypeStruct((nb, hkv, bs), np.float32)
-            jax.export.export(
-                jax.jit(lambda q, kc, vc, ks, vs, t, l: paged_flash_decode(
-                    q, kc, vc, t, l, k_scale=ks, v_scale=vs)),
-                platforms=["tpu"],
-            )(q, kc, kc, sc, sc, tb, ln)
-        else:
-            jax.export.export(
-                jax.jit(lambda q, kc, vc, t, l: paged_flash_decode(q, kc, vc, t, l)),
-                platforms=["tpu"],
-            )(q, kc, kc, tb, ln)
-        return True
-    except Exception:  # noqa: BLE001 - any lowering failure means "don't"
-        return False
-
-
 def paged_flash_decode(
     q: jax.Array,  # [B, HQ, D]
     key_cache: jax.Array,  # [NB, HKV, BS, D]
@@ -221,7 +185,7 @@ def paged_flash_decode(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         # batch and kv-head cells are independent; the block walk accumulates
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -319,38 +283,6 @@ def _chunk_kernel(
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
-@functools.lru_cache(maxsize=64)
-def chunk_lowering_supported(b: int, c: int, hq: int, hkv: int, d: int, nb: int,
-                             bs: int, mbs: int, dtype: str,
-                             kv_dtype: str = "") -> bool:
-    """Static Mosaic-lowering probe for the mixed prefill/decode kernel,
-    cached per geometry (same rule as :func:`lowering_supported`)."""
-    import numpy as np
-
-    q = jax.ShapeDtypeStruct((b, c, hq, d), np.dtype(dtype))
-    kc = jax.ShapeDtypeStruct((nb, hkv, bs, d), np.dtype(kv_dtype or dtype))
-    tb = jax.ShapeDtypeStruct((b, mbs), np.int32)
-    ln = jax.ShapeDtypeStruct((b,), np.int32)
-    try:
-        if kv_dtype:
-            sc = jax.ShapeDtypeStruct((nb, hkv, bs), np.float32)
-            jax.export.export(
-                jax.jit(lambda q, kc, vc, ks, vs, t, l, ql: paged_flash_chunk(
-                    q, kc, vc, t, l, ql, k_scale=ks, v_scale=vs)),
-                platforms=["tpu"],
-            )(q, kc, kc, sc, sc, tb, ln, ln)
-        else:
-            jax.export.export(
-                jax.jit(
-                    lambda q, kc, vc, t, l, ql: paged_flash_chunk(q, kc, vc, t, l, ql)
-                ),
-                platforms=["tpu"],
-            )(q, kc, kc, tb, ln, ln)
-        return True
-    except Exception:  # noqa: BLE001 - any lowering failure means "don't"
-        return False
-
-
 def paged_flash_chunk(
     q: jax.Array,  # [B, C, HQ, D] ragged chunk (row j valid iff j < q_lens)
     key_cache: jax.Array,  # [NB, HKV, BS, D] chunk KV ALREADY appended
@@ -431,7 +363,7 @@ def paged_flash_chunk(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, d), q.dtype),
         # batch and kv-head cells are independent; the block walk accumulates
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -534,43 +466,6 @@ def _decode_fused_kernel(
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.lru_cache(maxsize=64)
-def decode_fused_lowering_supported(b: int, hq: int, hkv: int, d: int, nb: int,
-                                    bs: int, mbs: int, dtype: str,
-                                    kv_dtype: str = "") -> bool:
-    """Static Mosaic-lowering probe for the rope-fused decode kernel (the
-    lane-dim concat split can fail lowering for some D — same routing rule
-    as :func:`lowering_supported`)."""
-    import numpy as np
-
-    q = jax.ShapeDtypeStruct((b, hq, d), np.dtype(dtype))
-    cs = jax.ShapeDtypeStruct((b, 1, d), np.dtype(dtype))
-    kc = jax.ShapeDtypeStruct((nb, hkv, bs, d), np.dtype(kv_dtype or dtype))
-    tb = jax.ShapeDtypeStruct((b, mbs), np.int32)
-    ln = jax.ShapeDtypeStruct((b,), np.int32)
-    try:
-        if kv_dtype:
-            sc = jax.ShapeDtypeStruct((nb, hkv, bs), np.float32)
-            jax.export.export(
-                jax.jit(lambda q, c, s, kc, vc, ks, vs, t, l:
-                        paged_flash_decode_fused(
-                            q, c, s, kc, vc, t, l, k_scale=ks, v_scale=vs)),
-                platforms=["tpu"],
-            )(q, cs, cs, kc, kc, sc, sc, tb, ln)
-        else:
-            jax.export.export(
-                jax.jit(
-                    lambda q, c, s, kc, vc, t, l: paged_flash_decode_fused(
-                        q, c, s, kc, vc, t, l
-                    )
-                ),
-                platforms=["tpu"],
-            )(q, cs, cs, kc, kc, tb, ln)
-        return True
-    except Exception:  # noqa: BLE001 - any lowering failure means "don't"
-        return False
-
-
 def paged_flash_decode_fused(
     q: jax.Array,  # [B, HQ, D] PRE-rope queries
     cos: jax.Array,  # [B, 1, D] offset-gathered rope rows
@@ -641,7 +536,7 @@ def paged_flash_decode_fused(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -729,42 +624,6 @@ def _chunk_fused_kernel(
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
-@functools.lru_cache(maxsize=64)
-def chunk_fused_lowering_supported(b: int, c: int, hq: int, hkv: int, d: int,
-                                   nb: int, bs: int, mbs: int, dtype: str,
-                                   kv_dtype: str = "") -> bool:
-    """Static Mosaic-lowering probe for the rope-fused mixed kernel, cached
-    per geometry (same rule as :func:`chunk_lowering_supported`)."""
-    import numpy as np
-
-    q = jax.ShapeDtypeStruct((b, c, hq, d), np.dtype(dtype))
-    cs = jax.ShapeDtypeStruct((b, c, d), np.dtype(dtype))
-    kc = jax.ShapeDtypeStruct((nb, hkv, bs, d), np.dtype(kv_dtype or dtype))
-    tb = jax.ShapeDtypeStruct((b, mbs), np.int32)
-    ln = jax.ShapeDtypeStruct((b,), np.int32)
-    try:
-        if kv_dtype:
-            sc = jax.ShapeDtypeStruct((nb, hkv, bs), np.float32)
-            jax.export.export(
-                jax.jit(lambda q, c, s, kc, vc, ks, vs, t, l, ql:
-                        paged_flash_chunk_fused(
-                            q, c, s, kc, vc, t, l, ql, k_scale=ks, v_scale=vs)),
-                platforms=["tpu"],
-            )(q, cs, cs, kc, kc, sc, sc, tb, ln, ln)
-        else:
-            jax.export.export(
-                jax.jit(
-                    lambda q, c, s, kc, vc, t, l, ql: paged_flash_chunk_fused(
-                        q, c, s, kc, vc, t, l, ql
-                    )
-                ),
-                platforms=["tpu"],
-            )(q, cs, cs, kc, kc, tb, ln, ln)
-        return True
-    except Exception:  # noqa: BLE001 - any lowering failure means "don't"
-        return False
-
-
 def paged_flash_chunk_fused(
     q: jax.Array,  # [B, C, HQ, D] PRE-rope ragged chunk
     cos: jax.Array,  # [B, C, D] offset-gathered rope rows per chunk token
@@ -844,7 +703,7 @@ def paged_flash_chunk_fused(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -14,9 +14,9 @@ composition (``(x_f32 @ w8_f32) * scale``) — the canonical semantics both
 paths implement; CPU CI always takes it (inference-only: no tape, no
 GradNode — the engine's decode step never differentiates through it).
 
-Dispatch follows the repo's kernel discipline (PG905): host-side lowering
-probe at trace time, ``warn_fallback``-counted degradation, autotune entry
-for the block geometry.
+Dispatch follows the repo's kernel discipline (PG905):
+``warn_fallback``-counted degradation, autotune entry for the block geometry.
+The kernel is required to compile on TPU (tests/test_tpu_aot_compile.py).
 """
 
 from __future__ import annotations
@@ -25,18 +25,15 @@ import functools
 from typing import Optional, Tuple
 
 import jax
-import jax.export  # noqa: F401  (jax 0.4.x: not re-exported by `import jax`)
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.kernels.select import _CompilerParams
 
 __all__ = [
     "quantize_weight_int8",
     "quantize_module_weights",
     "int8_weight_matmul",
-    "wo_lowering_supported",
 ]
 
 # Model leaf names whose nn.Linear weights the engine quantizes under
@@ -154,37 +151,19 @@ def _wo_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(x, w8, scale.reshape(1, n))
 
 
-@functools.lru_cache(maxsize=64)
-def wo_lowering_supported(m: int, k: int, n: int, block: Tuple[int, int, int],
-                          dtype: str) -> bool:
-    """Static Mosaic-lowering probe for the weight-only matmul, cached per
-    geometry — the same TRACE-time routing rule every paged kernel uses (a
-    lowering error inside the engine's jitted step is uncatchable)."""
-    import numpy as np
-
-    xs = jax.ShapeDtypeStruct((m, k), np.dtype(dtype))
-    ws = jax.ShapeDtypeStruct((k, n), np.int8)
-    ss = jax.ShapeDtypeStruct((n,), np.float32)
-    try:
-        jax.export.export(
-            jax.jit(lambda x, w, s: _wo_matmul_pallas(x, w, s, block)),
-            platforms=["tpu"],
-        )(xs, ws, ss)
-        return True
-    except Exception:  # noqa: BLE001 - any lowering failure means "don't"
-        return False
-
-
 def _default_block(m: int, k: int, n: int) -> Tuple[int, int, int]:
-    # MXU-friendly 128-multiples, shrunk to the actual geometry
-    return (min(256, m), min(256, n), min(512, k))
+    # MXU-friendly 128-multiples, shrunk to the actual geometry; the K tile
+    # is the largest that divides K (Llama-2-7B's down projection has
+    # K = 11008 = 2**8 * 43, which 512 does not divide)
+    bk = next((t for t in (512, 256, 128) if k % t == 0), min(512, k))
+    return (min(256, m), min(256, n), bk)
 
 
 def _autotune_block(m: int, k: int, n: int, dtype: str) -> Tuple[int, int, int]:
@@ -202,8 +181,6 @@ def _autotune_block(m: int, k: int, n: int, dtype: str) -> Tuple[int, int, int]:
     ]
 
     def build(cfg):
-        if not wo_lowering_supported(m, k, n, cfg, dtype):
-            return None
         xz = jnp.zeros((m, k), jnp.dtype(dtype))
         wz = jnp.zeros((k, n), jnp.int8)
         sz = jnp.ones((n,), jnp.float32)
@@ -227,10 +204,9 @@ def int8_weight_matmul(
     block: Optional[Tuple[int, int, int]] = None,
 ) -> jax.Array:
     """``(x @ dequant(w8)) = (x @ w8) * scale`` without materializing the
-    dequantized weight. Pallas on TPU when the geometry lowers (probed at
-    trace time), XLA composition elsewhere — ``warn_fallback``-counted on
-    kernel failure per the PG905 dispatch discipline."""
-    from paddle_tpu.distributed.tp import current_tp_mesh
+    dequantized weight. Pallas on TPU, XLA composition elsewhere —
+    ``warn_fallback``-counted on a trace-time kernel failure per the PG905
+    dispatch discipline."""
     from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
 
     lead = x.shape[:-1]
@@ -241,29 +217,16 @@ def int8_weight_matmul(
         m *= int(s)
     x2 = x.reshape(m, k)
 
-    # under an armed tp shard group this matmul is GSPMD-partitioned by the
-    # surrounding trace; a bare pallas_call cannot be (it would need its own
-    # shard_map) — route to the XLA composition, which GSPMD splits fine
-    if (
-        pallas_enabled("weight_only_int8") and not interpret
-        and current_tp_mesh() is None
-    ):
+    # under an armed tp shard group pallas_enabled is False: the XLA
+    # composition below is what GSPMD can split
+    if pallas_enabled("weight_only_int8") and not interpret:
         blk = block or _autotune_block(m, k, n, str(x.dtype))
-        blk = (min(blk[0], m), min(blk[1], n), min(blk[2], k))
-        if (
-            m % blk[0] == 0 and n % blk[1] == 0 and k % blk[2] == 0
-            and wo_lowering_supported(m, k, n, blk, str(x.dtype))
-        ):
-            try:
-                out = _wo_matmul_pallas(x2, w8, scale, blk)
-                return out.reshape(*lead, n)
-            except Exception as exc:  # noqa: BLE001 - XLA fallback below
-                warn_fallback("int8_weight_matmul", exc)
-        else:
-            warn_fallback(
-                "int8_weight_matmul",
-                RuntimeError("Mosaic lowering unsupported for geometry"),
-            )
+        try:
+            # a geometry the block does not divide raises here, at trace time
+            out = _wo_matmul_pallas(x2, w8, scale, blk)
+            return out.reshape(*lead, n)
+        except Exception as exc:  # noqa: BLE001 - XLA fallback below
+            warn_fallback("int8_weight_matmul", exc)
     elif interpret:
         out = _wo_matmul_pallas(
             x2, w8, scale, block or _default_block(m, k, n), interpret=True

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.distributed.fleet.meta_parallel.spmd_pipeline import shard_map
 
 NEG_INF = -1e30
 
@@ -113,7 +112,7 @@ def ring_flash_attention(
         out = (acc / l).reshape(b, h, s_local, d).astype(q.dtype)
         return jnp.moveaxis(out, 1, 2)  # [B, S/N, H, D]
 
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=jmesh,
         in_specs=(spec, spec, spec),

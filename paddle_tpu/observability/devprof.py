@@ -14,11 +14,9 @@ collectives actually cost. This module closes that gap in three pieces:
   lowering* (``fn.lower(...).compile()``): it re-runs the Python trace and
   pays one extra XLA compile, which is why capture arms only while
   ``FLAGS_devprof_sample_rate > 0`` — compile seams are seconds-scale
-  already, but doubling them must be opt-in. jax 0.4.x returns a dict, a
-  list of per-computation dicts, or raises depending on backend; the shim
-  normalizes all three (missing backends record ``cost_model:
-  "unavailable"`` with zeroed numbers rather than raising, so the CPU tier
-  exercises the full path). A **cost-regression ledger** compares each new
+  already, but doubling them must be opt-in. A backend without an XLA cost
+  model records ``cost_model: "unavailable"`` with zeroed numbers rather
+  than raising, so the CPU tier exercises the full path. A **cost-regression ledger** compares each new
   signature's flops/bytes against the function's previous program and flags
   drift past a tolerance — a re-trace that silently changed the program's
   cost is exactly the regression a recompile count alone cannot see.
@@ -127,30 +125,24 @@ _regression_counter = _metrics.GLOBAL_METRICS.counter(
 )
 
 
-# -- cost_analysis shims ------------------------------------------------------
+# -- cost_analysis -------------------------------------------------------------
 
 _COST_KEYS = {"flops": "flops", "bytes accessed": "bytes_accessed",
               "transcendentals": "transcendentals"}
 
 
 def normalize_cost_analysis(raw: Any) -> Dict[str, Any]:
-    """Normalize ``compiled.cost_analysis()`` output across jax versions:
-    a dict, a list of per-computation dicts (summed), or None/garbage —
-    the last records ``cost_model: "unavailable"`` with zeroed numbers
-    instead of raising, so backends without an XLA cost model (CPU in some
-    builds) still exercise the full capture path."""
-    dicts: List[Dict[str, Any]] = []
-    if isinstance(raw, dict):
-        dicts = [raw]
-    elif isinstance(raw, (list, tuple)):
-        dicts = [d for d in raw if isinstance(d, dict)]
+    """``compiled.cost_analysis()``'s dict under this module's key names;
+    None/garbage records ``cost_model: "unavailable"`` with zeroed numbers
+    instead of raising, so backends without an XLA cost model still
+    exercise the full capture path."""
     out: Dict[str, Any] = {k: 0.0 for k in _COST_KEYS.values()}
     seen_any = False
-    for d in dicts:
+    if isinstance(raw, dict):
         for src, dst in _COST_KEYS.items():
-            v = d.get(src)
+            v = raw.get(src)
             if isinstance(v, (int, float)):
-                out[dst] += float(v)
+                out[dst] = float(v)
                 seen_any = True
     out["cost_model"] = "xla" if seen_any else "unavailable"
     return out

@@ -317,6 +317,9 @@ def start_serving_server(
             port = int(GLOBAL_FLAGS.get("serving_port"))
             if port <= 0:
                 return None
+        from paddle_tpu.core.compile_cache import enable_compile_cache
+
+        enable_compile_cache()  # before the pump's first (step) compile
         handler = type(
             "_BoundServingHandler",
             (_ServingHandler,),

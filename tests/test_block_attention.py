@@ -210,7 +210,7 @@ class TestSlotMask:
         out_xla, _, _ = block_multihead_attention(
             q, k1, v1, kc, vc, tables, lens, slot_mask=mask
         )
-        monkeypatch.setattr(sel, "pallas_enabled", lambda flag: True)
+        monkeypatch.setattr(sel, "pallas_enabled", lambda flag, **_: True)
         real = pa.paged_flash_decode
         monkeypatch.setattr(
             pa, "paged_flash_decode",
@@ -277,7 +277,7 @@ class TestFusedDecodeWrapper:
         out_xla, _, _ = block_multihead_attention_fused(
             q, k1, v1, cos, sin, kc, vc, tables, lens, slot_mask=mask
         )
-        monkeypatch.setattr(sel, "pallas_enabled", lambda flag: True)
+        monkeypatch.setattr(sel, "pallas_enabled", lambda flag, **_: True)
         real = pa.paged_flash_decode_fused
         monkeypatch.setattr(
             pa, "paged_flash_decode_fused",

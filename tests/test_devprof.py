@@ -73,7 +73,7 @@ def devprof_on():
     devprof.drain_chrome_events()
 
 
-# -- cost_analysis shims ------------------------------------------------------
+# -- cost_analysis -------------------------------------------------------------
 
 class TestNormalizeCostAnalysis:
     def test_dict_form(self):
@@ -84,14 +84,6 @@ class TestNormalizeCostAnalysis:
             "flops": 100.0, "bytes_accessed": 40.0, "transcendentals": 2.0,
             "cost_model": "xla",
         }
-
-    def test_list_of_dicts_sums(self):
-        p = devprof.normalize_cost_analysis(
-            [{"flops": 60.0, "bytes accessed": 10.0}, {"flops": 40.0}]
-        )
-        assert p["flops"] == 100.0
-        assert p["bytes_accessed"] == 10.0
-        assert p["cost_model"] == "xla"
 
     @pytest.mark.parametrize("raw", [None, "nope", [], [1, 2], {"foo": "bar"}])
     def test_missing_or_garbage_records_unavailable_with_zeros(self, raw):

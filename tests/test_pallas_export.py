@@ -1,9 +1,11 @@
 """TPU-lowering regression tests that need NO hardware.
 
-``jax.export.export(jax.jit(fn), platforms=['tpu'])`` runs the full Mosaic
-kernel lowering on the CPU backend and raises the exact error a real chip
-would (BENCH_r02 died on an illegal ``(1, 1, blk_q)`` LSE BlockSpec that this
-file would have caught statically). Every gated Pallas kernel must export —
+``jax.export.export(jax.jit(fn), platforms=['tpu'])`` runs the Mosaic kernel
+LOWERING on the CPU backend and raises what Pallas' lowering rules raise (an
+early chip run died on an illegal ``(1, 1, blk_q)`` LSE BlockSpec that this
+file would have caught statically). It does not run the chip's compiler:
+VMEM limits and some layout checks fire only at compile — those are
+tests/test_tpu_aot_compile.py's. Every gated Pallas kernel must export —
 forward AND backward — for every configuration the framework routes to it.
 
 Grads are taken wrt every differentiable input: the backward pass runs as

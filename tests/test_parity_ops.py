@@ -56,7 +56,7 @@ class TestComplexViews:
     def test_complex_promotes_float64_to_complex128(self):
         import jax
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             r = t(np.array([1.0, -2.0], np.float64))
             i = t(np.array([0.5, 3.0], np.float64))
             c = paddle.complex(r, i)

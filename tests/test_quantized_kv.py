@@ -465,7 +465,7 @@ class TestQuantDequantFaultSite:
         call = self._setup(seed=61)
         out_xla = np.asarray(call()[0])  # CPU backend: the gather fallback
 
-        monkeypatch.setattr(sel, "pallas_enabled", lambda flag: True)
+        monkeypatch.setattr(sel, "pallas_enabled", lambda flag, **_: True)
         real = pa.paged_flash_chunk
         monkeypatch.setattr(
             pa, "paged_flash_chunk",

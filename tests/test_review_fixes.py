@@ -104,7 +104,6 @@ def test_broadcast_subgroup_uses_local_rank():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec
-    from jax.experimental.shard_map import shard_map
 
     import paddle_tpu.distributed as dist
 
@@ -117,7 +116,7 @@ def test_broadcast_subgroup_uses_local_rank():
 
     x = jnp.arange(4, dtype=jnp.float32).reshape(4, 1)
     out = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=PartitionSpec("g"), out_specs=PartitionSpec("g"))
+        jax.shard_map(body, mesh=mesh, in_specs=PartitionSpec("g"), out_specs=PartitionSpec("g"))
     )(x)
     # member at local index 2 (global rank 6) holds value 2.0
     np.testing.assert_allclose(np.asarray(out).reshape(-1), [2, 2, 2, 2])
@@ -127,7 +126,6 @@ def test_ppermute_shift():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec
-    from jax.experimental.shard_map import shard_map
 
     import paddle_tpu.distributed as dist
 
@@ -141,6 +139,6 @@ def test_ppermute_shift():
 
     x = jnp.arange(4, dtype=jnp.float32).reshape(4, 1)
     out = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=PartitionSpec("pp"), out_specs=PartitionSpec("pp"))
+        jax.shard_map(body, mesh=mesh, in_specs=PartitionSpec("pp"), out_specs=PartitionSpec("pp"))
     )(x)
     np.testing.assert_allclose(np.asarray(out).reshape(-1), [3, 0, 1, 2])

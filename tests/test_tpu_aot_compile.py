@@ -1,0 +1,214 @@
+"""Ahead-of-time compiles for a described TPU v5e, at Llama-2-7B widths.
+
+``jax.export`` (tests/test_pallas_export.py) lowers a kernel to a serialized
+Mosaic module but never runs the chip's compiler; VMEM limits and several
+layout checks fire only at compile. The TPU compiler is installed here and
+compiles for a chip that is described, not attached, so each case below
+raises exactly what the chip would raise — no hardware, ~2 s per kernel.
+
+Every kernel on the two main paths (jitted train step, serving engine step)
+is compiled directly at the shapes ``chip_smoke.py`` uses: the dispatch
+helpers ask ``jax.default_backend()`` and would take their CPU branch here.
+
+The topology is described inside a fixture (never at import, in a
+``skipif`` or in ``parametrize`` arguments): only one process may load the
+TPU library, and under xdist every worker imports every test file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# Llama-2-7B widths (LlamaConfig.llama2_7b)
+HIDDEN, HEADS, HEAD_DIM, MLP, VOCAB = 4096, 32, 128, 11008, 32000
+SEQ = 2048  # train sequence length
+SLOTS, CHUNK = 8, 16  # engine step [max_slots, prefill_chunk]
+NB, BS, MBS = 2048, 16, 128  # KV pool blocks, block size, blocks per sequence
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prior)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    # conftest pins "highest" for the CPU numerics tests; the chip runs the
+    # default, and Mosaic refuses an fp32-precision matmul on bf16 operands
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel is really in there
+    return compiled
+
+
+def _grad_all(fn, n_float):
+    """fwd+bwd wrt the first ``n_float`` args: an unused cotangent would let
+    DCE prune a backward kernel out before the compiler ever checked it."""
+    return jax.grad(
+        lambda *a: fn(*a).astype(jnp.float32).sum(), argnums=tuple(range(n_float))
+    )
+
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+_QKV = ((1, SEQ, HEADS, HEAD_DIM), BF16)
+_KV_POOL = (NB, HEADS, BS, HEAD_DIM)
+_PAGED_TAIL = (((SLOTS, MBS), I32), ((SLOTS,), I32), ((SLOTS,), I32))
+
+
+def _flash_attention():
+    from paddle_tpu.kernels.flash_attention import flash_attention_pallas
+
+    fn = _grad_all(lambda q, k, v: flash_attention_pallas(q, k, v, causal=True), 3)
+    return fn, (_QKV, _QKV, _QKV)
+
+
+def _paged_chunk(kv_dtype):
+    from paddle_tpu.kernels.paged_attention import paged_flash_chunk
+
+    q = ((SLOTS, CHUNK, HEADS, HEAD_DIM), BF16)
+    pool = (_KV_POOL, kv_dtype)
+    if kv_dtype == I8:
+        sc = ((NB, HEADS, BS), F32)
+        return (
+            lambda q, kc, vc, ks, vs, t, l, ql: paged_flash_chunk(
+                q, kc, vc, t, l, ql, k_scale=ks, v_scale=vs
+            ),
+            (q, pool, pool, sc, sc, *_PAGED_TAIL),
+        )
+    return paged_flash_chunk, (q, pool, pool, *_PAGED_TAIL)
+
+
+def _paged_chunk_fused(kv_dtype):
+    from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused
+
+    q = ((SLOTS, CHUNK, HEADS, HEAD_DIM), BF16)
+    cs = ((SLOTS, CHUNK, HEAD_DIM), BF16)
+    pool = (_KV_POOL, kv_dtype)
+    if kv_dtype == I8:
+        sc = ((NB, HEADS, BS), F32)
+        return (
+            lambda q, c, s, kc, vc, ks, vs, t, l, ql: paged_flash_chunk_fused(
+                q, c, s, kc, vc, t, l, ql, k_scale=ks, v_scale=vs
+            ),
+            (q, cs, cs, pool, pool, sc, sc, *_PAGED_TAIL),
+        )
+    return paged_flash_chunk_fused, (q, cs, cs, pool, pool, *_PAGED_TAIL)
+
+
+def _rms_norm():
+    from paddle_tpu.kernels.fused import fused_rms_norm_pallas
+
+    fn = _grad_all(lambda x, w: fused_rms_norm_pallas(x, w, 1e-6), 2)
+    return fn, (((1, SEQ, HIDDEN), BF16), ((HIDDEN,), BF16))
+
+
+def _rope():
+    from paddle_tpu.kernels.fused import fused_rope_pallas, rope_adjoint_pallas
+
+    def fn(x, g, cos, sin):
+        return fused_rope_pallas(x, cos, sin), rope_adjoint_pallas(g, cos, sin)
+
+    tab = ((SEQ, HEAD_DIM), F32)
+    return fn, (_QKV, _QKV, tab, tab)
+
+
+def _rms_norm_residual(shape):
+    from paddle_tpu.kernels.fused import (
+        fused_rms_norm_residual_pallas,
+        rms_norm_residual_adjoint_pallas,
+    )
+
+    def fn(x, res, w, g):
+        y, r = fused_rms_norm_residual_pallas(x, res, w, 1e-6)
+        return y, r, rms_norm_residual_adjoint_pallas(g, r, w, 1e-6)
+
+    x = (shape, BF16)
+    return fn, (x, x, ((HIDDEN,), BF16), x)
+
+
+def _embed_rms_norm():
+    from paddle_tpu.kernels.fused import fused_embed_rms_norm_pallas
+
+    return (
+        lambda ids, table, w: fused_embed_rms_norm_pallas(ids, table, w, 1e-6),
+        (((SLOTS, CHUNK), I32), ((VOCAB, HIDDEN), BF16), ((HIDDEN,), BF16)),
+    )
+
+
+def _fused_loss(vocab_major):
+    from paddle_tpu.kernels.fused_loss import _default_block, _pallas_path
+
+    block = _default_block(HIDDEN, 2)
+
+    def fn(x, w, lab):
+        return jax.grad(
+            lambda x, w: _pallas_path(
+                x, w, lab, v=VOCAB, h=HIDDEN, ignore_index=-100, reduction="mean",
+                vocab_major=vocab_major, interpret=False, block=block,
+            ),
+            argnums=(0, 1),
+        )(x, w)
+
+    w = (VOCAB, HIDDEN) if vocab_major else (HIDDEN, VOCAB)
+    return fn, (((SEQ, HIDDEN), BF16), (w, BF16), ((SEQ,), I32))
+
+
+def _wo_matmul():
+    from paddle_tpu.kernels.quant import _default_block, _wo_matmul_pallas
+
+    m, k, n = 128, MLP, HIDDEN  # the down projection: K = 11008 = 2**8 * 43
+    block = _default_block(m, k, n)
+    return (
+        lambda x, w8, s: _wo_matmul_pallas(x, w8, s, block),
+        (((m, k), BF16), ((k, n), I8), ((n,), F32)),
+    )
+
+
+CASES = {
+    "flash_attention_fwd_bwd_s2048": _flash_attention,
+    "paged_flash_chunk_bf16": lambda: _paged_chunk(BF16),
+    "paged_flash_chunk_int8": lambda: _paged_chunk(I8),
+    "paged_flash_chunk_fused_bf16": lambda: _paged_chunk_fused(BF16),
+    "paged_flash_chunk_fused_int8": lambda: _paged_chunk_fused(I8),
+    "fused_rms_norm_fwd_bwd": _rms_norm,
+    "fused_rope_and_adjoint": _rope,
+    "fused_rms_norm_residual_train": lambda: _rms_norm_residual((1, SEQ, HIDDEN)),
+    "fused_rms_norm_residual_step": lambda: _rms_norm_residual((SLOTS, CHUNK, HIDDEN)),
+    "fused_embed_rms_norm": _embed_rms_norm,
+    "fused_loss_fwd_bwd_hidden_major": lambda: _fused_loss(False),
+    "fused_loss_fwd_bwd_vocab_major": lambda: _fused_loss(True),
+    "wo_int8_matmul_k11008": _wo_matmul,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e_at_7b_width(case, one_chip):
+    fn, shapes = CASES[case]()
+    _compile(fn, one_chip, *shapes)
+
+
+def test_fused_loss_default_block_keeps_bench_width_on_512():
+    """The repair must not shrink the block at the width it already fit."""
+    from paddle_tpu.kernels.fused_loss import _default_block
+
+    assert _default_block(1536, 2) == (512, 512)
