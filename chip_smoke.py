@@ -80,10 +80,36 @@ def require_tpu(chips: int) -> Dict[str, Any]:
     return {"platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices())}
 
 
-def peak_bytes() -> List[int]:
+def lifetime_peak() -> List[int]:
     import jax
 
     return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in jax.devices()]
+
+
+def memory(peak_before: List[int]) -> Dict[str, List[Any]]:
+    """Per device: bytes in use now, and the allocator's high-water mark where
+    this phase raised it. The mark is the process's, not the phase's: where
+    an earlier phase peaked higher, this phase's own peak is not measured
+    (null)."""
+    import jax
+
+    in_use = [int((d.memory_stats() or {}).get("bytes_in_use", 0)) for d in jax.devices()]
+    peak = [now if now > was else None for was, now in zip(peak_before, lifetime_peak())]
+    return {"bytes_in_use": in_use, "phase_peak_bytes": peak}
+
+
+def routed_now() -> Dict[str, float]:
+    from paddle_tpu.kernels.select import partition_routed_counts
+
+    return partition_routed_counts()
+
+
+def routed_since(before: Dict[str, float]) -> Dict[str, float]:
+    """Kernels whose dispatch ran the XLA composition since ``before``
+    because the trace is partitioned over devices (kernels/select.py)."""
+    return {
+        k: v - before.get(k, 0.0) for k, v in routed_now().items() if v > before.get(k, 0.0)
+    }
 
 
 def at_depth(cfg: Any, depth: int) -> Any:
@@ -149,6 +175,7 @@ def phase_train(cfg: Any, *, batch: int, seq: int, steps: int, dtype: str = "bfl
     fp32 master weights, a fixed batch from seed 0."""
     import paddle_tpu as paddle
 
+    peak0 = lifetime_peak()
     model = build_model(cfg, dtype)
     opt = paddle.optimizer.AdamW(
         learning_rate=1e-4, parameters=model.parameters(), multi_precision=True
@@ -182,7 +209,7 @@ def phase_train(cfg: Any, *, batch: int, seq: int, steps: int, dtype: str = "bfl
         "train", depth=cfg.num_hidden_layers, params=count_params(model), dtype=dtype,
         batch=batch, seq=seq, tokens_per_step=batch * seq, losses=losses,
         first_call_seconds=compile_s, step_seconds=step_s,
-        grads_checked=n_grads, step_compiles=compiles, peak_bytes=peak_bytes(),
+        grads_checked=n_grads, step_compiles=compiles, **memory(peak0),
     )
     if not all(np.isfinite(losses)):
         raise AssertionError(f"non-finite loss: {losses}")
@@ -233,18 +260,41 @@ def assert_drained(engine: Any) -> Dict[str, Any]:
     return {k: s[k] for k in ("total", "free", "cached_blocks") if k in s}
 
 
+def dense_logits(model: Any, prompts: List[List[int]]) -> np.ndarray:
+    """fp32 logits ``[R, S, V]`` of the dense, cache-free forward: one batch,
+    right-padded to a whole 128-row block (causal attention: padding cannot
+    reach a prompt's own rows)."""
+    import paddle_tpu as paddle
+
+    padded = np.zeros((len(prompts), -(-max(map(len, prompts)) // 128) * 128), np.int32)
+    for row, p in zip(padded, prompts):
+        row[: len(p)] = p
+    return np.asarray(model(paddle.to_tensor(padded)).numpy(), np.float32)
+
+
+def first_token_gaps(rows: np.ndarray, tokens: List[int]) -> List[float]:
+    """How far below the reference's best logit each served first token sits
+    (0.0: it is the reference argmax). ``rows [R, V]``: the reference logits
+    of each request's last prompt position."""
+    return [float(row.max() - row[tok]) for row, tok in zip(np.asarray(rows, np.float32), tokens)]
+
+
 def phase_serve(
     cfg: Any, *, engine_kw: Dict[str, int], prompt_lens: Any, max_new_tokens: int,
     int8_num_blocks: int, dtype: str = "bfloat16", logit_tol: float = LOGIT_TOL,
 ) -> None:
     """The HTTP server over frontend over engine, default flags (paged chunk
-    kernel, fused decode layer, bf16 KV); one request held against the
-    dense-cache ``generate`` path; then an int8-KV engine."""
+    kernel, fused decode layer, bf16 KV), held against the dense paths on the
+    same weights: the engine step's own first-chunk logits and every
+    request's first served token are gated on the cache-free forward, one
+    request's whole stream is compared with ``generate`` (dense cache) and
+    printed; then an int8-KV engine."""
     import paddle_tpu as paddle
     from paddle_tpu.inference import ContinuousBatchingEngine
-    from paddle_tpu.inference.quality import engine_first_step_logits
+    from paddle_tpu.inference.quality import step_logit_error
     from paddle_tpu.serving import ServingFrontend, start_serving_server, stop_serving_server
 
+    peak0 = lifetime_peak()
     model = build_model(cfg, dtype)
     model.eval()
     engine = ContinuousBatchingEngine(model, **engine_kw)
@@ -265,15 +315,21 @@ def phase_serve(
     finally:
         stop_serving_server(frontend)
     compiles = step_compiles("ContinuousBatchingEngine.step") - before
+    mem = memory(peak0)  # with the model and the pool live
+    for p, r in zip(prompts, replies):
+        if r["final"].get("outcome") != "ok" or len(r["tokens"]) != max_new_tokens:
+            raise AssertionError(f"request (prompt {len(p)}) ended {r['final']}")
 
-    # the dense-cache XLA path on the same weights: logits gate, tokens printed
+    dense = dense_logits(model, prompts)
     probe = int(np.argmin([abs(len(p) - block_size) for p in prompts]))  # ~one chunk
     ids = np.asarray(prompts[probe], np.int32)
-    dense = np.asarray(model(paddle.to_tensor(ids[None])).numpy()[0], np.float32)
-    paged = engine_first_step_logits(engine, ids)
-    n = paged.shape[0]
-    logit_err = float(np.max(np.abs(paged - dense[:n])))
-    logit_scale = float(np.max(np.abs(dense[:n])))
+    n = min(len(ids), engine.prefill_chunk)
+    err = step_logit_error(engine, ids, reference=dense[probe, :n])
+    tol = logit_tol * err["max_abs_reference_logit"]
+    gaps = first_token_gaps(
+        np.stack([dense[i, len(p) - 1] for i, p in enumerate(prompts)]),
+        [r["tokens"][0] for r in replies],
+    )
     ref = model.generate(
         paddle.to_tensor(ids[None]), max_new_tokens=max_new_tokens, do_sample=False
     ).numpy()[0, len(ids):].tolist()
@@ -289,20 +345,20 @@ def phase_serve(
         ],
         first_compile_and_all_requests_seconds=wall_s, engine_steps=engine.stats["steps"],
         step_compiles=compiles, recoveries=engine.stats["recoveries"],
-        max_logit_error=logit_err, max_abs_dense_logit=logit_scale,
-        logit_tolerance=logit_tol * logit_scale, vs_dense_generate=token_match(ref, replies[probe]["tokens"]),
-        pool=assert_drained(engine), peak_bytes=peak_bytes(),
+        step_logits_vs_dense=err, logit_tolerance=tol, first_token_logit_gaps=gaps,
+        vs_dense_generate=token_match(ref, replies[probe]["tokens"]),
+        pool=assert_drained(engine), **mem,
     )
-    for p, r in zip(prompts, replies):
-        if r["final"].get("outcome") != "ok" or len(r["tokens"]) != max_new_tokens:
-            raise AssertionError(f"request (prompt {len(p)}) ended {r['final']}")
     if compiles != 1 or engine.stats["step_traces"] != 1:
         raise AssertionError(f"engine step compiled {compiles} times, expected 1")
     if engine.stats["recoveries"]:
         raise AssertionError(f"engine recovered {engine.stats['recoveries']} times")
-    if not logit_err <= logit_tol * logit_scale:
+    if not err["max_logit_error"] <= tol:
+        raise AssertionError(f"engine step logits vs dense: {err}, tolerance {tol}")
+    if not max(gaps) <= tol:
         raise AssertionError(
-            f"first-step logits off by {logit_err} (> {logit_tol} x {logit_scale})"
+            f"a served first token sits {max(gaps)} below the dense forward's best "
+            f"logit (tolerance {tol}): {gaps}"
         )
 
     # int8 KV on a second engine: proves the in-walk dequant kernels run
@@ -338,23 +394,33 @@ def run_engine(engine: Any, prompts: List[List[int]], max_new_tokens: int) -> Li
 
 def phase_tp_engine(
     cfg: Any, *, tp: int, engine_kw: Dict[str, int], prompt_lens: Any,
-    max_new_tokens: int, dtype: str = "bfloat16",
+    max_new_tokens: int, dtype: str = "bfloat16", logit_tol: float = LOGIT_TOL,
 ) -> None:
     """A ``tp``-way engine against a ``tp=1`` engine on the same seeded
-    requests and weights. The tp=1 engine runs first: the tp engine commits
-    the model's parameters onto its mesh in place."""
+    requests and weights: the step's own logits on every prompt that fits one
+    chunk (vs tp=1) and every request's first token (vs the dense forward)
+    are gated; whole streams are compared and printed. The dense forward and
+    the tp=1 engine run first: the tp engine commits the model's parameters
+    onto its mesh in place."""
     import jax
 
     from paddle_tpu.inference import ContinuousBatchingEngine
-    from paddle_tpu.inference.quality import engine_first_step_logits
+    from paddle_tpu.inference.quality import step_logit_error
+    from paddle_tpu.observability import GLOBAL_WATCHDOG
 
+    routed0 = routed_now()
     model = build_model(cfg, dtype)
     model.eval()
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, cfg.vocab_size, (n,)).tolist() for n in prompt_lens]
+    dense = dense_logits(model, prompts)
+    last_rows = np.stack([dense[i, len(p) - 1] for i, p in enumerate(prompts)])
+    tol = logit_tol * float(np.max(np.abs(last_rows)))
+    del dense
     one = ContinuousBatchingEngine(model, tp=1, **engine_kw)
     ref_tokens = run_engine(one, prompts, max_new_tokens)
-    ref_logits = engine_first_step_logits(one, prompts[0])
+    one_chunk = [i for i, p in enumerate(prompts) if len(p) <= one.prefill_chunk]
+    ref_logits = {i: one.step_logits(prompts[i]) for i in one_chunk}
     del one
     gc.collect()  # its pool sits in a reference cycle; device 0 needs the room
 
@@ -363,8 +429,10 @@ def phase_tp_engine(
     t0 = time.perf_counter()
     tokens = run_engine(engine, prompts, max_new_tokens)
     wall_s = time.perf_counter() - t0
-    logits = engine_first_step_logits(engine, prompts[0])
-    from paddle_tpu.observability import GLOBAL_WATCHDOG
+    errs = [step_logit_error(engine, prompts[i], reference=ref_logits[i]) for i in one_chunk]
+    tols = [logit_tol * e["max_abs_reference_logit"] for e in errs]
+    gaps = first_token_gaps(last_rows, [t[0] for t in tokens])
+    gaps_tp1 = first_token_gaps(last_rows, [t[0] for t in ref_tokens])
 
     signatures = GLOBAL_WATCHDOG.report()["ContinuousBatchingEngine.step"]["signatures"]
     stats = engine.tp_stats()
@@ -378,15 +446,18 @@ def phase_tp_engine(
                 live[s.device.id] += s.data.nbytes
     # the allocator's own figure, where the backend reports one (TPU does)
     in_use = {d.id: (d.memory_stats() or {}).get("bytes_in_use") for d in jax.devices()[:tp]}
-    matches = [token_match(r, t) for r, t in zip(ref_tokens, tokens)]
+    routed = routed_since(routed0)
     emit(
         "tp_engine", tp=tp, depth=cfg.num_hidden_layers, pool_blocks=engine.num_blocks,
         first_compile_and_all_requests_seconds=wall_s,
-        vs_tp1=matches, max_logit_error_vs_tp1=float(np.max(np.abs(logits - ref_logits))),
-        max_abs_tp1_logit=float(np.max(np.abs(ref_logits))),
+        vs_tp1=[token_match(r, t) for r, t in zip(ref_tokens, tokens)],
+        step_logits_vs_tp1=errs, logit_tolerances=tols,
+        first_token_logit_gaps_vs_dense={f"tp{tp}": gaps, "tp1": gaps_tp1},
+        first_token_tolerance=tol,
         step_compiles=step_compiles("ContinuousBatchingEngine.step") - before,
         signatures=signatures, tp_stats=stats, cache_shard_devices=shard_devices,
         live_array_bytes=live, bytes_in_use=in_use, pool=assert_drained(engine),
+        ran_xla_under_partitioning=routed,
     )
     if engine.stats["step_traces"] != 1 or not any(s.endswith(f"|tp{tp}") for s in signatures):
         raise AssertionError(f"expected one compile tagged |tp{tp}: {signatures}")
@@ -399,6 +470,15 @@ def phase_tp_engine(
         )
     if not all(len(t) == max_new_tokens for t in tokens):
         raise AssertionError("a tp request did not run to its token budget")
+    if not all(e["max_logit_error"] <= t for e, t in zip(errs, tols)):
+        raise AssertionError(f"tp={tp} vs tp=1 step logits {errs}, tolerances {tols}")
+    if not max(gaps + gaps_tp1) <= tol:
+        raise AssertionError(
+            f"a first token sits more than {tol} below the dense forward's best logit: "
+            f"tp={tp} {gaps}, tp=1 {gaps_tp1}"
+        )
+    if set(routed) - TP_ROUTED:
+        raise AssertionError(f"kernels routed to XLA under the tp mesh: {routed}")
 
 
 def phase_hybrid_train(
@@ -409,6 +489,7 @@ def phase_hybrid_train(
     import paddle_tpu as paddle
     from __graft_entry__ import build_hybrid_train_step, hybrid_mesh, shard_batch
 
+    peak0, routed0 = lifetime_peak(), routed_now()
     rng = np.random.default_rng(0)
     ids = rng.integers(0, cfg.vocab_size, (batch, seq)).astype(np.int32)
     labels = rng.integers(0, cfg.vocab_size, (batch, seq)).astype(np.int32)
@@ -429,8 +510,11 @@ def phase_hybrid_train(
 
     _single, ref_losses, _ = run(None)
     del _single
+    if routed_since(routed0):
+        raise AssertionError(f"single-device step routed kernels: {routed_since(routed0)}")
     mesh = hybrid_mesh(n_devices)
     model, losses, wall_s = run(mesh)
+    routed = routed_since(routed0)
     from jax.sharding import NamedSharding
 
     kept = [isinstance(p._data.sharding, NamedSharding) for p in model.parameters()]
@@ -440,29 +524,44 @@ def phase_hybrid_train(
         depth=cfg.num_hidden_layers, params=count_params(model), batch=batch, seq=seq,
         losses=losses, single_device_losses=ref_losses, seconds_with_compile=wall_s,
         params_keep_named_sharding=all(kept), param_device_counts=spread,
-        peak_bytes=peak_bytes(),
+        ran_xla_under_partitioning=routed, **memory(peak0),
     )
     if not all(np.isfinite(losses)) or not all(kept):
         raise AssertionError(f"hybrid step: losses {losses}, shardings kept {all(kept)}")
     if not np.allclose(losses, ref_losses, rtol=2e-2):
         raise AssertionError(f"sharded losses {losses} vs single-device {ref_losses}")
+    if set(routed) - HYBRID_ROUTED:
+        raise AssertionError(f"kernels routed to XLA under the hybrid mesh: {routed}")
 
 
 # ---------------------------------------------------------------------------
 
 
-# the kernels on the two paths, printed at 0 when they never fell back
-KERNELS = (
-    "flash_attention", "fused_rms_norm", "fused_rope", "fused_rope_bwd",
-    "fused_linear_cross_entropy", "fused_embed_norm", "fused_rms_norm_residual",
-    "paged_flash_chunk_fused", "paged_flash_chunk",
-)
+# the kernels each run dispatches to Pallas, printed at 0 when they never fell
+# back. One chip: the train step, then the engine step (rope and the
+# pre-attention norm ride inside the fused paged kernel). With --chips 4 the
+# same kernels are attempted by the tp=1 engine and the single-device step
+# the multi-chip runs are compared with; that step takes plain cross entropy.
+TRAIN_KERNELS = ("flash_attention", "fused_rms_norm", "fused_rope", "fused_rope_bwd")
+SERVE_KERNELS = ("fused_embed_norm", "fused_rms_norm_residual", "paged_flash_chunk_fused")
+KERNELS = {
+    1: TRAIN_KERNELS + ("fused_linear_cross_entropy",) + SERVE_KERNELS,
+    4: TRAIN_KERNELS + SERVE_KERNELS,
+}
+# what may run its XLA composition because the trace is partitioned over
+# devices (a bare pallas_call cannot be); anything else routed fails the run.
+# Under the engine's tp mesh: only the embedding entry (its table is vocab-
+# parallel; the norm kernels run per shard). Under the GSPMD-partitioned
+# hybrid train step: every kernel it dispatches (the layout is the compiler's;
+# that step takes plain cross entropy, not the fused loss head).
+TP_ROUTED = {"fused_embed_norm"}
+HYBRID_ROUTED = set(TRAIN_KERNELS)
 
 
-def fallback_counts() -> Dict[str, float]:
+def fallback_counts(chips: int = 1) -> Dict[str, float]:
     from paddle_tpu.kernels.select import fallback_counts as counted
 
-    return {**dict.fromkeys(KERNELS, 0.0), **counted()}
+    return {**dict.fromkeys(KERNELS[chips], 0.0), **counted()}
 
 
 def main() -> None:
@@ -506,11 +605,13 @@ def main() -> None:
         dryrun_pipeline(args.chips)
         emit("pipeline_dryrun", devices=args.chips, verdict="ok")
 
-    fallbacks = fallback_counts()
+    fallbacks, routed = fallback_counts(args.chips), routed_since({})
     emit("fallbacks", paddle_tpu_kernel_fallbacks_total=fallbacks,
-         compile_cache=dict(cache_events))
+         paddle_tpu_kernel_partition_routed_total=routed, compile_cache=dict(cache_events))
     if any(fallbacks.values()):
         raise AssertionError(f"kernels fell back to XLA on the chip: {fallbacks}")
+    if args.chips == 1 and routed:
+        raise AssertionError(f"kernels routed to XLA on one chip: {routed}")
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

@@ -33,12 +33,12 @@ plain GSPMD and needs no context.
 
 from __future__ import annotations
 
-import contextlib
-import threading
-from typing import Any, Iterator, List, Optional, Tuple
+from typing import Any, ContextManager, List, Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+from paddle_tpu.core.spmd import partitioned_trace, shard_group_mesh
 
 __all__ = [
     "COLUMN_PARALLEL_LEAVES",
@@ -153,21 +153,14 @@ def shard_model_params(model: Any, mesh: Mesh) -> int:
 
 
 # -- trace-time context ------------------------------------------------------
-# threading.local, not a contextvar: the serving pump drives each engine from
-# its own thread, and the armed mesh must be visible exactly to the trace
-# running on that thread.
-class _TpState(threading.local):
-    mesh: Optional[Mesh] = None
-
-
-_STATE = _TpState()
-
-
+# The armed mesh IS the trace's partition mark (core/spmd.py): the kernel
+# dispatch reads the same thread-local to keep bare Pallas kernels out of the
+# partitioned trace, the paged-attention functional to wrap its own.
 def current_tp_mesh() -> Optional[Mesh]:
     """The mesh armed by the innermost :func:`tp_shard_context` on this
     thread (None = single-chip semantics). Read at TRACE time by the paged-
     attention functional to decide the shard_map wrapping."""
-    return _STATE.mesh
+    return shard_group_mesh()
 
 
 def row_parallel_overlap_matmul(x: Any, weight: Any, tiles: int = 2) -> Any:
@@ -203,16 +196,10 @@ def row_parallel_overlap_matmul(x: Any, weight: Any, tiles: int = 2) -> Any:
     return jnp.concatenate(parts, axis=0).reshape(*lead, weight.shape[-1])
 
 
-@contextlib.contextmanager
-def tp_shard_context(mesh: Optional[Mesh]) -> Iterator[None]:
+def tp_shard_context(mesh: Optional[Mesh]) -> ContextManager[None]:
     """Arm ``mesh`` as the tensor-parallel shard group for traces started
     under this context (re-entrant; restores the previous value)."""
-    prev = _STATE.mesh
-    _STATE.mesh = mesh
-    try:
-        yield
-    finally:
-        _STATE.mesh = prev
+    return partitioned_trace(mesh)
 
 
 def analytic_cost_hints(

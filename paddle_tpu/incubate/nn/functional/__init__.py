@@ -181,7 +181,7 @@ def _rope_fwd_array(x, sin, cos, use_neox):
     from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
 
     tabs = _rope_kernel_tables(x, sin, cos, use_neox)
-    if tabs is not None and pallas_enabled("use_pallas_fused"):
+    if tabs is not None and pallas_enabled("use_pallas_fused", bare="fused_rope"):
         try:
             from paddle_tpu.kernels.fused import fused_rope_pallas
 
@@ -195,7 +195,7 @@ def _rope_bwd_array(g, sin, cos, use_neox):
     from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
 
     tabs = _rope_kernel_tables(g, sin, cos, use_neox)
-    if tabs is not None and pallas_enabled("use_pallas_fused"):
+    if tabs is not None and pallas_enabled("use_pallas_fused", bare="fused_rope_bwd"):
         try:
             from paddle_tpu.kernels.fused import rope_adjoint_pallas
 
@@ -507,17 +507,19 @@ __all__ += ["fused_softmax_mask", "fused_softmax_mask_upper_triangle"]
 
 
 def _rms_res_fwd_array(x, residual, weight, eps):
-    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
+    from paddle_tpu.kernels.select import pallas_enabled, per_shard, warn_fallback
 
     if (
         weight.dtype == x.dtype
         and x.shape[-1] % 128 == 0
-        and pallas_enabled("use_pallas_fused")
+        and pallas_enabled("use_pallas_fused", bare="fused_rms_norm_residual", row_wise=True)
     ):
         try:
             from paddle_tpu.kernels.fused import fused_rms_norm_residual_pallas
 
-            return fused_rms_norm_residual_pallas(x, residual, weight, eps)
+            return per_shard(
+                lambda x_, r_, w_: fused_rms_norm_residual_pallas(x_, r_, w_, eps)
+            )(x, residual, weight)
         except Exception as exc:  # pragma: no cover - TPU-only path
             warn_fallback("fused_rms_norm_residual", exc)
     r = x + residual
@@ -534,7 +536,7 @@ def _rms_res_bwd_array(g, r, weight, eps):
     if (
         weight.dtype == g.dtype
         and g.shape[-1] % 128 == 0
-        and pallas_enabled("use_pallas_fused")
+        and pallas_enabled("use_pallas_fused", bare="fused_rms_norm_residual_bwd")
     ):
         try:
             from paddle_tpu.kernels.fused import rms_norm_residual_adjoint_pallas
@@ -559,7 +561,7 @@ def _ln_res_fwd_array(x, residual, weight, bias, eps):
     if (
         weight.dtype == x.dtype
         and x.shape[-1] % 128 == 0
-        and pallas_enabled("use_pallas_fused")
+        and pallas_enabled("use_pallas_fused", bare="fused_layer_norm_residual")
     ):
         try:
             from paddle_tpu.kernels.fused import fused_layer_norm_residual_pallas
@@ -585,7 +587,7 @@ def _ln_res_bwd_array(g, r, weight, eps):
     if (
         weight.dtype == g.dtype
         and g.shape[-1] % 128 == 0
-        and pallas_enabled("use_pallas_fused")
+        and pallas_enabled("use_pallas_fused", bare="fused_layer_norm_residual_bwd")
     ):
         try:
             from paddle_tpu.kernels.fused import layer_norm_residual_adjoint_pallas
@@ -750,7 +752,9 @@ def fused_embed_rms_norm(
     if (
         w.dtype == table.dtype
         and table.shape[-1] % 128 == 0
-        and pallas_enabled("use_pallas_fused")
+        # under the engine's tp mesh the table is vocab-parallel: the XLA
+        # gather is what GSPMD can split
+        and pallas_enabled("use_pallas_fused", bare="fused_embed_norm")
     ):
         try:
             from paddle_tpu.kernels.fused import fused_embed_rms_norm_pallas

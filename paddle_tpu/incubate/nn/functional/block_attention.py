@@ -19,7 +19,6 @@ the reference where block tables are produced by the serving scheduler.
 
 from __future__ import annotations
 
-import sys
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -27,17 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.core.spmd import shard_group_mesh
 from paddle_tpu.testing.faults import fault_point as _fault_point
-
-
-def _current_tp_mesh() -> Optional[Any]:
-    """The tensor-parallel shard group armed by the serving engine's
-    dispatch (``distributed/tp.py``), read at TRACE time. Checked through
-    ``sys.modules`` so the single-chip path never imports the distributed
-    package: if no engine ever armed a tp mesh, the module is absent and
-    this is one dict lookup."""
-    mod = sys.modules.get("paddle_tpu.distributed.tp")
-    return mod.current_tp_mesh() if mod is not None else None
 
 
 def _tp_sharded_flash_chunk(
@@ -708,7 +698,7 @@ def block_multihead_chunk_attention(
             return out, key_cache, value_cache, key_scale, value_scale
         return out, key_cache, value_cache
 
-    if pallas_enabled("use_pallas_paged_attention", shard_mapped=True):
+    if pallas_enabled("use_pallas_paged_attention"):
         # ragged mixed prefill/decode kernel: one grid walks each sequence's
         # physical blocks once, serving its decode row and its prompt-chunk
         # rows alike. The kernel is REQUIRED to compile on TPU
@@ -717,7 +707,7 @@ def block_multihead_chunk_attention(
         # shard_mapped over the head partition.
         from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
-        tp_mesh = _current_tp_mesh()
+        tp_mesh = shard_group_mesh()
         try:
             if quantized:
                 # injected dequant failure degrades THIS dispatch to the
@@ -803,10 +793,10 @@ def block_multihead_chunk_attention_fused(
             return out, key_cache, value_cache, key_scale, value_scale
         return out, key_cache, value_cache
 
-    if pallas_enabled("use_pallas_paged_attention", shard_mapped=True):
+    if pallas_enabled("use_pallas_paged_attention"):
         from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused
 
-        tp_mesh = _current_tp_mesh()
+        tp_mesh = shard_group_mesh()
         cos3 = cos.reshape(b, c, d)
         sin3 = sin.reshape(b, c, d)
         try:
@@ -886,7 +876,7 @@ def block_multihead_attention(
             return out, key_cache, value_cache, key_scale, value_scale
         return out, key_cache, value_cache
 
-    if pallas_enabled("use_pallas_paged_attention"):
+    if pallas_enabled("use_pallas_paged_attention", bare="paged_flash_decode"):
         # block-table flash-decode kernel: streams only this sequence's
         # physical blocks HBM -> VMEM (no dense [B, MBS*BS, H, D] gather);
         # only a trace-time failure degrades to the XLA path below
@@ -968,7 +958,7 @@ def block_multihead_attention_fused(
             return out, key_cache, value_cache, key_scale, value_scale
         return out, key_cache, value_cache
 
-    if pallas_enabled("use_pallas_paged_attention"):
+    if pallas_enabled("use_pallas_paged_attention", bare="paged_flash_decode_fused"):
         # rope-fused flash-decode kernel; same contract as the unfused
         # decode dispatch above
         from paddle_tpu.kernels.paged_attention import paged_flash_decode_fused

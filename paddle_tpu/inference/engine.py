@@ -99,7 +99,7 @@ exhausted retries mark the engine permanently failed.
 
 from __future__ import annotations
 
-import contextlib
+import functools
 import itertools
 import time
 from collections import deque
@@ -109,6 +109,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.core.spmd import partitioned_trace
 from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.inference.kv_tier import HostKVTier, HostNode
 from paddle_tpu.inference.prefix_cache import ChainNode, PrefixCache, chain_digest
@@ -507,7 +508,6 @@ class ContinuousBatchingEngine:
                 build_tp_mesh,
                 kv_cache_sharding,
                 shard_model_params,
-                tp_shard_context,
                 validate_tp,
             )
 
@@ -539,7 +539,6 @@ class ContinuousBatchingEngine:
                 )
             else:
                 self._scale_sharding = None
-            self._tp_ctx = tp_shard_context
             # serving owns the model: params are committed onto the shard
             # group in place (Megatron column/row splits, vocab-parallel
             # embedding + lm-head)
@@ -548,7 +547,6 @@ class ContinuousBatchingEngine:
             self._tp_mesh = None
             self._cache_sharding = None
             self._scale_sharding = None
-            self._tp_ctx = None
             self._tp_split_params = 0
         # host-side refcounted block pool; the device pool lives below
         self._mgr = BlockKVCache(
@@ -1169,6 +1167,21 @@ class ContinuousBatchingEngine:
         at the last valid row), and the speculative verification surface (a
         drafted slot compares rows ``0..K-1`` against its draft left-to-
         right). Rows past ``q_lens`` are garbage and never read host-side."""
+        self.stats["step_traces"] += 1  # Python side: counts TRACES only
+        logits, new_caches = self._step_forward(
+            param_arrays, caches, toks, tables, lens, q_lens, active,
+            cow_src, cow_dst,
+        )
+        nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+        return nxt, new_caches  # nxt [S, C]: per-row argmax
+
+    def _step_forward(
+        self, param_arrays, caches, toks, tables, lens, q_lens, active,
+        cow_src, cow_dst,
+    ):
+        """The step's body up to the logits ``[S, C, V]`` (and the new
+        caches): what ``_step_impl`` takes its argmax of, and what
+        :meth:`step_logits` hands back for numeric comparison."""
         import paddle_tpu
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.incubate.nn.functional import block_cache_cow_copy
@@ -1177,7 +1190,6 @@ class ContinuousBatchingEngine:
             bind_quant_scales,
         )
 
-        self.stats["step_traces"] += 1  # Python side: counts TRACES only
         n_named = len(self._named)
         weights, wq_scales = param_arrays[:n_named], param_arrays[n_named:]
         with bind_param_arrays(self._named, weights), bind_quant_scales(
@@ -1220,16 +1232,71 @@ class ContinuousBatchingEngine:
                     use_cache=True,
                     cache_position=Tensor(lens),
                 )
-            nxt = jnp.argmax(
-                logits._data.astype(jnp.float32), axis=-1
-            ).astype(jnp.int32)  # [S, C] per-row argmax
             if self._quant_kv:
                 # quantized pasts are 8-tuples; scales come back at 6/7
-                return nxt, [
+                return logits._data, [
                     (c[0]._data, c[1]._data, c[6]._data, c[7]._data)
                     for c in new_pkv
                 ]
-            return nxt, [(c[0]._data, c[1]._data) for c in new_pkv]
+            return logits._data, [(c[0]._data, c[1]._data) for c in new_pkv]
+
+    def step_logits(self, prompt: Any) -> np.ndarray:
+        """fp32 logits ``[n, V]`` of the step's own body (``_step_forward``)
+        on the first chunk of ``prompt`` (``n`` tokens, slot 0) — the step
+        itself only hands back argmaxes. A debug surface for holding the
+        paged path against the dense forward: it runs against an empty
+        scratch pool of one sequence's blocks built inside its own trace, so
+        the live pool, the request state and ``stats["step_traces"]`` are
+        untouched (one extra compile, under the same shard group)."""
+        slots, chunk, mbs = self.max_slots, self.prefill_chunk, self.max_blocks_per_seq
+        ids = np.asarray(prompt, np.int32)[:chunk]
+        n = len(ids)
+        toks = np.zeros((slots, chunk), np.int32)
+        toks[0, :n] = ids
+        tables = np.zeros((slots, mbs), np.int32)
+        tables[0] = np.arange(mbs)
+        q_lens = np.zeros((slots,), np.int32)
+        q_lens[0] = n
+        active = np.zeros((slots,), bool)
+        active[0] = True
+        zeros = np.zeros((slots,), np.int32)
+        no_fork = np.full((slots,), mbs, np.int32)  # dst == pool size: dropped
+
+        with self._shard_ctx():
+            out = self._step_logits_fn(
+                self._param_arrays(), jnp.asarray(toks), jnp.asarray(tables),
+                jnp.asarray(zeros), jnp.asarray(q_lens), jnp.asarray(active),
+                jnp.asarray(zeros), jnp.asarray(no_fork),
+            )
+        return np.asarray(out)[:n]
+
+    @functools.cached_property
+    def _step_logits_fn(self) -> Callable[..., Any]:
+        """:meth:`step_logits`' program, compiled once per engine: slot 0's
+        ``[C, V]`` fp32 logits of ``_step_forward`` over a scratch pool."""
+        mbs = self.max_blocks_per_seq
+
+        def scratch_pool():
+            kv = jnp.zeros((mbs,) + self._cache_shape[1:], self._cache_dtype)
+            if not self._quant_kv:
+                return kv, kv
+            scale = jnp.ones((mbs,) + self._scale_shape[1:], jnp.float32)
+            return kv, kv, scale, scale
+
+        def run(param_arrays, *step_args):
+            caches = [scratch_pool() for _ in range(self._num_layers)]
+            logits, _ = self._step_forward(param_arrays, caches, *step_args)
+            return logits[0].astype(jnp.float32)
+
+        return jax.jit(run)
+
+    def _shard_ctx(self) -> Any:
+        """Marks a trace started under it with the tp shard group (none on
+        one chip): the kernel dispatch reads the mark at TRACE time (the
+        paged-attention functional wraps its Pallas kernel in shard_map over
+        the head shard); executions of an already-compiled program never
+        re-enter Python."""
+        return partitioned_trace(self._tp_mesh)
 
     # -- scheduling ----------------------------------------------------------
     def _blocks_needed(self, req: InferenceRequest) -> int:
@@ -1665,12 +1732,7 @@ class ContinuousBatchingEngine:
         def thunk():
             traces_before = self.stats["step_traces"]
             try:
-                tp_ctx = (
-                    self._tp_ctx(self._tp_mesh)
-                    if self._tp_mesh is not None
-                    else contextlib.nullcontext()
-                )
-                with tp_ctx:
+                with self._shard_ctx():
                     lowered = self._step_fn.lower(
                         self._param_arrays(), self._caches, jnp.asarray(toks),
                         jnp.asarray(tables), jnp.asarray(self._ntok.copy()),
@@ -1714,19 +1776,10 @@ class ContinuousBatchingEngine:
             tables = self._dense_tables()
             fault_point("engine.decode")
             traces_before = self.stats["step_traces"]
-            # arm the tp shard group for the (first-call / recovery) trace:
-            # the paged-attention functional reads it at TRACE time to wrap
-            # the Pallas kernel in shard_map over the head shard; executions
-            # of the already-compiled program never re-enter Python
-            tp_ctx = (
-                self._tp_ctx(self._tp_mesh)
-                if self._tp_mesh is not None
-                else contextlib.nullcontext()
-            )
             marks = self._devprof_marks  # non-None only on a sampled step
             if marks is not None:
                 marks["call_s"] = time.perf_counter()
-            with tp_ctx:
+            with self._shard_ctx():  # for the (first-call / recovery) trace
                 nxt, self._caches = self._step_fn(
                     self._param_arrays(), self._caches, jnp.asarray(toks),
                     jnp.asarray(tables), jnp.asarray(self._ntok.copy()),

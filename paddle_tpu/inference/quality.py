@@ -25,10 +25,10 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 __all__ = [
-    "engine_first_step_logits",
     "greedy_token_match",
     "max_logit_error",
     "quality_delta",
+    "step_logit_error",
 ]
 
 
@@ -101,67 +101,26 @@ def max_logit_error(
     return worst
 
 
-def engine_first_step_logits(engine: Any, prompt: Any) -> np.ndarray:
-    """fp32 logits ``[len(prompt), V]`` of ``engine``'s step math on its first
-    prompt chunk: the same paged 6-tuple past, ``[max_slots, prefill_chunk]``
-    token block and (under tp) armed shard group ``_step_impl`` traces, on an
-    empty scratch pool of one sequence's blocks — the step itself only hands
-    back argmaxes. Compared against the dense ``model(ids)`` forward this
-    isolates the paged kernels from everything host-side. bf16 KV only;
-    ``prompt`` is cut to one chunk."""
-    import contextlib
+def step_logit_error(
+    engine: Any, prompt: Any, reference: Optional[np.ndarray] = None
+) -> Dict[str, float]:
+    """Worst absolute fp32 difference between ``engine.step_logits(prompt)``
+    — the engine's own step body over the paged KV plane, first chunk of
+    ``prompt`` — and ``reference`` logits of the same rows (another engine's
+    ``step_logits``); by default the dense, cache-free forward of
+    ``engine.model`` on the same tokens. The reference's absmax comes back
+    too: a tolerance is stated relative to it."""
+    import paddle_tpu as paddle
 
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu
-    from paddle_tpu.core.tensor import Tensor
-    from paddle_tpu.nn.layer.layers import bind_param_arrays
-
-    if engine.kv_cache_dtype != "bf16":
-        raise ValueError("engine_first_step_logits compares the bf16 KV plane only")
-    slots, chunk = engine.max_slots, engine.prefill_chunk
-    ids = np.asarray(prompt, np.int32)[:chunk]
-    n = len(ids)
-    mbs = engine.max_blocks_per_seq
-    toks = np.zeros((slots, chunk), np.int32)
-    toks[0, :n] = ids
-    tables = np.zeros((slots, mbs), np.int32)
-    tables[0] = np.arange(mbs)
-    q_lens = np.zeros((slots,), np.int32)
-    q_lens[0] = n
-    active = np.zeros((slots,), bool)
-    active[0] = True
-    shape = (mbs,) + tuple(engine._cache_shape[1:])
-    named, model = engine._named, engine.model
-
-    def step(arrays, toks, tables, lens, q_lens, active):
-        with bind_param_arrays(named, arrays), paddle_tpu.no_grad():
-            pkv = [
-                (
-                    Tensor(jnp.zeros(shape, engine._cache_dtype)),
-                    Tensor(jnp.zeros(shape, engine._cache_dtype)),
-                    Tensor(tables), Tensor(lens), Tensor(active), Tensor(q_lens),
-                )
-                for _ in range(engine._num_layers)
-            ]
-            logits, _ = model(
-                Tensor(toks), past_key_values=pkv, use_cache=True,
-                cache_position=Tensor(lens),
-            )
-        return logits._data[0].astype(jnp.float32)
-
-    tp_ctx = (
-        engine._tp_ctx(engine._tp_mesh)
-        if engine._tp_mesh is not None
-        else contextlib.nullcontext()
-    )
-    with tp_ctx:
-        out = jax.jit(step)(
-            [p._data for _, p in named], jnp.asarray(toks), jnp.asarray(tables),
-            jnp.zeros((slots,), jnp.int32), jnp.asarray(q_lens), jnp.asarray(active),
-        )
-    return np.asarray(out)[:n]
+    got = engine.step_logits(prompt)
+    if reference is None:
+        ids = np.asarray(prompt, np.int32)[: got.shape[0]]
+        reference = engine.model(paddle.to_tensor(ids[None])).numpy()[0]
+    ref = np.asarray(reference, np.float32)
+    return {
+        "max_logit_error": float(np.max(np.abs(got - ref))),
+        "max_abs_reference_logit": float(np.max(np.abs(ref))),
+    }
 
 
 def quality_delta(

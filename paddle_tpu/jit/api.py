@@ -18,6 +18,7 @@ hand-rolled interpreter.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -25,8 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core import autograd as _ag
+from paddle_tpu.core.spmd import LAYOUT_UNKNOWN, partitioned_trace, spans_devices
 from paddle_tpu.core.tensor import Tensor
-from paddle_tpu.kernels.select import gspmd_trace
 from paddle_tpu.observability.recompile import (
     CAUSE_FIRST_CALL,
     CAUSE_MODE_FLIP,
@@ -209,7 +210,14 @@ class StaticFunction:
             if isinstance(obj, Layer):
                 training.append(obj.training)
                 training.extend(l.training for l in obj.sublayers())
-        return (treedef, tuple(sig), tuple(id(t) for t in state.tensors), tuple(training))
+        # last: whether state or inputs span devices. The traced body asks
+        # whether GSPMD will split it (core/spmd.py), which neither the
+        # shapes above nor jit's own trace key (avals) carry — a model
+        # re-placed in place (same Tensor ids) must retrace
+        partitioned = any(spans_devices(t._data) for t in state.tensors) or any(
+            spans_devices(l._data if isinstance(l, Tensor) else l) for l in flat_in
+        )
+        return (treedef, tuple(sig), tuple(id(t) for t in state.tensors), tuple(training), partitioned)
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
         leaves, treedef = jax.tree_util.tree_flatten((args, kwargs), is_leaf=_is_tensor)
@@ -218,6 +226,7 @@ class StaticFunction:
             scan_objs.append(self._bound_self)
         state = _discover_state(scan_objs)
         key = self._cache_key(leaves, treedef, state, scan_objs)
+        partitioned = key[-1]
 
         if key in self._eager_keys:  # guard cache: known graph break
             return self._fn(*args, **kwargs)
@@ -255,7 +264,10 @@ class StaticFunction:
                         else:
                             rebuilt[pos] = arr
                     a, k = jax.tree_util.tree_unflatten(treedef, rebuilt)
-                    out = fn(*a, **k)
+                    # runs whenever jax traces: this cache's misses and jit's
+                    # own re-traces (e.g. once the optimizer state exists)
+                    with partitioned_trace(LAYOUT_UNKNOWN) if partitioned else contextlib.nullcontext():
+                        out = fn(*a, **k)
                     out_arrays = jax.tree_util.tree_map(
                         lambda o: o._data if isinstance(o, Tensor) else o,
                         out,
@@ -275,19 +287,10 @@ class StaticFunction:
 
             self._cache[key] = jax.jit(staged, donate_argnums=(0, 1))
 
-        # the Python body runs inside this call whenever jax traces (this
-        # cache's misses, and jit's own re-traces, e.g. once the optimizer
-        # state exists): tell the kernel dispatch whether GSPMD will
-        # partition the trace
-        spans_devices = any(
-            len(getattr(getattr(a, "sharding", None), "device_set", ())) > 1
-            for a in state_arrays + in_arrays
-        )
         try:
-            with gspmd_trace(spans_devices):
-                out_arrays, new_state, new_opt, new_rng = self._cache[key](
-                    state_arrays, opt_states, rng_key, in_arrays
-                )
+            out_arrays, new_state, new_opt, new_rng = self._cache[key](
+                state_arrays, opt_states, rng_key, in_arrays
+            )
         except _TRACE_BREAK_ERRORS as exc:
             self._cache.pop(key, None)
             if self._full_graph:

@@ -623,7 +623,9 @@ def fused_linear_cross_entropy(
 
     if weight_scale is not None:
         loss = None
-        if bool(interpret) or (pallas_enabled("use_fused_loss") and h % 128 == 0):
+        if bool(interpret) or (
+            h % 128 == 0 and pallas_enabled("use_fused_loss", bare="fused_linear_xent_quant")
+        ):
             blk = tuple(block) if block is not None else _default_block(h, 1)
             try:
                 loss = _pallas_quant_path(
@@ -646,7 +648,9 @@ def fused_linear_cross_entropy(
 
     loss = None
     # pre-trace applicability: lane-aligned hidden (see kernels/select.py)
-    if bool(interpret) or (pallas_enabled("use_fused_loss") and h % 128 == 0):
+    if bool(interpret) or (
+        h % 128 == 0 and pallas_enabled("use_fused_loss", bare="fused_linear_cross_entropy")
+    ):
         blk = tuple(block) if block is not None else _autotune_fused_loss(
             n, v, h, x.dtype, vocab_major, bool(interpret)
         )

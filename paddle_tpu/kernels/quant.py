@@ -217,9 +217,7 @@ def int8_weight_matmul(
         m *= int(s)
     x2 = x.reshape(m, k)
 
-    # under an armed tp shard group pallas_enabled is False: the XLA
-    # composition below is what GSPMD can split
-    if pallas_enabled("weight_only_int8") and not interpret:
+    if not interpret and pallas_enabled("weight_only_int8", bare="int8_weight_matmul"):
         blk = block or _autotune_block(m, k, n, str(x.dtype))
         try:
             # a geometry the block does not divide raises here, at trace time

@@ -8,14 +8,13 @@ described chip at real widths."""
 
 from __future__ import annotations
 
-import contextlib
 import logging
-import sys
-import threading
-from typing import Any, Dict, Iterator, List
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
+from jax.sharding import PartitionSpec
 
+from paddle_tpu.core.spmd import shard_group_mesh, trace_partition
 from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.observability import get_registry
 
@@ -24,6 +23,13 @@ _warned: set = set()
 _fallbacks_total = get_registry().counter(
     "paddle_tpu_kernel_fallbacks_total",
     "Pallas kernel failures that degraded to the XLA fallback path, by kernel.",
+    labelnames=("kernel",),
+)
+_routed_warned: set = set()
+_routed_total = get_registry().counter(
+    "paddle_tpu_kernel_partition_routed_total",
+    "Dispatches that ran the XLA composition because the trace is partitioned "
+    "over devices and cannot hold a bare pallas_call, by kernel.",
     labelnames=("kernel",),
 )
 
@@ -48,40 +54,44 @@ def _cached_flag(flag: str) -> bool:
     return cell[0]
 
 
-_GSPMD = threading.local()
-
-
-@contextlib.contextmanager
-def gspmd_trace(active: bool) -> Iterator[None]:
-    """Mark the traces started under this context as partitioned by GSPMD
-    (their arguments span more than one device). ``jit.to_static`` arms it
-    around a first call; the serving engine's armed tp mesh counts too."""
-    prev = getattr(_GSPMD, "active", False)
-    _GSPMD.active = active
-    try:
-        yield
-    finally:
-        _GSPMD.active = prev
-
-
-def _gspmd_partitioned() -> bool:
-    if getattr(_GSPMD, "active", False):
-        return True
-    # sys.modules gate: the single-chip path never imports the distributed package
-    tp = sys.modules.get("paddle_tpu.distributed.tp")
-    return tp is not None and tp.current_tp_mesh() is not None
-
-
-def pallas_enabled(flag: str, shard_mapped: bool = False) -> bool:
+def pallas_enabled(flag: str, bare: Optional[str] = None, row_wise: bool = False) -> bool:
     """Flag on, running on a TPU backend, and in a trace the kernel can live
-    in: a ``pallas_call`` has no GSPMD partitioning rule (Mosaic refuses at
-    lowering, inside the captured step, where nothing can catch it), so under
-    a multi-device trace only a site that wraps its kernel in ``shard_map``
-    itself (``shard_mapped=True``) takes the Pallas path; the others run
-    their XLA composition, which GSPMD splits."""
+    in. ``bare`` is the kernel name of a site that emits its ``pallas_call``
+    as is: a trace that will be partitioned over devices (``core/spmd.py``)
+    cannot hold one, so there the site runs its XLA composition, which GSPMD
+    splits — counted per kernel in ``paddle_tpu_kernel_partition_routed_total``
+    and warned once, never silent. ``row_wise`` sites call their kernel
+    through :func:`per_shard`, so they keep it under a shard group of known
+    layout (the serving engine's tp mesh). A site that wraps its kernel in
+    ``shard_map`` itself passes no ``bare``. Ask this LAST in a site's
+    condition: it counts only a routing the site would otherwise have taken."""
     if not (_cached_flag(flag) and jax.default_backend() == "tpu"):
         return False
-    return shard_mapped or not _gspmd_partitioned()
+    partition = trace_partition()
+    if bare is None or partition is None or (row_wise and shard_group_mesh() is not None):
+        return True
+    _routed_total.labels(kernel=bare).inc()
+    if bare not in _routed_warned:
+        _routed_warned.add(bare)
+        _logger.warning(
+            "Pallas kernel %s runs its XLA composition in this trace: it is "
+            "partitioned over devices and cannot hold a bare pallas_call", bare
+        )
+    return False
+
+
+def per_shard(kernel_fn: Callable[..., Any]) -> Callable[..., Any]:
+    """A row-wise kernel (arrays in, arrays out) as the trace can hold it: as
+    is on one device; under a shard group's mesh, ``shard_map``-ped with every
+    operand replicated — the hidden states these kernels work on are, there —
+    so each shard runs the kernel on its own copy."""
+    mesh = shard_group_mesh()
+    if mesh is None:
+        return kernel_fn
+    return jax.shard_map(
+        kernel_fn, mesh=mesh, in_specs=PartitionSpec(), out_specs=PartitionSpec(),
+        check_vma=False,
+    )
 
 
 def warn_fallback(kernel: str, exc: Exception) -> None:
@@ -100,8 +110,17 @@ def warn_fallback(kernel: str, exc: Exception) -> None:
         _logger.warning("Pallas kernel %s failed (%s); using XLA fallback", kernel, exc)
 
 
+def _counts(family: str) -> Dict[str, float]:
+    values = get_registry().snapshot().get(family, {}).get("values", [])
+    return {row["labels"]["kernel"]: row["value"] for row in values}
+
+
 def fallback_counts() -> Dict[str, float]:
     """``{kernel: count}`` of every ``paddle_tpu_kernel_fallbacks_total``
     series that has counted (they count only under ``FLAGS_enable_metrics``)."""
-    family = get_registry().snapshot().get("paddle_tpu_kernel_fallbacks_total", {})
-    return {row["labels"]["kernel"]: row["value"] for row in family.get("values", [])}
+    return _counts("paddle_tpu_kernel_fallbacks_total")
+
+
+def partition_routed_counts() -> Dict[str, float]:
+    """``{kernel: count}`` of ``paddle_tpu_kernel_partition_routed_total``."""
+    return _counts("paddle_tpu_kernel_partition_routed_total")

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import paddle_tpu
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
+from paddle_tpu.core.spmd import shard_group_mesh
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.generation import GenerationMixin
@@ -32,16 +33,6 @@ from paddle_tpu.incubate.nn.functional import fused_rotary_position_embedding
 from paddle_tpu.kernels.fused import count_dispatch
 from paddle_tpu.ops.creation import arange
 from paddle_tpu.ops.manipulation import concat, reshape
-
-
-def _armed_tp_mesh() -> Any:
-    """The serving engine's tensor-parallel mesh, if one is armed on this
-    thread (``sys.modules`` gate so the single-chip path never imports the
-    distributed package — same rule as block_attention's)."""
-    import sys
-
-    mod = sys.modules.get("paddle_tpu.distributed.tp")
-    return mod.current_tp_mesh() if mod is not None else None
 
 
 @dataclass
@@ -336,7 +327,7 @@ class LlamaAttention(nn.Layer):
             out_a, kc2, vc2 = res
         count_dispatch("fused:attend")
         out_t = reshape(_T(out_a), [b, s, self.num_heads * self.head_dim])
-        mesh = _armed_tp_mesh()
+        mesh = shard_group_mesh()
         if mesh is None:
             out = self.o_proj(out_t)
         else:
@@ -505,7 +496,7 @@ class LlamaModel(nn.Layer):
         lens_t = lens if isinstance(lens, _T) else _T(lens)
         cos, sin = first.self_attn.rotary_emb(s, lens_t)  # once per STEP
         count_dispatch("fused:rope_gather")
-        mesh = _armed_tp_mesh()
+        mesh = shard_group_mesh()
         new_caches = [] if use_cache else None
         n = len(layers)
         for i, layer in enumerate(layers):

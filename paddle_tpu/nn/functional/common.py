@@ -166,19 +166,19 @@ def rms_norm(x, weight=None, epsilon=1e-6, upcast=True):
     ``paddle/phi/kernels/gpu/rms_norm_kernel``): compute in fp32, scale, cast
     back — numerics match the fused GPU kernel's accumulate-in-float behavior.
     On TPU the Pallas fused kernel pins the single-HBM-round-trip schedule."""
-    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
+    from paddle_tpu.kernels.select import pallas_enabled, per_shard, warn_fallback
 
     if (
         weight is not None
         and upcast  # kernel always accumulates fp32
         and weight.dtype == x.dtype  # kernel returns x.dtype; no promotion
         and x.shape[-1] % 128 == 0  # lane-aligned → guaranteed lowerable
-        and pallas_enabled("use_pallas_fused")
+        and pallas_enabled("use_pallas_fused", bare="fused_rms_norm", row_wise=True)
     ):
         try:
             from paddle_tpu.kernels.fused import fused_rms_norm_pallas
 
-            return fused_rms_norm_pallas(x, weight, epsilon)
+            return per_shard(lambda x_, w_: fused_rms_norm_pallas(x_, w_, epsilon))(x, weight)
         except Exception as exc:  # pragma: no cover - TPU-only path
             warn_fallback("fused_rms_norm", exc)
     dtype = x.dtype

@@ -43,7 +43,7 @@ def _use_pallas(q) -> bool:
     # train step cannot fall back (see kernels/select.py)
     if q.shape[-1] % 64 != 0:
         return False
-    return pallas_enabled("use_pallas_attention")
+    return pallas_enabled("use_pallas_attention", bare="flash_attention")
 
 
 def _xla_attention(q, k, v, bias=None, causal=False, scale=None, window=None, dropout=0.0, dropout_key=None):

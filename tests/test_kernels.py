@@ -204,7 +204,7 @@ class TestRopeTrainStepFallbackFlat:
         orig_enabled = sel.pallas_enabled
         monkeypatch.setattr(
             sel, "pallas_enabled",
-            lambda flag: flag == "use_pallas_fused" or orig_enabled(flag),
+            lambda flag, **kw: flag == "use_pallas_fused" or orig_enabled(flag, **kw),
         )
         fwd_calls, bwd_calls = [0], [0]
         orig_rope = fused.fused_rope_pallas
@@ -358,7 +358,7 @@ class TestFusedDecodeEpilogueFallbackFlat:
         orig_enabled = sel.pallas_enabled
         monkeypatch.setattr(
             sel, "pallas_enabled",
-            lambda flag: flag == "use_pallas_fused" or orig_enabled(flag),
+            lambda flag, **kw: flag == "use_pallas_fused" or orig_enabled(flag, **kw),
         )
 
         def boom(*a, **kw):

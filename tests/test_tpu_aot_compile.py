@@ -207,6 +207,36 @@ def test_kernel_compiles_for_v5e_at_7b_width(case, one_chip):
     _compile(fn, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("wrapped", [True, False], ids=["per_shard", "bare"])
+def test_norm_kernel_under_a_four_chip_tp_mesh(wrapped, topo):
+    """A bare ``pallas_call`` in a program partitioned over four chips is
+    refused by Mosaic at lowering — the fact the dispatch's routing rests on
+    (kernels/select.py). Wrapped by ``per_shard`` under the engine's tp mesh
+    the same kernel compiles, each shard on its replicated copy."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from paddle_tpu.core.spmd import partitioned_trace
+    from paddle_tpu.kernels.fused import fused_rms_norm_residual_pallas
+    from paddle_tpu.kernels.select import per_shard
+
+    mesh = Mesh(np.asarray(topo.devices[:4], dtype=object), ("tp",))
+    replicated = NamedSharding(mesh, PartitionSpec())
+    x = jax.ShapeDtypeStruct((SLOTS, CHUNK, HIDDEN), BF16, sharding=replicated)
+    w = jax.ShapeDtypeStruct((HIDDEN,), BF16, sharding=replicated)
+
+    def kernel(x, res, w):
+        return fused_rms_norm_residual_pallas(x, res, w, 1e-6)
+
+    if not wrapped:
+        with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+            jax.jit(kernel).lower(x, x, w)
+        return
+    with partitioned_trace(mesh):  # what the engine's dispatch arms
+        compiled = jax.jit(lambda *a: per_shard(kernel)(*a)).lower(x, x, w).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_fused_loss_default_block_keeps_bench_width_on_512():
     """The repair must not shrink the block at the width it already fit."""
     from paddle_tpu.kernels.fused_loss import _default_block
