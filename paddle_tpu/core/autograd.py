@@ -16,10 +16,15 @@ import threading
 from collections import defaultdict, deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import contextlib
+
 import jax
 import numpy as np
+from jax._src import source_info_util as _source_info  # no public reader of the name stack
 
 from paddle_tpu.errors import InvalidArgumentError, PreconditionNotMetError
+
+_NO_SCOPE = contextlib.nullcontext()
 
 _grad_state = threading.local()
 
@@ -103,6 +108,7 @@ class GradNode:
         "out_treedef",
         "released",
         "input_snapshots",
+        "scope",
         "__weakref__",
     )
 
@@ -126,6 +132,11 @@ class GradNode:
         self.fwd_fn = fwd_fn
         self.out_treedef = out_treedef
         self.released = False
+        # the jax.named_scope path the forward ran under ("" outside any):
+        # the reverse sweep runs this node's vjp under the same names, so a
+        # device trace files an op's backward with its forward
+        stack = _source_info.current_name_stack()
+        self.scope = str(stack) if stack.stack else ""
         # Snapshot the input BUFFERS at dispatch (reference TensorWrapper
         # saved tensors): create_graph re-derives the vjp from these, so an
         # in-place mutation after the forward (including the in-place op's
@@ -417,14 +428,15 @@ def _run_backward_impl(
                 _coerce_cotangent(cots_map.get(i), aval)
                 for i, aval in enumerate(node.out_avals)
             )
-            if node.out_treedef is not None:
-                in_grads = node.vjp_fn(
-                    jax.tree_util.tree_unflatten(node.out_treedef, list(cots))
-                )
-            elif len(node.out_avals) == 1:
-                in_grads = node.vjp_fn(cots[0])
-            else:
-                in_grads = node.vjp_fn(cots)
+            with jax.named_scope(node.scope) if node.scope else _NO_SCOPE:
+                if node.out_treedef is not None:
+                    in_grads = node.vjp_fn(
+                        jax.tree_util.tree_unflatten(node.out_treedef, list(cots))
+                    )
+                elif len(node.out_avals) == 1:
+                    in_grads = node.vjp_fn(cots[0])
+                else:
+                    in_grads = node.vjp_fn(cots)
         if len(in_grads) != len(node.input_tensors):
             raise PreconditionNotMetError(
                 f"vjp of {node.name} returned {len(in_grads)} grads for "

@@ -150,6 +150,12 @@ __all__ = [
     "block_cache_cow_copy",
 ]
 
+# jax.named_scope names of the pool's writes in the engine's step body: what a
+# device trace files the KV append scatters and the copy-on-write conditional
+# (with the pool-sized layout copies XLA hangs on them) under
+SCOPE_KV_WRITE = "kv_cache_update"
+SCOPE_KV_COW = "kv_cow"
+
 
 class BlockKVCache:
     """Host-side paged-cache manager: physical block pool + per-sequence block
@@ -405,6 +411,7 @@ def _quantize_kv_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
+@jax.named_scope(SCOPE_KV_WRITE)
 def block_cache_append(
     key_cache: jax.Array,  # [NB, H, BS, D]
     value_cache: jax.Array,
@@ -446,6 +453,7 @@ def block_cache_append(
     return key_cache, value_cache
 
 
+@jax.named_scope(SCOPE_KV_WRITE)
 def block_cache_prefill(
     key_cache: jax.Array,
     value_cache: jax.Array,
@@ -488,6 +496,7 @@ def block_cache_prefill(
     return key_cache, value_cache
 
 
+@jax.named_scope(SCOPE_KV_COW)
 def block_cache_cow_copy(
     key_cache: jax.Array,  # [NB, H, BS, D]
     value_cache: jax.Array,
@@ -539,6 +548,7 @@ def block_cache_cow_copy(
     )
 
 
+@jax.named_scope(SCOPE_KV_WRITE)
 def block_cache_append_chunk(
     key_cache: jax.Array,  # [NB, H, BS, D]
     value_cache: jax.Array,

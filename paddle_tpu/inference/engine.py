@@ -101,9 +101,10 @@ from __future__ import annotations
 
 import functools
 import itertools
+import sys
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +125,13 @@ from paddle_tpu.observability.recompile import (
     GLOBAL_WATCHDOG,
 )
 from paddle_tpu.testing.faults import InjectedFault, fault_point
+
+# stall accounting (ContinuousBatchingEngine.close_step): a step is a stall
+# when its wall time passes _STALL_FACTOR x the median of the last
+# _STALL_WINDOW steps; the median is refreshed every _STALL_REFRESH steps
+_STALL_WINDOW = 64
+_STALL_FACTOR = 5.0
+_STALL_REFRESH = 16
 
 __all__ = [
     "AdmissionPolicy",
@@ -667,6 +675,13 @@ class ContinuousBatchingEngine:
             "prompt_tokens_computed": 0, "prompt_tokens_reused": 0,
             "spec_steps": 0, "spec_drafted": 0, "spec_accepted": 0,
             "spec_rejected": 0, "gen_blocks_registered": 0,
+            # seconds inside each phase of the serving step, cumulative
+            # (observability/tracing.py phase; deliver is the frontend's)
+            "phase_s.plan": 0.0, "phase_s.launch": 0.0, "phase_s.wait": 0.0,
+            "phase_s.commit": 0.0, "phase_s.deliver": 0.0,
+            # close_step(): steps far above the running median, and what of
+            # their excess lay on the host and in the wait for the device
+            "stall_steps": 0, "stall_s.host": 0.0, "stall_s.device": 0.0,
         }
         self._metrics = _engine_metrics()
         self._update_pool_gauges()
@@ -726,7 +741,21 @@ class ContinuousBatchingEngine:
         # model prices too.
         self._devprof_gate = _devprof.SampleGate()
         self._devprof_timeline = _devprof.StepTimeline()
-        self._devprof_marks: Optional[Dict[str, float]] = None
+        # stall accounting (close_step): the open step's phase seconds, the
+        # last _STALL_WINDOW steps' (host, wait) seconds, and the limit
+        self._open_step: Optional[Tuple[float, ...]] = None
+        # the serving step's open phase (None outside a step: recovery
+        # replays are untimed) and the ones it has been through
+        self._phase: Any = None
+        self._phases_done: List[Any] = []
+        # where the last step's commit phase ended (perf_counter): a driver's
+        # own phase starts there, so nothing lies between the two
+        self.last_step_end_s: Optional[float] = None
+        self._step_compiled = False
+        self._step_walls: Deque[Tuple[float, float]] = deque(maxlen=_STALL_WINDOW)
+        self._stall_limit: Optional[float] = None
+        self._stall_age = 0
+        self._close_mark: Optional[Tuple[float, float]] = None  # (perf_counter, thread_time) at the last close
         from paddle_tpu.distributed.tp import analytic_cost_hints
 
         self._devprof_hints = analytic_cost_hints(
@@ -1172,7 +1201,8 @@ class ContinuousBatchingEngine:
             param_arrays, caches, toks, tables, lens, q_lens, active,
             cow_src, cow_dst,
         )
-        nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
         return nxt, new_caches  # nxt [S, C]: per-row argmax
 
     def _step_forward(
@@ -1624,12 +1654,16 @@ class ContinuousBatchingEngine:
         self._metrics["finished"].labels(reason=req.finish_reason or "unknown").inc()
         self._update_pool_gauges()
 
-    def step(self) -> List[InferenceRequest]:
+    def step(self, since: Optional[float] = None) -> List[InferenceRequest]:
         """One engine iteration: reclaim/admit, then one unified
         prefill/decode step over all active slots. Returns requests that
         finished during this step — the ONLY handback: the engine keeps no
         reference to finished requests (a step()-driven server never grows
         host memory), so a later run() will not re-deliver them.
+
+        ``since`` is the ``perf_counter`` instant at which the caller's own
+        phase ended (``ServingFrontend.pump``): the first attempt's
+        ``engine.plan`` phase starts there, so the phases tile the pump.
 
         Failure policy: a dispatch failure that left the cache buffers
         intact (no donation consumed them) re-raises immediately with host
@@ -1640,10 +1674,13 @@ class ContinuousBatchingEngine:
         exponential backoff, then marks the engine permanently failed and
         re-raises."""
         self._check_usable()
+        self.close_step()  # a bare driver never closed the previous step
         attempt = 0
         while True:
             try:
-                self._step_attempt()
+                self._step_attempt(None if attempt else since)
+                if attempt:
+                    self._open_step = None  # recovered: not a step to judge
                 break
             except BaseException as exc:
                 # broad on purpose: ANY dispatch failure must be classified
@@ -1745,6 +1782,17 @@ class ContinuousBatchingEngine:
 
         return thunk
 
+    def _next_phase(self, name: str, key: str) -> None:
+        """End the serving step's open phase and open the next at the same
+        instant (observability/tracing.py ``phase``); the ended one is kept
+        for the step's own accounting. No-op when none is open (recovery)."""
+        cur = self._phase
+        if cur is None:
+            return
+        cur.__exit__(None, None, None)
+        self._phases_done.append(cur)
+        self._phase = _tracing.phase(name, self.stats, key, cur.step, cur.end_s).__enter__()
+
     def _dispatch(
         self,
         toks: np.ndarray,  # [S, C]
@@ -1757,7 +1805,12 @@ class ContinuousBatchingEngine:
         blocks with the prefix cache. Host token bookkeeping (emission,
         finish checks) is the caller's. On failure every block allocated for
         this step is returned, so repeated failed steps cannot drift the
-        reservation invariant."""
+        reservation invariant.
+
+        Called from the serving step it moves that step's open phase on
+        (``engine.plan`` -> ``.launch`` at the host-to-device puts, ->
+        ``.wait`` at the jit call's return, -> ``.commit`` after the sync);
+        called from :meth:`recover` no phase is open and nothing is timed."""
         appended: List[Tuple[int, int]] = []  # (slot, block) rollback list
         active_slots = [i for i in range(self.max_slots) if active[i]]
         cow_src = np.zeros((self.max_slots,), np.int32)
@@ -1776,9 +1829,7 @@ class ContinuousBatchingEngine:
             tables = self._dense_tables()
             fault_point("engine.decode")
             traces_before = self.stats["step_traces"]
-            marks = self._devprof_marks  # non-None only on a sampled step
-            if marks is not None:
-                marks["call_s"] = time.perf_counter()
+            self._next_phase("engine.launch", "phase_s.launch")
             with self._shard_ctx():  # for the (first-call / recovery) trace
                 nxt, self._caches = self._step_fn(
                     self._param_arrays(), self._caches, jnp.asarray(toks),
@@ -1786,8 +1837,6 @@ class ContinuousBatchingEngine:
                     jnp.asarray(q_lens), jnp.asarray(active),
                     jnp.asarray(cow_src), jnp.asarray(cow_dst),
                 )
-            if marks is not None:
-                marks["ret_s"] = time.perf_counter()
         except BaseException:
             # roll the per-step allocations back so a transient failure
             # leaves the allocator in lockstep with _ntok (retried steps
@@ -1798,6 +1847,7 @@ class ContinuousBatchingEngine:
                 self._mgr.decref(blk)
             raise
         if self.stats["step_traces"] > traces_before:
+            self._step_compiled = True  # not a step whose time is judged
             # recorded HERE, after the jit call returned: a trace that died
             # mid-body bumped the stats counter but produced no program, and
             # the watchdog ledger must only count compiles that exist
@@ -1814,9 +1864,9 @@ class ContinuousBatchingEngine:
                 cost_hints=self._devprof_hints,
             )
             self._step_recorded = True
+        self._next_phase("engine.wait", "phase_s.wait")
         nxt = np.asarray(nxt)  # device sync: the step's tokens are real here
-        if marks is not None:
-            marks["sync_s"] = time.perf_counter()
+        self._next_phase("engine.commit", "phase_s.commit")
         if self._quant_kv and _obs.metrics_enabled():
             # host-side attribution of the step's quantized-plane traffic:
             # every new token was quantized on write, every active slot's
@@ -2035,13 +2085,50 @@ class ContinuousBatchingEngine:
             "speculative_steps": self.stats["spec_steps"],
         }
 
-    def _step_attempt(self) -> None:
+    def _step_attempt(self, since: Optional[float] = None) -> None:
         """One admit+dispatch pass; finished requests land in
-        ``_pending_done`` (never lost to an exception mid-attempt)."""
+        ``_pending_done`` (never lost to an exception mid-attempt).
+
+        The pass runs under four phases that tile it (``engine.plan`` ->
+        ``.launch`` -> ``.wait`` -> ``.commit``, children of
+        ``engine.decode_step``; ``_dispatch`` moves from one to the next):
+        each is on the device trace's clock while a profile is taken, and
+        always adds its seconds to ``stats["phase_s.*"]``
+        (observability/tracing.py ``phase``)."""
+        stats = self.stats
+        step_no = stats["steps"] + 1
+        self._step_compiled = False
+        self.last_step_end_s = None
+        done = self._phases_done = []
+        with _tracing.phase("engine.decode_step", None, None, step_no, since) as whole:
+            self._phase = _tracing.phase(
+                "engine.plan", stats, "phase_s.plan", step_no, whole.start_s
+            ).__enter__()
+            try:
+                stepped = self._step_in_phases(whole.start_s)
+            finally:
+                # whichever phase is open (plan, on an idle pass; commit, after
+                # a step; any, under an exception) ends here
+                last, self._phase = self._phase, None
+                last.__exit__(*sys.exc_info())
+                done.append(last)
+            if stepped is not None:
+                # engine.decode_step takes its instants from its children
+                whole.end_s = self.last_step_end_s = last.end_s
+                self._account_riders(whole, stepped)
+        if stepped is not None and not self._step_compiled:
+            # what close_step() judges: the four phases' seconds
+            self._open_step = tuple(ph.end_s - ph.start_s for ph in done)
+
+    def _step_in_phases(self, now: float) -> Optional[List[Any]]:
+        """The body of one pass begun at ``now``, from ``engine.plan`` (open on
+        entry) to ``engine.commit`` (open on return). Returns the step's riders: its
+        active slots' requests as they were before a finish released its
+        slot (empty unless tracing is on), or ``None`` if nothing stepped."""
+        stats = self.stats
         # mid-decode deadline expiry FIRST: evict before paying for another
         # step of this slot's compute, so the freed slot/blocks are available
         # to the admit pass below in the same boundary
-        now = time.perf_counter()
         for i, req in enumerate(self._slot_req):
             if req is not None and req.expired(now):
                 req.finish_reason = "deadline"
@@ -2066,7 +2153,7 @@ class ContinuousBatchingEngine:
                     i for i, r in enumerate(self._slot_req) if r is not None
                 ]
             if not active_slots:
-                return
+                return None
         C = self.prefill_chunk
         toks = np.zeros((self.max_slots, C), np.int32)
         q_lens = np.zeros((self.max_slots,), np.int32)
@@ -2102,64 +2189,36 @@ class ContinuousBatchingEngine:
         dp_sampled = self._devprof_gate.should_sample()
         comm_ops: Dict[str, float] = {}
         if dp_sampled:
-            self._devprof_marks = {}
             _devprof.begin_comm_window()
-        t0 = time.perf_counter()
         try:
             nxt = self._dispatch(toks, q_lens, active)
-        except BaseException:
-            # re-raised below: only dropping the armed marks dict so a later
-            # non-sampled step's _dispatch can't write into stale state — a
-            # failed sampled step records nothing
-            self._devprof_marks = None
-            raise
         finally:
             if dp_sampled:
                 comm_ops = _devprof.end_comm_window()
-        self.stats["steps"] += 1
-        self.stats["prompt_tokens_computed"] += prefill_tokens
+        stats["steps"] += 1
+        stats["prompt_tokens_computed"] += prefill_tokens
         if prefill_tokens:
             self._metrics["prefill_tokens"].inc(prefill_tokens)
-        t1 = time.perf_counter()
+        plan, launch, wait = self._phases_done
+        t0, t1 = plan.start_s, wait.end_s
         self._metrics["step"].observe(t1 - t0)
         if dp_sampled:
-            marks, self._devprof_marks = self._devprof_marks or {}, None
-            if {"call_s", "ret_s", "sync_s"} <= marks.keys():
-                _devprof.record_step_profile(
-                    "ContinuousBatchingEngine.step",
-                    f"toks[{self.max_slots},{self.prefill_chunk}]"
-                    + (f"|tp{self.tp}" if self.tp > 1 else ""),
-                    t0, marks["call_s"], marks["ret_s"], marks["sync_s"],
-                    comm_ops=comm_ops,
-                    n_active=len(active_slots),
-                    step=self.stats["steps"],
-                    timeline=self._devprof_timeline,
-                    flight=self._flight,
-                )
-        if _tracing.tracing_enabled():
-            # per-request decode time in a continuous batch is a SHARE of
-            # the batched step it rode; accumulate the even split on every
-            # active request, and emit one batch-step span (annotated with
-            # slot membership) when any rider is sampled
-            share = (t1 - t0) / len(active_slots)
-            membership: Dict[str, int] = {}
-            any_sampled = False
-            for i in active_slots:
-                req = self._slot_req[i]
-                req.decode_steps += 1
-                req.decode_share_s += share
-                membership[str(i)] = req.req_id
-                if req.trace is not None and req.trace.sampled:
-                    any_sampled = True
-            if any_sampled:
-                _tracing.GLOBAL_TRACER.add_span(
-                    "engine.decode_step", start_s=t0, end_s=t1,
-                    attrs={
-                        "slot_req_ids": membership,
-                        "n_active": len(active_slots),
-                        "share_s": round(share, 9),
-                    },
-                )
+            # devprof's four instants are the phases' own, no second set
+            _devprof.record_step_profile(
+                "ContinuousBatchingEngine.step",
+                f"toks[{self.max_slots},{self.prefill_chunk}]"
+                + (f"|tp{self.tp}" if self.tp > 1 else ""),
+                t0, launch.start_s, launch.end_s, t1,
+                comm_ops=comm_ops,
+                n_active=len(active_slots),
+                step=stats["steps"],
+                timeline=self._devprof_timeline,
+                flight=self._flight,
+            )
+        riders = (
+            [(i, self._slot_req[i]) for i in active_slots]
+            if _tracing.tracing_enabled() else []
+        )
         for i in active_slots:
             req = self._slot_req[i]
             if int(self._ntok[i]) < req.prompt.size:
@@ -2183,6 +2242,85 @@ class ContinuousBatchingEngine:
                 self._release(i, req)
                 self._pending_done.append(req)
         self._update_pool_gauges()  # step advanced every active slot
+        return riders
+
+    def _account_riders(self, whole: Any, riders: List[Any]) -> None:
+        """Per-request decode time in a continuous batch is a SHARE of the
+        batched step it rode: accumulate the even split on every rider, and
+        make the batch-step span (annotated with slot membership) a stored
+        one when any rider is sampled. ``riders`` is empty with tracing off."""
+        if not riders:
+            return
+        share = (whole.end_s - whole.start_s) / len(riders)
+        any_sampled = False
+        for _slot, req in riders:
+            req.decode_steps += 1
+            req.decode_share_s += share
+            if req.trace is not None and req.trace.sampled:
+                any_sampled = True
+        if any_sampled:
+            whole.record = True
+            whole.attrs = {
+                "slot_req_ids": {str(slot): req.req_id for slot, req in riders},
+                "n_active": len(riders),
+                "share_s": round(share, 9),
+            }
+
+    def close_step(self, deliver_s: float = 0.0) -> None:
+        """Close the last step's stall accounting; whoever drives the engine
+        calls it when the pump around the step is over (``ServingFrontend``
+        hands in its ``frontend.deliver`` seconds). Driven bare, the next
+        ``step()`` (and ``run()`` at its end) closes it with no delivery time.
+
+        A step whose wall time (the four phases + delivery) exceeds
+        ``_STALL_FACTOR`` x the median of the last ``_STALL_WINDOW`` steps is
+        a stall: what its host part (plan + launch + commit + deliver) and
+        its wait lie above their own medians goes to ``stats["stall_s.host"]``
+        / ``["stall_s.device"]``, and ONE flight-recorder event ``step_stall``
+        carries each phase's wall seconds and, over the stretch from the
+        previous step's close to this one's, the wall seconds
+        (``since_close_s``) beside the calling thread's CPU seconds
+        (``cpu_s``) — wall far above CPU: the thread was descheduled or
+        blocked (a shared host, a lock); wall about CPU: the program's own
+        Python ran that long. Steps that compiled or recovered never get
+        here. A step costs one ``thread_time`` read (a system call: the one
+        dear clock here), two float adds and a deque append; the median is
+        refreshed every ``_STALL_REFRESH`` steps."""
+        st = self._open_step
+        if st is None:
+            return
+        self._open_step = None
+        plan_s, launch_s, wait_s, commit_s = st
+        host_s = plan_s + launch_s + commit_s + deliver_s
+        wall_s = host_s + wait_s
+        walls = self._step_walls
+        limit = self._stall_limit
+        mark, self._close_mark = self._close_mark, (time.perf_counter(), time.thread_time())
+        if limit is not None and wall_s > limit:
+            hosts = sorted(h for h, _ in walls)
+            waits = sorted(w for _, w in walls)
+            host_x = max(0.0, host_s - hosts[len(hosts) // 2])
+            wait_x = max(0.0, wait_s - waits[len(waits) // 2])
+            self.stats["stall_s.host"] += host_x
+            self.stats["stall_s.device"] += wait_x
+            self.stats["stall_steps"] += 1
+            now = self._close_mark
+            self._flight.record(
+                "step_stall", step=self.stats["steps"],
+                wall_s=round(wall_s, 6), median_wall_s=round(limit / _STALL_FACTOR, 6),
+                plan_s=round(plan_s, 6), launch_s=round(launch_s, 6),
+                wait_s=round(wait_s, 6), commit_s=round(commit_s, 6),
+                deliver_s=round(deliver_s, 6),
+                stall_host_s=round(host_x, 6), stall_device_s=round(wait_x, 6),
+                since_close_s=None if mark is None else round(now[0] - mark[0], 6),
+                cpu_s=None if mark is None else round(now[1] - mark[1], 6),
+            )
+        walls.append((host_s, wait_s))
+        self._stall_age += 1
+        if self._stall_age >= _STALL_REFRESH and len(walls) >= _STALL_REFRESH:
+            self._stall_age = 0
+            both = sorted(h + w for h, w in walls)
+            self._stall_limit = _STALL_FACTOR * both[len(both) // 2]
 
     def recover(self) -> None:
         """Rebuild device KV state after a dispatch failure consumed the
@@ -2300,4 +2438,5 @@ class ContinuousBatchingEngine:
         while self.has_work():
             for req in self.step():
                 out[req.req_id] = req
+        self.close_step()
         return out

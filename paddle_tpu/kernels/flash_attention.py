@@ -31,6 +31,10 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
+# pallas_call name= of each kernel here: what a device trace calls it (stable, no shapes)
+KERNEL_FWD = "flash_attention_fwd"
+KERNEL_DQ = "flash_attention_dq"
+KERNEL_DKV = "flash_attention_dkv"
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -188,6 +192,7 @@ def _run_fwd(q, k, v, idx, *, sq, sk, scale, causal, blk_q, blk_k, interpret):
             jax.ShapeDtypeStruct((b, h, sq_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_FWD,
     )(*args)
     return out, lse
 
@@ -340,6 +345,7 @@ def _run_bwd(q, k, v, idx, g, out, lse, *, sq, sk, scale, causal, blk_q, blk_k, 
         out_specs=pl.BlockSpec((1, 1, blk_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
         interpret=interpret,
+        name=KERNEL_DQ,
     )(*dq_args, g, lse, delta)
 
     # dk/dv: grid over kv blocks, one q-head at a time (GQA: accumulate
@@ -391,6 +397,7 @@ def _run_bwd(q, k, v, idx, g, out, lse, *, sq, sk, scale, causal, blk_q, blk_k, 
             jax.ShapeDtypeStruct((b, h, sk_pad, d), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_DKV,
     )(*dkv_args, g, lse, delta)
     if group > 1:
         dk = dk_h.reshape(b, hk, group, sk_pad, d).sum(axis=2)

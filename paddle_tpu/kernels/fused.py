@@ -18,6 +18,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# pallas_call name= of each kernel here: what a device trace calls it (stable, no shapes)
+KERNEL_RMS_FWD = "rms_norm_fwd"
+KERNEL_RMS_BWD = "rms_norm_bwd"
+KERNEL_ROPE_FWD = "rope_fwd"
+KERNEL_ROPE_ADJOINT = "rope_adjoint"
+KERNEL_RMS_RES_FWD = "rms_norm_residual_fwd"
+KERNEL_RMS_RES_ADJOINT = "rms_norm_residual_adjoint"
+KERNEL_LN_RES_FWD = "layer_norm_residual_fwd"
+KERNEL_LN_RES_ADJOINT = "layer_norm_residual_adjoint"
+KERNEL_EMBED_RMS = "embed_rms_norm"
+
 
 __all__ = [
     "fused_rms_norm_pallas",
@@ -122,6 +133,7 @@ def _make_rms(rows, h, eps, blk_rows, interpret):
                 jax.ShapeDtypeStruct((1, rows), jnp.float32),
             ],
             interpret=interpret,
+            name=KERNEL_RMS_FWD,
         )(x, w)
 
     @jax.custom_vjp
@@ -156,6 +168,7 @@ def _make_rms(rows, h, eps, blk_rows, interpret):
                 jax.ShapeDtypeStruct((1, h), jnp.float32),
             ],
             interpret=interpret,
+            name=KERNEL_RMS_BWD,
         )(x, w, rstd, g)
         return dx, dw[0].astype(w.dtype)
 
@@ -240,7 +253,7 @@ def _make_rope_runner(bh, s, d, interpret):
     ]
     out_spec = pl.BlockSpec((1, 1, s, d), lambda i: (i, 0, 0, 0))
 
-    def run(kernel, xh, cos2, sin2):
+    def run(kernel, name, xh, cos2, sin2):
         return pl.pallas_call(
             kernel,
             grid=grid,
@@ -250,6 +263,7 @@ def _make_rope_runner(bh, s, d, interpret):
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((bh, 1, s, d), xh.dtype),
             interpret=interpret,
+            name=name,
         )(xh, cos2, sin2)
 
     return run
@@ -261,14 +275,14 @@ def _make_rope(bh, s, d, interpret):
 
     @jax.custom_vjp
     def core(xh, cos2, sin2):
-        return run(_rope_kernel, xh, cos2, sin2)
+        return run(_rope_kernel, KERNEL_ROPE_FWD, xh, cos2, sin2)
 
     def core_fwd(xh, cos2, sin2):
-        return run(_rope_kernel, xh, cos2, sin2), (xh, cos2, sin2)
+        return run(_rope_kernel, KERNEL_ROPE_FWD, xh, cos2, sin2), (xh, cos2, sin2)
 
     def core_bwd(res, g):
         xh, cos2, sin2 = res
-        dx = run(_rope_bwd_kernel, g, cos2, sin2)
+        dx = run(_rope_bwd_kernel, KERNEL_ROPE_ADJOINT, g, cos2, sin2)
         # Table cotangents: trig tables are constants in every real model, so
         # XLA dead-code-eliminates these sums; computed exactly for parity.
         gf = g.astype(jnp.float32)
@@ -315,7 +329,7 @@ def rope_adjoint_pallas(
     cos2 = cos.reshape(1, s, d)
     sin2 = sin.reshape(1, s, d)
     run = _make_rope_runner(b * h, s, d, bool(interpret))
-    dx = run(_rope_bwd_kernel, gh, cos2, sin2)
+    dx = run(_rope_bwd_kernel, KERNEL_ROPE_ADJOINT, gh, cos2, sin2)
     return jnp.moveaxis(dx.reshape(b, h, s, d), 1, 2)
 
 
@@ -447,6 +461,7 @@ def _rms_res_fwd_call(x2, res2, w, eps, blk, interpret):
             jax.ShapeDtypeStruct((1, rows, h), x2.dtype),
         ],
         interpret=interpret,
+        name=KERNEL_RMS_RES_FWD,
     )(x2, res2, w)
 
 
@@ -465,6 +480,7 @@ def _rms_res_adjoint_call(g2, r2, w, eps, blk, interpret):
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_RMS_RES_ADJOINT,
     )(r2, w, g2)
 
 
@@ -483,6 +499,7 @@ def _ln_res_fwd_call(x2, res2, w, b, eps, blk, interpret):
             jax.ShapeDtypeStruct((1, rows, h), x2.dtype),
         ],
         interpret=interpret,
+        name=KERNEL_LN_RES_FWD,
     )(x2, res2, w, b)
 
 
@@ -505,6 +522,7 @@ def _ln_res_adjoint_call(g2, r2, w, eps, blk, interpret):
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_LN_RES_ADJOINT,
     )(r2, w, g2)
 
 
@@ -684,5 +702,6 @@ def fused_embed_rms_norm_pallas(
         # consecutive cells revisit one output block: must run in order
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=KERNEL_EMBED_RMS,
     )(flat, table, weight)
     return emb[:n].reshape(b, c, h), y[:n].reshape(b, c, h)

@@ -53,6 +53,11 @@ from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
 __all__ = ["fused_linear_cross_entropy"]
 
 NEG_INF = -1e30
+# pallas_call name= of each kernel here: what a device trace calls it (stable, no shapes)
+KERNEL_FWD = "fused_loss_fwd"
+KERNEL_DX = "fused_loss_dx"
+KERNEL_DW = "fused_loss_dw"
+KERNEL_FWD_QUANT = "fused_loss_fwd_quant"
 _REF_BLOCK = 512  # scan-reference vocab chunk; any value works, numerics-pinning only
 
 
@@ -389,6 +394,7 @@ def _make_pallas_core(
             out_specs=[col_spec, col_spec, col_spec],
             out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.float32)] * 3,
             interpret=interpret,
+            name=KERNEL_FWD,
         )(x2, wp, lab.reshape(n_pad, 1))
         return (m + jnp.log(l))[:, 0], tl[:, 0]
 
@@ -405,6 +411,7 @@ def _make_pallas_core(
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((n_pad, h), jnp.float32),
             interpret=interpret,
+            name=KERNEL_DX,
         )(x2, wp, lab2, lse2, gc2)
         # dW: transposed grid so its accumulation dim (rows) is innermost —
         # an output block may only be revisited on consecutive grid steps
@@ -430,6 +437,7 @@ def _make_pallas_core(
             out_specs=dw_spec,
             out_shape=dw_shape,
             interpret=interpret,
+            name=KERNEL_DW,
         )(x2, wp, lab2, lse2, gc2)
         return dx.astype(x2.dtype), dw.astype(wp.dtype)
 
@@ -486,6 +494,7 @@ def _make_pallas_quant_fwd(n_pad, v, vp, h, blk_rows, blk_v, vocab_major, interp
             out_specs=[col_spec, col_spec, col_spec],
             out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.float32)] * 3,
             interpret=interpret,
+            name=KERNEL_FWD_QUANT,
         )(x2, wp, lab.reshape(n_pad, 1), sp.reshape(1, vp))
         return (m + jnp.log(l))[:, 0], tl[:, 0]
 

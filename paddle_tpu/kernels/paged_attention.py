@@ -31,6 +31,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# pallas_call name= of each kernel here: what a device trace calls it (stable, no shapes)
+KERNEL_DECODE = "paged_attention_decode"
+KERNEL_CHUNK = "paged_attention_chunk"
+KERNEL_DECODE_FUSED = "paged_attention_decode_fused"
+KERNEL_CHUNK_FUSED = "paged_attention_chunk_fused"
 
 
 def _dequant_tile(k_ref, v_ref, ks_ref, vs_ref):
@@ -189,6 +194,7 @@ def paged_flash_decode(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=KERNEL_DECODE,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
     return out.reshape(b, hq, d)
 
@@ -367,6 +373,7 @@ def paged_flash_chunk(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=KERNEL_CHUNK,
     )(
         block_tables.astype(jnp.int32),
         seq_lens.astype(jnp.int32),
@@ -540,6 +547,7 @@ def paged_flash_decode_fused(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=KERNEL_DECODE_FUSED,
     )(
         block_tables.astype(jnp.int32),
         seq_lens.astype(jnp.int32),
@@ -707,6 +715,7 @@ def paged_flash_chunk_fused(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=KERNEL_CHUNK_FUSED,
     )(
         block_tables.astype(jnp.int32),
         seq_lens.astype(jnp.int32),

@@ -41,6 +41,8 @@ __all__ = [
 # projections and (tied) embeddings are excluded (an embedding weight also
 # feeds the token gather, which must stay full-precision).
 WEIGHT_ONLY_LEAVES = ("gate_proj", "up_proj", "down_proj", "fc1", "fc2", "lm_head")
+# pallas_call name= of each kernel here: what a device trace calls it (stable, no shapes)
+KERNEL_WO_MATMUL = "weight_only_int8_matmul"
 
 
 def quantize_weight_int8(w: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -155,6 +157,7 @@ def _wo_matmul_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=KERNEL_WO_MATMUL,
     )(x, w8, scale.reshape(1, n))
 
 

@@ -20,6 +20,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Any, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu
@@ -33,6 +34,15 @@ from paddle_tpu.incubate.nn.functional import fused_rotary_position_embedding
 from paddle_tpu.kernels.fused import count_dispatch
 from paddle_tpu.ops.creation import arange
 from paddle_tpu.ops.manipulation import concat, reshape
+
+# jax.named_scope names of the model's parts: what a device trace files their
+# operations (and the backward's, as transpose(jvp(<scope>))) under
+SCOPE_EMBEDDING = "embedding"
+SCOPE_NORM = "norm"
+SCOPE_ATTENTION = "attention"
+SCOPE_MLP = "mlp"
+SCOPE_LM_HEAD = "lm_head"
+SCOPE_LOSS_HEAD = "loss_head"
 
 
 @dataclass
@@ -369,19 +379,23 @@ class LlamaDecoderLayer(nn.Layer):
         cache_position: Optional[Tensor] = None,
     ) -> Any:
         residual = hidden_states
-        h = self.input_layernorm(hidden_states)
+        with jax.named_scope(SCOPE_NORM):
+            h = self.input_layernorm(hidden_states)
         count_dispatch("unfused:input_norm")
-        attn_out = self.self_attn(
-            h, startend_row_indices, past_key_value, use_cache, cache_position
-        )
+        with jax.named_scope(SCOPE_ATTENTION):
+            attn_out = self.self_attn(
+                h, startend_row_indices, past_key_value, use_cache, cache_position
+            )
         if use_cache:
             attn_out, cache = attn_out
         h = residual + attn_out
         count_dispatch("unfused:attn_residual_add")
         residual = h
-        h = self.post_attention_layernorm(h)
+        with jax.named_scope(SCOPE_NORM):
+            h = self.post_attention_layernorm(h)
         count_dispatch("unfused:post_attn_norm")
-        h = self.mlp(h)
+        with jax.named_scope(SCOPE_MLP):
+            h = self.mlp(h)
         count_dispatch("unfused:mlp")
         h = residual + h
         count_dispatch("unfused:mlp_residual_add")
@@ -419,7 +433,8 @@ class LlamaModel(nn.Layer):
             # same math, fewer dispatches. generate_paged's 4/5-tuple pasts
             # and every train/prefill path stay on the layer modules below.
             return self._forward_paged_fused(input_ids, past_key_values, use_cache)
-        h = self.embed_tokens(input_ids)
+        with jax.named_scope(SCOPE_EMBEDDING):
+            h = self.embed_tokens(input_ids)
         count_dispatch("unfused:embed")
         new_caches = [] if use_cache else None
         use_recompute = (
@@ -439,7 +454,8 @@ class LlamaModel(nn.Layer):
             if use_cache:
                 h, cache = h
                 new_caches.append(cache)
-        h = self.norm(h)
+        with jax.named_scope(SCOPE_NORM):
+            h = self.norm(h)
         count_dispatch("unfused:final_norm")
         if use_cache:
             return h, new_caches
@@ -484,34 +500,39 @@ class LlamaModel(nn.Layer):
 
         layers = list(self.layers)
         first = layers[0]
-        residual, h = fused_embed_rms_norm(
-            input_ids,
-            self.embed_tokens.weight,
-            first.input_layernorm.weight,
-            first.input_layernorm.epsilon,
-        )
+        with jax.named_scope(SCOPE_EMBEDDING):
+            residual, h = fused_embed_rms_norm(
+                input_ids,
+                self.embed_tokens.weight,
+                first.input_layernorm.weight,
+                first.input_layernorm.epsilon,
+            )
         count_dispatch("fused:embed_norm")
         s = input_ids.shape[1]
         lens = past_key_values[0][3]
         lens_t = lens if isinstance(lens, _T) else _T(lens)
-        cos, sin = first.self_attn.rotary_emb(s, lens_t)  # once per STEP
+        with jax.named_scope(SCOPE_ATTENTION):
+            cos, sin = first.self_attn.rotary_emb(s, lens_t)  # once per STEP
         count_dispatch("fused:rope_gather")
         mesh = shard_group_mesh()
         new_caches = [] if use_cache else None
         n = len(layers)
         for i, layer in enumerate(layers):
-            attn_out, cache = layer.self_attn.forward_paged_fused(
-                h, past_key_values[i], cos, sin
-            )
-            h, residual = fused_rms_norm_residual(
-                attn_out,
-                layer.post_attention_layernorm.weight,
-                residual,
-                layer.post_attention_layernorm.epsilon,
-            )
+            with jax.named_scope(SCOPE_ATTENTION):
+                attn_out, cache = layer.self_attn.forward_paged_fused(
+                    h, past_key_values[i], cos, sin
+                )
+            with jax.named_scope(SCOPE_NORM):
+                h, residual = fused_rms_norm_residual(
+                    attn_out,
+                    layer.post_attention_layernorm.weight,
+                    residual,
+                    layer.post_attention_layernorm.epsilon,
+                )
             count_dispatch("fused:residual_norm")
             if mesh is None:
-                mlp_out = layer.mlp(h)
+                with jax.named_scope(SCOPE_MLP):
+                    mlp_out = layer.mlp(h)
             else:
                 from paddle_tpu.distributed.tp import row_parallel_overlap_matmul
 
@@ -534,9 +555,10 @@ class LlamaModel(nn.Layer):
                 )
             count_dispatch("fused:mlp")
             next_norm = layers[i + 1].input_layernorm if i + 1 < n else self.norm
-            h, residual = fused_rms_norm_residual(
-                mlp_out, next_norm.weight, residual, next_norm.epsilon
-            )
+            with jax.named_scope(SCOPE_NORM):
+                h, residual = fused_rms_norm_residual(
+                    mlp_out, next_norm.weight, residual, next_norm.epsilon
+                )
             count_dispatch("fused:residual_norm")
             if use_cache:
                 new_caches.append(cache)
@@ -586,25 +608,28 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
         if use_cache:
             out, caches = out
         if labels is not None and GLOBAL_FLAGS.get("use_fused_loss"):
-            if self.lm_head is not None:
-                loss = F.fused_linear_cross_entropy(
-                    out, self.lm_head.weight, labels, ignore_index=-100,
-                    reduction="mean",
-                    weight_scale=getattr(self.lm_head.weight, "_quant_scale", None),
-                )
-            else:
-                loss = F.fused_linear_cross_entropy(
-                    out, self.llama.embed_tokens.weight, labels,
-                    ignore_index=-100, reduction="mean", weight_vocab_major=True,
-                )
+            with jax.named_scope(SCOPE_LOSS_HEAD):
+                if self.lm_head is not None:
+                    loss = F.fused_linear_cross_entropy(
+                        out, self.lm_head.weight, labels, ignore_index=-100,
+                        reduction="mean",
+                        weight_scale=getattr(self.lm_head.weight, "_quant_scale", None),
+                    )
+                else:
+                    loss = F.fused_linear_cross_entropy(
+                        out, self.llama.embed_tokens.weight, labels,
+                        ignore_index=-100, reduction="mean", weight_vocab_major=True,
+                    )
             return loss, None
-        if self.lm_head is not None:
-            logits = self.lm_head(out)
-        else:
-            logits = paddle_tpu.matmul(out, self.llama.embed_tokens.weight, transpose_y=True)
+        with jax.named_scope(SCOPE_LM_HEAD):
+            if self.lm_head is not None:
+                logits = self.lm_head(out)
+            else:
+                logits = paddle_tpu.matmul(out, self.llama.embed_tokens.weight, transpose_y=True)
         if labels is not None:
             # F.cross_entropy upcasts to fp32 internally (stable logsumexp)
-            loss = F.cross_entropy(logits, labels, ignore_index=-100, reduction="mean")
+            with jax.named_scope(SCOPE_LOSS_HEAD):
+                loss = F.cross_entropy(logits, labels, ignore_index=-100, reduction="mean")
             return loss, logits
         if use_cache:
             return logits, caches

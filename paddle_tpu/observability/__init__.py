@@ -29,6 +29,44 @@ Three pieces, one substrate every perf/robustness PR reports through:
   permanent failure, watchdog timeout and pump-thread death; read dumps
   with ``python -m paddle_tpu.observability.dump``.
 
+**Phases of the serving step** (:class:`.tracing.phase`, always on). One
+``ServingFrontend.pump()`` is tiled by ``frontend.deliver`` (the controller
+update at entry; progress, finalisation, controller and gauges after the
+step) and the four children of ``engine.decode_step``: ``engine.plan``
+(admission up to the jit call), ``engine.launch`` (host-to-device puts and
+the call up to its return), ``engine.wait`` (the host blocked on the
+device), ``engine.commit`` (bookkeeping and token emission after the sync).
+Recovery replays run under ``engine.recover``. Each phase is
+
+- a ``jax.profiler.TraceAnnotation("paddle_tpu.<phase>")``: start
+  ``profiler.Profiler`` or ``jax.profiler.start_trace`` and the phases lie
+  over the device's "XLA Ops" in the same ``.xplane.pb``, on its clock; the
+  kernels there carry their ``pallas_call`` names (``paged_attention_chunk``,
+  ``flash_attention_fwd``, ``fused_loss_dw``, ...) and every other operation
+  its ``jax.named_scope`` path in the ``tf_op`` stat (``embedding``, ``norm``,
+  ``attention``, ``mlp``, ``lm_head``, ``loss_head``, ``optimizer_update``,
+  ``kv_cache_update``, ``kv_cow``, ``sample``; a backward operation under its
+  forward's);
+- seconds added to ``engine.stats``: ``phase_s.plan``, ``phase_s.launch``,
+  ``phase_s.wait``, ``phase_s.commit``, ``phase_s.deliver`` (cumulative;
+  divide a delta by the delta of ``steps``);
+- at ``FLAGS_trace_sample_rate >= 1`` a span in the ring, child of the
+  enclosing phase, with the step number.
+
+**Stalls.** A step whose wall time passes 5 x the median of the last 64
+steps adds what lay above the median to ``engine.stats["stall_s.host"]``
+(plan + launch + commit + deliver) or ``["stall_s.device"]`` (wait), bumps
+``["stall_steps"]`` and records ONE flight-recorder event ``step_stall``:
+each phase's wall seconds, ``median_wall_s``, and over the stretch from the
+previous step's close to this one's the wall seconds (``since_close_s``)
+beside the pump thread's CPU seconds (``cpu_s``, ``time.thread_time()``, one
+read a step). ``cpu_s`` far below ``since_close_s``: the thread was
+descheduled or blocked (a shared host, a lock);
+about equal: the program's own Python ran that long. Steps that compile or
+recover are not judged. Read it from a dump
+(``obs.GLOBAL_FLIGHT_RECORDER.dump("why")`` then ``python -m
+paddle_tpu.observability.dump <file>``) or from ``snapshot()``.
+
 Instrumented call sites: ``inference/engine.py`` (TTFT, decode-step latency,
 queue depth, admits/evicts/finished, KV-pool gauges), ``jit/api.py``
 (StaticFunction cache misses feed the watchdog), ``distributed/collective.py``
@@ -52,6 +90,7 @@ from paddle_tpu.observability.tracing import (  # noqa: F401
     format_traceparent,
     get_tracer,
     parse_traceparent,
+    phase,
     tracing_enabled,
     tracing_full,
 )

@@ -31,6 +31,18 @@ export, ``RecordEvent`` spans):
   ``tracing.export`` fault site: a failing export must never take down the
   path that called it (callers use the ``safe_*`` forms on failure seams).
 
+**Phases** (:class:`phase`) are the one primitive for timing a stretch of
+the serving step from INSIDE the program. A phase is always a
+``jax.profiler.TraceAnnotation("paddle_tpu.<name>")`` — so whenever a profile
+is being taken (``profiler.Profiler``, ``jax.profiler.start_trace``) it lies
+on the device trace's clock, in the same ``.xplane.pb`` as the "XLA Ops"
+line — and always adds its wall seconds to a cumulative counter handed to it
+(``engine.stats["phase_s.<name>"]``); only at ``FLAGS_trace_sample_rate >= 1``
+does it also add a span to the ring, parented to the enclosing phase.
+``profiler.RecordEvent`` makes its annotation through the same
+:func:`annotate`, so every host span the program puts into a device trace
+comes from here.
+
 The ``traceparent`` header follows the W3C shape
 ``00-<32 hex trace_id>-<16 hex span_id>-<2 hex flags>`` (flag bit 0x01 =
 sampled); malformed headers are ignored and a fresh trace starts.
@@ -47,16 +59,21 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Union
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from paddle_tpu.flags import GLOBAL_FLAGS
 
 __all__ = [
     "GLOBAL_TRACER",
+    "PHASE_PREFIX",
     "Span",
     "TraceContext",
     "Tracer",
     "format_traceparent",
+    "annotate",
     "get_tracer",
     "parse_traceparent",
+    "phase",
     "tracing_enabled",
     "tracing_full",
 ]
@@ -255,6 +272,17 @@ class Tracer:
             sampled = rate > 0.0 and self._rng.random() < rate
             return TraceContext(self._gen_id(16), self._gen_id(8), None, sampled)
 
+    def new_context(self, parent: Optional[TraceContext] = None) -> TraceContext:
+        """Fresh ids for a span the caller will record itself with
+        :meth:`add_span`: a child of ``parent`` (same trace), or the root of
+        a new trace."""
+        with self._lock:
+            if parent is None:
+                return TraceContext(self._gen_id(16), self._gen_id(8), None, True)
+            return TraceContext(
+                parent.trace_id, self._gen_id(8), parent.span_id, parent.sampled
+            )
+
     # -- recording -----------------------------------------------------------
     def span(
         self,
@@ -433,3 +461,97 @@ def _reseed_global(value: Any) -> None:
 
 
 GLOBAL_FLAGS.on_change("trace_seed", _reseed_global)
+
+
+# -- phases: the program's own spans on the device trace's clock -------------
+PHASE_PREFIX = "paddle_tpu."
+_phase_local = threading.local()  # .stack: contexts of the open recorded phases
+
+
+def annotate(name: str) -> Any:
+    """An un-entered ``jax.profiler.TraceAnnotation``: the one place the
+    program makes one. With a profile running, the span lands on the host
+    plane of the profile's ``.xplane.pb``, on the same clock as the device's
+    "XLA Ops" line."""
+    return _TraceAnnotation(name)
+
+
+class phase:
+    """One named stretch of host work, as ``with phase(name, sink, key):``.
+
+    Always: a ``TraceAnnotation("paddle_tpu.<name>")`` (entered whenever a
+    profile is being taken; otherwise the check of one flag) and two
+    ``perf_counter`` instants (``start_s``/``end_s``, kept on the object for
+    whoever else needs them: devprof, the stall accounting), whose difference
+    is added to ``sink[key]``. ``start_s=`` hands over the previous phase's
+    ``end_s`` so that consecutive phases tile with nothing between them.
+    Only at ``FLAGS_trace_sample_rate >= 1``: a span in ``GLOBAL_TRACER``
+    whose ``parent_id`` is the enclosing phase's span (per thread), with
+    ``step`` and ``attrs`` as attributes. Before exit a caller may set
+    ``record`` (store a parentless span at a partial rate) and ``end_s`` (a
+    parent that ends where its last child ended reads no clock of its own)."""
+
+    __slots__ = (
+        "name", "sink", "key", "step", "attrs", "record", "start_s", "end_s",
+        "_ann", "_ctx",
+    )
+
+    def __init__(
+        self,
+        name: str,
+        sink: Optional[Dict[str, Any]] = None,
+        key: Optional[str] = None,
+        step: Optional[int] = None,
+        start_s: Optional[float] = None,
+    ) -> None:
+        self.name = name
+        self.sink = sink
+        self.key = key
+        self.step = step
+        self.attrs: Optional[Dict[str, Any]] = None
+        self.record = False
+        self.start_s = start_s
+        self.end_s: Optional[float] = None
+        self._ann: Any = None
+        self._ctx: Optional[TraceContext] = None
+
+    def __enter__(self) -> "phase":
+        if _TraceAnnotation.is_enabled():  # a profile is being taken: one flag
+            self._ann = annotate(PHASE_PREFIX + self.name)
+            self._ann.__enter__()
+        if _FULL[0]:
+            stack = getattr(_phase_local, "stack", None)
+            if stack is None:
+                stack = _phase_local.stack = []
+            self._ctx = GLOBAL_TRACER.new_context(stack[-1] if stack else None)
+            stack.append(self._ctx)
+            self.record = True
+        if self.start_s is None:
+            self.start_s = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
+        end = self.end_s  # preset by a parent that takes its children's instants
+        if end is None:
+            self.end_s = end = time.perf_counter()
+        if self.sink is not None:
+            self.sink[self.key] += end - self.start_s
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        ctx = self._ctx
+        if ctx is not None:
+            _phase_local.stack.pop()
+        if self.record:
+            attrs = dict(self.attrs) if self.attrs else {}
+            if self.step is not None:
+                attrs["step"] = self.step
+            GLOBAL_TRACER.add_span(
+                self.name,
+                trace_id=ctx.trace_id if ctx is not None else None,
+                span_id=ctx.span_id if ctx is not None else None,
+                parent_id=ctx.parent_id if ctx is not None else None,
+                start_s=self.start_s,
+                end_s=end,
+                attrs=attrs,
+                status="ok" if exc_type is None else f"error:{exc_type.__name__}",
+            )

@@ -22,6 +22,8 @@ import paddle_tpu
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.errors import InvalidArgumentError
 
+SCOPE_UPDATE = "optimizer_update"  # jax.named_scope of the fused update
+
 
 class Optimizer:
     def __init__(
@@ -163,6 +165,8 @@ class Optimizer:
         if self._jit_step_fn is None:
             update = self.update
 
+            # what a device trace files the update's operations under
+            @jax.named_scope(SCOPE_UPDATE)
             def fused(ps, gs, sts, lr_, step_, wd):
                 new_ps, new_sts = [], []
                 for p_, g_, st in zip(ps, gs, sts):

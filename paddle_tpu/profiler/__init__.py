@@ -107,8 +107,10 @@ _recorder = _HostEventRecorder()
 
 class RecordEvent:
     """RAII host span (reference ``paddle/phi/api/profiler/event_tracing.h``
-    RecordEvent). Also forwards to jax TraceAnnotation so spans appear in XLA
-    device traces."""
+    RecordEvent). Its annotation in XLA device traces is made by
+    ``observability.tracing.annotate``, the same call the program's own
+    phases use; what stays here is the user-facing begin/end API and the
+    chrome-export recorder."""
 
     def __init__(self, name: str, event_type: Any = None) -> None:
         self.name = name
@@ -118,9 +120,9 @@ class RecordEvent:
     def begin(self) -> None:
         self._start = time.perf_counter() * 1e6
         try:
-            import jax.profiler
+            from paddle_tpu.observability.tracing import annotate
 
-            self._jax_ann = jax.profiler.TraceAnnotation(self.name)
+            self._jax_ann = annotate(self.name)
             self._jax_ann.__enter__()
         except Exception:  # device annotation is best-effort; host span still recorded
             self._jax_ann = None

@@ -483,31 +483,51 @@ class ServingFrontend:
         the overload controller. Returns handles that reached a terminal
         state during this call."""
         finished: List[ServingRequest] = []
-        with self._lock:
-            # sample pressure at boundary ENTRY: the backlog as offered, not
-            # as already drained by this step's admissions — shedding must
-            # react to what clients are experiencing, and a deep queue that
-            # momentarily empties into slots is still a deep queue
-            self._update_controller()
-            done_inner: List[InferenceRequest] = []
-            if self.engine.has_work():
-                t0 = time.perf_counter()
-                done_inner = self.engine.step()
-                self._step_times.append(time.perf_counter() - t0)
-            now = time.perf_counter()
-            # stream tokens for everything still holding a slot
-            for inner in self.engine.live_requests():
-                handle = self._live.get(inner.req_id)
-                if handle is not None:
-                    self._note_progress(handle, now)
-            for inner in done_inner:
-                handle = self._live.pop(inner.req_id, None)
-                if handle is None:
-                    continue  # direct engine user / already cancelled
-                self._note_progress(handle, now)
-                finished.append(self._finalize(handle, now))
-            self._update_controller()
-            self._update_gauges()
+        engine = self.engine
+        stats = engine.stats
+        # frontend.pump = engine.step()'s four phases + frontend.deliver (two
+        # stretches, one counter): the phases tile the pump
+        with _tracing.phase("frontend.pump") as whole:
+            with self._lock:
+                with _tracing.phase(
+                    "frontend.deliver", stats, "phase_s.deliver", None, whole.start_s
+                ) as before:
+                    # sample pressure at boundary ENTRY: the backlog as
+                    # offered, not as already drained by this step's
+                    # admissions — shedding must react to what clients are
+                    # experiencing, and a deep queue that momentarily empties
+                    # into slots is still a deep queue
+                    self._update_controller()
+                    stepping = engine.has_work()
+                done_inner: List[InferenceRequest] = []
+                if stepping:
+                    done_inner = engine.step(since=before.end_s)
+                with _tracing.phase(
+                    "frontend.deliver", stats, "phase_s.deliver", None,
+                    engine.last_step_end_s if stepping else before.end_s,
+                ) as after:
+                    now = after.start_s
+                    if stepping:
+                        self._step_times.append(now - before.end_s)
+                    # stream tokens for everything still holding a slot
+                    for inner in engine.live_requests():
+                        handle = self._live.get(inner.req_id)
+                        if handle is not None:
+                            self._note_progress(handle, now)
+                    for inner in done_inner:
+                        handle = self._live.pop(inner.req_id, None)
+                        if handle is None:
+                            continue  # direct engine user / already cancelled
+                        self._note_progress(handle, now)
+                        finished.append(self._finalize(handle, now))
+                    self._update_controller()
+                    self._update_gauges()
+                    if stepping:
+                        engine.close_step(
+                            (before.end_s - before.start_s)
+                            + (time.perf_counter() - after.start_s)
+                        )
+            whole.end_s = after.end_s  # the pump ends where its last phase did
         return finished
 
     def _note_progress(self, handle: ServingRequest, now: float) -> None:

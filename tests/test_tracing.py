@@ -628,3 +628,205 @@ class TestBlackBoxOnPermanentFailure:
             assert dump_cli.main([str(dumps[-1])]) == 0
         finally:
             paddle.set_flags(prior)
+
+
+# -- PR 23: phases inside the serving step, always-on stall accounting --------
+ENGINE_PHASES = ("plan", "launch", "wait", "commit")
+PHASE_KEYS = tuple(f"phase_s.{p}" for p in ENGINE_PHASES + ("deliver",))
+
+
+def _phase_frontend(seed=31, n_requests=2, **engine_kw):
+    """A frontend whose one compile is behind it, with work to pump."""
+    fe, eng, cfg = _frontend(seed=seed, **engine_kw)
+    rng = np.random.default_rng(seed)
+    warm = fe.submit(_prompt(rng, cfg), max_new_tokens=2)
+    _drain(fe, [warm])
+    handles = [fe.submit(_prompt(rng, cfg, 6), max_new_tokens=120) for _ in range(n_requests)]
+    return fe, eng, handles
+
+
+class TestStepPhases:
+    def test_phase_is_an_annotation_a_counter_and_at_full_rate_a_span(self, tracing_on):
+        sink = {"k": 0.0}
+        with tracing.phase("outer") as outer:
+            with tracing.phase("inner", sink, "k", step=7, start_s=outer.start_s) as inner:
+                pass
+        assert inner.start_s == outer.start_s and inner.end_s <= outer.end_s
+        assert sink["k"] == pytest.approx(inner.end_s - inner.start_s)
+        by_name = {s["name"]: s for s in tracing_on.spans()}
+        assert by_name["inner"]["parent_id"] == by_name["outer"]["span_id"]
+        assert by_name["inner"]["trace_id"] == by_name["outer"]["trace_id"]
+        assert by_name["inner"]["attrs"] == {"step": 7}
+        assert by_name["outer"]["parent_id"] is None
+
+    def test_phases_tile_the_pump(self):
+        import time
+
+        fe, eng, _handles = _phase_frontend()
+        before = dict(eng.stats)
+        walls = []
+        for _ in range(20):
+            t0 = time.perf_counter()
+            fe.pump()
+            walls.append(time.perf_counter() - t0)
+        grown = {k: eng.stats[k] - before[k] for k in PHASE_KEYS}
+        assert all(v > 0 for v in grown.values()), grown
+        # the five counters tile the pumps: nothing of a pump lies outside them
+        assert sum(grown.values()) == pytest.approx(sum(walls), rel=0.02)
+        assert sum(grown.values()) <= sum(walls)
+
+    def test_phases_nest_in_the_ring(self, tracing_on):
+        fe, eng, _handles = _phase_frontend()
+        tracing_on.clear()
+        before = dict(eng.stats)
+        for _ in range(20):
+            fe.pump()
+        grown = {k: eng.stats[k] - before[k] for k in PHASE_KEYS}
+        spans = tracing_on.spans()
+        by_id = {s["span_id"]: s for s in spans}
+        parent_of = {
+            "engine.plan": "engine.decode_step", "engine.launch": "engine.decode_step",
+            "engine.wait": "engine.decode_step", "engine.commit": "engine.decode_step",
+            "engine.decode_step": "frontend.pump", "frontend.deliver": "frontend.pump",
+        }
+        counts = {}
+        for s in spans:
+            counts[s["name"]] = counts.get(s["name"], 0) + 1
+            if s["name"] in parent_of:
+                parent = by_id[s["parent_id"]]
+                assert parent["name"] == parent_of[s["name"]], (s["name"], parent["name"])
+                assert parent["ts_us"] <= s["ts_us"] + 1e-3
+                assert s["ts_us"] + s["dur_us"] <= parent["ts_us"] + parent["dur_us"] + 1e-3
+                if s["name"].startswith("engine."):
+                    assert s["attrs"]["step"] == parent["attrs"].get("step", s["attrs"]["step"])
+        assert counts["frontend.pump"] == 20 and counts["engine.decode_step"] == 20
+        # stored pumps and counters are the same instants: they agree exactly
+        pumps_s = sum(s["dur_us"] for s in spans if s["name"] == "frontend.pump") / 1e6
+        assert sum(grown.values()) == pytest.approx(pumps_s, rel=1e-6)
+        assert counts["frontend.deliver"] == 40  # two stretches a pump, one counter
+        for p in ENGINE_PHASES:
+            assert counts[f"engine.{p}"] == 20
+        # the decode step takes its instants from its children
+        for s in spans:
+            if s["name"] == "engine.decode_step":
+                kids = sorted((k for k in spans if k["parent_id"] == s["span_id"]), key=lambda k: k["ts_us"])
+                assert [k["name"] for k in kids] == [f"engine.{p}" for p in ENGINE_PHASES]
+                assert kids[0]["ts_us"] == s["ts_us"]
+                assert kids[-1]["ts_us"] + kids[-1]["dur_us"] == pytest.approx(s["ts_us"] + s["dur_us"], abs=1e-3)
+                for a, b in zip(kids, kids[1:]):  # consecutive phases share an instant
+                    assert a["ts_us"] + a["dur_us"] == pytest.approx(b["ts_us"], abs=1e-3)
+
+    def test_rate_zero_adds_nothing_to_the_ring_and_counters_still_grow(self):
+        assert not tracing.tracing_enabled()
+        fe, eng, _handles = _phase_frontend(seed=32)
+        obs.GLOBAL_TRACER.clear()
+        before = dict(eng.stats)
+        for _ in range(100):
+            fe.pump()
+        assert eng.stats["steps"] - before["steps"] == 100
+        assert obs.GLOBAL_TRACER.records() == []
+        assert all(eng.stats[k] > before[k] for k in PHASE_KEYS)
+
+    def test_bare_engine_counts_its_four_phases_and_no_delivery(self):
+        m, cfg = _model(33)
+        eng = ContinuousBatchingEngine(m, max_slots=2, block_size=4, prompt_bucket=8)
+        eng.add_request(_prompt(np.random.default_rng(33), cfg), max_new_tokens=6)
+        eng.run()
+        assert all(eng.stats[f"phase_s.{p}"] > 0 for p in ENGINE_PHASES)
+        assert eng.stats["phase_s.deliver"] == 0.0 and eng.stats["stall_steps"] == 0
+
+
+class TestStallAccounting:
+    BASE_S, STALL_S = 0.004, 0.25
+
+    def _slowed(self, eng, stall_at):
+        """Every plan pays BASE_S in ``_dense_tables`` (so that the running
+        median is far above the suite's scheduling noise); call number
+        ``stall_at`` pays STALL_S more: a sleep, so wall and not CPU."""
+        import time
+
+        real, calls = eng._dense_tables, [0]
+
+        def slow():
+            calls[0] += 1
+            time.sleep(self.BASE_S + (self.STALL_S if calls[0] == stall_at else 0.0))
+            return real()
+
+        eng._dense_tables = slow
+        return calls
+
+    def test_one_sleep_in_plan_is_one_host_stall(self):
+        fe, eng, _handles = _phase_frontend(seed=34)
+        flight = flightrec.FlightRecorder(capacity=512)
+        eng._flight = flight
+        self._slowed(eng, stall_at=40)
+        for _ in range(60):
+            fe.pump()
+        events = [e for e in flight.snapshot() if e["kind"] == "step_stall"]
+        assert len(events) == 1 and eng.stats["stall_steps"] == 1
+        ev = events[0]
+        assert eng.stats["stall_s.host"] == pytest.approx(self.STALL_S, rel=0.2)
+        assert eng.stats["stall_s.device"] == pytest.approx(0.0, abs=0.02)
+        assert ev["plan_s"] > self.STALL_S and ev["stall_host_s"] == pytest.approx(eng.stats["stall_s.host"], abs=1e-5)
+        assert ev["wall_s"] > 5 * ev["median_wall_s"]
+        # asleep, not computing: over the stretch since the previous step closed the
+        # thread's CPU seconds are far below the wall seconds
+        assert ev["since_close_s"] >= ev["wall_s"]
+        assert ev["cpu_s"] < 0.2 * ev["since_close_s"]
+        for key in ("launch_s", "wait_s", "commit_s", "deliver_s", "step"):
+            assert key in ev
+        # and the dump CLI's reader shows it as any other flight event
+        assert "step_stall" in json.dumps(flight.snapshot())
+
+    def test_a_busy_plan_reads_as_cpu_not_as_descheduled(self):
+        import time
+
+        fe, eng, _handles = _phase_frontend(seed=35)
+        flight = flightrec.FlightRecorder(capacity=512)
+        eng._flight = flight
+        calls = self._slowed(eng, stall_at=-1)
+        real = eng._dense_tables
+
+        def spin():
+            if calls[0] == 39:  # the 40th plan burns CPU instead of sleeping
+                until = time.perf_counter() + self.STALL_S
+                while time.perf_counter() < until:
+                    pass
+            return real()
+
+        eng._dense_tables = spin
+        for _ in range(60):
+            fe.pump()
+        (ev,) = [e for e in flight.snapshot() if e["kind"] == "step_stall"]
+        assert ev["cpu_s"] > 0.5 * self.STALL_S and ev["cpu_s"] > 0.5 * ev["since_close_s"]
+
+    def test_a_recovery_is_not_a_stall(self):
+        fe, eng, _handles = _phase_frontend(seed=36, max_recoveries=2, recovery_backoff=0.05)
+        flight = flightrec.FlightRecorder(capacity=512)
+        eng._flight = flight
+        self._slowed(eng, stall_at=-1)
+        for _ in range(30):
+            fe.pump()
+        before = eng.stats["steps"]
+        with faults.inject(faults.FaultPlan([faults.FaultTrigger("engine.decode", 0)])):
+            fe.pump()  # dispatch dies, backoff sleeps, recover() replays, retry succeeds
+        assert eng.stats["recoveries"] == 1 and eng.stats["steps"] == before + 1
+        for _ in range(10):
+            fe.pump()
+        assert eng.stats["stall_steps"] == 0
+        assert not [e for e in flight.snapshot() if e["kind"] == "step_stall"]
+        assert any(e["kind"] == "recovery" for e in flight.snapshot())
+
+    def test_recovery_dispatches_run_under_engine_recover_not_step_phases(self, tracing_on):
+        fe, eng, _handles = _phase_frontend(seed=37, max_recoveries=2, recovery_backoff=0.0)
+        for _ in range(3):
+            fe.pump()
+        tracing_on.clear()
+        launch_before = eng.stats["phase_s.launch"]
+        with faults.inject(faults.FaultPlan([faults.FaultTrigger("engine.decode", 0)])):
+            fe.pump()
+        names = [s["name"] for s in tracing_on.spans()]
+        assert names.count("engine.recover") == 1
+        # the replay dispatched several times; only the retried step launched under a phase
+        assert names.count("engine.launch") == 1
+        assert eng.stats["phase_s.launch"] > launch_before
