@@ -682,6 +682,9 @@ class ContinuousBatchingEngine:
             # close_step(): steps far above the running median, and what of
             # their excess lay on the host and in the wait for the device
             "stall_steps": 0, "stall_s.host": 0.0, "stall_s.device": 0.0,
+            # KV pages the paged kernel's length-bounded walk visits, a layer:
+            # sum over a step's active slots of ceil((cached + new) / block)
+            "paged_pages_walked": 0,
         }
         self._metrics = _engine_metrics()
         self._update_pool_gauges()
@@ -2158,7 +2161,7 @@ class ContinuousBatchingEngine:
         toks = np.zeros((self.max_slots, C), np.int32)
         q_lens = np.zeros((self.max_slots,), np.int32)
         active = np.zeros((self.max_slots,), bool)
-        prefill_tokens = 0
+        prefill_tokens = pages_walked = 0
         # slot -> draft packed into this attempt's chunk rows; LOCAL on
         # purpose: a failed dispatch retries through a fresh _step_attempt
         # that re-proposes, so no speculative state can ever go stale
@@ -2183,6 +2186,7 @@ class ContinuousBatchingEngine:
                         toks[i, 1 : 1 + k] = draft
                         q_lens[i] = 1 + k
                         drafts[i] = draft
+            pages_walked += -(-(cur + int(q_lens[i])) // self.block_size)
         # devprof sampling decision: one cached-bool read at rate 0 (the
         # stride counter only advances while the flag is on, and the stride
         # is deterministic — no RNG draw, seeded runs stay byte-identical)
@@ -2197,6 +2201,7 @@ class ContinuousBatchingEngine:
                 comm_ops = _devprof.end_comm_window()
         stats["steps"] += 1
         stats["prompt_tokens_computed"] += prefill_tokens
+        stats["paged_pages_walked"] += pages_walked
         if prefill_tokens:
             self._metrics["prefill_tokens"].inc(prefill_tokens)
         plan, launch, wait = self._phases_done

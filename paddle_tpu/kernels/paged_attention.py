@@ -1,22 +1,25 @@
-"""Pallas TPU paged-attention decode kernel.
+"""Pallas TPU paged-attention kernels.
 
-Replaces the dense-gather XLA path of
+Replace the dense-gather XLA path of
 ``incubate/nn/functional/block_attention.py`` (reference CUDA kernel:
-``paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu``) with a
-block-table-aware flash-decode kernel: each grid cell walks ONE sequence's
-logical blocks, the scalar-prefetched block table steers the BlockSpec index
-map so only that sequence's physical KV blocks are streamed HBM -> VMEM
-(never the dense ``[B, MBS*BS, H, D]`` gather), and an online softmax
-accumulates in fp32 VMEM scratch. Grouped-query attention keeps the G query
-heads of one KV head together as the kernel's row dimension.
+``paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu``) with
+block-table-aware flash kernels: only a sequence's own physical KV blocks are
+streamed HBM -> VMEM (never the dense ``[B, MBS*BS, H, D]`` gather), and an
+online softmax accumulates in fp32 VMEM scratch. Grouped-query attention keeps
+the G query heads of one KV head together as the kernel's row dimension.
+
+The chunk kernels (the engine's one step signature) walk a sequence's LIVE
+pages in a loop inside the kernel, bounded by its length (below). The decode
+kernels (``generate_paged``, ``block_multihead_attention*``) still spend one
+grid step a logical block, the scalar-prefetched block table steering the
+BlockSpec index map.
 
 Quantized KV (``FLAGS_kv_cache_dtype=int8``): every kernel accepts optional
 ``k_scale``/``v_scale`` planes (``[NB, HKV, BS]`` fp32 — per block, per head,
-per token slot, addressed by the SAME block ids the KV planes use), streamed
-through the identical block-table-steered index map. The dequant epilogue
-lives inside the block walk: int8 loads, one fp32 multiply per (BS, D) tile,
-fp32 accumulate — no dequantized copy of the cache ever materializes. The
-dequant composition (``x.astype(f32) * scale``) is the byte-for-byte op
+per token slot, addressed by the SAME block ids the KV planes use). The dequant
+epilogue lives inside the block walk: int8 loads, one fp32 multiply per page
+tile, fp32 accumulate — no dequantized copy of the cache ever materializes.
+The dequant composition (``x.astype(f32) * scale``) is the byte-for-byte op
 sequence the XLA gather fallback applies, keeping the two paths in lockstep.
 """
 
@@ -198,192 +201,6 @@ def paged_flash_decode(
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
     return out.reshape(b, hq, d)
 
-
-# ---------------------------------------------------------------------------
-# Ragged MIXED prefill/decode kernel (chunked prefill)
-# ---------------------------------------------------------------------------
-#
-# One grid cell serves every new token of one sequence at once: the row
-# dimension packs the chunk's C token positions x the G grouped query heads
-# of one KV head, so a decode row (1 valid token) and a prompt-chunk row
-# (up to C tokens) are the SAME kernel — the engine's single compiled
-# signature. Each packed row carries its own causal limit
-# (``seq_lens + j + 1`` for chunk token j), which is what makes the batch
-# ragged rather than rectangular ("Ragged Paged Attention", arxiv
-# 2604.15464).
-
-
-def _chunk_kernel(
-    tables_ref,  # scalar prefetch: [B, MBS] int32
-    lens_ref,  # scalar prefetch: [B] int32 tokens cached BEFORE the chunk
-    qlens_ref,  # scalar prefetch: [B] int32 valid new tokens (0 = skip row)
-    q_ref,  # [1, 1, C*G, D] chunk-major packed rows (row = j*G + g)
-    k_ref,  # [1, 1, BS, D] this logical block's physical KV (one head)
-    v_ref,
-    *rest,  # quantized: ks_ref, vs_ref [1, 1, BS] then outputs/scratch
-    scale: float,
-    block_size: int,
-    num_blocks: int,
-    group: int,
-    quantized: bool = False,
-):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
-    bi = pl.program_id(0)
-    i = pl.program_id(2)
-    rows = q_ref.shape[2]
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # ragged skip: the LAST position any of this sequence's rows may see is
-    # lens + q_lens - 1 (the chunk's final token attending to itself); blocks
-    # wholly past it are predicated away — a decode row costs the same blocks
-    # it did under the decode-only kernel, and an inactive slot (q_lens == 0)
-    # never takes this branch at all.
-    @pl.when(i * block_size < lens_ref[bi] + qlens_ref[bi])
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [C*G, D]
-        k, v = _dequant_tile(k_ref, v_ref, ks_ref, vs_ref)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [C*G, BS]
-        pos = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 1
-        )
-        # per-row causal limit: packed row r serves chunk token j = r // G at
-        # absolute position lens + j, so it may see pos <= lens + j
-        row_j = jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0) // group
-        valid = (pos < lens_ref[bi] + row_j + 1) & (row_j < qlens_ref[bi])
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[...]  # [C*G, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        # the explicit valid multiply keeps fully-masked rows at p == 0 (a
-        # row past q_lens has every position masked: exp(s - NEG_INF) would
-        # otherwise be 1 everywhere — silent garbage)
-        p = jnp.exp(s - m_new) * valid.astype(jnp.float32)  # [C*G, BS]
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
-
-    @pl.when(i == num_blocks - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        out = acc_ref[...] / denom  # [C*G, D]
-        # rows past q_lens emitted exact zeros (their l stayed 0 -> out is
-        # 0/1e-30 = 0 already via the masked p), but force it explicitly so
-        # the contract does not hinge on the epsilon
-        row_j = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group
-        out = jnp.where(row_j < qlens_ref[bi], out, 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def paged_flash_chunk(
-    q: jax.Array,  # [B, C, HQ, D] ragged chunk (row j valid iff j < q_lens)
-    key_cache: jax.Array,  # [NB, HKV, BS, D] chunk KV ALREADY appended
-    value_cache: jax.Array,
-    block_tables: jax.Array,  # [B, MBS] int32
-    seq_lens: jax.Array,  # [B] tokens cached BEFORE the chunk
-    q_lens: jax.Array,  # [B] valid new tokens (0 = inactive slot)
-    scale: Optional[float] = None,
-    interpret: bool = False,
-    k_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Flash attention for one mixed prefill/decode step over the paged
-    cache. Returns ``[B, C, HQ, D]`` with rows past ``q_lens`` exactly 0."""
-    b, c, hq, d = q.shape
-    nb, hkv, bs, _ = key_cache.shape
-    mbs = block_tables.shape[1]
-    if hq % hkv != 0:
-        raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
-    g = hq // hkv
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    # pack rows chunk-major per KV head: [B, C, HKV, G, D] -> [B, HKV, C*G, D]
-    qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, hkv, c * g, d)
-    quantized = k_scale is not None
-
-    grid = (b, hkv, mbs)
-    kernel = functools.partial(
-        _chunk_kernel, scale=float(scale), block_size=bs, num_blocks=mbs,
-        group=g, quantized=quantized,
-    )
-
-    def _kv_index(bi, hi, i, tables, lens, qlens):
-        # logical blocks past the LAST in-use block (which now includes the
-        # freshly appended chunk) clamp onto it: the pipeline sees the same
-        # physical index as the previous grid step and skips the HBM->VMEM
-        # copy, so ragged tails cost no DMA (the matching compute skip is the
-        # pl.when in the kernel)
-        last = jnp.maximum((lens[bi] + qlens[bi] + bs - 1) // bs - 1, 0)
-        return (tables[bi, jnp.minimum(i, last)], hi, 0, 0)
-
-    def _scale_index(bi, hi, i, tables, lens, qlens):
-        # the scale plane is addressed by the SAME physical block id
-        last = jnp.maximum((lens[bi] + qlens[bi] + bs - 1) // bs - 1, 0)
-        return (tables[bi, jnp.minimum(i, last)], hi, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, c * g, d),
-            lambda bi, hi, i, tables, lens, qlens: (bi, hi, 0, 0),
-        ),
-        pl.BlockSpec((1, 1, bs, d), _kv_index),
-        pl.BlockSpec((1, 1, bs, d), _kv_index),
-    ]
-    operands = [qg, key_cache, value_cache]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, bs, 1), _scale_index),
-            pl.BlockSpec((1, 1, bs, 1), _scale_index),
-        ]
-        operands += [k_scale[..., None], v_scale[..., None]]
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, c * g, d),
-                lambda bi, hi, i, tables, lens, qlens: (bi, hi, 0, 0),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((c * g, 1), jnp.float32),
-                pltpu.VMEM((c * g, 1), jnp.float32),
-                pltpu.VMEM((c * g, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, d), q.dtype),
-        # batch and kv-head cells are independent; the block walk accumulates
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-        name=KERNEL_CHUNK,
-    )(
-        block_tables.astype(jnp.int32),
-        seq_lens.astype(jnp.int32),
-        q_lens.astype(jnp.int32),
-        *operands,
-    )
-    # [B, HKV, C*G, D] -> [B, C, HQ, D]
-    return out.reshape(b, hkv, c, g, d).transpose(0, 2, 1, 3, 4).reshape(b, c, hq, d)
-
-
 # ---------------------------------------------------------------------------
 # Fused-epilogue variants: q-RoPE folded into the block walk
 # ---------------------------------------------------------------------------
@@ -556,80 +373,276 @@ def paged_flash_decode_fused(
     return out.reshape(b, hq, d)
 
 
-def _chunk_fused_kernel(
+# ---------------------------------------------------------------------------
+# Ragged MIXED prefill/decode kernel (chunked prefill): the length-bounded walk
+# ---------------------------------------------------------------------------
+#
+# One grid cell serves every new token of one sequence at once: the row
+# dimension packs the chunk's C token positions x the G grouped query heads
+# of one KV head, so a decode row (1 valid token) and a prompt-chunk row
+# (up to C tokens) are the SAME kernel — the engine's single compiled
+# signature. Each packed row carries its own causal limit
+# (``seq_lens + j + 1`` for chunk token j), which is what makes the batch
+# ragged rather than rectangular ("Ragged Paged Attention", arxiv
+# 2604.15464).
+#
+# The grid is slots x KV-head groups, NOT pages: the pool stays in HBM and the
+# cell walks its sequence's LIVE pages in a loop whose trip count is
+# ``ceil(ceil((seq_lens + q_lens) / BS) / P)`` tiles of P pages. A tile's
+# pages arrive by one async copy per page per plane (a page's heads are
+# contiguous in ``[NB, HKV, BS, D]``) into a double-buffered VMEM scratch, the
+# next tile's copies in flight under the current tile's compute. Page slots
+# of the last tile past the live bound re-read the last live page (their
+# positions are masked), so nothing outside the sequence's pages is touched.
+# q-RoPE (``rope``) and the int8 dequant (``quantized``) are static options
+# of the one body: the rotation is computed in q's dtype (exactly
+# ``_rope_apply_xla`` with tables cast to x.dtype) and only THEN cast fp32
+# and scaled, KV is roped before the cache append in both modes.
+
+_KV_VMEM_BUDGET = 4 << 20  # bytes of VMEM the double-buffered page tiles may take
+
+
+def _walk_geometry(hkv: int, bs: int, d: int, kv_dtype):
+    """(pages per tile, KV heads per cell), from shapes alone: a tile is 128
+    key positions (one lane-width of scores), and a cell takes as many of the
+    operand's KV heads as keep its page buffers (two slots x K and V) inside
+    the VMEM budget."""
+    pages = max(1, 128 // bs)
+    itemsize = jnp.dtype(kv_dtype).itemsize
+    sublanes = 32 // itemsize  # rows of one (sublane, 128-lane) tile of this dtype
+    per_head = 2 * 2 * pages * -(-bs // sublanes) * sublanes * d * itemsize
+    heads = max(h for h in range(1, hkv + 1) if hkv % h == 0 and (h == 1 or h * per_head <= _KV_VMEM_BUDGET))
+    return pages, heads
+
+
+def _scale_columns(buf, slot, head, bs):
+    """One head's per-token scales of a tile's pages as ``[P, BS, 1]`` columns
+    over D. A page's scales lie along lanes (``buf[slot, p]`` is ``[1, HKV*BS]``,
+    head-major); the rows spread over BS sublanes, masked to the head's diagonal
+    and summed along lanes are the same numbers down a column, exactly."""
+    pages, _, lanes = buf.shape[1:]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (pages, bs, lanes), 2)
+    diag = lane == head * bs + jax.lax.broadcasted_iota(jnp.int32, (pages, bs, lanes), 1)
+    rows = jnp.broadcast_to(buf[slot], (pages, bs, lanes))
+    return jnp.sum(jnp.where(diag, rows, 0.0), axis=-1, keepdims=True)
+
+
+def _walk_kernel(
     tables_ref,  # scalar prefetch: [B, MBS] int32
     lens_ref,  # scalar prefetch: [B] int32 tokens cached BEFORE the chunk
-    qlens_ref,  # scalar prefetch: [B] int32 valid new tokens
-    q_ref,  # [1, 1, C*G, D] chunk-major packed PRE-rope rows
-    cos_ref,  # [1, C, D] this slot's offset-gathered rope rows
-    sin_ref,
-    k_ref,
-    v_ref,
-    *rest,  # quantized: ks_ref, vs_ref [1, 1, BS] then outputs/scratch
+    qlens_ref,  # scalar prefetch: [B] int32 valid new tokens (0 = skip slot)
+    q_ref,  # [1, HG, C*G, D] chunk-major packed rows (row = j*G + g)
+    *rest,  # rope: cos, sin [1, C, D]; k, v pools in HBM; quantized: scale planes
     scale: float,
     block_size: int,
-    num_blocks: int,
+    pages: int,
     group: int,
-    quantized: bool = False,
+    rope: bool,
+    quantized: bool,
 ):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
-    bi = pl.program_id(0)
-    i = pl.program_id(2)
-    rows = q_ref.shape[2]
+    rest = list(rest)
+    cos_ref, sin_ref = (rest.pop(0), rest.pop(0)) if rope else (None, None)
+    pools = [rest.pop(0) for _ in range(4 if quantized else 2)]  # k, v[, ks, vs]
+    o_ref, qs_ref, m_ref, l_ref, acc_ref = rest[:5]
+    # one buffer per pool: [2, P, HG, BS, D] pages, [2, P, 1, HKV*BS] scale rows
+    bufs, sem = rest[5:-1], rest[-1]
+    bi, hj = pl.program_id(0), pl.program_id(1)
+    lens, qlens = lens_ref[bi], qlens_ref[bi]
+    _, hg, rows, d = q_ref.shape
+    tile = pages * block_size
+    # the LAST position any of this sequence's rows may see is lens + qlens - 1
+    # (the chunk's final token attending to itself): the walk ends with its
+    # page. An inactive slot (q_lens == 0) walks nothing at all.
+    n_pages = jnp.where(qlens > 0, (lens + qlens + block_size - 1) // block_size, 0)
+    n_tiles = (n_pages + pages - 1) // pages
 
-    @pl.when(i == 0)
-    def _init():
+    def copy_tile(t, slot, wait):
+        """Start (or wait for) tile ``t``'s copies into buffer ``slot``: one
+        per page per pool. The loops over pages here and over heads in the
+        walk are ``fori_loop``s unrolled at lowering, so that their bodies are
+        traced ONCE (Python loops cost the host seconds of tracing per step
+        program) and the scheduler still overlaps one head's work with the next's."""
+
+        def one_page(p, carry):
+            page = tables_ref[bi, jnp.minimum(t * pages + p, n_pages - 1)]
+            for pool, buf in zip(pools, bufs):
+                # a page's scales ride whole (one row holds every head's)
+                whole = pool.ndim == 3 or hg == pool.shape[1]
+                src = pool.at[page] if whole else pool.at[page, pl.ds(hj * hg, hg)]
+                cp = pltpu.make_async_copy(src, buf.at[slot, p], sem.at[slot])
+                cp.wait() if wait else cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, pages, one_page, None, unroll=True)
+
+    @pl.when(n_pages == 0)
+    def _skip():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_pages > 0)
+    def _attend():
+        copy_tile(0, 0, wait=False)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if rope:
+            # expand [C, D] rope rows to the packed [C*G, D] row layout (row =
+            # j*G + g shares token j's rotation across its G query heads),
+            # materialized BEFORE the arithmetic: the op order the XLA rope
+            # composition lowers to
+            c_dim = rows // group
+            cos = jnp.broadcast_to(cos_ref[0][:, None, :], (c_dim, group, d)).reshape(rows, d).astype(q_ref.dtype)
+            sin = jnp.broadcast_to(sin_ref[0][:, None, :], (c_dim, group, d)).reshape(rows, d).astype(q_ref.dtype)
+        for h in range(hg):  # a few [C*G, D] ops a head, once a cell: left unrolled
+            q = q_ref[0, h]
+            if rope:
+                q = _rope_rows(q, cos, sin, d // 2)  # in q.dtype
+            qs_ref[h] = q.astype(jnp.float32) * scale
 
-    @pl.when(i * block_size < lens_ref[bi] + qlens_ref[bi])
-    def _attend():
-        d = q_ref.shape[-1]
-        c_dim = rows // group
-        # expand [C, D] rope rows to the packed [C*G, D] row layout (row =
-        # j*G + g shares token j's rotation across its G query heads)
-        c = jnp.broadcast_to(
-            cos_ref[0][:, None, :], (c_dim, group, d)
-        ).reshape(rows, d).astype(q_ref.dtype)
-        s_t = jnp.broadcast_to(
-            sin_ref[0][:, None, :], (c_dim, group, d)
-        ).reshape(rows, d).astype(q_ref.dtype)
-        q = _rope_rows(q_ref[0, 0], c, s_t, d // 2)  # [C*G, D] in q.dtype
-        q = q.astype(jnp.float32) * scale
-        k, v = _dequant_tile(k_ref, v_ref, ks_ref, vs_ref)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        pos = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 1
-        )
-        row_j = jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0) // group
-        valid = (pos < lens_ref[bi] + row_j + 1) & (row_j < qlens_ref[bi])
-        s = jnp.where(valid, s, NEG_INF)
+        def walk(t, carry):
+            slot = t % 2
 
-        m_prev = m_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
+            @pl.when(t + 1 < n_tiles)
+            def _prefetch():
+                copy_tile(t + 1, 1 - slot, wait=False)
 
-    @pl.when(i == num_blocks - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        out = acc_ref[...] / denom
-        row_j = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group
-        out = jnp.where(row_j < qlens_ref[bi], out, 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+            copy_tile(t, slot, wait=True)
+            pos = t * tile + jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1)
+            # per-row causal limit: packed row r serves chunk token j = r // G at
+            # absolute position lens + j, so it may see pos <= lens + j
+            row_j = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 0) // group
+            valid = (pos < lens + row_j + 1) & (row_j < qlens)
+
+            def one_head(h, carry):
+                # the in-walk dequant: fp32 upcast, then (int8) one multiply per
+                # page against its per-token scale column — the op sequence of
+                # the XLA gather fallback
+                k = bufs[0][slot, :, h].astype(jnp.float32)  # [P, BS, D]
+                v = bufs[1][slot, :, h].astype(jnp.float32)
+                if quantized:
+                    k = k * _scale_columns(bufs[2], slot, hj * hg + h, block_size)
+                    v = v * _scale_columns(bufs[3], slot, hj * hg + h, block_size)
+                k, v = k.reshape(tile, d), v.reshape(tile, d)
+                s = jax.lax.dot_general(
+                    qs_ref[h], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )  # [C*G, P*BS]
+                s = jnp.where(valid, s, NEG_INF)
+                m_prev = m_ref[h]  # [C*G, 1]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # the explicit valid multiply keeps fully-masked rows at p == 0 (a
+                # row past q_lens has every position masked: exp(s - NEG_INF) would
+                # otherwise be 1 everywhere — silent garbage)
+                p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
+                l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                m_ref[h] = m_new
+                return carry
+
+            return jax.lax.fori_loop(0, hg, one_head, carry, unroll=True)
+
+        jax.lax.fori_loop(0, n_tiles, walk, None)
+        # rows past q_lens emitted exact zeros already (their l stayed 0 -> 0 /
+        # 1e-30), but force it so the contract does not hinge on the epsilon
+        live = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group < qlens
+
+        def finish(h, carry):
+            out = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, h] = jnp.where(live, out, 0.0).astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, hg, finish, None, unroll=True)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "name"))
+def _paged_chunk_call(q, cos, sin, key_cache, value_cache, block_tables, seq_lens,
+                      q_lens, scale, interpret, k_scale, v_scale, name):
+    """The one ``pallas_call`` of the chunk kernels; ``cos is None`` = q comes
+    roped, ``k_scale is None`` = a floating cache. Jitted so that a step's
+    layers share ONE trace and one lowering of the kernel."""
+    b, c, hq, d = q.shape
+    nb, hkv, bs, _ = key_cache.shape
+    if hq % hkv != 0:
+        raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
+    g = hq // hkv
+    if scale is None:
+        scale = 1.0 / (d**0.5)
+    if not interpret and (d % 128 or bs % 8):
+        # raised at trace time, where the dispatch site degrades to the XLA path
+        raise ValueError(
+            f"the page walk copies [heads, {bs}, {d}] pages out of HBM: head_dim must be "
+            "a multiple of 128 lanes and block_size of 8 sublanes"
+        )
+    rope, quantized = cos is not None, k_scale is not None
+    pages, hg = _walk_geometry(hkv, bs, d, key_cache.dtype)
+    # pack rows chunk-major per KV head: [B, C, HKV, G, D] -> [B, HKV, C*G, D]
+    qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, hkv, c * g, d)
+    q_spec = pl.BlockSpec((1, hg, c * g, d), lambda bi, hj, tables, lens, qlens: (bi, hj, 0, 0))
+    in_specs, operands = [q_spec], [qg]
+    if rope:
+        in_specs += [pl.BlockSpec((1, c, d), lambda bi, hj, tables, lens, qlens: (bi, 0, 0))] * 2
+        operands += [cos, sin]
+    # the pool (and the scale planes) stay in HBM: the walk copies live pages
+    # only. A copy out of HBM wants a minor dimension of whole 128-lane tiles, so
+    # a page's scales ride as ONE row [1, HKV*BS], padded to such a multiple
+    pools = [key_cache, value_cache]
+    if quantized:
+        pad = -(hkv * bs) % 128
+        pools += [jnp.pad(sc.reshape(nb, 1, hkv * bs), ((0, 0), (0, 0), (0, pad))) for sc in (k_scale, v_scale)]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+    out = pl.pallas_call(
+        functools.partial(
+            _walk_kernel, scale=float(scale), block_size=bs, pages=pages, group=g,
+            rope=rope, quantized=quantized,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, hkv // hg),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((hg, c * g, d), jnp.float32),  # roped, scaled q
+                pltpu.VMEM((hg, c * g, 1), jnp.float32),  # m
+                pltpu.VMEM((hg, c * g, 1), jnp.float32),  # l
+                pltpu.VMEM((hg, c * g, d), jnp.float32),  # acc
+                *[pltpu.VMEM((2, pages, hg) + p.shape[2:], p.dtype) for p in pools[:2]],
+                *[pltpu.VMEM((2, pages) + p.shape[1:], p.dtype) for p in pools[2:]],
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, d), q.dtype),
+        # slot and head-group cells are independent; the page walk is inside
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name=name,
+    )(
+        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+        q_lens.astype(jnp.int32), *operands, *pools,
+    )
+    # [B, HKV, C*G, D] -> [B, C, HQ, D]
+    return out.reshape(b, hkv, c, g, d).transpose(0, 2, 1, 3, 4).reshape(b, c, hq, d)
+
+
+def paged_flash_chunk(
+    q: jax.Array,  # [B, C, HQ, D] ragged chunk (row j valid iff j < q_lens)
+    key_cache: jax.Array,  # [NB, HKV, BS, D] chunk KV ALREADY appended
+    value_cache: jax.Array,
+    block_tables: jax.Array,  # [B, MBS] int32
+    seq_lens: jax.Array,  # [B] tokens cached BEFORE the chunk
+    q_lens: jax.Array,  # [B] valid new tokens (0 = inactive slot)
+    scale: Optional[float] = None,
+    interpret: bool = False,
+    k_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
+    v_scale: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Flash attention for one mixed prefill/decode step over the paged
+    cache. Returns ``[B, C, HQ, D]`` with rows past ``q_lens`` exactly 0."""
+    return _paged_chunk_call(
+        q, None, None, key_cache, value_cache, block_tables, seq_lens, q_lens,
+        scale, interpret, k_scale, v_scale, KERNEL_CHUNK,
+    )
 
 
 def paged_flash_chunk_fused(
@@ -646,80 +659,9 @@ def paged_flash_chunk_fused(
     k_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """:func:`paged_flash_chunk` with q-RoPE folded into the block walk —
+    """:func:`paged_flash_chunk` with q-RoPE folded into the page walk —
     the decode layer's rope pass + attention collapse to ONE dispatch."""
-    b, c, hq, d = q.shape
-    nb, hkv, bs, _ = key_cache.shape
-    mbs = block_tables.shape[1]
-    if hq % hkv != 0:
-        raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
-    g = hq // hkv
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, hkv, c * g, d)
-    quantized = k_scale is not None
-
-    kernel = functools.partial(
-        _chunk_fused_kernel, scale=float(scale), block_size=bs, num_blocks=mbs,
-        group=g, quantized=quantized,
+    return _paged_chunk_call(
+        q, cos, sin, key_cache, value_cache, block_tables, seq_lens, q_lens,
+        scale, interpret, k_scale, v_scale, KERNEL_CHUNK_FUSED,
     )
-
-    def _kv_index(bi, hi, i, tables, lens, qlens):
-        last = jnp.maximum((lens[bi] + qlens[bi] + bs - 1) // bs - 1, 0)
-        return (tables[bi, jnp.minimum(i, last)], hi, 0, 0)
-
-    def _scale_index(bi, hi, i, tables, lens, qlens):
-        last = jnp.maximum((lens[bi] + qlens[bi] + bs - 1) // bs - 1, 0)
-        return (tables[bi, jnp.minimum(i, last)], hi, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, c * g, d),
-            lambda bi, hi, i, tables, lens, qlens: (bi, hi, 0, 0),
-        ),
-        pl.BlockSpec(
-            (1, c, d), lambda bi, hi, i, tables, lens, qlens: (bi, 0, 0)
-        ),
-        pl.BlockSpec(
-            (1, c, d), lambda bi, hi, i, tables, lens, qlens: (bi, 0, 0)
-        ),
-        pl.BlockSpec((1, 1, bs, d), _kv_index),
-        pl.BlockSpec((1, 1, bs, d), _kv_index),
-    ]
-    operands = [qg, cos, sin, key_cache, value_cache]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, bs, 1), _scale_index),
-            pl.BlockSpec((1, 1, bs, 1), _scale_index),
-        ]
-        operands += [k_scale[..., None], v_scale[..., None]]
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(b, hkv, mbs),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, c * g, d),
-                lambda bi, hi, i, tables, lens, qlens: (bi, hi, 0, 0),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((c * g, 1), jnp.float32),
-                pltpu.VMEM((c * g, 1), jnp.float32),
-                pltpu.VMEM((c * g, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-        name=KERNEL_CHUNK_FUSED,
-    )(
-        block_tables.astype(jnp.int32),
-        seq_lens.astype(jnp.int32),
-        q_lens.astype(jnp.int32),
-        *operands,
-    )
-    return out.reshape(b, hkv, c, g, d).transpose(0, 2, 1, 3, 4).reshape(b, c, hq, d)

@@ -430,3 +430,26 @@ def test_engine_smoke():
     assert all(len(r.generated) == 3 for r in out.values())
     assert eng.stats["step_traces"] == 1
     _assert_drained(eng)
+
+
+def test_paged_pages_walked_counts_the_live_pages_of_each_step():
+    """``stats["paged_pages_walked"]``: every step adds, over its active slots,
+    ceil((tokens cached + new tokens) / block_size) — what the paged kernel's
+    length-bounded walk visits, against slots x max_blocks_per_seq a step."""
+    m, cfg = _model(seed=11)
+    rng = np.random.default_rng(11)
+    eng = ContinuousBatchingEngine(m, max_slots=3, block_size=4, prompt_bucket=16)
+    for n, new in ((3, 4), (9, 2), (16, 3), (5, 5)):
+        eng.add_request(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new_tokens=new)
+    expected = 0
+    real = eng._dispatch
+
+    def spy(toks, q_lens, active):
+        nonlocal expected
+        expected += sum(-(-(int(eng._ntok[i]) + int(q_lens[i])) // 4) for i in range(3) if active[i])
+        return real(toks, q_lens, active)
+
+    eng._dispatch = spy
+    eng.run()
+    steps = eng.stats["steps"]
+    assert 0 < expected == eng.stats["paged_pages_walked"] <= steps * 3 * eng.max_blocks_per_seq

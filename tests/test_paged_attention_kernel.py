@@ -1,6 +1,8 @@
 """Pallas paged flash-decode kernel: interpret-mode numerics parity with the
 XLA gather path, ragged lengths, GQA, and static TPU (Mosaic) lowering."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -294,3 +296,162 @@ class TestPagedFlashChunk:
             return paged_flash_chunk(q, kc, vc, tables, lens, q_lens)
 
         jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
+
+
+# -- the length-bounded page walk (one body: plain / rope-fused x float / int8) --
+
+from paddle_tpu.incubate.nn.functional import _rope_apply_xla  # noqa: E402
+from paddle_tpu.incubate.nn.functional.block_attention import _gather_chunk_attend  # noqa: E402
+from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused  # noqa: E402
+
+W_B, W_C, W_D, W_MBS, W_NB = 4, 4, 64, 17, 80  # max_model_len = 17 pages = 272
+W_TILE = 128  # key positions of one tile of the walk: 8 pages of 16
+W_FULL = W_MBS * BS
+# live length = lens + q_lens; every case mixes q_lens of 0, 1 and C
+WALK_CASES = {
+    # live 0 (inactive), 1, block_size, block_size + 1
+    "short_decode": ([0, 0, BS - 1, BS], [0, 1, 1, 1]),
+    # live C from an empty cache, block_size and block_size + 1 ending a chunk, inactive with a history
+    "short_chunk": ([0, BS - W_C, BS - W_C + 1, 5], [W_C, W_C, W_C, 0]),
+    # one tile of pages exactly, one position more, one page more exactly, and one position past that
+    "tile_edges_decode": ([W_TILE - 1, W_TILE, W_TILE + BS - 1, W_TILE + BS], [1, 1, 1, 1]),
+    "tile_edges_chunk": ([W_TILE - W_C, W_TILE - W_C + 1, W_TILE + BS - W_C, 3], [W_C, W_C, W_C, 1]),
+    # the full max_model_len beside length-0 slots
+    "full_beside_empty": ([W_FULL - W_C, 0, W_FULL - 1, 0], [W_C, 0, 1, 0]),
+}
+WALK_TOL = {"f32": 2e-5, "bf16": 2e-2, "int8": 2e-5}
+
+
+def _walk_setup(group, kv, seed=0):
+    rng = np.random.default_rng(seed)
+    hkv = 2
+    hq = hkv * group
+    qdt = jnp.bfloat16 if kv == "bf16" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(W_B, W_C, hq, W_D)), qdt)
+    ang = rng.uniform(0, 6.28, size=(W_B, W_C, 1, W_D // 2))
+    cos = jnp.asarray(np.concatenate([np.cos(ang), np.cos(ang)], -1), jnp.float32)
+    sin = jnp.asarray(np.concatenate([np.sin(ang), np.sin(ang)], -1), jnp.float32)
+    shape = (W_NB, hkv, BS, W_D)
+    if kv == "int8":
+        kc = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        vc = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        scales = dict(
+            k_scale=jnp.asarray(rng.uniform(0.005, 0.02, shape[:3]), jnp.float32),
+            v_scale=jnp.asarray(rng.uniform(0.005, 0.02, shape[:3]), jnp.float32),
+        )
+    else:
+        kc, vc, scales = jnp.asarray(rng.normal(size=shape), qdt), jnp.asarray(rng.normal(size=shape), qdt), {}
+    tables = jnp.asarray(rng.permutation(W_NB)[: W_B * W_MBS].reshape(W_B, W_MBS), jnp.int32)
+    return q, cos, sin, kc, vc, scales, tables
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_fns(fused, interpret=True):
+    """(kernel, XLA gather reference) over the same arguments, jitted once a variant."""
+    scale = 1.0 / np.sqrt(W_D)
+
+    def kernel(q, cos, sin, kc, vc, scales, tables, lens, q_lens):
+        if fused:
+            return paged_flash_chunk_fused(
+                q, cos[:, :, 0], sin[:, :, 0], kc, vc, tables, lens, q_lens, scale=scale, interpret=interpret, **scales
+            )
+        q = _rope_apply_xla(q, sin, cos, True)
+        return paged_flash_chunk(q, kc, vc, tables, lens, q_lens, scale=scale, interpret=interpret, **scales)
+
+    def reference(q, cos, sin, kc, vc, scales, tables, lens, q_lens):
+        q = _rope_apply_xla(q, sin, cos, True)
+        return _gather_chunk_attend(q, kc, vc, tables, lens, q_lens, scale, **scales)
+
+    return jax.jit(kernel), jax.jit(reference)
+
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+@pytest.mark.parametrize("kv", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("fused", [False, True], ids=["plain", "fused"])
+@pytest.mark.parametrize("group", [4, 1], ids=["gqa4", "mha"])
+def test_page_walk_matches_xla_gather(group, fused, kv, case):
+    args = _walk_setup(group, kv, seed=len(case))
+    lens, q_lens = (jnp.asarray(x, jnp.int32) for x in WALK_CASES[case])
+    kernel, reference = _walk_fns(fused)
+    out = np.asarray(kernel(*args, lens, q_lens), np.float32)
+    ref = np.asarray(reference(*args, lens, q_lens), np.float32)
+    np.testing.assert_allclose(out, ref, rtol=WALK_TOL[kv], atol=WALK_TOL[kv])
+    # rows past q_lens (and whole inactive slots) are exact zeros
+    dead = np.arange(W_C)[None, :] >= np.asarray(q_lens)[:, None]
+    assert (out[dead] == 0.0).all()
+    assert np.abs(out[~dead]).sum() > 0
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+@pytest.mark.parametrize("fused", [False, True], ids=["plain", "fused"])
+def test_page_walk_never_reads_past_the_live_bound(fused, kv, case):
+    """Every pool page that no live position uses holds NaN (int8: NaN scales),
+    and the tables' tail entries point at such pages: the bounded loop and the
+    prefetch of the next tile must not run one page too far."""
+    q, cos, sin, kc, vc, scales, tables = _walk_setup(4, kv, seed=3)
+    lens, q_lens = (np.asarray(x, np.int32) for x in WALK_CASES[case])
+    live = np.zeros(W_NB, bool)
+    for b in range(W_B):
+        if q_lens[b]:
+            live[np.asarray(tables)[b, : -(-(lens[b] + q_lens[b]) // BS)]] = True
+    assert not live.all()
+
+    def poison(x):
+        x = np.array(x)
+        x[~live] = np.nan
+        return jnp.asarray(x)
+
+    if kv == "int8":
+        bad = (q, cos, sin, kc, vc, {k: poison(v) for k, v in scales.items()}, tables)
+    else:
+        bad = (q, cos, sin, poison(kc), poison(vc), scales, tables)
+    kernel, reference = _walk_fns(fused)
+    out = np.asarray(kernel(*bad, jnp.asarray(lens), jnp.asarray(q_lens)))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, np.asarray(kernel(q, cos, sin, kc, vc, scales, tables, lens, q_lens)))
+    ref = np.asarray(reference(q, cos, sin, kc, vc, scales, tables, lens, q_lens))
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_page_walk_under_the_tpu_interpreter(kv):
+    """The same walk under ``pltpu.InterpretParams()``, which simulates the
+    DMAs and their semaphores: every copy started is waited for."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    args = _walk_setup(4, kv, seed=5)
+    lens, q_lens = (jnp.asarray(x, jnp.int32) for x in WALK_CASES["tile_edges_chunk"])
+    kernel, reference = _walk_fns(True, pltpu.InterpretParams())
+    np.testing.assert_allclose(
+        np.asarray(kernel(*args, lens, q_lens)), np.asarray(reference(*args, lens, q_lens)), rtol=2e-5, atol=2e-5
+    )
+
+
+def test_walk_geometry_follows_the_operand():
+    """Pages a tile and heads a cell come from shapes alone: the tp=4 shard's 2
+    KV heads ride whole, 32 MHA heads of bf16 fill the budget exactly, and
+    float32 pages of the same count split in two."""
+    from paddle_tpu.kernels.paged_attention import _walk_geometry
+
+    assert _walk_geometry(8, 16, 128, jnp.bfloat16) == (8, 8)
+    assert _walk_geometry(2, 16, 128, jnp.int8) == (8, 2)
+    assert _walk_geometry(32, 16, 128, jnp.bfloat16) == (8, 32)
+    assert _walk_geometry(32, 16, 128, jnp.float32) == (8, 16)
+    assert _walk_geometry(8, 256, 128, jnp.bfloat16) == (1, 8)
+
+
+@pytest.mark.parametrize("d, bs", [(64, 16), (128, 4)], ids=["head_dim_64", "block_4"])
+def test_page_walk_refuses_pages_it_cannot_copy_at_trace_time(d, bs):
+    """A copy out of HBM wants 128-lane rows and whole sublane tiles: such a
+    geometry raises while the kernel is traced for the chip, where the dispatch
+    site's try/except still degrades to the XLA path (interpret mode, which
+    copies nothing, takes any geometry)."""
+    q = jnp.zeros((2, 4, 4, d), jnp.bfloat16)
+    kc = jnp.zeros((8, 2, bs, d), jnp.bfloat16)
+    tables = jnp.zeros((2, 4), jnp.int32)
+    lens = jnp.ones((2,), jnp.int32)
+    with pytest.raises(ValueError, match="multiple of 128 lanes"):
+        jax.eval_shape(lambda *a: paged_flash_chunk(*a), q, kc, kc, tables, lens, lens)
+    jax.eval_shape(lambda *a: paged_flash_chunk(*a, interpret=True), q, kc, kc, tables, lens, lens)

@@ -71,8 +71,6 @@ def _grad_all(fn, n_float):
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 _QKV = ((1, SEQ, HEADS, HEAD_DIM), BF16)
-_KV_POOL = (NB, HEADS, BS, HEAD_DIM)
-_PAGED_TAIL = (((SLOTS, MBS), I32), ((SLOTS,), I32), ((SLOTS,), I32))
 
 
 def _flash_attention():
@@ -82,37 +80,29 @@ def _flash_attention():
     return fn, (_QKV, _QKV, _QKV)
 
 
-def _paged_chunk(kv_dtype):
-    from paddle_tpu.kernels.paged_attention import paged_flash_chunk
-
-    q = ((SLOTS, CHUNK, HEADS, HEAD_DIM), BF16)
-    pool = (_KV_POOL, kv_dtype)
-    if kv_dtype == I8:
-        sc = ((NB, HEADS, BS), F32)
-        return (
-            lambda q, kc, vc, ks, vs, t, l, ql: paged_flash_chunk(
-                q, kc, vc, t, l, ql, k_scale=ks, v_scale=vs
-            ),
-            (q, pool, pool, sc, sc, *_PAGED_TAIL),
-        )
-    return paged_flash_chunk, (q, pool, pool, *_PAGED_TAIL)
+# the chat cell's engine step (benchmarks/workloads/mistral7b.serve_chat.json:
+# Mistral-7B GQA, 16 slots, 4096-block pool, 256 blocks a sequence); its tp=4
+# shard holds 2 of the 8 KV heads
+_CHAT = dict(slots=16, hq=32, hkv=8, nb=4096, mbs=256)
+_CHAT_TP4 = dict(slots=16, hq=8, hkv=2, nb=4096, mbs=256)
 
 
-def _paged_chunk_fused(kv_dtype):
-    from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused
+def _paged_chunk(kv_dtype, fused=False, slots=SLOTS, hq=HEADS, hkv=HEADS, nb=NB, mbs=MBS):
+    from paddle_tpu.kernels.paged_attention import paged_flash_chunk, paged_flash_chunk_fused
 
-    q = ((SLOTS, CHUNK, HEADS, HEAD_DIM), BF16)
-    cs = ((SLOTS, CHUNK, HEAD_DIM), BF16)
-    pool = (_KV_POOL, kv_dtype)
-    if kv_dtype == I8:
-        sc = ((NB, HEADS, BS), F32)
-        return (
-            lambda q, c, s, kc, vc, ks, vs, t, l, ql: paged_flash_chunk_fused(
-                q, c, s, kc, vc, t, l, ql, k_scale=ks, v_scale=vs
-            ),
-            (q, cs, cs, pool, pool, sc, sc, *_PAGED_TAIL),
-        )
-    return paged_flash_chunk_fused, (q, cs, cs, pool, pool, *_PAGED_TAIL)
+    q = ((slots, CHUNK, hq, HEAD_DIM), BF16)
+    rope = (((slots, CHUNK, HEAD_DIM), BF16),) * 2 if fused else ()
+    pool = ((nb, hkv, BS, HEAD_DIM), kv_dtype)
+    scales = (((nb, hkv, BS), F32),) * 2 if kv_dtype == I8 else ()
+    tail = (((slots, mbs), I32), ((slots,), I32), ((slots,), I32))
+    kernel = paged_flash_chunk_fused if fused else paged_flash_chunk
+
+    def fn(q, *rest):
+        n = len(rope) + 2  # the rope rows and the two pools, then the scale planes
+        sc = dict(zip(("k_scale", "v_scale"), rest[n : n + len(scales)]))
+        return kernel(q, *rest[:n], *rest[n + len(scales) :], **sc)
+
+    return fn, (q, *rope, pool, pool, *scales, *tail)
 
 
 def _rms_norm():
@@ -188,8 +178,14 @@ CASES = {
     "flash_attention_fwd_bwd_s2048": _flash_attention,
     "paged_flash_chunk_bf16": lambda: _paged_chunk(BF16),
     "paged_flash_chunk_int8": lambda: _paged_chunk(I8),
-    "paged_flash_chunk_fused_bf16": lambda: _paged_chunk_fused(BF16),
-    "paged_flash_chunk_fused_int8": lambda: _paged_chunk_fused(I8),
+    "paged_flash_chunk_fused_bf16": lambda: _paged_chunk(BF16, fused=True),
+    "paged_flash_chunk_fused_int8": lambda: _paged_chunk(I8, fused=True),
+    "paged_flash_chunk_chat_bf16": lambda: _paged_chunk(BF16, **_CHAT),
+    "paged_flash_chunk_fused_chat_bf16": lambda: _paged_chunk(BF16, fused=True, **_CHAT),
+    "paged_flash_chunk_fused_chat_int8": lambda: _paged_chunk(I8, fused=True, **_CHAT),
+    "paged_flash_chunk_fused_chat_scratch_pool": lambda: _paged_chunk(BF16, fused=True, **{**_CHAT, "nb": 256}),
+    "paged_flash_chunk_fused_chat_tp4_shard_bf16": lambda: _paged_chunk(BF16, fused=True, **_CHAT_TP4),
+    "paged_flash_chunk_fused_chat_tp4_shard_int8": lambda: _paged_chunk(I8, fused=True, **_CHAT_TP4),
     "fused_rms_norm_fwd_bwd": _rms_norm,
     "fused_rope_and_adjoint": _rope,
     "fused_rms_norm_residual_train": lambda: _rms_norm_residual((1, SEQ, HIDDEN)),
