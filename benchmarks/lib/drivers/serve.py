@@ -35,14 +35,13 @@ from __future__ import annotations
 
 import collections
 import gc
-import importlib
 import math
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
 import numpy as np
 
-from .. import program, stats, traffic
+from .. import arch, program, stats, traffic
 from .. import weights as W
 from ..spans import GcPauses, Spans
 from ..tracing import TraceSlice
@@ -243,35 +242,29 @@ def probe_step_logits(engine: Any, requests: List[traffic.Request], seed: int, n
     return out
 
 
-def reference_logits(ctx: Any, cfg: Dict[str, Any], depth: int, token_seqs: List[np.ndarray],
+def reference_logits(ctx: Any, cfg: Dict[str, Any], token_seqs: List[np.ndarray],
                      buckets: List[int]) -> Iterator[Any]:
     """The reference's logits ``[len(seq), V]`` of each sequence, one after the
-    other; the layers are walked once for all of them, one layer's float32
-    weights at a time. Sequence ``i`` is padded to a multiple of
-    ``buckets[i]`` (causal, so the padding changes no row that is read)."""
+    other, by the walk of the configuration's reference file
+    (``sequence_logits``), which is handed the top's leaves as they are served
+    (rounded once to the cell's dtype; it upcasts them) and the float32 leaves
+    of one layer at a time, made again from the seed. Sequence ``i`` is padded
+    to a multiple of ``buckets[i]`` (causal, so the padding changes no row that
+    is read)."""
     import jax
     import jax.numpy as jnp
 
-    ref = importlib.import_module(f"reference.{cfg['reference']}")
     dtype = ctx.cell["dtype"]
-    layer_fn = jax.jit(ref.decoder_layer, static_argnums=(2,))
-    head = jax.jit(ref.head_logits, static_argnums=(2,))
-    cfg_static = _Frozen(cfg)
-    top = W.top_weights(ctx.seed, cfg, dtype)
-    hidden = []
-    for toks, bucket in zip(token_seqs, buckets):
-        padded = np.zeros(-(-len(toks) // bucket) * bucket, np.int32)
-        padded[: len(toks)] = toks
-        hidden.append(ref.embed(jnp.asarray(padded), top["embed"]))
-    for i in range(depth):
-        w = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), W.layer_weights(ctx.seed, cfg, i, dtype))
-        hidden = [layer_fn(h, w, cfg_static) for h in hidden]
-        del w
-    for toks, h in zip(token_seqs, hidden):
-        yield head(h, top, cfg_static)[: len(toks)]
+    f32 = lambda t: jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), t)  # noqa: E731
+    padded = [np.pad(np.asarray(toks, np.int32), (0, -len(toks) % bucket)) for toks, bucket in zip(token_seqs, buckets)]
+    logits = arch.reference(cfg).sequence_logits(
+        padded, W.top_weights(ctx.seed, cfg, dtype),
+        lambda i: f32(W.layer_weights(ctx.seed, cfg, i, dtype)), cfg)
+    for toks, rows in zip(token_seqs, logits):
+        yield rows[: len(toks)]
 
 
-def compare(ctx: Any, cfg: Dict[str, Any], depth: int, sample: List[Record], probes: List[Any]) -> Dict[str, Any]:
+def compare(ctx: Any, cfg: Dict[str, Any], sample: List[Record], probes: List[Any]) -> Dict[str, Any]:
     """Served tokens and step logits against the reference (one pass over
     both): how far below the reference's best logit each served token lies,
     and the root-mean-square gap between the engine's step logits and the
@@ -280,7 +273,7 @@ def compare(ctx: Any, cfg: Dict[str, Any], depth: int, sample: List[Record], pro
 
     seqs = [np.concatenate([r.request.prompt, np.asarray(r.tokens, np.int32)]) for r in sample]
     chunks = [ids for ids, _got in probes]
-    logits = reference_logits(ctx, cfg, depth, seqs + chunks, [256] * len(seqs) + [len(c) for c in chunks])
+    logits = reference_logits(ctx, cfg, seqs + chunks, [256] * len(seqs) + [len(c) for c in chunks])
     served = []
     for rec in sample:
         n_prompt = len(rec.request.prompt)
@@ -312,15 +305,8 @@ def probe_only(ctx: Any) -> Dict[str, Any]:
     obj.clear()
     gc.collect()
     jax.clear_caches()
-    got = compare(ctx, cfg, cfg["num_hidden_layers"], [], probes)
+    got = compare(ctx, cfg, [], probes)
     return {k: got[k] for k in ("step_logit_rel_rms", "step_logit_gap_max", "probe_rows")}
-
-
-class _Frozen(dict):
-    """A configuration that jit can take as a static argument."""
-
-    def __hash__(self) -> int:  # type: ignore[override]
-        return hash(tuple(sorted((k, v) for k, v in self.items() if isinstance(v, (int, float, str)))))
 
 
 def run(ctx: Any) -> Dict[str, Any]:
@@ -384,7 +370,7 @@ def run(ctx: Any) -> Dict[str, Any]:
     gc.collect()  # the program's jit closures sit in reference cycles
     jax.clear_caches()
     t_ref = time.perf_counter()
-    got = compare(ctx, cfg, depth, sample, probes)
+    got = compare(ctx, cfg, sample, probes)
     ctx.log("reference", seconds=time.perf_counter() - t_ref, requests=len(sample), tokens=got["tokens"],
             probe_rows=got["probe_rows"], step_logit_gap_max=got["step_logit_gap_max"])
     widest = max((float(g.max()) for g in got["served"]), default=float("inf"))

@@ -15,7 +15,6 @@ change (see ``compare``).
 from __future__ import annotations
 
 import gc
-import importlib
 import math
 import statistics
 import time
@@ -23,7 +22,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from .. import program, traffic
+from .. import arch, program, traffic
 from .. import weights as W
 from ..spans import GcPauses, Spans
 from ..tracing import TraceSlice
@@ -39,7 +38,7 @@ def _leaf_norms(tree: Any) -> Any:
 
 
 def _norms_by_leaf(named: Dict[str, Any], cfg: Dict[str, Any], depth: int) -> Dict[str, float]:
-    """``{"L0.wq": norm, ..., "top.head": norm}`` from arrays keyed by the
+    """``{"L0.<leaf>": norm, ..., "top.<leaf>": norm}`` from arrays keyed by the
     program's parameter paths."""
     import jax
 
@@ -91,7 +90,7 @@ def reference_steps(ctx: Any, cfg: Dict[str, Any], depth: int, stream: traffic.B
     import jax
     import jax.numpy as jnp
 
-    ref = importlib.import_module(f"reference.{cfg['reference']}")
+    ref = arch.reference(cfg)
     dtype = ctx.cell["dtype"]
     f32 = lambda t: jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), t)  # noqa: E731
     params = {"top": f32(W.top_weights(ctx.seed, cfg, dtype)),

@@ -8,6 +8,7 @@ from __future__ import annotations
 import importlib
 from typing import Any, Dict, List
 
+from . import arch
 from . import weights as W
 
 
@@ -42,12 +43,19 @@ def build_model(cfg: Dict[str, Any], seed: int, dtype: str) -> Any:
 
 def param_names(cfg: Dict[str, Any], depth: int) -> Dict[str, Any]:
     """Program parameter name of every benchmark leaf: ``{"top": {leaf: name},
-    "layers": [{leaf: name}, ...]}``."""
+    "layers": [{leaf: name}, ...]}``. The leaves are the reference file's
+    table; their names come from the configuration file's ``program.params``,
+    where every key beside ``layer_prefix`` and ``layer`` names a top leaf and
+    ``layer`` is one map over whatever leaves layer ``i`` has."""
     names = cfg["program"]["params"]
+    ref = arch.reference(cfg)
+    top, table = set(names) - {"layer_prefix", "layer"}, set(ref.top_leaves(cfg))
+    if top != table:
+        raise KeyError(f"program.params names the top leaves {sorted(top)}, the reference has {sorted(table)}")
     return {
-        "top": {k: names[k] for k in ("embed", "final_norm", "head")},
+        "top": {leaf: names[leaf] for leaf in top},
         "layers": [
-            {leaf: names["layer_prefix"].format(i=i) + suffix for leaf, suffix in names["layer"].items()}
+            {leaf: names["layer_prefix"].format(i=i) + names["layer"][leaf] for leaf in ref.layer_leaves(cfg, i)}
             for i in range(depth)
         ],
     }

@@ -19,7 +19,7 @@ def read(run):
         return None
     cfg = run["cfg"]
     nh = cfg["num_attention_heads"]
-    bhsd = f"[{run['batch']},{nh},{run['seq']},{cfg['hidden_size'] // nh}]"
+    bhsd = f"[{run['batch']},{nh},{run['seq']},{flops.head_dim(cfg)}]"
     lse = f"f32[{run['batch']},{nh},{run['seq']},1]"
     fwd, dq, dkv = [], [], []
     for name, a, b in xplane.pallas_events(run["trace"]["raw"]):

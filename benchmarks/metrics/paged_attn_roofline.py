@@ -1,6 +1,6 @@
 """The paged chunk kernel's share of its roofline over the traced slice: the
 KV bytes the live lengths need (every decoding slot's keys and values once per
-layer per step, ``lib/flops.py``) at the HBM peak, over the device time of the
+attention call per step, ``lib/flops.py``) at the HBM peak, over the device time of the
 kernel's events. Memory bound by construction at decode.
 
 A Pallas kernel shows in the trace as a ``custom-call`` with the target
@@ -18,7 +18,7 @@ def read(run):
     if not run.get("trace") or run["driver"] != "serve" or not run.get("traced_pumps"):
         return None
     cfg, c = run["cfg"], run["counters"]
-    hd = cfg["hidden_size"] // cfg["num_attention_heads"]
+    hd, passes = flops.head_dim(cfg), flops.attention_passes(cfg, run["depth"])
     tables = f"s32[{c['max_slots']},{c['max_blocks_per_seq']}]"
     pool = f"[{c['num_blocks']},{cfg['num_key_value_heads']},{c['block_size']},{hd}]"
     seconds = 0.0
@@ -28,5 +28,5 @@ def read(run):
             seconds += b - a
     if not seconds:
         return None
-    need = sum(run["depth"] * flops.paged_attention_bytes(cfg, pump[2]) for pump in run["traced_pumps"])
+    need = sum(passes * flops.paged_attention_bytes(cfg, pump[2]) for pump in run["traced_pumps"])
     return 100.0 * (need / run["peaks"]["hbm_bytes_per_s"]) / seconds

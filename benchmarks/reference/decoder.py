@@ -10,6 +10,12 @@ dictionaries of arrays in the layout ``[in, out]``:
     layer: wq wk wv wo w_gate w_up w_down norm_attn norm_mlp
     top:   embed [V, H], final_norm [H], head [H, V]
 
+It is also what tells the harness the shape of this block (``lib/arch.py``;
+``benchmarks/README.md`` has the list): the leaf table (``top_leaves``,
+``layer_leaves``), the walk (``sequence_logits`` for serving,
+``batch_loss_and_grads`` and ``adamw_update`` for training) and the counts
+(``head_dim``, ``matmul_params``, ``attention_passes``).
+
 ``lower`` names a deliberately lower matmul precision, used only by the
 controls that must come out as not correct: ``"int8"`` rounds both operands
 of every matmul to 8-bit integers (per-row / per-column absmax scales),
@@ -19,12 +25,49 @@ of every matmul to 8-bit integers (per-row / per-column absmax scales),
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
 PRECISION = "highest"
+
+
+def head_dim(cfg: Dict[str, Any]) -> int:
+    """The file's ``head_dim`` where it gives one, else what the family does
+    (listed under the file's ``assumed``)."""
+    return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
+
+
+def top_leaves(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``{leaf: (shape, init)}`` of the leaves outside the layers; ``init`` as
+    ``lib/weights.py`` lists them."""
+    h, v = cfg["hidden_size"], cfg["vocab_size"]
+    return {"embed": ((v, h), "normal"), "final_norm": ((h,), "ones"), "head": ((h, v), "normal")}
+
+
+def layer_leaves(cfg: Dict[str, Any], index: int) -> Dict[str, Any]:
+    """The leaves of layer ``index``: here every layer has the same."""
+    h, i = cfg["hidden_size"], cfg["intermediate_size"]
+    q, kv = cfg["num_attention_heads"] * head_dim(cfg), cfg["num_key_value_heads"] * head_dim(cfg)
+    matrices = {"wq": (h, q), "wk": (h, kv), "wv": (h, kv), "wo": (q, h),
+                "w_gate": (h, i), "w_up": (h, i), "w_down": (i, h)}
+    return {**{n: (s, "normal") for n, s in matrices.items()},
+            "norm_attn": ((h,), "ones"), "norm_mlp": ((h,), "ones")}
+
+
+def matmul_params(cfg: Dict[str, Any], depth: int) -> int:
+    """Weights that a token passes through by matrix multiplication: every
+    layer's projections and the output head. The embedding is a lookup."""
+    h, i, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
+    nh, nkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], head_dim(cfg)
+    layer = h * (nh * hd) + 2 * h * (nkv * hd) + (nh * hd) * h + 3 * h * i
+    return depth * layer + h * v
+
+
+def attention_passes(cfg: Dict[str, Any], depth: int) -> int:
+    """Causal-attention calls, and so KV sets, that a token makes: one a layer."""
+    return depth
 
 
 def _round_int8(x: jax.Array, axis: int) -> jax.Array:
@@ -87,8 +130,7 @@ def decoder_layer(h: jax.Array, w: Dict[str, jax.Array], cfg: Dict[str, Any],
                   lower: Optional[str] = None) -> jax.Array:
     """One block on one sequence ``h [T, H]`` at positions ``0..T-1``."""
     t = h.shape[0]
-    nh, nkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg["hidden_size"] // nh
+    nh, nkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], head_dim(cfg)
     pos = jnp.arange(t)
     x = rms_norm(h, w["norm_attn"], cfg["rms_norm_eps"])
     q = rope(matmul(x, w["wq"], lower).reshape(t, nh, hd), pos, cfg["rope_theta"])
@@ -119,6 +161,33 @@ def forward_logits(tokens: jax.Array, weights: Dict[str, Any], cfg: Dict[str, An
     for w in weights["layers"]:
         h = decoder_layer(h, w, cfg, lower)
     return head_logits(h, weights["top"], cfg, lower)
+
+
+class _Frozen(dict):
+    """A configuration that jit can take as a static argument."""
+
+    def __hash__(self) -> int:  # type: ignore[override]
+        return hash(tuple(sorted((k, v) for k, v in self.items() if isinstance(v, (int, float, str)))))
+
+
+_layer_jit = jax.jit(decoder_layer, static_argnums=(2,))
+_head_jit = jax.jit(head_logits, static_argnums=(2,))
+
+
+def sequence_logits(token_seqs: Sequence[Any], top: Dict[str, jax.Array],
+                    layer_weights: Callable[[int], Dict[str, jax.Array]], cfg: Dict[str, Any]) -> Iterator[jax.Array]:
+    """The float32 logits ``[len(seq), V]`` of each sequence, one after the
+    other. The layers are walked once for all of them; ``layer_weights(i)``
+    makes layer ``i``'s float32 leaves when asked, so one layer is held at a
+    time. ``top`` holds its leaves in the type they are served in."""
+    cfg = _Frozen(cfg)
+    hidden = [embed(jnp.asarray(toks), top["embed"]) for toks in token_seqs]
+    for i in range(cfg["num_hidden_layers"]):
+        w = layer_weights(i)
+        hidden = [_layer_jit(h, w, cfg) for h in hidden]
+        del w
+    for h in hidden:
+        yield _head_jit(h, top, cfg)
 
 
 def sequence_loss_sum(weights: Dict[str, Any], tokens: jax.Array, labels: jax.Array,

@@ -10,7 +10,9 @@ configuration in ``benchmarks/configs/<config>.json``, its traffic mix in
 ``benchmarks/lib/drivers/<driver>.py`` and each per-layer metric's reader in
 ``benchmarks/metrics/<metric>.py``: this file names none of them. It fails,
 with no result line, unless JAX finds a TPU with the chips the cell asks for.
-Earlier stdout lines are JSON notes; the LAST line is the result.
+Earlier stdout lines are JSON notes; the LAST line is the result, whose last
+key ``checks`` holds every number ``correct`` compared beside its limit; the
+same are the last lines of stderr.
 """
 
 from __future__ import annotations
@@ -177,8 +179,20 @@ def main(argv: Optional[List[str]] = None) -> int:
                 longest_gaps=reduced["longest_gaps"])
         out["device"].update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         out["breakdown"] = xplane.breakdown(reduced)
+    # every number compared beside its limit: last in the result's line, and the last lines of stderr
+    out["checks"] = {row["name"]: {"value": _plain(row["value"]), "limit": _plain(row["limit"]), "ok": bool(row["ok"])}
+                     for row in result["checks"]}
+    for name, row in out["checks"].items():
+        print(f"check {name} = {row['value']} limit {row['limit']} {'ok' if row['ok'] else 'NOT OK'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)
     return 0
+
+
+def _plain(x: Any) -> Any:
+    """A number as JSON can carry it (a non-finite one as its name)."""
+    x = float(x)
+    return x if x == x and abs(x) != float("inf") else str(x)
 
 
 if __name__ == "__main__":

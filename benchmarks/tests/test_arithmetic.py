@@ -95,7 +95,7 @@ def test_batches_are_a_function_of_seed_and_step():
 
 def test_weights_per_layer_equal_the_one_call():
     cfg = {"hidden_size": 16, "intermediate_size": 32, "num_attention_heads": 4, "num_key_value_heads": 2,
-           "vocab_size": 64, "initializer_range": 0.02}
+           "vocab_size": 64, "initializer_range": 0.02, "reference": "decoder"}
     big = 2**31 + 12345
     whole = weights.all_weights(big, cfg, 2, "bfloat16")
     for i in range(2):

