@@ -462,7 +462,13 @@ class ContinuousBatchingEngine:
 
         kvh = cfg.num_key_value_heads
         hd = cfg.hidden_size // cfg.num_attention_heads
-        self._num_layers = cfg.num_hidden_layers
+        # KV sets a token holds, and so cache planes this engine owns: the
+        # model's to say (a looped stack holds passes x layers); every seam
+        # that moves "a token's KV" walks this many planes
+        self._num_kv_sets = int(getattr(cfg, "num_kv_sets", cfg.num_hidden_layers))
+        # passes of the stack a step runs (the loop_passes counter): the
+        # model's to say too; once unless its config says otherwise
+        self._stack_passes = int(getattr(cfg, "stack_passes", 1))
         dtype = next(iter(model.parameters())).dtype
         # cache geometry, kept so recover() can rebuild identical buffers
         # (identical shapes/dtypes/shardings -> the compiled program is reused)
@@ -646,9 +652,10 @@ class ContinuousBatchingEngine:
             else None
         )
         # ONE global paged pool shared by every layer's sequences would alias
-        # writes across layers — each layer owns its [NB, KVH, BS, D] pair,
-        # all indexed by the SAME block tables (the reference layout).
-        self._caches = [self._new_cache_pair() for _ in range(self._num_layers)]
+        # writes across layers — each KV set (a layer; a layer in one pass of
+        # a looped stack) owns its [NB, KVH, BS, D] pair, all indexed by the
+        # SAME block tables (the reference layout).
+        self._caches = [self._new_cache_pair() for _ in range(self._num_kv_sets)]
 
         # per-slot host state (rewritten freely between steps — it is DATA to
         # the compiled step, never part of its shape)
@@ -685,7 +692,16 @@ class ContinuousBatchingEngine:
             # KV pages the paged kernel's length-bounded walk visits, a layer:
             # sum over a step's active slots of ceil((cached + new) / block)
             "paged_pages_walked": 0,
+            # passes of the model's stack run, summed over steps (one a step
+            # unless the stack is looped); gauges: the KV sets a token holds
+            # and their bytes
+            "loop_passes": 0, "kv_sets": self._num_kv_sets,
+            "kv_bytes_per_token": self._bytes_per_token(),
+            # steps at whose planning a waiting request was held back: for
+            # want of blocks with a slot free / for want of a slot
+            "admit_blocked_steps.blocks": 0, "admit_blocked_steps.slots": 0,
         }
+        self._admit_blocked: Optional[str] = None  # the step being planned
         self._metrics = _engine_metrics()
         self._update_pool_gauges()
         # On donating backends (TPU) a step that fails AFTER dispatch has
@@ -722,9 +738,9 @@ class ContinuousBatchingEngine:
             cs = self._cache_sharding
             if self._quant_kv:
                 ss = self._scale_sharding
-                cache_sh = [(cs, cs, ss, ss)] * self._num_layers
+                cache_sh = [(cs, cs, ss, ss)] * self._num_kv_sets
             else:
-                cache_sh = [(cs, cs)] * self._num_layers
+                cache_sh = [(cs, cs)] * self._num_kv_sets
             self._step_fn = jax.jit(
                 self._step_impl,
                 donate_argnums=(1,) if donate else (),
@@ -762,7 +778,7 @@ class ContinuousBatchingEngine:
         from paddle_tpu.distributed.tp import analytic_cost_hints
 
         self._devprof_hints = analytic_cost_hints(
-            num_layers=self._num_layers,
+            num_layers=self._num_kv_sets,  # layer bodies a step runs
             hidden=cfg.hidden_size,
             intermediate=getattr(cfg, "intermediate_size", 4 * cfg.hidden_size),
             vocab=getattr(cfg, "vocab_size", 0),
@@ -844,15 +860,15 @@ class ContinuousBatchingEngine:
         return _devprof.summarize_timeline(self._devprof_timeline.entries())
 
     def _bytes_per_token(self) -> int:
-        """KV bytes across all layers for one token (sizes the bytes-saved
+        """KV bytes across all KV sets for one token (sizes the bytes-saved
         gauge and the host tier's per-block cost). Quantized pools count the
         TRUE footprint: the int8 payload plus one fp32 scale per (token,
         head) — ``2·L·KVH·(D+4)`` vs bf16's ``2·L·KVH·2D``, a ``2D/(D+4)``
         reduction (1.94x at D=128)."""
         if self._quant_kv:
-            return 2 * self._num_layers * self._kvh * (self._hd + 4)
+            return 2 * self._num_kv_sets * self._kvh * (self._hd + 4)
         return (
-            2 * self._num_layers * self._kvh * self._hd
+            2 * self._num_kv_sets * self._kvh * self._hd
             * jnp.dtype(self._cache_dtype).itemsize
         )
 
@@ -872,8 +888,8 @@ class ContinuousBatchingEngine:
         return cache
 
     def _capture_block_kv(self, block: int) -> np.ndarray:
-        """D2H capture of one physical block's KV across every layer —
-        ``[layers, 2, KVH, BS, D]`` — for a spill. Synchronous by design:
+        """D2H capture of one physical block's KV across every KV set —
+        ``[sets, 2, KVH, BS, D]`` — for a spill. Synchronous by design:
         the copy must complete before the block's pool reference drops and
         the slot can be reallocated and overwritten (the caller holds that
         ordering). Under tensor parallelism the head shards gather here —
@@ -1317,7 +1333,7 @@ class ContinuousBatchingEngine:
             return kv, kv, scale, scale
 
         def run(param_arrays, *step_args):
-            caches = [scratch_pool() for _ in range(self._num_layers)]
+            caches = [scratch_pool() for _ in range(self._num_kv_sets)]
             logits, _ = self._step_forward(param_arrays, caches, *step_args)
             return logits[0].astype(jnp.float32)
 
@@ -1380,12 +1396,17 @@ class ContinuousBatchingEngine:
 
     def _admit_waiting(self, done: List[InferenceRequest]) -> None:
         self._shed_expired_queued(done)
+        self._admit_blocked = None
         while self._waiting:
             free_slots = [i for i, r in enumerate(self._slot_req) if r is None]
             if not free_slots:
+                self._admit_blocked = "slots"
                 return
             req = self._policy.select(tuple(self._waiting), self._can_fit)
             if req is None:
+                # a slot is free and the policy admits nothing: the pool's
+                # unreserved blocks do not cover what it looked at
+                self._admit_blocked = "blocks"
                 return
             # a buggy policy must fail loudly, not corrupt the worst-case
             # reservation invariant the pool depends on
@@ -1469,7 +1490,7 @@ class ContinuousBatchingEngine:
                 hd = self._hd
                 for hn, blk in zip(copies, blocks):
                     dst = jnp.asarray(np.int32(blk))
-                    for li in range(self._num_layers):
+                    for li in range(self._num_kv_sets):
                         if self._quant_kv:
                             # packed host block [2, KVH, BS, D+4] int8: split
                             # the payload from the 4 trailing scale bytes and
@@ -2202,6 +2223,9 @@ class ContinuousBatchingEngine:
         stats["steps"] += 1
         stats["prompt_tokens_computed"] += prefill_tokens
         stats["paged_pages_walked"] += pages_walked
+        stats["loop_passes"] += self._stack_passes
+        if self._admit_blocked is not None:
+            stats["admit_blocked_steps." + self._admit_blocked] += 1
         if prefill_tokens:
             self._metrics["prefill_tokens"].inc(prefill_tokens)
         plan, launch, wait = self._phases_done
@@ -2357,7 +2381,7 @@ class ContinuousBatchingEngine:
         )
         # identical shapes/dtypes/shardings (tp pools come back committed on
         # the same mesh partition) -> the compiled program is reused
-        self._caches = [self._new_cache_pair() for _ in range(self._num_layers)]
+        self._caches = [self._new_cache_pair() for _ in range(self._num_kv_sets)]
         self._mgr = BlockKVCache(
             self.num_blocks, self.block_size, self._kvh, self._hd,
             self.max_blocks_per_seq, dtype=self._cache_dtype,

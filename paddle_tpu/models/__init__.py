@@ -1,5 +1,5 @@
 """Model zoo for the BASELINE workloads (SURVEY §6):
-llama (flagship), gpt, ernie/bert, moe, unet."""
+llama (flagship), ouro (looped llama-style stack), gpt, ernie/bert, moe, unet."""
 
 from paddle_tpu.models.ernie import (  # noqa: F401
     ErnieConfig,
@@ -14,4 +14,5 @@ from paddle_tpu.models.gpt import (  # noqa: F401
     gpt_shard_fn,
 )
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM, LlamaModel  # noqa: F401
+from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM, OuroModel  # noqa: F401
 from paddle_tpu.models.sd_unet import UNet2DConditionModel, UNetConfig  # noqa: F401

@@ -64,6 +64,11 @@ class LlamaConfig:
     context_parallel: bool = False
     dtype: str = "bfloat16"
 
+    @property
+    def num_kv_sets(self) -> int:
+        """KV sets a token holds (what a cache owner allocates): one a layer."""
+        return self.num_hidden_layers
+
     @staticmethod
     def llama2_7b() -> "LlamaConfig":
         return LlamaConfig()
@@ -125,7 +130,9 @@ class LlamaRotaryEmbedding(nn.Layer):
 
 
 class LlamaAttention(nn.Layer):
-    def __init__(self, config: LlamaConfig) -> None:
+    def __init__(self, config: LlamaConfig, rotary_emb: Optional[LlamaRotaryEmbedding] = None) -> None:
+        """``rotary_emb``: a table to share (every layer's holds the same
+        values); by default the layer builds its own."""
         super().__init__()
         self.config = config
         self.hidden_size = config.hidden_size
@@ -137,7 +144,7 @@ class LlamaAttention(nn.Layer):
         self.k_proj = nn.Linear(self.hidden_size, self.num_kv_heads * self.head_dim, bias_attr=bias)
         self.v_proj = nn.Linear(self.hidden_size, self.num_kv_heads * self.head_dim, bias_attr=bias)
         self.o_proj = nn.Linear(self.num_heads * self.head_dim, self.hidden_size, bias_attr=bias)
-        self.rotary_emb = LlamaRotaryEmbedding(
+        self.rotary_emb = rotary_emb if rotary_emb is not None else LlamaRotaryEmbedding(
             self.head_dim, config.max_position_embeddings, config.rope_theta
         )
 
