@@ -8,6 +8,14 @@ blocks held in VMEM, fp32 accumulation on the MXU, and a custom-VJP backward
 pair (dq kernel / dkv kernel) recomputing probabilities from the saved
 logsumexp — the standard flash-attention-2 decomposition.
 
+Matmul operands follow the inputs' dtype (``_operand_dtype``): bf16 tensors
+feed the MXU as they are and the probabilities and ``ds`` the kernels compute
+are rounded to bf16 for their matmuls; float32 inputs keep float32 operands.
+Accumulators and the whole softmax (running max, exponent, row sums, lse,
+delta) are float32 either way. Block shapes come from the shapes and a VMEM
+budget (``_block_geometry``): the tile, not the operands, sets the kernels'
+time.
+
 Layouts: public entry takes paddle's ``[B, S, H, D]``; kernels run
 ``[B, H, S, D]``. Grouped-query attention is handled by BlockSpec index maps
 (kv head = q head // group), never materializing repeated KV.
@@ -28,9 +36,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
+_MAX_BLOCK = 512  # a side of the score tile; the chip gains nothing past 512 x 512 (PERF.md, PR 28)
+_VMEM_BUDGET = 16 << 20  # bytes of VMEM a kernel may take (Mosaic's default scoped limit)
 # pallas_call name= of each kernel here: what a device trace calls it (stable, no shapes)
 KERNEL_FWD = "flash_attention_fwd"
 KERNEL_DQ = "flash_attention_dq"
@@ -41,6 +49,32 @@ def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
 
+def _block_geometry(sq: int, sk: int, d: int, itemsize: int) -> Tuple[int, int]:
+    """(blk_q, blk_k) from the shapes alone. A grid cell's inner loop pays a
+    fixed latency an iteration (the dependent matmul - softmax - matmul chain,
+    the per-row statistics on lane-sparse [blk, 1] vectors), so the score tile
+    is as large as VMEM allows, up to 512 x 512. The dKV kernel is the one that
+    binds. Double-buffered, it holds q and dO of a whole sequence with lse and
+    delta (their unit lane dimension pads to 128 lanes), the k and v blocks and
+    the float32 dk and dv blocks; beside them two float32 accumulators and the
+    tile's float32 temporaries, three by what the chip's compiler took and
+    refused (tests/test_tpu_aot_compile.py holds it to that). Each sequence is
+    then cut into the fewest such blocks, evenly, in multiples of 128:
+    2048 -> 4 x 512, 600 -> 2 x 384 (not 512 + 512)."""
+    d = _cdiv(d, 128) * 128  # a row takes whole 128-lane tiles in VMEM
+    whole_seq = 2 * max(2 * sq * d * itemsize + 2 * sq * 128 * 4, 2 * sk * d * itemsize)  # or K, V: forward, dQ
+    per_key = d * (2 * 2 * itemsize + 2 * 2 * 4 + 2 * 4)
+    blk_q = blk_k = _MAX_BLOCK
+    while whole_seq + blk_k * per_key + 3 * blk_q * blk_k * 4 > _VMEM_BUDGET and blk_k > 128:
+        # q rows first: a wide blk_k is what spreads the row statistics' cost
+        blk_q, blk_k = (blk_q // 2, blk_k) if blk_q > 128 else (blk_q, blk_k // 2)
+
+    def even(s, cap):
+        return _cdiv(_cdiv(s, _cdiv(s, cap)), 128) * 128
+
+    return even(sq, blk_q), even(sk, blk_k)
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     size = x.shape[axis]
     rem = size % mult
@@ -49,6 +83,16 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     pad = [(0, 0)] * x.ndim
     pad[axis] = (0, mult - rem)
     return jnp.pad(x, pad)
+
+
+def _operand_dtype(dtype) -> Any:
+    """The dtype the kernels hand the MXU: bf16 as it arrives (a bf16 x bf16
+    product accumulated in float32 is exactly what the upcast operands give);
+    anything else float32. On the chip a float32 ``dot_general`` at the default
+    precision rounds its operands to bf16 for one MXU pass anyway (PERF.md,
+    PR 28); stating the operands makes that hold whatever the precision says,
+    and keeps a scaled q from being rounded on its way in."""
+    return jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
 
 
 def _mask_block(
@@ -91,7 +135,13 @@ def _fwd_kernel(
     q_ref, k_ref, v_ref, idx_ref, o_ref, lse_ref, *, sq, sk, scale, causal, blk_q, blk_k, num_kv_blocks
 ):
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # [blk_q, D]
+    mm = _operand_dtype(q_ref.dtype)
+    q = q_ref[0, 0].astype(mm)  # [blk_q, D]
+    # a float32 operand carries the scale into the dot; bf16 cannot hold
+    # q * scale, so there the float32 logits are scaled
+    prescaled = mm == jnp.float32
+    if prescaled:
+        q = q * scale
     d = q.shape[-1]
     rows = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, 1), 0)
 
@@ -104,11 +154,13 @@ def _fwd_kernel(
 
     def body(ki, carry):
         acc, m, l = carry
-        k = k_ref[0, 0, pl.dslice(ki * blk_k, blk_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.dslice(ki * blk_k, blk_k), :].astype(jnp.float32)
+        k = k_ref[0, 0, pl.dslice(ki * blk_k, blk_k), :].astype(mm)
+        v = v_ref[0, 0, pl.dslice(ki * blk_k, blk_k), :].astype(mm)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [blk_q, blk_k]
+        if not prescaled:
+            logits = logits * scale
         cols = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
         bounds = None
         if idx_ref is not None:
@@ -120,7 +172,7 @@ def _fwd_kernel(
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(axis=-1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(mm), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         return acc, m_new, l
 
@@ -207,8 +259,9 @@ def _bwd_dq_kernel(
     *, sq, sk, scale, causal, blk_q, blk_k, num_kv_blocks
 ):
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)  # [blk_q, D]
-    g = g_ref[0, 0].astype(jnp.float32)
+    mm = _operand_dtype(q_ref.dtype)
+    q = q_ref[0, 0].astype(mm)  # [blk_q, D]
+    g = g_ref[0, 0].astype(mm)
     lse = lse_ref[0, 0]  # [blk_q, 1]
     delta = delta_ref[0, 0]
     d = q.shape[-1]
@@ -221,8 +274,8 @@ def _bwd_dq_kernel(
         hi = num_kv_blocks
 
     def body(ki, dq):
-        k = k_ref[0, 0, pl.dslice(ki * blk_k, blk_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.dslice(ki * blk_k, blk_k), :].astype(jnp.float32)
+        k = k_ref[0, 0, pl.dslice(ki * blk_k, blk_k), :].astype(mm)
+        v = v_ref[0, 0, pl.dslice(ki * blk_k, blk_k), :].astype(mm)
         logits = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -237,7 +290,7 @@ def _bwd_dq_kernel(
         )
         ds = p * (dp - delta) * scale
         dq = dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(mm), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         return dq
 
@@ -250,8 +303,9 @@ def _bwd_dkv_kernel(
     *, sq, sk, scale, causal, blk_q, blk_k, num_q_blocks, group
 ):
     ki = pl.program_id(2)
-    k = k_ref[0, 0].astype(jnp.float32)  # [blk_k, D]
-    v = v_ref[0, 0].astype(jnp.float32)
+    mm = _operand_dtype(q_ref.dtype)
+    k = k_ref[0, 0].astype(mm)  # [blk_k, D]
+    v = v_ref[0, 0].astype(mm)
     d = k.shape[-1]
     cols = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
     bounds = idx_ref[0, 0] if idx_ref is not None else None  # [blk_k, C]
@@ -263,8 +317,8 @@ def _bwd_dkv_kernel(
 
     def body(qi, carry):
         dk, dv = carry
-        q = q_ref[0, 0, pl.dslice(qi * blk_q, blk_q), :].astype(jnp.float32)
-        g = g_ref[0, 0, pl.dslice(qi * blk_q, blk_q), :].astype(jnp.float32)
+        q = q_ref[0, 0, pl.dslice(qi * blk_q, blk_q), :].astype(mm)
+        g = g_ref[0, 0, pl.dslice(qi * blk_q, blk_q), :].astype(mm)
         lse = lse_ref[0, 0, pl.dslice(qi * blk_q, blk_q), :]  # [blk_q, 1]
         delta = delta_ref[0, 0, pl.dslice(qi * blk_q, blk_q), :]
         rows = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, 1), 0)
@@ -277,14 +331,14 @@ def _bwd_dkv_kernel(
         masked = masked | (rows >= sq)
         p = jnp.where(masked, 0.0, jnp.exp(logits - lse))
         dv = dv + jax.lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(mm), g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )  # [blk_k, D]
         dp = jax.lax.dot_general(
             g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [blk_q, blk_k]
         ds = p * (dp - delta) * scale
         dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(mm), q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         return dk, dv
 
@@ -460,7 +514,7 @@ def _make_flash_core(sq, sk, scale, causal, blk_q, blk_k, interpret):
 
 def _autotune_blocks(q_shape, kv_heads, dtype, sq, sk, d, scale, causal, mask_c, interpret):
     """Benchmark-pick (blk_q, blk_k) for this attention shape (reference
-    ``auto_tune_base.h:48``); returns the defaults when tuning is off."""
+    ``auto_tune_base.h:48``); returns ``_block_geometry``'s when tuning is off."""
     from paddle_tpu.kernels.autotune import autotune
 
     b, h = q_shape[0], q_shape[2]
@@ -469,7 +523,7 @@ def _autotune_blocks(q_shape, kv_heads, dtype, sq, sk, d, scale, causal, mask_c,
         (bq, bk)
         for bq in (128, 256, 512)
         for bk in (128, 256, 512)
-        if bq <= max(sq, 128) and bk <= max(sk, 128) and bq * bk <= 512 * 256
+        if bq <= max(sq, 128) and bk <= max(sk, 128)
     ]
 
     def build(cfg):
@@ -488,7 +542,7 @@ def _autotune_blocks(q_shape, kv_heads, dtype, sq, sk, d, scale, causal, mask_c,
 
     return autotune(
         "flash_attention", key, candidates, build,
-        default=(DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K),
+        default=_block_geometry(sq, sk, d, jnp.dtype(dtype).itemsize),
     )
 
 
@@ -507,7 +561,7 @@ def flash_attention_pallas(
     FlashMask bounds tensor ``[B, Hm, Sk, C]``). Differentiable.
 
     ``block_q``/``block_k`` default to the autotuner's pick for this shape
-    when ``FLAGS_use_kernel_autotune`` is on, else (128, 128)."""
+    when ``FLAGS_use_kernel_autotune`` is on, else to ``_block_geometry``'s."""
     sq, sk = q.shape[1], k.shape[1]
     d = q.shape[-1]
     if scale is None:

@@ -6,11 +6,17 @@ Mirrors the reference's OpTest analytic-grad methodology (SURVEY §4) for the
 kernels that replace flash_attn_kernel.cu / rms_norm / fused_rope.
 """
 
+import hashlib
+import itertools
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels.flash_attention import flash_attention_pallas
 from paddle_tpu.kernels.flashmask import flashmask_attention_pallas, flashmask_maxmin
 from paddle_tpu.kernels.fused import fused_rms_norm_pallas, fused_rope_pallas
@@ -88,6 +94,164 @@ class TestFlashAttentionPallas:
         assert out.dtype == jnp.bfloat16
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref), rtol=3e-2, atol=3e-2
+        )
+
+
+# --------------------------------------------------------------------------
+# matmul operands follow the inputs' dtype (PR 28): bf16 tensors feed the MXU
+# bf16, float32 tensors float32, and float32 results stay what they were
+# --------------------------------------------------------------------------
+
+# {causal, not} x {MHA, GQA 4:1} x {no bounds, FlashMask C = 1, 2, 4} x {sq = sk, sq < sk}
+OPERAND_CASES = list(itertools.product((True, False), (4, 1), (0, 1, 2, 4), (40, 24)))
+_CASE_IDS = [
+    f"{'causal' if c else 'full'}-{'mha' if hk == 4 else 'gqa4'}-c{mc}-sq{sq}"
+    for c, hk, mc, sq in OPERAND_CASES
+]
+_SK, _H, _D, _BLK = 40, 4, 32, 16  # 3 blocks of 16 a side, the last one padded
+# sha256 of out, dq, dk, dv of every case at float32 inputs, recorded from the
+# kernel as it stood before PR 28 (commit f5235e7) on this installation
+_F32_RECORD = pathlib.Path(__file__).parent / "testdata" / "flash_attention_f32.sha256.json"
+
+
+def _case_bounds(mask_c, sq, sk, seed):
+    """FlashMask bounds [1, 1, Sk, C] that leave column 0 open to every row, so
+    that no query row is masked out whole."""
+    if not mask_c:
+        return None
+    rng = np.random.default_rng(seed)
+    start = rng.integers(1, sq + 1, sk).astype(np.int32)
+    end = np.minimum(start + rng.integers(0, 12, sk), sq).astype(np.int32)
+    if mask_c == 1:
+        cols = [start]
+    elif mask_c == 2:
+        cols = [start, end]
+    else:
+        uts = rng.integers(0, sq // 2, sk).astype(np.int32)
+        ute = np.minimum(uts + rng.integers(0, 4, sk), sq).astype(np.int32)
+        cols = [start, end, uts, ute]
+    idx = np.stack(cols, -1)
+    idx[0] = sq  # empty bands: [sq, sq) masks nothing
+    return jnp.asarray(idx.reshape(1, 1, sk, mask_c))
+
+
+def _operand_case(causal, hk, mask_c, sq, dtype):
+    seed = 100 + OPERAND_CASES.index((causal, hk, mask_c, sq))
+    q, k, v = (x.astype(dtype) for x in _qkv(b=1, sq=sq, sk=_SK, h=_H, hk=hk, d=_D, seed=seed))
+    w = jax.random.normal(jax.random.PRNGKey(seed + 1000), (1, sq, _H, _D), jnp.float32)
+    return q, k, v, w, _case_bounds(mask_c, sq, _SK, seed)
+
+
+def _out_and_grads(attend, q, k, v, w):
+    """Output and the three gradients under a random float32 cotangent ``w``."""
+    out, vjp = jax.vjp(attend, q, k, v)
+    return (out,) + vjp(w.astype(out.dtype))
+
+
+def _pallas(idx, causal):
+    return lambda q, k, v: flash_attention_pallas(
+        q, k, v, startend_row_indices=idx, causal=causal,
+        block_q=_BLK, block_k=_BLK, interpret=True,
+    )
+
+
+class TestFlashOperandDtype:
+    # one rounding of p / ds to bf16 (2**-9 relative, averaged over a row) and
+    # the bf16 outputs' own rounding (2**-9 of values up to ~4) stay under this
+    # against the dense float32 reference on the same bf16 values upcast
+    BF16_TOL = dict(rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("causal,hk,mask_c,sq", OPERAND_CASES, ids=_CASE_IDS)
+    def test_bf16_fwd_and_grads_match_dense_f32(self, causal, hk, mask_c, sq):
+        q, k, v, w, idx = _operand_case(causal, hk, mask_c, sq, jnp.bfloat16)
+        got = _out_and_grads(_pallas(idx, causal), q, k, v, w)
+        bias = None if idx is None else make_flashmask_bias(idx, sq, _SK, causal)
+        ref = _out_and_grads(
+            lambda q, k, v: _xla_attention(q, k, v, bias=bias, causal=causal),
+            *(x.astype(jnp.float32) for x in (q, k, v)), w,
+        )
+        for name, a, b_ in zip(("out", "dq", "dk", "dv"), got, ref):
+            assert a.dtype == jnp.bfloat16, name
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b_), err_msg=name, **self.BF16_TOL
+            )
+
+    @pytest.mark.parametrize("causal,hk,mask_c,sq", OPERAND_CASES, ids=_CASE_IDS)
+    def test_f32_bitwise_as_before(self, causal, hk, mask_c, sq, request):
+        q, k, v, w, idx = _operand_case(causal, hk, mask_c, sq, jnp.float32)
+        got = _out_and_grads(_pallas(idx, causal), q, k, v, w)
+        digest = hashlib.sha256(b"".join(np.asarray(a).tobytes() for a in got)).hexdigest()
+        assert digest == json.loads(_F32_RECORD.read_text())[request.node.callspec.id]
+
+
+def _kernel_dots(jaxpr, inside=False):
+    """(operand dtypes) of every dot_general inside a pallas_call body, and the
+    result avals of every pallas_call, anywhere under ``jaxpr``."""
+    dots, calls = [], []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and inside:
+            dots.append(tuple(v.aval.dtype for v in eqn.invars))
+        if eqn.primitive.name == "pallas_call":
+            calls.append(tuple((v.aval.dtype, v.aval.shape) for v in eqn.outvars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            d, c = _kernel_dots(sub, inside or eqn.primitive.name == "pallas_call")
+            dots += d
+            calls += c
+    return dots, calls
+
+
+class TestFlashKernelStructure:
+    """What keeps a later edit from bringing the upcasts back, or from changing
+    the results ``benchmarks/metrics/flash_attn_roofline.py`` tells the three
+    kernels by."""
+
+    B, S, H, HK, D = 2, 256, 8, 2, 128
+
+    def _trace(self, dtype):
+        shapes = [
+            jax.ShapeDtypeStruct((self.B, self.S, h, self.D), dtype) for h in (self.H, self.HK, self.HK)
+        ]
+        fn = jax.grad(
+            lambda q, k, v: flash_attention_pallas(q, k, v, causal=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )
+        return _kernel_dots(jax.make_jaxpr(fn)(*shapes).jaxpr)
+
+    def test_bf16_inputs_no_float32_operand_pair(self):
+        dots, _ = self._trace(jnp.bfloat16)
+        assert len(dots) == 9  # 2 forward, 3 dq, 4 dkv
+        assert all(pair == (jnp.bfloat16, jnp.bfloat16) for pair in dots), dots
+
+    def test_float32_inputs_all_float32_operands(self):
+        dots, _ = self._trace(jnp.float32)
+        assert len(dots) == 9
+        assert all(pair == (jnp.float32, jnp.float32) for pair in dots), dots
+
+    @pytest.mark.parametrize(
+        "sq,sk,d,itemsize,blocks",
+        [
+            (2048, 2048, 128, 2, (512, 512)),  # the train cell: the largest tile
+            (2048, 2048, 128, 4, (512, 512)),
+            (4096, 4096, 128, 2, (256, 512)),  # whole-sequence q, dO, lse, delta take 12 of 16 MiB
+            (4096, 4096, 64, 2, (256, 512)),  # 64 lanes pad to 128
+            (600, 600, 128, 2, (384, 384)),  # two even blocks, not 512 + 512
+            (40, 40, 32, 4, (128, 128)),  # the entry then clamps a block to the sequence
+            (128, 4096, 128, 2, (128, 512)),
+        ],
+    )
+    def test_block_geometry_from_shapes(self, sq, sk, d, itemsize, blocks):
+        assert fa._block_geometry(sq, sk, d, itemsize) == blocks
+
+    def test_results_are_what_the_roofline_reader_matches(self):
+        _, calls = self._trace(jnp.bfloat16)
+        bhsd = (self.B, self.H, self.S, self.D)
+        assert calls == [
+            ((jnp.bfloat16, bhsd), (jnp.float32, bhsd[:3] + (1,))),  # forward: out, lse
+            ((jnp.bfloat16, bhsd),),  # dq
+            ((jnp.float32, bhsd), (jnp.float32, bhsd)),  # dk, dv per q head
+        ]
+        assert (fa.KERNEL_FWD, fa.KERNEL_DQ, fa.KERNEL_DKV) == (
+            "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv",
         )
 
 

@@ -73,11 +73,14 @@ BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 _QKV = ((1, SEQ, HEADS, HEAD_DIM), BF16)
 
 
-def _flash_attention():
+def _flash_attention(batch=1, seq=SEQ, kv_heads=HEADS, dtype=BF16):
+    """Forward and both backward kernels at the blocks ``_block_geometry``
+    derives for the shape: what holds its VMEM model to the chip's compiler."""
     from paddle_tpu.kernels.flash_attention import flash_attention_pallas
 
     fn = _grad_all(lambda q, k, v: flash_attention_pallas(q, k, v, causal=True), 3)
-    return fn, (_QKV, _QKV, _QKV)
+    kv = ((batch, seq, kv_heads, HEAD_DIM), dtype)
+    return fn, (((batch, seq, HEADS, HEAD_DIM), dtype), kv, kv)
 
 
 # the chat cell's engine step (benchmarks/workloads/mistral7b.serve_chat.json:
@@ -176,6 +179,11 @@ def _wo_matmul():
 
 CASES = {
     "flash_attention_fwd_bwd_s2048": _flash_attention,
+    # the train cell (benchmarks/workloads/mistral7b.train_2k.json): batch 8, GQA 4:1, 512 x 512 blocks
+    "flash_attention_fwd_bwd_train_cell_gqa": lambda: _flash_attention(batch=8, kv_heads=8),
+    "flash_attention_fwd_bwd_s2048_float32": lambda: _flash_attention(batch=8, dtype=F32),
+    # twice the sequence: the whole-sequence operands leave room for 256 x 512 only
+    "flash_attention_fwd_bwd_s4096_gqa": lambda: _flash_attention(batch=2, seq=4096, kv_heads=8),
     "paged_flash_chunk_bf16": lambda: _paged_chunk(BF16),
     "paged_flash_chunk_int8": lambda: _paged_chunk(I8),
     "paged_flash_chunk_fused_bf16": lambda: _paged_chunk(BF16, fused=True),
