@@ -1,7 +1,7 @@
 """Persistent XLA compile cache, placed from outside.
 
 Entry points call :func:`enable_compile_cache` once before their first
-compile — ``chip_smoke.py``, ``bench.py``, ``start_serving_server`` and the
+compile — ``chip_smoke.py``, ``benchmarks/run.py``, ``start_serving_server`` and the
 ``distributed.launch`` launcher (for its workers). It is never called at
 ``import paddle_tpu``: a library import must not decide where a process
 writes.

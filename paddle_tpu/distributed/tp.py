@@ -222,10 +222,8 @@ def analytic_cost_hints(
     layer, plus the lm-head 2hV; attention 2·2·h·kv per layer); the
     collective weight converts the per-layer all-reduce's wire time
     (2 ramp-up·bytes/bw for a ring over ``tp`` shards) into
-    flop-equivalents at peak so the three shares stay in one unit. These
-    are the same ICI/MXU constants ``bench.py``'s analytic estimate uses —
-    the point is that devprof's MEASURED shares can now be laid against
-    this prior to validate it."""
+    flop-equivalents at peak so the three shares stay in one unit: a
+    prior that devprof's measured segments are laid against."""
     matmul = float(tokens) * (
         num_layers * 2.0 * (4.0 * hidden * hidden + 3.0 * hidden * intermediate)
         + 2.0 * hidden * vocab

@@ -121,8 +121,7 @@ def _define_builtin_flags() -> None:
     d("eager_op_cache_size", int, 4096, "Max entries in the eager per-op compiled-executable cache.")
     d("use_pallas_attention", bool, True, "Use Pallas flash-attention kernels on TPU when applicable.")
     d("use_pallas_fused", bool, True, "Use Pallas fused rms_norm/rope kernels on TPU when applicable.")
-    d("use_pallas_paged_attention", bool, True, "Use the Pallas block-table flash-decode kernel on TPU.")
-    d("use_fused_decode_layer", bool, True, "Fuse the decode step's per-layer epilogues (RoPE into the paged-attention kernel's block walk, residual-add + norm pairs into one kernel, token embedding gather + first norm at the step entry) behind one flag: fewer dispatches per layer per step, byte-identical outputs fused on or off, and the same ONE compiled step signature. On CPU both settings lower to the identical XLA composition; under tp the fused layer loop also tiles row-parallel matmuls so each tile's all-reduce overlaps the next tile's compute.")
+    d("use_pallas_paged_attention", bool, True, "Use the Pallas paged-attention kernel (the block-table page walk) on TPU.")
     d("use_fused_loss", bool, True, "Fuse the lm-head matmul with softmax cross-entropy at model training-loss sites (vocab-chunked, never materializes [B,S,V] logits; Pallas on TPU, lax.scan reference elsewhere). Models return (loss, None) on this path.")
     d("benchmark", bool, False, "Block on every op (sync dispatch) for timing.")
     d("log_memory_stats", bool, False, "Log live/peak device memory stats per allocation event.")

@@ -144,7 +144,10 @@ class GenerationMixin:
         sequences as they grow and reclaimed at the end — the serving-side
         memory model, vs ``generate()``'s fixed dense buffers. The host
         allocator runs between steps; each decode step is one jitted program
-        (block tables and lengths are data, so shapes never change).
+        (block tables and lengths are data, so shapes never change) over the
+        serving engine's own path: a ``PagedKV`` set per layer under a batch
+        of one new token a slot, the chunk kernel at ``C == 1``. The pool's
+        storage dtype follows ``FLAGS_kv_cache_dtype`` as the engine's does.
 
         This runs ONE static batch to completion (a finished sequence holds
         its slot and blocks until all are done); for mixed-length serving
@@ -154,7 +157,9 @@ class GenerationMixin:
         import numpy as np
 
         from paddle_tpu.core.tensor import Tensor
+        from paddle_tpu.flags import GLOBAL_FLAGS
         from paddle_tpu.incubate.nn.functional import BlockKVCache, block_cache_prefill
+        from paddle_tpu.inference.paged_kv import PagedBatch, PagedKV
 
         ids = input_ids._data if isinstance(input_ids, Tensor) else jnp.asarray(input_ids)
         ids = ids.astype(jnp.int32)
@@ -174,7 +179,7 @@ class GenerationMixin:
         if num_blocks is None:
             num_blocks = b * mbs
         dtype = next(iter(self.parameters())).dtype
-        L = cfg.num_hidden_layers
+        kv_dtype = jnp.int8 if GLOBAL_FLAGS.get("kv_cache_dtype") == "int8" else dtype
         mgr = BlockKVCache(num_blocks, block_size, kvh, hd, mbs, dtype=dtype)
         for i in range(b):
             mgr.allocate(i, prompt)
@@ -189,13 +194,14 @@ class GenerationMixin:
 
         with paddle_tpu.no_grad():
             logits, dense_caches = self(Tensor(ids), use_cache=True)
-        layer_caches = []
+        layer_caches = []  # a KV set's planes each: (key, value[, key_scale, value_scale])
         for k_t, v_t in dense_caches:
             # paged layout [NB, H, BS, D] (see BlockKVCache)
-            kc = jnp.zeros((num_blocks, kvh, block_size, hd), dtype)
-            vc = jnp.zeros_like(kc)
-            kc, vc = block_cache_prefill(kc, vc, k_t._data, v_t._data, tables, lens)
-            layer_caches.append((kc, vc))
+            pool = PagedKV.zeros((num_blocks, kvh, block_size, hd), kv_dtype)
+            layer_caches.append(block_cache_prefill(
+                pool.key, pool.value, k_t._data, v_t._data, tables, lens,
+                key_scale=pool.key_scale, value_scale=pool.value_scale,
+            ))
         tok = jnp.argmax(logits._data[:, -1, :].astype(jnp.float32), axis=-1).astype(jnp.int32)
         done = tok == eos_token_id if eos_token_id is not None else jnp.zeros((b,), bool)
 
@@ -206,29 +212,21 @@ class GenerationMixin:
         if step_cache is None:
             step_cache = {}
             object.__setattr__(self, "_paged_step_cache", step_cache)
-        step_key = (b, L, num_blocks, block_size, mbs, str(dtype))
+        step_key = (b, num_blocks, block_size, mbs, jnp.dtype(kv_dtype).name)
         if step_key not in step_cache and len(step_cache) >= 8:
             step_cache.pop(next(iter(step_cache)))
 
         @jax.jit
         def _paged_step(param_arrays, tok, caches, tables, lens):
             with bind_param_arrays(named, param_arrays):
-                pkv = [
-                    (Tensor(kc), Tensor(vc), Tensor(tables), Tensor(lens))
-                    for kc, vc in caches
-                ]
+                batch = PagedBatch.decode(tables, lens)
+                pkv = [PagedKV(*planes, batch=batch) for planes in caches]
                 with paddle_tpu.no_grad():
-                    step_logits, new_caches = self(
-                        Tensor(tok[:, None]),
-                        past_key_values=pkv,
-                        use_cache=True,
-                        cache_position=Tensor(lens),
-                    )
+                    step_logits, new_caches = self(Tensor(tok[:, None]), past_key_values=pkv, use_cache=True)
                 nxt = jnp.argmax(
                     step_logits._data[:, -1, :].astype(jnp.float32), axis=-1
                 ).astype(jnp.int32)
-                out_caches = [(c[0]._data, c[1]._data) for c in new_caches]
-                return nxt, out_caches
+                return nxt, [kv.planes for kv in new_caches]
 
         step = step_cache.setdefault(step_key, _paged_step)
 

@@ -28,11 +28,8 @@ __all__ = [
     "fused_dropout_add",
     "masked_multihead_attention",
     "block_multihead_attention",
-    "block_multihead_attention_fused",
     "block_multihead_chunk_attention",
-    "block_multihead_chunk_attention_fused",
     "block_cache_prefill",
-    "block_cache_append",
     "block_cache_append_chunk",
     "block_cache_cow_copy",
     "BlockKVCache",
@@ -41,14 +38,11 @@ __all__ = [
 
 from paddle_tpu.incubate.nn.functional.block_attention import (  # noqa: E402,F401
     BlockKVCache,
-    block_cache_append,
     block_cache_append_chunk,
     block_cache_cow_copy,
     block_cache_prefill,
     block_multihead_attention,
-    block_multihead_attention_fused,
     block_multihead_chunk_attention,
-    block_multihead_chunk_attention_fused,
 )
 from paddle_tpu.incubate.nn.functional.fused_moe import fused_moe  # noqa: E402,F401
 

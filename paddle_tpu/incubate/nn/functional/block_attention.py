@@ -9,8 +9,8 @@ grow without reserving max_seq_len per slot and freed blocks are reused.
 TPU-native shape: the cache is a dense ``[num_blocks, H, block_size, D]``
 array (heads OUTSIDE the token dim, so one head's physical block tiles as an
 ``(block_size, D)`` VMEM plane); appends are batched scatters
-(``.at[phys, :, off].set``) and decode attention runs the Pallas block-table
-flash-decode kernel (``kernels/paged_attention.py``) when enabled, falling
+(``.at[phys, :, off].set``) and attention runs the Pallas paged kernel
+(``kernels/paged_attention.py``: a walk over each slot's live pages) when enabled, falling
 back to a dense gather with a static ``max_blocks_per_seq`` bound — all
 static shapes, so the whole decode step jits once. The block allocator is
 host-side Python (it runs between steps, not inside the program), mirroring
@@ -42,6 +42,8 @@ def _tp_sharded_flash_chunk(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    cos: Optional[jax.Array] = None,
+    sin: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Run the mixed ragged Pallas kernel PER SHARD over the head partition:
     a ``pallas_call`` has no SPMD partitioning rule, so under a tp mesh the
@@ -50,102 +52,52 @@ def _tp_sharded_flash_chunk(
     inside the paged block walk; tables/lens are replicated host data).
     Quantization scale planes ([NB, KVH, BS]) partition on the SAME head
     axis as the KV planes they describe — scales are just more pool data.
-    ``interpret`` runs the per-shard kernel in Pallas interpret mode so the
-    shard split itself is testable off-TPU."""
+    The rope rows (``cos`` / ``sin``: q comes PRE-rope) are position data
+    shared by every head, so they ride replicated. ``interpret`` runs the
+    per-shard kernel in Pallas interpret mode so the shard split itself is
+    testable off-TPU."""
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
-    in_specs = [
-        P(None, None, "tp", None),  # q [B, C, HQ, D]: heads split
-        P(None, "tp", None, None),  # key_cache [NB, KVH, BS, D]
-        P(None, "tp", None, None),  # value_cache
-        P(None, None),  # block_tables: replicated host truth
-        P(None),  # seq_lens
-        P(None),  # q_lens
+    heads = P(None, "tp", None, None)  # a pool plane [NB, KVH, BS, D]
+    rope = [] if cos is None else [(cos, P(None, None, None)), (sin, P(None, None, None))]  # [B, C, D]
+    scales = [] if k_scale is None else [(k_scale, P(None, "tp", None)), (v_scale, P(None, "tp", None))]
+    operands = [
+        (q, P(None, None, "tp", None)),  # [B, C, HQ, D]: heads split
+        *rope,  # replicated position data
+        (key_cache, heads),
+        (value_cache, heads),
+        (block_tables, P(None, None)),  # replicated host truth
+        (seq_lens, P(None)),
+        (q_lens, P(None)),
+        *scales,
     ]
-    operands = [q, key_cache, value_cache, block_tables, seq_lens, q_lens]
-    if k_scale is not None:
-        in_specs += [P(None, "tp", None), P(None, "tp", None)]
-        operands += [k_scale, v_scale]
 
-    def _shard_chunk_attend(q_l, kc_l, vc_l, tables_l, lens_l, qlens_l,
-                            ks_l=None, vs_l=None):
+    def _shard_chunk_attend(q_l, *rest):
+        rest = list(rest)
+        cos_l, sin_l = (rest.pop(0), rest.pop(0)) if rope else (None, None)
+        kc_l, vc_l, tables_l, lens_l, qlens_l, *scales_l = rest
+        ks_l, vs_l = scales_l or (None, None)
         return paged_flash_chunk(
             q_l, kc_l, vc_l, tables_l, lens_l, qlens_l, scale=scale,
-            interpret=interpret, k_scale=ks_l, v_scale=vs_l,
+            interpret=interpret, k_scale=ks_l, v_scale=vs_l, cos=cos_l, sin=sin_l,
         )
 
     return jax.shard_map(
         _shard_chunk_attend,
         mesh=mesh,
-        in_specs=tuple(in_specs),
+        in_specs=tuple(spec for _, spec in operands),
         out_specs=P(None, None, "tp", None),
         check_vma=False,
-    )(*operands)
-
-def _tp_sharded_flash_chunk_fused(
-    q: jax.Array,
-    cos: jax.Array,
-    sin: jax.Array,
-    key_cache: jax.Array,
-    value_cache: jax.Array,
-    block_tables: jax.Array,
-    seq_lens: jax.Array,
-    q_lens: jax.Array,
-    scale: float,
-    mesh: Any,
-    interpret: bool = False,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """:func:`_tp_sharded_flash_chunk` for the rope-fused kernel: the rope
-    rows are position data shared by every head, so they ride replicated
-    while q/caches (and scale planes) split over the head partition."""
-    from jax.sharding import PartitionSpec as P
-
-    from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused
-
-    in_specs = [
-        P(None, None, "tp", None),  # q [B, C, HQ, D]: heads split
-        P(None, None, None),  # cos [B, C, D]: replicated position data
-        P(None, None, None),  # sin
-        P(None, "tp", None, None),  # key_cache [NB, KVH, BS, D]
-        P(None, "tp", None, None),  # value_cache
-        P(None, None),  # block_tables: replicated host truth
-        P(None),  # seq_lens
-        P(None),  # q_lens
-    ]
-    operands = [q, cos, sin, key_cache, value_cache, block_tables,
-                seq_lens, q_lens]
-    if k_scale is not None:
-        in_specs += [P(None, "tp", None), P(None, "tp", None)]
-        operands += [k_scale, v_scale]
-
-    def _shard_chunk_attend(q_l, cos_l, sin_l, kc_l, vc_l, tables_l, lens_l,
-                            qlens_l, ks_l=None, vs_l=None):
-        return paged_flash_chunk_fused(
-            q_l, cos_l, sin_l, kc_l, vc_l, tables_l, lens_l, qlens_l,
-            scale=scale, interpret=interpret, k_scale=ks_l, v_scale=vs_l,
-        )
-
-    return jax.shard_map(
-        _shard_chunk_attend,
-        mesh=mesh,
-        in_specs=tuple(in_specs),
-        out_specs=P(None, None, "tp", None),
-        check_vma=False,
-    )(*operands)
+    )(*[x for x, _ in operands])
 
 
 __all__ = [
     "BlockKVCache",
     "block_multihead_attention",
-    "block_multihead_attention_fused",
     "block_multihead_chunk_attention",
-    "block_multihead_chunk_attention_fused",
     "block_cache_prefill",
-    "block_cache_append",
     "block_cache_append_chunk",
     "block_cache_cow_copy",
 ]
@@ -411,46 +363,21 @@ def _quantize_kv_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
-@jax.named_scope(SCOPE_KV_WRITE)
-def block_cache_append(
-    key_cache: jax.Array,  # [NB, H, BS, D]
-    value_cache: jax.Array,
-    k: jax.Array,  # [B, H, D] one new token per sequence
-    v: jax.Array,
-    block_tables: jax.Array,  # [B, MBS]
-    positions: jax.Array,  # [B] token index being written (0-based)
-    slot_mask: Optional[jax.Array] = None,  # [B] bool; False = padded slot
-    key_scale: Optional[jax.Array] = None,  # [NB, H, BS] fp32 (int8 cache)
-    value_scale: Optional[jax.Array] = None,
-):
-    """Scatter one new KV token per sequence into its physical block slot.
-
-    With ``slot_mask``, masked-off (padded) batch slots write NOTHING: their
-    block-table row may alias physical blocks owned by live sequences (the
-    engine keeps evicted rows at 0), so their scatter is routed out of bounds
-    and dropped instead of clobbering another sequence's KV.
-
-    With ``key_scale``/``value_scale`` (the int8 pool), quantization happens
-    INSIDE this fused write: the same scatter indices that place the int8
-    rows place their per-token scales, so the scale table rides every
-    lifecycle seam the KV planes do. Returns 4 arrays instead of 2."""
-    nb, _h, bs, _d = key_cache.shape
-    blk_idx = positions // bs
-    off = positions % bs
-    phys = jnp.take_along_axis(block_tables, blk_idx[:, None], axis=1)[:, 0]
-    if slot_mask is not None:
-        phys = jnp.where(slot_mask, phys, nb)
+def _scatter_kv_rows(key_cache, value_cache, k, v, phys, off, key_scale, value_scale):
+    """Write the token rows of ``k`` / ``v`` (``[B, T, H, D]``) at ``(phys, :,
+    off)`` (both ``[B, T]``); rows routed to page ``NB`` are out of bounds
+    and dropped. With scale planes (the int8
+    pool) quantization happens INSIDE this write: the same scatter indices
+    that place the int8 rows place their per-token scales, so the scale
+    planes ride every lifecycle seam the KV planes do (4 arrays back, not 2)."""
+    phys, off = phys.reshape(-1), off.reshape(-1)
+    rows = lambda x: x.reshape((-1,) + x.shape[2:])  # noqa: E731  [B * T, H, D]
     if key_scale is not None:
-        qk, sk = _quantize_kv_rows(k)  # [B, H, D] int8, [B, H] f32
-        qv, sv = _quantize_kv_rows(v)
-        key_cache = key_cache.at[phys, :, off].set(qk, mode="drop")
-        value_cache = value_cache.at[phys, :, off].set(qv, mode="drop")
-        key_scale = key_scale.at[phys, :, off].set(sk, mode="drop")
-        value_scale = value_scale.at[phys, :, off].set(sv, mode="drop")
-        return key_cache, value_cache, key_scale, value_scale
-    key_cache = key_cache.at[phys, :, off].set(k.astype(key_cache.dtype), mode="drop")
-    value_cache = value_cache.at[phys, :, off].set(v.astype(value_cache.dtype), mode="drop")
-    return key_cache, value_cache
+        (qk, sk), (qv, sv) = _quantize_kv_rows(rows(k)), _quantize_kv_rows(rows(v))
+        writes = [(key_cache, qk), (value_cache, qv), (key_scale, sk), (value_scale, sv)]
+    else:
+        writes = [(key_cache, rows(k).astype(key_cache.dtype)), (value_cache, rows(v).astype(value_cache.dtype))]
+    return tuple(plane.at[phys, :, off].set(rows, mode="drop") for plane, rows in writes)
 
 
 @jax.named_scope(SCOPE_KV_WRITE)
@@ -465,10 +392,9 @@ def block_cache_prefill(
     value_scale: Optional[jax.Array] = None,
 ):
     """Write whole prompts into the paged cache (encoder phase of the
-    reference kernel). Positions past ``seq_lens`` scatter into a scratch
-    slot (block 0 / slot recomputed) are avoided via clamping + final mask.
-    With scale planes the write quantizes in-flight (returns 4 arrays)."""
-    b, s, h, d = k.shape
+    reference kernel). With scale planes the write quantizes in-flight
+    (returns 4 arrays)."""
+    s = k.shape[1]
     nb, bs = key_cache.shape[0], key_cache.shape[2]
     t = jnp.arange(s)[None, :]  # [1, S]
     valid = t < seq_lens[:, None]  # [B, S]
@@ -479,21 +405,9 @@ def block_cache_prefill(
     # clamping them onto a real block would collide with a valid write at the
     # same slot, and duplicate-index scatter order is undefined
     phys = jnp.where(valid, phys, nb)
-    flat_phys = phys.reshape(-1)
-    flat_off = jnp.broadcast_to(off, phys.shape).reshape(-1)
-    if key_scale is not None:
-        qk, sk = _quantize_kv_rows(k.reshape(b * s, h, d))
-        qv, sv = _quantize_kv_rows(v.reshape(b * s, h, d))
-        key_cache = key_cache.at[flat_phys, :, flat_off].set(qk, mode="drop")
-        value_cache = value_cache.at[flat_phys, :, flat_off].set(qv, mode="drop")
-        key_scale = key_scale.at[flat_phys, :, flat_off].set(sk, mode="drop")
-        value_scale = value_scale.at[flat_phys, :, flat_off].set(sv, mode="drop")
-        return key_cache, value_cache, key_scale, value_scale
-    flat_k = k.reshape(b * s, h, d).astype(key_cache.dtype)
-    flat_v = v.reshape(b * s, h, d).astype(value_cache.dtype)
-    key_cache = key_cache.at[flat_phys, :, flat_off].set(flat_k, mode="drop")
-    value_cache = value_cache.at[flat_phys, :, flat_off].set(flat_v, mode="drop")
-    return key_cache, value_cache
+    return _scatter_kv_rows(
+        key_cache, value_cache, k, v, phys, jnp.broadcast_to(off, phys.shape), key_scale, value_scale
+    )
 
 
 @jax.named_scope(SCOPE_KV_COW)
@@ -522,30 +436,12 @@ def block_cache_cow_copy(
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     csrc = jnp.clip(src, 0, nb - 1)
+    planes = tuple(p for p in (key_cache, value_cache, key_scale, value_scale) if p is not None)
 
-    if key_scale is not None:
-        def _copy4(kv):
-            kc, vc, ks, vs = kv
-            kc = kc.at[dst].set(kc[csrc], mode="drop")
-            vc = vc.at[dst].set(vc[csrc], mode="drop")
-            ks = ks.at[dst].set(ks[csrc], mode="drop")
-            vs = vs.at[dst].set(vs[csrc], mode="drop")
-            return kc, vc, ks, vs
+    def _copy(planes):
+        return tuple(p.at[dst].set(p[csrc], mode="drop") for p in planes)
 
-        return jax.lax.cond(
-            jnp.any(dst < nb), _copy4, lambda kv: kv,
-            (key_cache, value_cache, key_scale, value_scale),
-        )
-
-    def _copy(kv):
-        kc, vc = kv
-        kc = kc.at[dst].set(kc[csrc], mode="drop")
-        vc = vc.at[dst].set(vc[csrc], mode="drop")
-        return kc, vc
-
-    return jax.lax.cond(
-        jnp.any(dst < nb), _copy, lambda kv: kv, (key_cache, value_cache)
-    )
+    return jax.lax.cond(jnp.any(dst < nb), _copy, lambda planes: planes, planes)
 
 
 @jax.named_scope(SCOPE_KV_WRITE)
@@ -563,13 +459,14 @@ def block_cache_append_chunk(
 ):
     """Scatter a ragged chunk of new KV per sequence into its physical
     blocks: token ``j`` of sequence ``b`` lands at logical position
-    ``seq_lens[b] + j``. Rows past ``q_lens`` (and masked-off slots) are
-    routed out of bounds and dropped — a decode row (``q_lens == 1``) and a
-    prompt-chunk row (``q_lens == C``) ride the same scatter. With scale
-    planes the write quantizes in-flight per token row (returns 4 arrays):
-    the scale scatter uses the SAME out-of-bounds routing, so dropped KV rows
-    drop their scales with them."""
-    b, c, h, d = k.shape
+    ``seq_lens[b] + j``. Rows past ``q_lens`` (and masked-off slots: their
+    block-table row may alias blocks owned by live sequences) are routed out
+    of bounds and dropped — a decode row (``q_lens == 1``) and a prompt-chunk
+    row (``q_lens == C``) ride the same scatter. With scale planes the write
+    quantizes in-flight per token row (returns 4 arrays): the scale scatter
+    uses the SAME out-of-bounds routing, so dropped KV rows drop their scales
+    with them."""
+    c = k.shape[1]
     nb, bs = key_cache.shape[0], key_cache.shape[2]
     j = jnp.arange(c)[None, :]  # [1, C]
     pos = seq_lens[:, None] + j  # [B, C] absolute token index
@@ -583,21 +480,7 @@ def block_cache_append_chunk(
     # them onto a real block would collide with valid writes (duplicate-index
     # scatter order is undefined), exactly the block_cache_prefill rule
     phys = jnp.where(valid, phys, nb)
-    flat_phys = phys.reshape(-1)
-    flat_off = off.reshape(-1)
-    if key_scale is not None:
-        qk, sk = _quantize_kv_rows(k.reshape(b * c, h, d))
-        qv, sv = _quantize_kv_rows(v.reshape(b * c, h, d))
-        key_cache = key_cache.at[flat_phys, :, flat_off].set(qk, mode="drop")
-        value_cache = value_cache.at[flat_phys, :, flat_off].set(qv, mode="drop")
-        key_scale = key_scale.at[flat_phys, :, flat_off].set(sk, mode="drop")
-        value_scale = value_scale.at[flat_phys, :, flat_off].set(sv, mode="drop")
-        return key_cache, value_cache, key_scale, value_scale
-    flat_k = k.reshape(b * c, h, d).astype(key_cache.dtype)
-    flat_v = v.reshape(b * c, h, d).astype(value_cache.dtype)
-    key_cache = key_cache.at[flat_phys, :, flat_off].set(flat_k, mode="drop")
-    value_cache = value_cache.at[flat_phys, :, flat_off].set(flat_v, mode="drop")
-    return key_cache, value_cache
+    return _scatter_kv_rows(key_cache, value_cache, k, v, phys, off, key_scale, value_scale)
 
 
 def _gather_chunk_attend(
@@ -668,6 +551,8 @@ def block_multihead_chunk_attention(
     slot_mask: Optional[jax.Array] = None,  # [B] bool; False = padded slot
     key_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
     value_scale: Optional[jax.Array] = None,
+    cos: Optional[jax.Array] = None,  # [B, C, 1, D] offset-gathered rope rows
+    sin: Optional[jax.Array] = None,  # (model layout); given: q and k come PRE-rope
 ):
     """One MIXED prefill/decode step over the paged cache — the chunked-
     prefill dispatch ("Ragged Paged Attention", arxiv 2604.15464): every
@@ -678,46 +563,50 @@ def block_multihead_chunk_attention(
     history before it. Rows past ``q_lens`` and masked-off slots return
     exactly zeros (lockstep with the Pallas kernel's skip).
 
+    With ``cos`` / ``sin`` (the serving step's path) RoPE is folded in: k is
+    rotated by the XLA elementwise composition (it fuses into the
+    cache-append scatter) and q's rotation moves INSIDE the paged kernel's
+    page walk, so a layer's rope pass + attention are one kernel dispatch.
+    The XLA fallback stays in lockstep by applying the identical
+    ``_rope_apply_xla`` to q before the shared dense-gather attention.
+
     Returns ``(out [B, C, HQ, D], key_cache, value_cache)``, plus the
     updated ``(key_scale, value_scale)`` planes when given (the int8 pool:
-    quantize-on-write in the same fused append, dequant inside the kernel's
-    block walk — or the identical composition in the XLA fallback).
+    quantize-on-write in the same fused append, AFTER the rope — the cache
+    stores roped, quantized keys — and dequant inside the kernel's page
+    walk, or the identical composition in the XLA fallback).
     """
+    from paddle_tpu.incubate.nn.functional import _rope_apply_xla
+    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
+
     b, c, hq, d = q.shape
-    hkv = k.shape[2]
     if scale is None:
         scale = 1.0 / (d**0.5)
-    quantized = key_scale is not None
-    if quantized:
-        key_cache, value_cache, key_scale, value_scale = block_cache_append_chunk(
-            key_cache, value_cache, k, v, block_tables, seq_lens, q_lens,
-            slot_mask=slot_mask, key_scale=key_scale, value_scale=value_scale,
-        )
-    else:
-        key_cache, value_cache = block_cache_append_chunk(
-            key_cache, value_cache, k, v, block_tables, seq_lens, q_lens,
-            slot_mask=slot_mask,
-        )
+    rope, quantized = cos is not None, key_scale is not None
+    if rope:
+        k = _rope_apply_xla(k, sin, cos, True)
+    planes = block_cache_append_chunk(
+        key_cache, value_cache, k, v, block_tables, seq_lens, q_lens,
+        slot_mask=slot_mask, key_scale=key_scale, value_scale=value_scale,
+    )
+    key_cache, value_cache, *scales = planes
+    key_scale, value_scale = scales or (None, None)
     attend_q = q_lens
     if slot_mask is not None:
         attend_q = jnp.where(slot_mask, attend_q, 0)
-    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
-
-    def _ret(out):
-        if quantized:
-            return out, key_cache, value_cache, key_scale, value_scale
-        return out, key_cache, value_cache
-
     if pallas_enabled("use_pallas_paged_attention"):
         # ragged mixed prefill/decode kernel: one grid walks each sequence's
-        # physical blocks once, serving its decode row and its prompt-chunk
-        # rows alike. The kernel is REQUIRED to compile on TPU
+        # live pages once, serving its decode row and its prompt-chunk rows
+        # alike. The kernel is REQUIRED to compile on TPU
         # (tests/test_tpu_aot_compile.py): only a trace-time failure degrades
         # to the XLA path below. Under a tensor-parallel mesh the kernel runs
         # shard_mapped over the head partition.
         from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
         tp_mesh = shard_group_mesh()
+        walk = dict(k_scale=key_scale, v_scale=value_scale)
+        if rope:
+            walk.update(cos=cos.reshape(b, c, d), sin=sin.reshape(b, c, d))
         try:
             if quantized:
                 # injected dequant failure degrades THIS dispatch to the
@@ -727,114 +616,29 @@ def block_multihead_chunk_attention(
             if tp_mesh is not None:
                 out = _tp_sharded_flash_chunk(
                     q, key_cache, value_cache, block_tables,
-                    seq_lens, attend_q, scale, tp_mesh,
-                    k_scale=key_scale, v_scale=value_scale,
+                    seq_lens, attend_q, scale, tp_mesh, **walk,
                 )
             else:
                 out = paged_flash_chunk(
                     q, key_cache, value_cache, block_tables,
-                    seq_lens, attend_q, scale=scale,
-                    k_scale=key_scale, v_scale=value_scale,
+                    seq_lens, attend_q, scale=scale, **walk,
                 )
-            return _ret(out)
+            return (out,) + planes
         except Exception as exc:  # noqa: BLE001 - XLA fallback below
-            warn_fallback("paged_flash_chunk", exc)
-    out = _gather_chunk_attend(
-        q, key_cache, value_cache, block_tables, seq_lens, attend_q, scale,
-        k_scale=key_scale, v_scale=value_scale,
-    )
-    return _ret(out)
-
-
-def block_multihead_chunk_attention_fused(
-    q: jax.Array,  # [B, C, HQ, D] PRE-rope ragged chunk of new tokens
-    k: jax.Array,  # [B, C, HKV, D] PRE-rope new keys
-    v: jax.Array,
-    cos: jax.Array,  # [B, C, 1, D] offset-gathered rope rows (model layout)
-    sin: jax.Array,
-    key_cache: jax.Array,  # [NB, HKV, BS, D]
-    value_cache: jax.Array,
-    block_tables: jax.Array,  # [B, MBS] int32
-    seq_lens: jax.Array,  # [B] tokens already cached (EXCLUDING this chunk)
-    q_lens: jax.Array,  # [B] valid new tokens this step (1 = decode row)
-    scale: Optional[float] = None,
-    slot_mask: Optional[jax.Array] = None,  # [B] bool; False = padded slot
-    key_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
-    value_scale: Optional[jax.Array] = None,
-):
-    """:func:`block_multihead_chunk_attention` with RoPE folded in — the
-    fused decode layer's attention entry (``FLAGS_use_fused_decode_layer``).
-
-    Takes PRE-rope q/k plus the per-slot rope rows and collapses the layer's
-    rope pass + attention to one kernel dispatch: k is rotated by the same
-    XLA elementwise composition the unfused path uses (it fuses into the
-    cache-append scatter), while q's rotation moves INSIDE the paged kernel's
-    block walk. The XLA fallback stays in lockstep by applying the identical
-    ``_rope_apply_xla`` to q before the shared dense-gather attention — so on
-    a backend without the kernel (CPU reference), fused on/off execute the
-    SAME op composition and outputs are byte-identical by construction.
-    Scale planes follow the :func:`block_multihead_chunk_attention` contract
-    (quantize AFTER the rope — the cache stores roped, quantized keys).
-    """
-    from paddle_tpu.incubate.nn.functional import _rope_apply_xla
-
-    b, c, hq, d = q.shape
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    quantized = key_scale is not None
-    k = _rope_apply_xla(k, sin, cos, True)
-    if quantized:
-        key_cache, value_cache, key_scale, value_scale = block_cache_append_chunk(
-            key_cache, value_cache, k, v, block_tables, seq_lens, q_lens,
-            slot_mask=slot_mask, key_scale=key_scale, value_scale=value_scale,
-        )
-    else:
-        key_cache, value_cache = block_cache_append_chunk(
-            key_cache, value_cache, k, v, block_tables, seq_lens, q_lens,
-            slot_mask=slot_mask,
-        )
-    attend_q = q_lens
-    if slot_mask is not None:
-        attend_q = jnp.where(slot_mask, attend_q, 0)
-    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
-
-    def _ret(out):
-        if quantized:
-            return out, key_cache, value_cache, key_scale, value_scale
-        return out, key_cache, value_cache
-
-    if pallas_enabled("use_pallas_paged_attention"):
-        from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused
-
-        tp_mesh = shard_group_mesh()
-        cos3 = cos.reshape(b, c, d)
-        sin3 = sin.reshape(b, c, d)
-        try:
-            if quantized:
-                _fault_point("quant.dequant")
-            if tp_mesh is not None:
-                out = _tp_sharded_flash_chunk_fused(
-                    q, cos3, sin3, key_cache, value_cache, block_tables,
-                    seq_lens, attend_q, scale, tp_mesh,
-                    k_scale=key_scale, v_scale=value_scale,
-                )
+            # the label is the counter's series: the names the two entries had
+            if rope:
+                warn_fallback("paged_flash_chunk_fused", exc)
             else:
-                out = paged_flash_chunk_fused(
-                    q, cos3, sin3, key_cache, value_cache, block_tables,
-                    seq_lens, attend_q, scale=scale,
-                    k_scale=key_scale, v_scale=value_scale,
-                )
-            return _ret(out)
-        except Exception as exc:  # noqa: BLE001 - XLA fallback below
-            warn_fallback("paged_flash_chunk_fused", exc)
-    # lockstep fallback: the SAME rope composition the unfused path applies,
-    # then the shared dense-gather attention
-    q = _rope_apply_xla(q, sin, cos, True)
+                warn_fallback("paged_flash_chunk", exc)
+    if rope:
+        # lockstep fallback: the SAME rope composition, then the shared
+        # dense-gather attention
+        q = _rope_apply_xla(q, sin, cos, True)
     out = _gather_chunk_attend(
         q, key_cache, value_cache, block_tables, seq_lens, attend_q, scale,
         k_scale=key_scale, v_scale=value_scale,
     )
-    return _ret(out)
+    return (out,) + planes
 
 
 def block_multihead_attention(
@@ -850,150 +654,10 @@ def block_multihead_attention(
     key_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
     value_scale: Optional[jax.Array] = None,
 ):
-    """One paged-cache decode step: append the new KV, attend over the
-    sequence's blocks. Returns ``(out [B, 1, HQ, D], key_cache, value_cache)``
-    — pass donated caches under jit for true in-place update (the reference
-    op is declared ``inplace``) — plus the updated scale planes when given.
-
-    ``slot_mask`` is the continuous-batching engine's ragged-batch contract:
-    masked-off slots append nothing, attend over nothing (their effective
-    length is forced to 0 so the ragged kernel skips them entirely), and
-    return exactly zeros — in lockstep between the Pallas kernel and this XLA
-    fallback so slot padding never changes numerics."""
-    b, one, hq, d = q.shape
-    hkv = k.shape[2]
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    quantized = key_scale is not None
-    if quantized:
-        key_cache, value_cache, key_scale, value_scale = block_cache_append(
-            key_cache, value_cache, k[:, 0], v[:, 0], block_tables, seq_lens,
-            slot_mask=slot_mask, key_scale=key_scale, value_scale=value_scale,
-        )
-    else:
-        key_cache, value_cache = block_cache_append(
-            key_cache, value_cache, k[:, 0], v[:, 0], block_tables, seq_lens,
-            slot_mask=slot_mask,
-        )
-    # length INCLUDING the freshly appended token; 0 for padded slots
-    attend_lens = seq_lens + 1
-    if slot_mask is not None:
-        attend_lens = jnp.where(slot_mask, attend_lens, 0)
-    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
-
-    def _ret(out):
-        if quantized:
-            return out, key_cache, value_cache, key_scale, value_scale
-        return out, key_cache, value_cache
-
-    if pallas_enabled("use_pallas_paged_attention", bare="paged_flash_decode"):
-        # block-table flash-decode kernel: streams only this sequence's
-        # physical blocks HBM -> VMEM (no dense [B, MBS*BS, H, D] gather);
-        # only a trace-time failure degrades to the XLA path below
-        from paddle_tpu.kernels.paged_attention import paged_flash_decode
-
-        try:
-            if quantized:
-                _fault_point("quant.dequant")
-            out = paged_flash_decode(
-                q[:, 0], key_cache, value_cache, block_tables,
-                attend_lens,  # kernel masks pos < len INCLUDING this token
-                scale=scale,
-                k_scale=key_scale, v_scale=value_scale,
-            )
-            return _ret(out[:, None])
-        except Exception as exc:  # noqa: BLE001 - XLA fallback below
-            warn_fallback("paged_flash_decode", exc)
-    # the decode step IS the C == 1 chunk: one new row per sequence whose
-    # causal limit is seq_lens + 1 (attend_lens), masked slots exact zeros
-    out = _gather_chunk_attend(
-        q, key_cache, value_cache, block_tables, seq_lens,
-        attend_lens - seq_lens, scale,
-        k_scale=key_scale, v_scale=value_scale,
+    """The reference API's name (``block_multihead_attention_``,
+    ``fused_ops.yaml:45``) for one paged decode step: the ``C == 1`` case of
+    :func:`block_multihead_chunk_attention`, every sequence one new token."""
+    return block_multihead_chunk_attention(
+        q, k, v, key_cache, value_cache, block_tables, seq_lens, jnp.ones_like(seq_lens),
+        scale=scale, slot_mask=slot_mask, key_scale=key_scale, value_scale=value_scale,
     )
-    return _ret(out.astype(q.dtype))
-
-
-def block_multihead_attention_fused(
-    q: jax.Array,  # [B, 1, HQ, D] PRE-rope decode query
-    k: jax.Array,  # [B, 1, HKV, D] PRE-rope new key
-    v: jax.Array,  # [B, 1, HKV, D] new value
-    cos: jax.Array,  # [B, 1, 1, D] offset-gathered rope rows (model layout)
-    sin: jax.Array,
-    key_cache: jax.Array,  # [NB, HKV, BS, D]
-    value_cache: jax.Array,
-    block_tables: jax.Array,  # [B, MBS] int32
-    seq_lens: jax.Array,  # [B] tokens already cached (EXCLUDING this one)
-    scale: Optional[float] = None,
-    slot_mask: Optional[jax.Array] = None,  # [B] bool; False = padded slot
-    key_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
-    value_scale: Optional[jax.Array] = None,
-):
-    """:func:`block_multihead_attention` with RoPE folded in — the pure-decode
-    counterpart of :func:`block_multihead_chunk_attention_fused`.
-
-    Takes PRE-rope q/k plus the per-slot rope rows: k is rotated by the same
-    XLA elementwise composition the unfused path uses (it fuses into the
-    cache-append scatter) while q's rotation moves INSIDE the flash-decode
-    block walk (``paged_flash_decode_fused``). The XLA fallback applies the
-    identical ``_rope_apply_xla`` to q before the shared dense-gather
-    attention, so fused on/off execute the same op composition off-TPU and
-    outputs are byte-identical by construction.
-    """
-    from paddle_tpu.incubate.nn.functional import _rope_apply_xla
-
-    b, one, hq, d = q.shape
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    quantized = key_scale is not None
-    k = _rope_apply_xla(k, sin, cos, True)
-    if quantized:
-        key_cache, value_cache, key_scale, value_scale = block_cache_append(
-            key_cache, value_cache, k[:, 0], v[:, 0], block_tables, seq_lens,
-            slot_mask=slot_mask, key_scale=key_scale, value_scale=value_scale,
-        )
-    else:
-        key_cache, value_cache = block_cache_append(
-            key_cache, value_cache, k[:, 0], v[:, 0], block_tables, seq_lens,
-            slot_mask=slot_mask,
-        )
-    # length INCLUDING the freshly appended token; 0 for padded slots
-    attend_lens = seq_lens + 1
-    if slot_mask is not None:
-        attend_lens = jnp.where(slot_mask, attend_lens, 0)
-    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
-
-    def _ret(out):
-        if quantized:
-            return out, key_cache, value_cache, key_scale, value_scale
-        return out, key_cache, value_cache
-
-    if pallas_enabled("use_pallas_paged_attention", bare="paged_flash_decode_fused"):
-        # rope-fused flash-decode kernel; same contract as the unfused
-        # decode dispatch above
-        from paddle_tpu.kernels.paged_attention import paged_flash_decode_fused
-
-        cos3 = cos.reshape(b, 1, d)
-        sin3 = sin.reshape(b, 1, d)
-        try:
-            if quantized:
-                _fault_point("quant.dequant")
-            out = paged_flash_decode_fused(
-                q[:, 0], cos3, sin3, key_cache, value_cache,
-                block_tables,
-                attend_lens,  # kernel masks pos < len INCLUDING this token
-                scale=scale,
-                k_scale=key_scale, v_scale=value_scale,
-            )
-            return _ret(out[:, None])
-        except Exception as exc:  # noqa: BLE001 - XLA fallback below
-            warn_fallback("paged_flash_decode_fused", exc)
-    # lockstep fallback: the SAME rope composition the unfused path applies,
-    # then the shared dense-gather attention (C == 1 chunk)
-    q = _rope_apply_xla(q, sin, cos, True)
-    out = _gather_chunk_attend(
-        q, key_cache, value_cache, block_tables, seq_lens,
-        attend_lens - seq_lens, scale,
-        k_scale=key_scale, v_scale=value_scale,
-    )
-    return _ret(out.astype(q.dtype))

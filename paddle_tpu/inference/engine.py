@@ -113,6 +113,7 @@ import numpy as np
 from paddle_tpu.core.spmd import partitioned_trace
 from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.inference.kv_tier import HostKVTier, HostNode
+from paddle_tpu.inference.paged_kv import PagedBatch, PagedKV
 from paddle_tpu.inference.prefix_cache import ChainNode, PrefixCache, chain_digest
 from paddle_tpu.inference.spec_decode import NGramDrafter, count_accepted
 from paddle_tpu.observability import devprof as _devprof
@@ -805,13 +806,7 @@ class ContinuousBatchingEngine:
                     self._shard_zeros_scale(), self._shard_zeros_scale(),
                 )
             return self._shard_zeros(), self._shard_zeros()
-        kc = jnp.zeros(self._cache_shape, self._cache_dtype)
-        vc = jnp.zeros(self._cache_shape, self._cache_dtype)
-        if self._quant_kv:
-            ks = jnp.ones(self._scale_shape, jnp.float32)
-            vs = jnp.ones(self._scale_shape, jnp.float32)
-            return kc, vc, ks, vs
-        return kc, vc
+        return PagedKV.zeros(self._cache_shape, self._cache_dtype).planes
 
     @property
     def tp_degree(self) -> int:
@@ -1233,7 +1228,6 @@ class ContinuousBatchingEngine:
         :meth:`step_logits` hands back for numeric comparison."""
         import paddle_tpu
         from paddle_tpu.core.tensor import Tensor
-        from paddle_tpu.incubate.nn.functional import block_cache_cow_copy
         from paddle_tpu.nn.layer.layers import (
             bind_param_arrays,
             bind_quant_scales,
@@ -1244,50 +1238,18 @@ class ContinuousBatchingEngine:
         with bind_param_arrays(self._named, weights), bind_quant_scales(
             self._wq_params, wq_scales
         ):
-            if self._quant_kv:
-                # scale planes ride the same CoW fork set as their payload:
-                # a forked block gets its source's scales in the same step
-                forked = [
-                    block_cache_cow_copy(
-                        kc, vc, cow_src, cow_dst,
-                        key_scale=ks, value_scale=vs,
-                    )
-                    for kc, vc, ks, vs in caches
-                ]
-                pkv = [
-                    (
-                        Tensor(kc), Tensor(vc), Tensor(tables), Tensor(lens),
-                        Tensor(active), Tensor(q_lens),
-                        Tensor(ks), Tensor(vs),
-                    )
-                    for kc, vc, ks, vs in forked
-                ]
-            else:
-                forked = [
-                    block_cache_cow_copy(kc, vc, cow_src, cow_dst)
-                    for kc, vc in caches
-                ]
-                pkv = [
-                    (
-                        Tensor(kc), Tensor(vc), Tensor(tables), Tensor(lens),
-                        Tensor(active), Tensor(q_lens),
-                    )
-                    for kc, vc in forked
-                ]
+            # one batch a step, shared by every KV set; a set is its planes
+            # (scales included, when the pool is quantized) under that batch.
+            # Scale planes ride the same CoW fork set as their payload: a
+            # forked block gets its source's scales in the same step
+            batch = PagedBatch(tables, lens, active, q_lens)
+            pkv = [
+                PagedKV(*planes, batch=batch).fork(cow_src, cow_dst)
+                for planes in caches
+            ]
             with paddle_tpu.no_grad():
-                logits, new_pkv = self.model(
-                    Tensor(toks),
-                    past_key_values=pkv,
-                    use_cache=True,
-                    cache_position=Tensor(lens),
-                )
-            if self._quant_kv:
-                # quantized pasts are 8-tuples; scales come back at 6/7
-                return logits._data, [
-                    (c[0]._data, c[1]._data, c[6]._data, c[7]._data)
-                    for c in new_pkv
-                ]
-            return logits._data, [(c[0]._data, c[1]._data) for c in new_pkv]
+                logits, new_pkv = self.model(Tensor(toks), past_key_values=pkv, use_cache=True)
+            return logits._data, [kv.planes for kv in new_pkv]
 
     def step_logits(self, prompt: Any) -> np.ndarray:
         """fp32 logits ``[n, V]`` of the step's own body (``_step_forward``)
@@ -1325,15 +1287,10 @@ class ContinuousBatchingEngine:
         ``[C, V]`` fp32 logits of ``_step_forward`` over a scratch pool."""
         mbs = self.max_blocks_per_seq
 
-        def scratch_pool():
-            kv = jnp.zeros((mbs,) + self._cache_shape[1:], self._cache_dtype)
-            if not self._quant_kv:
-                return kv, kv
-            scale = jnp.ones((mbs,) + self._scale_shape[1:], jnp.float32)
-            return kv, kv, scale, scale
+        scratch = (mbs,) + self._cache_shape[1:]
 
         def run(param_arrays, *step_args):
-            caches = [scratch_pool() for _ in range(self._num_kv_sets)]
+            caches = [PagedKV.zeros(scratch, self._cache_dtype).planes for _ in range(self._num_kv_sets)]
             logits, _ = self._step_forward(param_arrays, caches, *step_args)
             return logits[0].astype(jnp.float32)
 

@@ -13,9 +13,9 @@ engine and a quantized engine, and the delta is
   full-forward on the same seeded prompts, isolating the weight-only int8
   projections from the KV path.
 
-Both bench records (``bench.py``) and the tier-1 tolerance tests
-(``tests/test_quantized_kv.py``) call this module, so the number the CI
-gate enforces is the number the bench reports.
+The tier-1 tolerance tests (``tests/test_quantized_kv.py``) call this
+module; on the chip the benchmark's int8 controls (``benchmarks/control.py``)
+hold the same paths to the float32 reference instead.
 """
 
 from __future__ import annotations
@@ -131,7 +131,7 @@ def quality_delta(
     kv_cache_dtype: str = "int8",
     weight_only_int8: bool = True,
 ) -> Dict[str, Any]:
-    """The full measured delta a bench record (or the tier-1 gate) carries:
+    """The full measured delta the tier-1 gate carries:
     token-match rate through the engines, max logit error through a direct
     forward, and the effective KV bytes/token of both configurations (the
     reduction factor the tentpole promises)."""

@@ -39,46 +39,7 @@ __all__ = [
     "fused_layer_norm_residual_pallas",
     "layer_norm_residual_adjoint_pallas",
     "fused_embed_rms_norm_pallas",
-    "arm_dispatch_probe",
-    "disarm_dispatch_probe",
-    "count_dispatch",
 ]
-
-
-# ---------------------------------------------------------------------------
-# Trace-time dispatch probe
-# ---------------------------------------------------------------------------
-#
-# The fused-decode-layer work exists to cut dispatches per layer per step, so
-# the win must be observable: model code calls ``count_dispatch(site)`` at
-# every kernel-dispatch site of the paged serving path (both the fused and
-# the unfused variants). The calls run at TRACE time only — the Python body
-# of a jitted step executes once per compile, the same property the engine's
-# ``step_traces`` counter rides — so an armed probe records exactly one count
-# per dispatch site per compiled program, and a disarmed probe costs one
-# ``is None`` check. Tests and bench.py arm it around an engine's first step.
-
-_DISPATCH_PROBE: Optional[dict] = None
-
-
-def arm_dispatch_probe() -> None:
-    """Start recording dispatch sites (clears any previous counts)."""
-    global _DISPATCH_PROBE
-    _DISPATCH_PROBE = {}
-
-
-def disarm_dispatch_probe() -> dict:
-    """Stop recording; returns {site: count} seen since arming."""
-    global _DISPATCH_PROBE
-    out = _DISPATCH_PROBE or {}
-    _DISPATCH_PROBE = None
-    return out
-
-
-def count_dispatch(site: str) -> None:
-    """Record one dispatch-site hit (no-op unless the probe is armed)."""
-    if _DISPATCH_PROBE is not None:
-        _DISPATCH_PROBE[site] = _DISPATCH_PROBE.get(site, 0) + 1
 
 
 def _rms_fwd_kernel(x_ref, w_ref, y_ref, rstd_ref, *, eps):

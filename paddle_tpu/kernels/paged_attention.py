@@ -1,26 +1,23 @@
-"""Pallas TPU paged-attention kernels.
+"""The Pallas TPU paged-attention kernel.
 
-Replace the dense-gather XLA path of
+Replaces the dense-gather XLA path of
 ``incubate/nn/functional/block_attention.py`` (reference CUDA kernel:
-``paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu``) with
-block-table-aware flash kernels: only a sequence's own physical KV blocks are
-streamed HBM -> VMEM (never the dense ``[B, MBS*BS, H, D]`` gather), and an
-online softmax accumulates in fp32 VMEM scratch. Grouped-query attention keeps
-the G query heads of one KV head together as the kernel's row dimension.
+``paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu``) with a
+block-table-aware flash kernel: only a sequence's own LIVE physical KV pages
+are streamed HBM -> VMEM (never the dense ``[B, MBS*BS, H, D]`` gather), in a
+loop inside the kernel bounded by the sequence's length, and an online softmax
+accumulates in fp32 VMEM scratch. Grouped-query attention keeps the G query
+heads of one KV head together as the kernel's row dimension. One body and one
+``pallas_call`` serve the engine's mixed prefill/decode step and a plain
+decode step (the chunk at ``C == 1``).
 
-The chunk kernels (the engine's one step signature) walk a sequence's LIVE
-pages in a loop inside the kernel, bounded by its length (below). The decode
-kernels (``generate_paged``, ``block_multihead_attention*``) still spend one
-grid step a logical block, the scalar-prefetched block table steering the
-BlockSpec index map.
-
-Quantized KV (``FLAGS_kv_cache_dtype=int8``): every kernel accepts optional
-``k_scale``/``v_scale`` planes (``[NB, HKV, BS]`` fp32 — per block, per head,
-per token slot, addressed by the SAME block ids the KV planes use). The dequant
-epilogue lives inside the block walk: int8 loads, one fp32 multiply per page
-tile, fp32 accumulate — no dequantized copy of the cache ever materializes.
-The dequant composition (``x.astype(f32) * scale``) is the byte-for-byte op
-sequence the XLA gather fallback applies, keeping the two paths in lockstep.
+Quantized KV (``FLAGS_kv_cache_dtype=int8``): optional ``k_scale``/``v_scale``
+planes (``[NB, HKV, BS]`` fp32 — per block, per head, per token slot, addressed
+by the SAME block ids the KV planes use). The dequant epilogue lives inside the
+page walk: int8 loads, one fp32 multiply per page tile, fp32 accumulate — no
+dequantized copy of the cache ever materializes. The dequant composition
+(``x.astype(f32) * scale``) is the byte-for-byte op sequence the XLA gather
+fallback applies, keeping the two paths in lockstep.
 """
 
 from __future__ import annotations
@@ -35,186 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 # pallas_call name= of each kernel here: what a device trace calls it (stable, no shapes)
-KERNEL_DECODE = "paged_attention_decode"
 KERNEL_CHUNK = "paged_attention_chunk"
-KERNEL_DECODE_FUSED = "paged_attention_decode_fused"
-KERNEL_CHUNK_FUSED = "paged_attention_chunk_fused"
-
-
-def _dequant_tile(k_ref, v_ref, ks_ref, vs_ref):
-    """The in-walk dequant epilogue shared by every paged kernel: one fp32
-    multiply per (BS, D) tile against this block's per-token scale rows. The
-    scale planes ride as [NB, HKV, BS, 1] (the trailing 1 keeps the (1, 1,
-    bs, 1) block legal under the TPU last-two-dims tiling rule), so the
-    [BS, 1] tile broadcasts over D. With no scale refs this is the plain
-    fp32 upcast — the bf16 path's op sequence, untouched."""
-    k = k_ref[0, 0].astype(jnp.float32)  # [BS, D]
-    v = v_ref[0, 0].astype(jnp.float32)
-    if ks_ref is not None:
-        k = k * ks_ref[0, 0].astype(jnp.float32)  # [BS, 1] broadcast over D
-        v = v * vs_ref[0, 0].astype(jnp.float32)
-    return k, v
-
-
-def _decode_kernel(
-    tables_ref,  # scalar prefetch: [B, MBS] int32
-    lens_ref,  # scalar prefetch: [B] int32 (length INCLUDING current token)
-    q_ref,  # [1, 1, G, D]
-    k_ref,  # [1, 1, BS, D] this logical block's physical KV (one head)
-    v_ref,
-    *rest,  # quantized: ks_ref, vs_ref [1, 1, BS] then outputs/scratch
-    scale: float,
-    block_size: int,
-    num_blocks: int,
-    quantized: bool = False,
-):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
-    bi = pl.program_id(0)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # ragged skip: a block whose first position is already past this
-    # sequence's length contributes nothing (its p would be masked to 0), so
-    # the MXU work is predicated away entirely. A fully-padded slot
-    # (len == 0) never takes this branch at all — the engine's inactive batch
-    # slots cost no compute, only the final zero-write below.
-    @pl.when(i * block_size < lens_ref[bi])
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [G, D]
-        k, v = _dequant_tile(k_ref, v_ref, ks_ref, vs_ref)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [G, BS]
-        pos = i * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-        valid = pos < lens_ref[bi]
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[...]  # [G, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        # the explicit valid multiply keeps fully-masked rows at p == 0: with
-        # every position masked, m_new == NEG_INF and exp(s - m_new) would be
-        # 1 everywhere — silent garbage for zero-length sequences
-        p = jnp.exp(s - m_new) * valid.astype(jnp.float32)  # [G, BS]
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
-
-    @pl.when(i == num_blocks - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
-
-
-def paged_flash_decode(
-    q: jax.Array,  # [B, HQ, D]
-    key_cache: jax.Array,  # [NB, HKV, BS, D]
-    value_cache: jax.Array,
-    block_tables: jax.Array,  # [B, MBS] int32
-    seq_lens: jax.Array,  # [B] length INCLUDING the current token
-    scale: Optional[float] = None,
-    interpret: bool = False,
-    k_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Flash decode over the paged cache. Returns ``[B, HQ, D]``."""
-    b, hq, d = q.shape
-    nb, hkv, bs, _ = key_cache.shape
-    mbs = block_tables.shape[1]
-    if hq % hkv != 0:
-        raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
-    g = hq // hkv
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    qg = q.reshape(b, hkv, g, d)
-    quantized = k_scale is not None
-
-    grid = (b, hkv, mbs)
-    kernel = functools.partial(
-        _decode_kernel, scale=float(scale), block_size=bs, num_blocks=mbs,
-        quantized=quantized,
-    )
-
-    def _kv_index(bi, hi, i, tables, lens):
-        # the block table steers which PHYSICAL block is streamed in; block
-        # (1, 1, BS, D) tiles the (BS, D) plane of one head. Logical blocks
-        # past the sequence's last in-use block are clamped onto that last
-        # block: the pipeline sees the same physical index as the previous
-        # grid step and skips the HBM->VMEM copy, so ragged tails (and fully
-        # padded slots, which clamp to block-table entry 0) cost no DMA
-        # traffic — the matching compute skip is the pl.when in the kernel.
-        last = jnp.maximum((lens[bi] + bs - 1) // bs - 1, 0)
-        return (tables[bi, jnp.minimum(i, last)], hi, 0, 0)
-
-    def _scale_index(bi, hi, i, tables, lens):
-        # the scale plane is addressed by the SAME physical block id
-        last = jnp.maximum((lens[bi] + bs - 1) // bs - 1, 0)
-        return (tables[bi, jnp.minimum(i, last)], hi, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda bi, hi, i, tables, lens: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d), _kv_index),
-        pl.BlockSpec((1, 1, bs, d), _kv_index),
-    ]
-    operands = [qg, key_cache, value_cache]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, bs, 1), _scale_index),
-            pl.BlockSpec((1, 1, bs, 1), _scale_index),
-        ]
-        operands += [k_scale[..., None], v_scale[..., None]]
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, g, d), lambda bi, hi, i, tables, lens: (bi, hi, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        # batch and kv-head cells are independent; the block walk accumulates
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-        name=KERNEL_DECODE,
-    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
-    return out.reshape(b, hq, d)
-
-# ---------------------------------------------------------------------------
-# Fused-epilogue variants: q-RoPE folded into the block walk
-# ---------------------------------------------------------------------------
-#
-# The decode step's unfused path ropes q in a separate XLA elementwise pass —
-# one extra HBM round-trip over [B, C, HQ, D] per layer just to feed the
-# attention kernel. The *_fused kernels take the per-slot cos/sin rows
-# (already offset-gathered, the per-batch tables the XLA path uses) as two
-# extra VMEM inputs and apply the rotation to the q block in-register before
-# the first dot. Numerics are LOCKSTEP with the unfused TPU path: the
-# rotation is computed in q's dtype (exactly ``_rope_apply_xla`` with
-# tables cast to x.dtype) and only THEN cast fp32 and scaled — so fused
-# on/off stay byte-identical. KV is roped before the cache append (cache
-# holds roped keys) in both modes; only q's rope moves into the kernel.
+KERNEL_CHUNK_FUSED = "paged_attention_chunk_fused"  # q-RoPE folded into the walk
 
 
 def _rope_rows(q, c, s, half):
@@ -223,154 +42,6 @@ def _rope_rows(q, c, s, half):
     q2 = q[..., half:]
     rot = jnp.concatenate([-q2, q1], axis=-1)
     return q * c + rot * s
-
-
-def _decode_fused_kernel(
-    tables_ref,  # scalar prefetch: [B, MBS] int32
-    lens_ref,  # scalar prefetch: [B] int32 (length INCLUDING current token)
-    q_ref,  # [1, 1, G, D] pre-rope q
-    cos_ref,  # [1, 1, D] this slot's rope row
-    sin_ref,
-    k_ref,  # [1, 1, BS, D]
-    v_ref,
-    *rest,  # quantized: ks_ref, vs_ref [1, 1, BS] then outputs/scratch
-    scale: float,
-    block_size: int,
-    num_blocks: int,
-    quantized: bool = False,
-):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
-    bi = pl.program_id(0)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(i * block_size < lens_ref[bi])
-    def _attend():
-        d = q_ref.shape[-1]
-        g_rows = q_ref.shape[2]
-        # materialize the [G, D] rope rows BEFORE the arithmetic — the same
-        # op order the chunk kernel and the XLA rope composition lower to
-        # (a [1, D] broadcast operand contracts differently and costs bitwise
-        # parity with the unfused path)
-        c = jnp.broadcast_to(cos_ref[0], (g_rows, d)).astype(q_ref.dtype)
-        s_t = jnp.broadcast_to(sin_ref[0], (g_rows, d)).astype(q_ref.dtype)
-        q = _rope_rows(q_ref[0, 0], c, s_t, d // 2)  # [G, D] in q.dtype
-        q = q.astype(jnp.float32) * scale
-        k, v = _dequant_tile(k_ref, v_ref, ks_ref, vs_ref)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        pos = i * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-        valid = pos < lens_ref[bi]
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
-
-    @pl.when(i == num_blocks - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
-
-
-def paged_flash_decode_fused(
-    q: jax.Array,  # [B, HQ, D] PRE-rope queries
-    cos: jax.Array,  # [B, 1, D] offset-gathered rope rows
-    sin: jax.Array,
-    key_cache: jax.Array,  # [NB, HKV, BS, D] (keys already roped on append)
-    value_cache: jax.Array,
-    block_tables: jax.Array,
-    seq_lens: jax.Array,
-    scale: Optional[float] = None,
-    interpret: bool = False,
-    k_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """:func:`paged_flash_decode` with q-RoPE folded into the block walk —
-    one dispatch replaces the rope pass + attention pair."""
-    b, hq, d = q.shape
-    nb, hkv, bs, _ = key_cache.shape
-    mbs = block_tables.shape[1]
-    if hq % hkv != 0:
-        raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
-    g = hq // hkv
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    qg = q.reshape(b, hkv, g, d)
-    quantized = k_scale is not None
-
-    kernel = functools.partial(
-        _decode_fused_kernel, scale=float(scale), block_size=bs, num_blocks=mbs,
-        quantized=quantized,
-    )
-
-    def _kv_index(bi, hi, i, tables, lens):
-        last = jnp.maximum((lens[bi] + bs - 1) // bs - 1, 0)
-        return (tables[bi, jnp.minimum(i, last)], hi, 0, 0)
-
-    def _scale_index(bi, hi, i, tables, lens):
-        last = jnp.maximum((lens[bi] + bs - 1) // bs - 1, 0)
-        return (tables[bi, jnp.minimum(i, last)], hi, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda bi, hi, i, tables, lens: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, 1, d), lambda bi, hi, i, tables, lens: (bi, 0, 0)),
-        pl.BlockSpec((1, 1, d), lambda bi, hi, i, tables, lens: (bi, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d), _kv_index),
-        pl.BlockSpec((1, 1, bs, d), _kv_index),
-    ]
-    operands = [qg, cos, sin, key_cache, value_cache]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, bs, 1), _scale_index),
-            pl.BlockSpec((1, 1, bs, 1), _scale_index),
-        ]
-        operands += [k_scale[..., None], v_scale[..., None]]
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, hkv, mbs),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, g, d), lambda bi, hi, i, tables, lens: (bi, hi, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-        name=KERNEL_DECODE_FUSED,
-    )(
-        block_tables.astype(jnp.int32),
-        seq_lens.astype(jnp.int32),
-        *operands,
-    )
-    return out.reshape(b, hq, d)
 
 
 # ---------------------------------------------------------------------------
@@ -627,7 +298,7 @@ def _paged_chunk_call(q, cos, sin, key_cache, value_cache, block_tables, seq_len
 
 def paged_flash_chunk(
     q: jax.Array,  # [B, C, HQ, D] ragged chunk (row j valid iff j < q_lens)
-    key_cache: jax.Array,  # [NB, HKV, BS, D] chunk KV ALREADY appended
+    key_cache: jax.Array,  # [NB, HKV, BS, D] chunk KV ALREADY appended (keys roped)
     value_cache: jax.Array,
     block_tables: jax.Array,  # [B, MBS] int32
     seq_lens: jax.Array,  # [B] tokens cached BEFORE the chunk
@@ -636,32 +307,14 @@ def paged_flash_chunk(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
+    cos: Optional[jax.Array] = None,  # [B, C, D] offset-gathered rope rows per
+    sin: Optional[jax.Array] = None,  # chunk token; given: q comes PRE-rope
 ) -> jax.Array:
     """Flash attention for one mixed prefill/decode step over the paged
-    cache. Returns ``[B, C, HQ, D]`` with rows past ``q_lens`` exactly 0."""
-    return _paged_chunk_call(
-        q, None, None, key_cache, value_cache, block_tables, seq_lens, q_lens,
-        scale, interpret, k_scale, v_scale, KERNEL_CHUNK,
-    )
-
-
-def paged_flash_chunk_fused(
-    q: jax.Array,  # [B, C, HQ, D] PRE-rope ragged chunk
-    cos: jax.Array,  # [B, C, D] offset-gathered rope rows per chunk token
-    sin: jax.Array,
-    key_cache: jax.Array,  # [NB, HKV, BS, D] (keys already roped on append)
-    value_cache: jax.Array,
-    block_tables: jax.Array,
-    seq_lens: jax.Array,  # [B] tokens cached BEFORE the chunk
-    q_lens: jax.Array,  # [B] valid new tokens (0 = inactive slot)
-    scale: Optional[float] = None,
-    interpret: bool = False,
-    k_scale: Optional[jax.Array] = None,  # [NB, HKV, BS] fp32 (int8 cache)
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """:func:`paged_flash_chunk` with q-RoPE folded into the page walk —
-    the decode layer's rope pass + attention collapse to ONE dispatch."""
+    cache. Returns ``[B, C, HQ, D]`` with rows past ``q_lens`` exactly 0.
+    With ``cos`` / ``sin`` q's RoPE is folded into the page walk: a decode
+    layer's rope pass + attention are ONE dispatch."""
     return _paged_chunk_call(
         q, cos, sin, key_cache, value_cache, block_tables, seq_lens, q_lens,
-        scale, interpret, k_scale, v_scale, KERNEL_CHUNK_FUSED,
+        scale, interpret, k_scale, v_scale, KERNEL_CHUNK if cos is None else KERNEL_CHUNK_FUSED,
     )

@@ -120,28 +120,21 @@ class ErnieLayer(nn.Layer):
         self.dropout = nn.Dropout(config.dropout)
 
     def forward(self, x: Tensor, attn_mask: Optional[Tensor] = None) -> Tensor:
-        from paddle_tpu.flags import GLOBAL_FLAGS
+        # Post-LN: the norm REPLACES the residual stream, so only the normed
+        # output of the fused residual-add + norm is consumed; its XLA
+        # fallback is the plain composition ``ln(x + branch)``
+        from paddle_tpu.incubate.nn.functional import fused_layer_norm_residual
 
-        if GLOBAL_FLAGS.get("use_fused_decode_layer"):
-            # Post-LN: the norm REPLACES the residual stream, so only the
-            # normed output of the fused op is consumed. ``a + b`` commutes
-            # bitwise under IEEE and the fallback is the exact unfused
-            # composition, so flag on/off stay byte-identical per backend.
-            from paddle_tpu.incubate.nn.functional import fused_layer_norm_residual
-
-            x, _ = fused_layer_norm_residual(
-                self.dropout(self.attn(x, attn_mask)),
-                self.ln_1.weight, self.ln_1.bias, x, self.ln_1.epsilon,
-            )
-            ffn = self.fc2(F.gelu(self.fc1(x)))
-            x, _ = fused_layer_norm_residual(
-                self.dropout(ffn), self.ln_2.weight, self.ln_2.bias, x,
-                self.ln_2.epsilon,
-            )
-            return x
-        x = self.ln_1(x + self.dropout(self.attn(x, attn_mask)))
+        x, _ = fused_layer_norm_residual(
+            self.dropout(self.attn(x, attn_mask)),
+            self.ln_1.weight, self.ln_1.bias, x, self.ln_1.epsilon,
+        )
         ffn = self.fc2(F.gelu(self.fc1(x)))
-        return self.ln_2(x + self.dropout(ffn))
+        x, _ = fused_layer_norm_residual(
+            self.dropout(ffn), self.ln_2.weight, self.ln_2.bias, x,
+            self.ln_2.epsilon,
+        )
+        return x
 
 
 class ErnieModel(nn.Layer):

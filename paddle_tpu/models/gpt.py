@@ -109,22 +109,16 @@ class GPTBlock(nn.Layer):
         self.mlp = GPTMLP(config)
 
     def forward(self, x: Tensor) -> Tensor:
-        from paddle_tpu.flags import GLOBAL_FLAGS
+        # residual add + ln_2 in ONE dispatch (tape backward runs the
+        # standalone adjoint kernel); its XLA fallback is the plain
+        # composition ``ln_2(x + attn_out)``
+        from paddle_tpu.incubate.nn.functional import fused_layer_norm_residual
 
-        if GLOBAL_FLAGS.get("use_fused_decode_layer"):
-            # residual add + ln_2 in ONE dispatch (tape backward runs the
-            # standalone adjoint kernel). The fallback composition is the
-            # exact unfused one, and ``a + b`` commutes bitwise under IEEE,
-            # so flag on/off stay byte-identical per backend.
-            from paddle_tpu.incubate.nn.functional import fused_layer_norm_residual
-
-            attn_out = self.attn(self.ln_1(x))
-            h2, x2 = fused_layer_norm_residual(
-                attn_out, self.ln_2.weight, self.ln_2.bias, x, self.ln_2.epsilon
-            )
-            return x2 + self.mlp(h2)
-        x = x + self.attn(self.ln_1(x))
-        return x + self.mlp(self.ln_2(x))
+        attn_out = self.attn(self.ln_1(x))
+        h2, x2 = fused_layer_norm_residual(
+            attn_out, self.ln_2.weight, self.ln_2.bias, x, self.ln_2.epsilon
+        )
+        return x2 + self.mlp(h2)
 
 
 class GPTModel(nn.Layer):

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, List, Optional, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +31,7 @@ from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.generation import GenerationMixin
 from paddle_tpu.incubate.nn.functional import fused_rotary_position_embedding
-from paddle_tpu.kernels.fused import count_dispatch
+from paddle_tpu.inference.paged_kv import PagedKV
 from paddle_tpu.ops.creation import arange
 from paddle_tpu.ops.manipulation import concat, reshape
 
@@ -160,89 +160,6 @@ class LlamaAttention(nn.Layer):
         q = reshape(self.q_proj(hidden_states), [b, s, self.num_heads, self.head_dim])
         k = reshape(self.k_proj(hidden_states), [b, s, self.num_kv_heads, self.head_dim])
         v = reshape(self.v_proj(hidden_states), [b, s, self.num_kv_heads, self.head_dim])
-        if (
-            cache_position is not None
-            and past_key_value is not None
-            and len(past_key_value) in (4, 5, 6, 8)
-        ):
-            # paged serving: past is (key_cache [NB,HK,BS,D], value_cache,
-            # block_tables [B,MBS], seq_lens [B][, slot_mask [B][, q_lens
-            # [B]]]) — the vLLM-style serving cache (reference
-            # `block_multihead_attention_` fused_ops.yaml:45). Positions are
-            # ragged per sequence: rope tables gather per-seq. The optional
-            # 5th element is the continuous-batching engine's active-slot
-            # mask: padded batch slots write no KV and return zeros, so the
-            # step's shape stays fixed while the live batch composition
-            # changes. The optional 6th element is the CHUNKED-PREFILL row
-            # count: each slot carries up to ``s`` new tokens (a decode row
-            # has q_lens == 1, a prompt chunk up to s) through ONE mixed
-            # ragged dispatch — the engine's single compiled signature. An
-            # 8-tuple past (FLAGS_kv_cache_dtype=int8) additionally carries
-            # the pool's per-block-per-head fp32 scale planes; quantize-on-
-            # write/dequant-on-read ride the same kernels, still one
-            # signature.
-            from paddle_tpu.core.tensor import Tensor as _T
-            from paddle_tpu.incubate.nn.functional import (
-                block_multihead_attention,
-                block_multihead_chunk_attention,
-            )
-
-            kc, vc, tables, lens = past_key_value[:4]
-            slot_mask = past_key_value[4] if len(past_key_value) >= 5 else None
-            q_lens = past_key_value[5] if len(past_key_value) >= 6 else None
-            k_scale = past_key_value[6] if len(past_key_value) == 8 else None
-            v_scale = past_key_value[7] if len(past_key_value) == 8 else None
-            lens_t = lens if isinstance(lens, _T) else _T(lens)
-            lens_arr = lens_t._data
-            cos, sin = self.rotary_emb(s, lens_t)  # ragged: [B, s, 1, D]
-            count_dispatch("unfused:rope_gather")
-            q, k, _ = fused_rotary_position_embedding(q, k, None, sin=sin, cos=cos)
-            count_dispatch("unfused:rope_apply")
-            mask_arr = slot_mask._data if isinstance(slot_mask, _T) else slot_mask
-            ks_arr = k_scale._data if isinstance(k_scale, _T) else k_scale
-            vs_arr = v_scale._data if isinstance(v_scale, _T) else v_scale
-            if q_lens is not None:
-                res = block_multihead_chunk_attention(
-                    q._data,
-                    k._data,
-                    v._data,
-                    kc._data if isinstance(kc, _T) else kc,
-                    vc._data if isinstance(vc, _T) else vc,
-                    tables._data if isinstance(tables, _T) else tables,
-                    lens_arr,
-                    q_lens._data if isinstance(q_lens, _T) else q_lens,
-                    slot_mask=mask_arr,
-                    key_scale=ks_arr,
-                    value_scale=vs_arr,
-                )
-            else:
-                res = block_multihead_attention(
-                    q._data,
-                    k._data,
-                    v._data,
-                    kc._data if isinstance(kc, _T) else kc,
-                    vc._data if isinstance(vc, _T) else vc,
-                    tables._data if isinstance(tables, _T) else tables,
-                    lens_arr,
-                    slot_mask=mask_arr,
-                )
-            if ks_arr is not None:
-                out_a, kc2, vc2, ks2, vs2 = res
-            else:
-                out_a, kc2, vc2 = res
-            count_dispatch("unfused:attend")
-            out = self.o_proj(reshape(_T(out_a), [b, s, self.num_heads * self.head_dim]))
-            count_dispatch("unfused:o_proj")
-            if not use_cache:
-                return out
-            new_past = (_T(kc2), _T(vc2), tables, lens)
-            if len(past_key_value) >= 5:
-                new_past = new_past + (slot_mask,)
-            if len(past_key_value) >= 6:
-                new_past = new_past + (q_lens,)
-            if ks_arr is not None:
-                new_past = new_past + (_T(ks2), _T(vs2))
-            return out, new_past
         if cache_position is not None and past_key_value is not None:
             # static-cache decode: past is a FIXED [B, S_max, HK, D] buffer
             # pair; append this step's K/V at cache_position and attend with a
@@ -294,67 +211,35 @@ class LlamaAttention(nn.Layer):
             return out, new_cache
         return out
 
-    def forward_paged_fused(
+    def forward_paged(
         self,
         hidden_states: Tensor,  # pre-normed [B, s, H] (norm fused upstream)
-        past_key_value: Tuple[Any, ...],  # the engine's 6-tuple paged past
+        past_key_value: PagedKV,  # this layer's KV set under the step's batch
         cos: Tensor,  # [B, s, 1, D] offset-gathered rope rows (shared by
         sin: Tensor,  # every layer — gathered ONCE per step by the caller)
-    ) -> Tuple[Tensor, Tuple[Any, ...]]:
-        """The fused decode layer's attention half: qkv projections feed the
-        rope-fused paged kernel (q's rotation runs inside the block walk, k's
+    ) -> Tuple[Tensor, PagedKV]:
+        """The paged serving step's attention: qkv projections feed the
+        rope-fused paged kernel (q's rotation runs inside the page walk, k's
         fuses into the cache-append scatter), so the per-layer rope pass +
-        attention collapse to one dispatch. Under an armed tp mesh o_proj
-        runs the tile-split row-parallel matmul so its all-reduce overlaps
-        the next tile's compute."""
-        from paddle_tpu.core.tensor import Tensor as _T
-        from paddle_tpu.incubate.nn.functional import (
-            block_multihead_chunk_attention_fused,
-        )
-
+        attention are one dispatch (reference ``block_multihead_attention_``,
+        fused_ops.yaml:45). Positions are ragged per slot; padded slots write
+        no KV and return zeros, so the step's shape stays fixed while the
+        live batch changes. Under an armed tp mesh o_proj runs the tile-split
+        row-parallel matmul so its all-reduce overlaps the next tile's
+        compute."""
         b, s, _ = hidden_states.shape
         q = reshape(self.q_proj(hidden_states), [b, s, self.num_heads, self.head_dim])
         k = reshape(self.k_proj(hidden_states), [b, s, self.num_kv_heads, self.head_dim])
         v = reshape(self.v_proj(hidden_states), [b, s, self.num_kv_heads, self.head_dim])
-        if len(past_key_value) == 8:
-            kc, vc, tables, lens, slot_mask, q_lens, k_scale, v_scale = past_key_value
-        else:
-            kc, vc, tables, lens, slot_mask, q_lens = past_key_value
-            k_scale = v_scale = None
-        ks_arr = k_scale._data if isinstance(k_scale, _T) else k_scale
-        vs_arr = v_scale._data if isinstance(v_scale, _T) else v_scale
-        res = block_multihead_chunk_attention_fused(
-            q._data,
-            k._data,
-            v._data,
-            cos._data if isinstance(cos, _T) else cos,
-            sin._data if isinstance(sin, _T) else sin,
-            kc._data if isinstance(kc, _T) else kc,
-            vc._data if isinstance(vc, _T) else vc,
-            tables._data if isinstance(tables, _T) else tables,
-            lens._data if isinstance(lens, _T) else lens,
-            q_lens._data if isinstance(q_lens, _T) else q_lens,
-            slot_mask=slot_mask._data if isinstance(slot_mask, _T) else slot_mask,
-            key_scale=ks_arr,
-            value_scale=vs_arr,
-        )
-        if ks_arr is not None:
-            out_a, kc2, vc2, ks2, vs2 = res
-        else:
-            out_a, kc2, vc2 = res
-        count_dispatch("fused:attend")
-        out_t = reshape(_T(out_a), [b, s, self.num_heads * self.head_dim])
+        out_a, new_past = past_key_value.attend(q._data, k._data, v._data, cos._data, sin._data)
+        out_t = reshape(Tensor(out_a), [b, s, self.num_heads * self.head_dim])
         mesh = shard_group_mesh()
         if mesh is None:
             out = self.o_proj(out_t)
         else:
             from paddle_tpu.distributed.tp import row_parallel_overlap_matmul
 
-            out = _T(row_parallel_overlap_matmul(out_t._data, self.o_proj.weight._data))
-        count_dispatch("fused:o_proj")
-        new_past = (_T(kc2), _T(vc2), tables, lens, slot_mask, q_lens)
-        if ks_arr is not None:
-            new_past = new_past + (_T(ks2), _T(vs2))
+            out = Tensor(row_parallel_overlap_matmul(out_t._data, self.o_proj.weight._data))
         return out, new_past
 
 
@@ -388,7 +273,6 @@ class LlamaDecoderLayer(nn.Layer):
         residual = hidden_states
         with jax.named_scope(SCOPE_NORM):
             h = self.input_layernorm(hidden_states)
-        count_dispatch("unfused:input_norm")
         with jax.named_scope(SCOPE_ATTENTION):
             attn_out = self.self_attn(
                 h, startend_row_indices, past_key_value, use_cache, cache_position
@@ -396,16 +280,12 @@ class LlamaDecoderLayer(nn.Layer):
         if use_cache:
             attn_out, cache = attn_out
         h = residual + attn_out
-        count_dispatch("unfused:attn_residual_add")
         residual = h
         with jax.named_scope(SCOPE_NORM):
             h = self.post_attention_layernorm(h)
-        count_dispatch("unfused:post_attn_norm")
         with jax.named_scope(SCOPE_MLP):
             h = self.mlp(h)
-        count_dispatch("unfused:mlp")
         h = residual + h
-        count_dispatch("unfused:mlp_residual_add")
         if use_cache:
             return h, cache
         return h
@@ -427,22 +307,14 @@ class LlamaModel(nn.Layer):
         use_cache: bool = False,
         cache_position: Optional[Tensor] = None,
     ) -> Any:
-        if (
-            cache_position is not None
-            and startend_row_indices is None
-            and past_key_values is not None
-            and GLOBAL_FLAGS.get("use_fused_decode_layer")
-            and len(past_key_values) == len(self.layers)
-            and all(p is not None and len(p) in (6, 8) for p in past_key_values)
-        ):
-            # the continuous-batching engine's one-signature mixed ragged
-            # step (6-tuple paged past): run the FUSED decode layer loop —
-            # same math, fewer dispatches. generate_paged's 4/5-tuple pasts
-            # and every train/prefill path stay on the layer modules below.
-            return self._forward_paged_fused(input_ids, past_key_values, use_cache)
+        if past_key_values is not None and isinstance(past_key_values[0], PagedKV):
+            # paged serving (the continuous-batching engine's one-signature
+            # mixed ragged step, generate_paged's decode step): one typed KV
+            # set per layer; every train / prefill / static-cache path stays
+            # on the layer modules below
+            return self._forward_paged(input_ids, past_key_values, use_cache)
         with jax.named_scope(SCOPE_EMBEDDING):
             h = self.embed_tokens(input_ids)
-        count_dispatch("unfused:embed")
         new_caches = [] if use_cache else None
         use_recompute = (
             self.config.recompute
@@ -463,30 +335,26 @@ class LlamaModel(nn.Layer):
                 new_caches.append(cache)
         with jax.named_scope(SCOPE_NORM):
             h = self.norm(h)
-        count_dispatch("unfused:final_norm")
         if use_cache:
             return h, new_caches
         return h
 
-    def _forward_paged_fused(
+    def _forward_paged(
         self,
         input_ids: Tensor,
-        past_key_values: Any,
+        past_key_values: Sequence[PagedKV],
         use_cache: bool,
     ) -> Any:
-        """The decode step's FUSED layer loop (``FLAGS_use_fused_decode_layer``).
-
-        The unfused step issues ~9 dispatches per layer (input norm, rope
-        gather, rope apply, attend, o_proj, two residual adds, post-attention
-        norm, mlp). Here the epilogues pair up into single kernels:
+        """The paged serving step's layer loop, its epilogues paired into
+        single kernels:
 
         - entry: token gather + embedding lookup + layer 0's input RMSNorm
           fuse into one scalar-prefetch kernel seeding BOTH the residual
           stream and the normed hidden;
         - rope rows gather ONCE per step (every layer's rotary buffers hold
-          identical values — the unfused per-layer gathers are redundant);
+          identical values);
         - per layer: the rope-fused paged-attention kernel (q rotates inside
-          the block walk), then residual-add + post-attention norm as ONE
+          the page walk), then residual-add + post-attention norm as ONE
           kernel, the MLP, and residual-add + the NEXT layer's input norm as
           ONE kernel — the last layer pairs with the model's final norm, so
           the loop returns ``h`` already normed;
@@ -494,12 +362,9 @@ class LlamaModel(nn.Layer):
           split into token tiles so each tile's all-reduce overlaps the next
           tile's compute (byte-identical: the split only partitions rows).
 
-        Byte-identity with the unfused loop holds per backend: every fused
-        op's XLA fallback is the exact unfused composition, residual adds
-        commute bitwise under IEEE, and the Pallas kernels replicate the
-        unfused kernels' op order.
+        Every fused op's XLA fallback is the plain composition (norm of the
+        sum, rope then attend), which is what the dense forward computes.
         """
-        from paddle_tpu.core.tensor import Tensor as _T
         from paddle_tpu.incubate.nn.functional import (
             fused_embed_rms_norm,
             fused_rms_norm_residual,
@@ -514,21 +379,16 @@ class LlamaModel(nn.Layer):
                 first.input_layernorm.weight,
                 first.input_layernorm.epsilon,
             )
-        count_dispatch("fused:embed_norm")
         s = input_ids.shape[1]
-        lens = past_key_values[0][3]
-        lens_t = lens if isinstance(lens, _T) else _T(lens)
+        lens = Tensor(past_key_values[0].batch.seq_lens)  # one batch, shared by every set
         with jax.named_scope(SCOPE_ATTENTION):
-            cos, sin = first.self_attn.rotary_emb(s, lens_t)  # once per STEP
-        count_dispatch("fused:rope_gather")
+            cos, sin = first.self_attn.rotary_emb(s, lens)  # once per STEP
         mesh = shard_group_mesh()
         new_caches = [] if use_cache else None
         n = len(layers)
         for i, layer in enumerate(layers):
             with jax.named_scope(SCOPE_ATTENTION):
-                attn_out, cache = layer.self_attn.forward_paged_fused(
-                    h, past_key_values[i], cos, sin
-                )
+                attn_out, cache = layer.self_attn.forward_paged(h, past_key_values[i], cos, sin)
             with jax.named_scope(SCOPE_NORM):
                 h, residual = fused_rms_norm_residual(
                     attn_out,
@@ -536,7 +396,6 @@ class LlamaModel(nn.Layer):
                     residual,
                     layer.post_attention_layernorm.epsilon,
                 )
-            count_dispatch("fused:residual_norm")
             if mesh is None:
                 with jax.named_scope(SCOPE_MLP):
                     mlp_out = layer.mlp(h)
@@ -557,16 +416,12 @@ class LlamaModel(nn.Layer):
                     dw_data = (
                         dw._data.astype(jnp.float32) * dscale[None, :]
                     ).astype(inner._data.dtype)
-                mlp_out = _T(
-                    row_parallel_overlap_matmul(inner._data, dw_data)
-                )
-            count_dispatch("fused:mlp")
+                mlp_out = Tensor(row_parallel_overlap_matmul(inner._data, dw_data))
             next_norm = layers[i + 1].input_layernorm if i + 1 < n else self.norm
             with jax.named_scope(SCOPE_NORM):
                 h, residual = fused_rms_norm_residual(
                     mlp_out, next_norm.weight, residual, next_norm.epsilon
                 )
-            count_dispatch("fused:residual_norm")
             if use_cache:
                 new_caches.append(cache)
         # h left the loop already final-normed (the last pairing used

@@ -41,8 +41,8 @@ import paddle_tpu
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu.core.tensor import Tensor
-from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.generation import GenerationMixin
+from paddle_tpu.inference.paged_kv import PagedKV
 from paddle_tpu.models.llama import (
     SCOPE_ATTENTION,
     SCOPE_EMBEDDING,
@@ -126,14 +126,14 @@ class OuroDecoderLayer(nn.Layer):
         rope: Optional[Tuple[Tensor, Tensor]] = None,
     ) -> Any:
         """``rope`` = the step's offset-gathered (cos, sin) rows: with it the
-        engine's 6/8-tuple paged past takes the rope-fused paged kernel
-        (``LlamaAttention.forward_paged_fused``)."""
+        past is this set's ``PagedKV`` and attention is the rope-fused paged
+        kernel (``LlamaAttention.forward_paged``)."""
         with jax.named_scope(SCOPE_NORM):
             h = self.input_layernorm(hidden_states)
         cache = None
         with jax.named_scope(SCOPE_ATTENTION):
             if rope is not None:
-                attn_out, cache = self.self_attn.forward_paged_fused(h, past_key_value, *rope)
+                attn_out, cache = self.self_attn.forward_paged(h, past_key_value, *rope)
             else:
                 attn_out = self.self_attn(
                     h, startend_row_indices, past_key_value, use_cache, cache_position
@@ -193,53 +193,43 @@ class OuroModel(nn.Layer):
         return h, caches
 
     def _paged_layer_fn(self) -> Any:
-        """ONE layer over the engine's paged past as a jitted function of raw
+        """ONE layer over a ``PagedKV`` set as a jitted function of raw
         arrays, built once per model. Every layer has the same leaves and
         shapes, so layer 0's module stands in for all of them with the
         called layer's weights (and weight-only-int8 scales) bound to it:
         the ``T x L`` calls inside a step program share one traced and
         lowered body. Weights are arguments, so nothing of an enclosing
-        trace is closed over."""
+        trace is closed over. It hands back the set's PLANES only: the batch
+        is the caller's, shared by every set."""
         fn = getattr(self, "_layer_jit", None)
         if fn is None:
             template = self.layers[0]
             named = list(template.named_parameters())
 
-            def paged_layer(arrays, scales, h, plane, shared, cos, sin, fused):
+            def paged_layer(arrays, scales, h, kv, cos, sin):
                 quant = [(p, s) for (_n, p), s in zip(named, scales) if s is not None]
                 with bind_param_arrays(named, arrays), bind_quant_scales(
                     [p for p, _s in quant], [s for _p, s in quant]
                 ):
-                    tables, lens, mask, q_lens = (Tensor(a) for a in shared)
-                    past = (
-                        (Tensor(plane[0]), Tensor(plane[1]), tables, lens, mask, q_lens)
-                        + tuple(Tensor(s) for s in plane[2:])
-                    )
-                    rope = (Tensor(cos), Tensor(sin)) if fused else None
-                    out, c = template(Tensor(h), None, past, True, lens, rope)
-                # a quantized past is an 8-tuple: its scale planes are at 6, 7
-                return out._data, tuple(t._data for t in (c[0], c[1]) + tuple(c[6:]))
+                    out, kv = template(Tensor(h), None, kv, True, None, (Tensor(cos), Tensor(sin)))
+                return out._data, kv.planes
 
-            fn = jax.jit(paged_layer, static_argnames=("fused",))
+            fn = jax.jit(paged_layer)
             object.__setattr__(self, "_layer_jit", fn)
         return fn
 
-    def _forward_paged(self, input_ids: Tensor, past_key_values: Sequence[Any], use_cache: bool) -> Any:
-        """The engine's one-signature mixed ragged step: ``T x L`` 6/8-tuple
-        paged pasts, pass-major."""
-        raw = lambda x: x._data if isinstance(x, Tensor) else x  # noqa: E731
+    def _forward_paged(self, input_ids: Tensor, past_key_values: Sequence[PagedKV], use_cache: bool) -> Any:
+        """The paged serving step (the engine's one-signature mixed ragged
+        step, ``generate_paged``'s decode step): ``T x L`` ``PagedKV`` sets,
+        pass-major, under ONE shared batch."""
         n_layers = len(self.layers)
         with jax.named_scope(SCOPE_EMBEDDING):
             h = self.embed_tokens(input_ids)._data
-        first = past_key_values[0]
-        shared = tuple(raw(x) for x in first[2:6])  # tables, lens, slot mask, q_lens
-        fused = bool(GLOBAL_FLAGS.get("use_fused_decode_layer"))
-        cos = sin = None
-        if fused:
-            with jax.named_scope(SCOPE_ATTENTION):
-                # once per STEP: every pass and layer rotates at the same positions
-                cos, sin = self.layers[0].self_attn.rotary_emb(input_ids.shape[1], Tensor(shared[1]))
-            cos, sin = cos._data, sin._data
+        batch = past_key_values[0].batch
+        with jax.named_scope(SCOPE_ATTENTION):
+            # once per STEP: every pass and layer rotates at the same positions
+            cos, sin = self.layers[0].self_attn.rotary_emb(input_ids.shape[1], Tensor(batch.seq_lens))
+        cos, sin = cos._data, sin._data
         weights = []
         for layer in self.layers:  # read once a step: every pass takes the same
             params = list(layer.parameters())
@@ -249,13 +239,8 @@ class OuroModel(nn.Layer):
         for t in range(self.config.total_ut_steps):
             with jax.named_scope(SCOPE_LOOP_PASS):
                 for i, (arrays, scales) in enumerate(weights):
-                    p = past_key_values[t * n_layers + i]
-                    plane = tuple(raw(x) for x in (p[0], p[1]) + tuple(p[6:]))
-                    h, plane = run(arrays, scales, h, plane, shared, cos, sin, fused=fused)
-                    new_caches.append(
-                        (Tensor(plane[0]), Tensor(plane[1])) + tuple(first[2:6])
-                        + tuple(Tensor(x) for x in plane[2:])
-                    )
+                    h, planes = run(arrays, scales, h, past_key_values[t * n_layers + i], cos, sin)
+                    new_caches.append(PagedKV(*planes, batch=batch))
             with jax.named_scope(SCOPE_NORM), jax.named_scope(SCOPE_LOOP_NORM):
                 h = self.norm(Tensor(h))._data
         h = Tensor(h)
@@ -275,12 +260,7 @@ class OuroModel(nn.Layer):
                 f"a past of {len(past_key_values)} KV sets was given; {passes} passes over "
                 f"{n_layers} layers hold {passes * n_layers}"
             )
-        if (
-            cache_position is not None
-            and startend_row_indices is None
-            and past_key_values is not None
-            and all(p is not None and len(p) in (6, 8) for p in past_key_values)
-        ):
+        if past_key_values is not None and isinstance(past_key_values[0], PagedKV):
             return self._forward_paged(input_ids, past_key_values, use_cache)
         with jax.named_scope(SCOPE_EMBEDDING):
             h = self.embed_tokens(input_ids)

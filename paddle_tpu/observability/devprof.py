@@ -44,9 +44,8 @@ collectives actually cost. This module closes that gap in three pieces:
   measured (``comm_source: "wrapper"``); when the program's collectives are
   GSPMD-inserted (the tp engine's all-reduces — invisible to host
   wrappers), the share falls back to the cost-model prior (``comm_source:
-  "cost_model"``) applied to the *measured* device segment. ``bench.py``
-  reports this as ``comm_share_measured`` next to the analytic estimate
-  (now labeled ``comm_share_analytic``) plus ``host_bubble_fraction``.
+  "cost_model"``) applied to the *measured* device segment: a model, not
+  a measurement (ROADMAP D7).
 """
 
 from __future__ import annotations

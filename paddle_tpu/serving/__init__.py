@@ -15,9 +15,8 @@ The host-side policy stack between clients and ``engine.step()``:
   kill/revive) and :class:`ReplicaRouter` (rendezvous prefix-affinity
   routing, health-gated failover with salvage + bounded deadline-aware
   re-dispatch, drain, cross-replica spill);
-- :mod:`.loadgen` — the open-loop Poisson arrival harness behind bench.py's
-  ``serving_goodput`` / ``cluster_goodput`` records and the overload
-  acceptance tests;
+- :mod:`.loadgen` — the open-loop Poisson arrival harness behind the overload
+  acceptance tests (the benchmark's arrivals are ``benchmarks/lib/traffic.py``);
 - :mod:`.errors` — :class:`Overloaded` (429) and the re-exported typed
   :class:`IntakeError` taxonomy (4xx).
 

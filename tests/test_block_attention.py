@@ -11,6 +11,7 @@ from paddle_tpu.incubate.nn.functional import (
     BlockKVCache,
     block_cache_prefill,
     block_multihead_attention,
+    block_multihead_chunk_attention,
 )
 
 B, HQ, HKV, D = 2, 4, 2, 8
@@ -211,9 +212,9 @@ class TestSlotMask:
             q, k1, v1, kc, vc, tables, lens, slot_mask=mask
         )
         monkeypatch.setattr(sel, "pallas_enabled", lambda flag, **_: True)
-        real = pa.paged_flash_decode
+        real = pa.paged_flash_chunk
         monkeypatch.setattr(
-            pa, "paged_flash_decode",
+            pa, "paged_flash_chunk",
             lambda *a, **kw: real(*a, interpret=True, **kw),
         )
         out_k, _, _ = block_multihead_attention(
@@ -225,12 +226,19 @@ class TestSlotMask:
         )
 
 
-class TestFusedDecodeWrapper:
-    """``block_multihead_attention_fused``: the rope-fused counterpart of the
-    decode wrapper. On a backend without the kernel, fused on/off must
-    execute the SAME op composition (byte-identical outputs); with the
-    kernel forced on (interpret mode), numerics stay in lockstep with the
-    XLA fallback."""
+def _decode_roped(q, k, v, cos, sin, kc, vc, tables, lens, **kw):
+    """One decode token a sequence through the serving step's entry: the
+    chunk at ``C == 1`` with RoPE folded in (q and k come pre-rope)."""
+    return block_multihead_chunk_attention(
+        q, k, v, kc, vc, tables, lens, jnp.ones_like(lens), cos=cos, sin=sin, **kw
+    )
+
+
+class TestRopedDecodeStep:
+    """The rope-folded entry at ``C == 1``. On a backend without the kernel
+    it must execute the SAME op composition as rope-then-attend
+    (byte-identical outputs); with the kernel forced on (interpret mode),
+    numerics stay in lockstep with the XLA fallback."""
 
     def _setup(self, seed=13):
         rng = np.random.default_rng(seed)
@@ -247,15 +255,10 @@ class TestFusedDecodeWrapper:
         return q, k1, v1, cos, sin, kc, vc, tables, lens
 
     def test_fallback_byte_identical_to_unfused_composition(self):
-        from paddle_tpu.incubate.nn.functional import (
-            _rope_apply_xla,
-            block_multihead_attention_fused,
-        )
+        from paddle_tpu.incubate.nn.functional import _rope_apply_xla
 
         q, k1, v1, cos, sin, kc, vc, tables, lens = self._setup()
-        out_f, kc_f, vc_f = block_multihead_attention_fused(
-            q, k1, v1, cos, sin, kc, vc, tables, lens
-        )
+        out_f, kc_f, vc_f = _decode_roped(q, k1, v1, cos, sin, kc, vc, tables, lens)
         q_r = _rope_apply_xla(q, sin, cos, True)
         k_r = _rope_apply_xla(k1, sin, cos, True)
         out_u, kc_u, vc_u = block_multihead_attention(
@@ -268,24 +271,17 @@ class TestFusedDecodeWrapper:
     def test_kernel_lockstep_with_xla_fallback(self, monkeypatch):
         import paddle_tpu.kernels.paged_attention as pa
         import paddle_tpu.kernels.select as sel
-        from paddle_tpu.incubate.nn.functional import (
-            block_multihead_attention_fused,
-        )
 
         q, k1, v1, cos, sin, kc, vc, tables, lens = self._setup(seed=14)
         mask = jnp.asarray([False, True])
-        out_xla, _, _ = block_multihead_attention_fused(
-            q, k1, v1, cos, sin, kc, vc, tables, lens, slot_mask=mask
-        )
+        out_xla, _, _ = _decode_roped(q, k1, v1, cos, sin, kc, vc, tables, lens, slot_mask=mask)
         monkeypatch.setattr(sel, "pallas_enabled", lambda flag, **_: True)
-        real = pa.paged_flash_decode_fused
+        real = pa.paged_flash_chunk
         monkeypatch.setattr(
-            pa, "paged_flash_decode_fused",
+            pa, "paged_flash_chunk",
             lambda *a, **kw: real(*a, interpret=True, **kw),
         )
-        out_k, _, _ = block_multihead_attention_fused(
-            q, k1, v1, cos, sin, kc, vc, tables, lens, slot_mask=mask
-        )
+        out_k, _, _ = _decode_roped(q, k1, v1, cos, sin, kc, vc, tables, lens, slot_mask=mask)
         assert (np.asarray(out_k)[0] == 0.0).all()
         assert np.abs(np.asarray(out_k)[1]).sum() > 0
         np.testing.assert_allclose(

@@ -105,6 +105,10 @@ class TestRanking:
 
 
 class TestRankingCorrelation:
+    # a wall-clock ranking of four tiny CPU programs: it passes alone and fails
+    # now and then under the six loaded workers of tier-1 (CHANGES, PR 26, 28,
+    # 29), where a red run hides a real failure (ROADMAP D9): not in tier-1
+    @pytest.mark.slow
     def test_predicted_ranking_matches_measured_cpu_trials(self):
         """Spearman(predicted, measured) on a tiny GPT over configs differing
         in recompute and micro-batching — the two axes whose relative cost

@@ -1,6 +1,7 @@
 """Continuous-batching engine: the no-retrace invariant (exactly ONE
 compiled signature over a mixed prefill/decode workload — chunked prefill),
-token-for-token parity with per-sequence ``generate_paged``, and exact
+token-for-token parity with per-sequence ``generate_paged`` (which shares the
+engine's kernel path) and with the dense ``generate`` (which does not), and exact
 refcounted block-pool accounting under adversarial admit/evict orders.
 
 Everything here runs on CPU and fast — this file IS the tier-1 guard that
@@ -50,6 +51,13 @@ def _reference(m, prompt, max_new, block_size, eos=None):
     return out
 
 
+def _dense_reference(m, prompt, max_new):
+    """The independent oracle: greedy ``generate`` over dense KV (no paged
+    code; ``generate_paged`` shares the engine's kernel path)."""
+    out = m.generate(paddle.to_tensor(prompt[None]), max_new_tokens=max_new, do_sample=False)
+    return np.asarray(out.numpy())[0]
+
+
 class TestNoRetraceInvariant:
     def test_mixed_workload_exactly_one_compile_and_token_parity(self):
         """The acceptance test: staggered admits (7 requests through 3
@@ -80,6 +88,7 @@ class TestNoRetraceInvariant:
         for rid, p, (_, t) in zip(rids, prompts, specs):
             ref = _reference(m, p, t, block_size=4)
             np.testing.assert_array_equal(out[rid].tokens(), ref)
+            np.testing.assert_array_equal(out[rid].tokens(), _dense_reference(m, p, t))
 
     def test_late_submits_mid_flight_no_retrace(self):
         """Requests added AFTER decoding started enter freed slots without a

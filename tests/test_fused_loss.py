@@ -265,9 +265,16 @@ class TestFlagEnvSeeding:
 
 
 class TestCompiledMemoryRegression:
-    """The no-materialization claim, enforced: the jitted fused train loss
-    must peak strictly below the unfused composition (core/memory.py
-    compiled stats, the test_memory.py methodology)."""
+    """The no-materialization claim, enforced on what the compiler holds
+    live: the jitted fused train loss's TEMPORARIES must stay below the
+    unfused composition's by more than an ``[N, V]`` buffer (core/memory.py
+    compiled stats, the test_memory.py methodology). It reads
+    ``temp_size_in_bytes``: this backend's ``peak_memory_in_bytes`` is
+    arguments + outputs only (2 361 380 = 1 181 696 + 1 179 676 + 8 for
+    BOTH programs here, 30 bytes apart by an output tuple's layout), so it
+    cannot see a temporary at all, which is what this test asserted on and
+    failed on since the seed. For the Pallas kernels on a described v5e:
+    tests/test_tpu_aot_compile.py::test_fused_loss_never_holds_the_logits."""
 
     def test_fused_peak_below_unfused(self):
         n, h, v = 512, 128, 4096
@@ -283,7 +290,7 @@ class TestCompiledMemoryRegression:
 
         def peak(fn):
             c = jax.jit(jax.value_and_grad(fn, argnums=(0, 1))).lower(x, w, lab).compile()
-            return M.compiled_memory_stats(c)["peak_memory_in_bytes"]
+            return M.compiled_memory_stats(c)["temp_size_in_bytes"]
 
         p_unfused = peak(unfused)
         p_fused = peak(fused)

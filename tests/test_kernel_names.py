@@ -116,19 +116,6 @@ _TABLES = ((SLOTS, MBS), I32)
 _LENS = ((SLOTS,), I32)
 
 
-def _paged_decode():
-    from paddle_tpu.kernels.paged_attention import paged_flash_decode
-
-    return paged_flash_decode, (((SLOTS, HEADS, D), BF16), _POOL, _POOL, _TABLES, _LENS)
-
-
-def _paged_decode_fused():
-    from paddle_tpu.kernels.paged_attention import paged_flash_decode_fused
-
-    cs = ((SLOTS, 1, D), BF16)
-    return paged_flash_decode_fused, (((SLOTS, HEADS, D), BF16), cs, cs, _POOL, _POOL, _TABLES, _LENS)
-
-
 def _paged_chunk():
     from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
@@ -136,10 +123,11 @@ def _paged_chunk():
 
 
 def _paged_chunk_fused():
-    from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused
+    from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
     cs = ((SLOTS, CHUNK, D), BF16)
-    return paged_flash_chunk_fused, (((SLOTS, CHUNK, HEADS, D), BF16), cs, cs, _POOL, _POOL, _TABLES, _LENS, _LENS)
+    fn = lambda q, cos, sin, *rest: paged_flash_chunk(q, *rest, cos=cos, sin=sin)  # noqa: E731
+    return fn, (((SLOTS, CHUNK, HEADS, D), BF16), cs, cs, _POOL, _POOL, _TABLES, _LENS, _LENS)
 
 
 # kernel name -> the entry whose lowering has to hold it (one pallas_call site
@@ -148,8 +136,7 @@ SITES = {
     "flash_attention_fwd": _flash, "flash_attention_dq": _flash, "flash_attention_dkv": _flash,
     "fused_loss_fwd": _fused_loss, "fused_loss_dx": _fused_loss, "fused_loss_dw": _fused_loss,
     "fused_loss_fwd_quant": _fused_loss_quant,
-    "paged_attention_decode": _paged_decode, "paged_attention_chunk": _paged_chunk,
-    "paged_attention_decode_fused": _paged_decode_fused, "paged_attention_chunk_fused": _paged_chunk_fused,
+    "paged_attention_chunk": _paged_chunk, "paged_attention_chunk_fused": _paged_chunk_fused,
     "rms_norm_fwd": _rms, "rms_norm_bwd": _rms,
     "rope_fwd": _rope, "rope_adjoint": _rope,
     "rms_norm_residual_fwd": _rms_residual, "rms_norm_residual_adjoint": _rms_residual,
@@ -177,8 +164,8 @@ def test_lowered_kernel_carries_its_name(name):
 
 
 def test_every_pallas_call_site_passes_a_name_constant():
-    """The 19 sites, read from the source (the paged chunk kernels, plain and
-    rope-fused, share one): each ``pl.pallas_call(`` has a ``name=`` keyword,
+    """The 17 sites, read from the source (the paged chunk kernel, plain and
+    rope-fused, is one): each ``pl.pallas_call(`` has a ``name=`` keyword,
     and every name is one of the constants above."""
     import ast
     import inspect
@@ -193,7 +180,7 @@ def test_every_pallas_call_site_passes_a_name_constant():
                 sites += 1
                 assert any(kw.arg == "name" for kw in node.keywords), f"{module.__name__}:{node.lineno}"
         constants |= {v for k, v in vars(module).items() if k.startswith("KERNEL_")}
-    assert sites == 19
+    assert sites == 17
     assert constants == set(SITES)
     assert all(re.fullmatch(r"[a-z][a-z0-9_]*", c) for c in constants)  # no shapes, trace-safe
 

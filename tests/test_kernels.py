@@ -288,7 +288,6 @@ class TestFusedDecodeEpilogueFallbackFlat:
         "fused_layer_norm_residual_bwd",
         "fused_embed_norm",
         "paged_flash_chunk_fused",
-        "paged_flash_decode_fused",
     )
 
     @staticmethod

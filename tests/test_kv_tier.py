@@ -458,22 +458,3 @@ class TestObservability:
         for k in ("host_bytes", "spilled_blocks", "prefetched_blocks",
                   "dropped_blocks"):
             assert k in snap["kv_tier"]
-
-
-def test_bench_smoke_kv_tier_multi_turn_ttft():
-    """The guarded bench secondary runs end to end on CPU and reports the
-    sweep, counters and the 1-compile honesty field."""
-    import bench
-
-    rec = bench._bench_kv_tier_multi_turn(paddle, "cpu")
-    assert "error" not in rec, rec
-    assert rec["metric"] == "kv_tier_multi_turn_ttft"
-    assert rec["compiled_signatures_per_engine"] == 1
-    sweep = rec["sweep"]
-    assert sweep[0]["kv_host_tier_bytes"] == 0
-    assert len(sweep) >= 3
-    on = sweep[-1]
-    assert on["spilled_blocks"] > 0 and on["prefetched_blocks"] > 0
-    assert on["host_hit_rate"] > 0
-    for pt in sweep:
-        assert "warm_ttft_ms" in pt and "p50" in pt["warm_ttft_ms"]

@@ -1,5 +1,7 @@
-"""Pallas paged flash-decode kernel: interpret-mode numerics parity with the
-XLA gather path, ragged lengths, GQA, and static TPU (Mosaic) lowering."""
+"""The Pallas paged-attention kernel: interpret-mode numerics parity with the
+XLA gather path, ragged lengths, GQA, and static TPU (Mosaic) lowering. A
+plain decode step is the chunk kernel at ``C == 1`` (``chunk_decode_step``
+below)."""
 
 import functools
 
@@ -8,9 +10,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.kernels.paged_attention import paged_flash_decode
+from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
 BS = 16  # tokens per physical block
+
+
+def chunk_decode_step(q, key_cache, value_cache, tables, lens, **kw):
+    """One decode token a sequence (``q [B, HQ, D]``) through the chunk
+    kernel at ``C == 1``. ``lens`` INCLUDES the current token, whose KV is
+    already in the pool; a sequence of length 0 is an inactive slot."""
+    out = paged_flash_chunk(
+        q[:, None], key_cache, value_cache, tables, jnp.maximum(lens - 1, 0),
+        (lens > 0).astype(jnp.int32), **kw,
+    )
+    return out[:, 0]
 
 
 def _setup(b=3, hq=4, hkv=4, d=64, mbs=4, nb=16, seed=0, dtype=jnp.float32):
@@ -45,20 +58,20 @@ def _reference(q, key_cache, value_cache, tables, lens):
 class TestPagedFlashDecode:
     def test_matches_dense_gather(self):
         args = _setup()
-        out = paged_flash_decode(*args, interpret=True)
+        out = chunk_decode_step(*args, interpret=True)
         ref = _reference(*args)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
     def test_gqa(self):
         args = _setup(hq=8, hkv=2, seed=1)
-        out = paged_flash_decode(*args, interpret=True)
+        out = chunk_decode_step(*args, interpret=True)
         ref = _reference(*args)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
     def test_single_token_sequence(self):
         q, kc, vc, tables, _ = _setup(seed=2)
         lens = jnp.ones((q.shape[0],), jnp.int32)
-        out = paged_flash_decode(q, kc, vc, tables, lens, interpret=True)
+        out = chunk_decode_step(q, kc, vc, tables, lens, interpret=True)
         ref = _reference(q, kc, vc, tables, lens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -70,13 +83,13 @@ class TestPagedFlashDecode:
         vc = jnp.asarray(rng.normal(size=(8, 4, BS, 64)), jnp.float32)
         tables = jnp.asarray([[5, 1], [5, 2]], jnp.int32)  # shared block 5
         lens = jnp.asarray([20, 24], jnp.int32)
-        out = paged_flash_decode(q, kc, vc, tables, lens, interpret=True)
+        out = chunk_decode_step(q, kc, vc, tables, lens, interpret=True)
         ref = _reference(q, kc, vc, tables, lens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
     def test_bf16(self):
         args = _setup(seed=4, dtype=jnp.bfloat16)
-        out = paged_flash_decode(*args, interpret=True)
+        out = chunk_decode_step(*args, interpret=True)
         ref = _reference(*args)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=2e-2, atol=2e-2
@@ -92,13 +105,13 @@ class TestPagedFlashDecode:
         called = {}
         import paddle_tpu.kernels.paged_attention as pa
 
-        real = pa.paged_flash_decode
+        real = pa.paged_flash_chunk
 
         def spy(*a, **kw):
             called["yes"] = True
             return real(*a, interpret=True, **{k: v for k, v in kw.items() if k != "interpret"})
 
-        monkeypatch.setattr(pa, "paged_flash_decode", spy)
+        monkeypatch.setattr(pa, "paged_flash_chunk", spy)
         rng = np.random.default_rng(5)
         b, hq, d, nb, mbs = 2, 4, 64, 8, 2
         q = jnp.asarray(rng.normal(size=(b, 1, hq, d)), jnp.float32)
@@ -123,7 +136,7 @@ class TestPagedDecodeExport:
         args = _setup(b=2, hq=8, hkv=2, d=128, mbs=8, nb=32, dtype=jnp.bfloat16)
 
         def fn(q, kc, vc, tables, lens):
-            return paged_flash_decode(q, kc, vc, tables, lens)
+            return chunk_decode_step(q, kc, vc, tables, lens)
 
         jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
@@ -132,7 +145,7 @@ class TestPagedDecodeExport:
         args = _setup(b=8, hq=32, hkv=32, d=128, mbs=16, nb=256, dtype=jnp.bfloat16)
 
         def fn(q, kc, vc, tables, lens):
-            return paged_flash_decode(q, kc, vc, tables, lens)
+            return chunk_decode_step(q, kc, vc, tables, lens)
 
         jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
@@ -142,7 +155,7 @@ def test_zero_length_sequence_yields_zeros():
     mean over physical block 0 (fully-masked softmax degeneracy)."""
     q, kc, vc, tables, _ = _setup(seed=7)
     lens = jnp.asarray([0, 5, 0], jnp.int32)
-    out = np.asarray(paged_flash_decode(q, kc, vc, tables, lens, interpret=True))
+    out = np.asarray(chunk_decode_step(q, kc, vc, tables, lens, interpret=True))
     assert np.all(out[0] == 0.0) and np.all(out[2] == 0.0)
     assert np.abs(out[1]).sum() > 0
 
@@ -158,7 +171,7 @@ def test_invalid_head_geometry_raises_at_trace_time():
     tables = jnp.zeros((2, 8), jnp.int32)
     lens = jnp.ones((2,), jnp.int32)
     with pytest.raises(ValueError, match="not a multiple of kv heads"):
-        jax.eval_shape(lambda *a: paged_flash_decode(*a), q, kc, kc, tables, lens)
+        jax.eval_shape(lambda *a: chunk_decode_step(*a), q, kc, kc, tables, lens)
 
 
 class TestRaggedSkip:
@@ -178,7 +191,7 @@ class TestRaggedSkip:
         vc = jnp.asarray(rng.normal(size=(nb, hq, BS, d)), jnp.float32)
         tables = jnp.asarray(rng.permutation(nb)[: b * mbs].reshape(b, mbs), jnp.int32)
         lens = jnp.asarray([BS + 3, 2 * BS], jnp.int32)  # tails: 2 blocks each
-        clean = paged_flash_decode(q, kc, vc, tables, lens, interpret=True)
+        clean = chunk_decode_step(q, kc, vc, tables, lens, interpret=True)
         # poison the tail blocks (logical blocks >= ceil(len/BS))
         kc_p, vc_p = np.array(kc), np.array(vc)
         for bi in range(b):
@@ -186,7 +199,7 @@ class TestRaggedSkip:
             for lb in range(used, mbs):
                 kc_p[int(tables[bi, lb])] = np.nan
                 vc_p[int(tables[bi, lb])] = np.nan
-        out = paged_flash_decode(
+        out = chunk_decode_step(
             q, jnp.asarray(kc_p), jnp.asarray(vc_p), tables, lens, interpret=True
         )
         assert np.isfinite(np.asarray(out)).all()
@@ -200,13 +213,12 @@ class TestRaggedSkip:
         kc = jnp.asarray(np.full(kc.shape, np.nan, np.float32))
         vc = jnp.asarray(np.full(vc.shape, np.nan, np.float32))
         lens = jnp.zeros((q.shape[0],), jnp.int32)
-        out = np.asarray(paged_flash_decode(q, kc, vc, tables, lens, interpret=True))
+        out = np.asarray(chunk_decode_step(q, kc, vc, tables, lens, interpret=True))
         assert (out == 0.0).all()
 
 
 # -- ragged MIXED prefill/decode kernel (chunked prefill) ---------------------
 
-from paddle_tpu.kernels.paged_attention import paged_flash_chunk  # noqa: E402
 
 
 def _chunk_reference(q, key_cache, value_cache, tables, lens, q_lens):
@@ -269,8 +281,8 @@ class TestPagedFlashChunk:
         assert np.array_equal(np.asarray(out), np.zeros_like(np.asarray(out)))
 
     def test_decode_row_equals_decode_kernel(self):
-        """A chunk with q_lens == 1 must reproduce the decode kernel's
-        output for its first row — the two raggednesses agree."""
+        """A chunk of C == 4 with q_lens == 1 must reproduce the C == 1
+        call's output for its first row — the two raggednesses agree."""
         q, kc, vc, tables, lens = _setup(seed=5)
         b, hq, d = q.shape
         c = 4
@@ -281,7 +293,7 @@ class TestPagedFlashChunk:
         out_c = paged_flash_chunk(
             qc, kc, vc, tables, jnp.maximum(lens - 1, 0), q_lens, interpret=True
         )
-        out_d = paged_flash_decode(q, kc, vc, tables, lens, interpret=True)
+        out_d = chunk_decode_step(q, kc, vc, tables, lens, interpret=True)
         np.testing.assert_allclose(
             np.asarray(out_c[:, 0]), np.asarray(out_d), rtol=2e-5, atol=2e-5
         )
@@ -302,7 +314,6 @@ class TestPagedFlashChunk:
 
 from paddle_tpu.incubate.nn.functional import _rope_apply_xla  # noqa: E402
 from paddle_tpu.incubate.nn.functional.block_attention import _gather_chunk_attend  # noqa: E402
-from paddle_tpu.kernels.paged_attention import paged_flash_chunk_fused  # noqa: E402
 
 W_B, W_C, W_D, W_MBS, W_NB = 4, 4, 64, 17, 80  # max_model_len = 17 pages = 272
 W_TILE = 128  # key positions of one tile of the walk: 8 pages of 16
@@ -352,8 +363,9 @@ def _walk_fns(fused, interpret=True):
 
     def kernel(q, cos, sin, kc, vc, scales, tables, lens, q_lens):
         if fused:
-            return paged_flash_chunk_fused(
-                q, cos[:, :, 0], sin[:, :, 0], kc, vc, tables, lens, q_lens, scale=scale, interpret=interpret, **scales
+            return paged_flash_chunk(
+                q, kc, vc, tables, lens, q_lens, scale=scale, interpret=interpret,
+                cos=cos[:, :, 0], sin=sin[:, :, 0], **scales,
             )
         q = _rope_apply_xla(q, sin, cos, True)
         return paged_flash_chunk(q, kc, vc, tables, lens, q_lens, scale=scale, interpret=interpret, **scales)

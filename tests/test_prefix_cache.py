@@ -241,7 +241,7 @@ class TestHitParity:
     def test_shared_prefix_computed_once_across_requests(self):
         """N staggered requests sharing a system prompt: the shared full
         blocks are computed exactly once; warm admissions compute only their
-        tails (the honesty counter the bench records)."""
+        tails (the computed-exactly-once counter)."""
         m, cfg = _model(seed=43)
         rng = np.random.default_rng(43)
         shared = rng.integers(0, cfg.vocab_size, (8,)).astype(np.int32)

@@ -13,8 +13,7 @@ The acceptance surface of ``serving/router.py`` + ``serving/cluster.py``:
   (the seeded churn property test and the kill-mid-storm acceptance test);
 - drain semantics, health-probe fault degradation, flight-recorder state
   transitions, the ``router.failover`` trace span, and the all-replicas-dead
-  black-box dump;
-- the ``cluster_goodput_tokens_per_sec`` bench record (CPU smoke).
+  black-box dump.
 
 Everything runs on CPU with the tiny Llama config, same as test_serving.py.
 Replicas share one model object (read-only at inference): identical weights
@@ -777,38 +776,3 @@ class TestClusterHTTP:
             assert sum(snap["routes"].values()) >= 1
         finally:
             stop_serving_server(router)
-
-
-# -- bench smoke ---------------------------------------------------------------
-
-def test_bench_cluster_goodput_cpu_smoke():
-    """The guarded cluster bench runs on CPU with a tiny budget and carries
-    the fields reruns are compared on (ISSUE: CPU-smoked in tier-1)."""
-    import bench
-
-    rec = bench._bench_cluster_goodput(paddle, "cpu")
-    assert "error" not in rec, rec
-    assert rec["metric"] == "cluster_goodput_tokens_per_sec"
-    assert rec["value"] >= 0
-    assert rec["replicas"] == 3
-    assert rec["killed_replica"] in ("r0", "r1", "r2")
-    assert rec["compiled_signatures"] == 3, rec
-    assert rec["compiles_during_storm"] == 0, rec
-    assert set(rec["slo_attainment"]) == {
-        "chat/interactive", "app/standard", "batch/best_effort"
-    }
-    assert set(rec["affinity_hit_rate"]) == {"before_kill", "after_kill", "overall"}
-    assert rec["failovers"] + rec["salvaged"] >= 1
-    assert rec["offered_rate_rps"] == pytest.approx(
-        2 * 3 * rec["sustainable_rate_per_replica_rps"], rel=0.02
-    )
-    # fleet observability rides the storm: the monitor's state timeline is
-    # part of the record, and the whole layer adds zero compiled signatures
-    assert rec["one_compile_per_engine"] is True
-    mon = rec["slo_monitor"]
-    assert mon["final_state"] in ("ok", "warn", "page")
-    assert {"time_in_warn_s", "time_in_page_s", "transitions"} <= set(mon)
-    # the kill produces failovers/sheds: the monitor must have left OK at
-    # some point during the storm
-    assert any(e["to"] in ("warn", "page") for e in mon["transitions"]), mon
-    assert rec["incidents_written"] >= 1

@@ -834,23 +834,3 @@ class TestEngineAdmissionPolicy:
         assert eng.cancel_request(running) is None  # exactly once
         assert not eng.has_work()
         assert eng.run() == {}  # cancelled requests are NOT re-delivered
-
-
-# -- bench smoke --------------------------------------------------------------
-
-def test_bench_serving_goodput_cpu_smoke():
-    """The guarded bench record runs on CPU with a tiny budget and carries
-    the fields reruns are compared on."""
-    import bench
-
-    rec = bench._bench_serving_goodput(paddle, "cpu")
-    assert "error" not in rec, rec
-    assert rec["metric"] == "serving_goodput_tokens_per_sec"
-    assert rec["value"] >= 0
-    assert rec["compiled_signatures"] == 1, rec
-    assert rec["compiles_during_overload"] == 0, rec
-    assert set(rec["slo_attainment"]) == {
-        "chat/interactive", "app/standard", "batch/best_effort"
-    }
-    assert isinstance(rec["shed_total_by_reason"], dict)
-    assert rec["offered_rate_rps"] == pytest.approx(2 * rec["sustainable_rate_rps"], rel=0.02)

@@ -436,19 +436,3 @@ class TestSpecObservability:
         assert events, "rejections must leave spec_rewind events in the black box"
         e = events[-1]
         assert e["drafted"] == e["accepted"] + e["rejected"]
-
-
-def test_bench_spec_decode_cpu_smoke():
-    """Tier-1 smoke of the guarded bench: machinery runs, honesty fields
-    present (byte-identical greedy, 1 compile per engine), acceptance rate
-    reported. The >= 2x speedup itself is asserted loosely (> 1.2x) to stay
-    robust to CI-machine noise; the full number lands in the bench record."""
-    import bench
-
-    rec = bench._bench_spec_decode(paddle, "cpu")
-    assert "error" not in rec, rec
-    assert rec["greedy_identical_on_vs_off"] is True
-    assert rec["compiled_signatures_per_engine"] == {"off": 1, "on": 1}
-    assert 0.0 <= rec["acceptance_rate"] <= 1.0
-    assert rec["steps_on"] < rec["steps_off"]
-    assert rec["speedup_vs_off"] > 1.2

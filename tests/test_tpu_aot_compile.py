@@ -91,19 +91,19 @@ _CHAT_TP4 = dict(slots=16, hq=8, hkv=2, nb=4096, mbs=256)
 
 
 def _paged_chunk(kv_dtype, fused=False, slots=SLOTS, hq=HEADS, hkv=HEADS, nb=NB, mbs=MBS):
-    from paddle_tpu.kernels.paged_attention import paged_flash_chunk, paged_flash_chunk_fused
+    from paddle_tpu.kernels.paged_attention import paged_flash_chunk
 
     q = ((slots, CHUNK, hq, HEAD_DIM), BF16)
     rope = (((slots, CHUNK, HEAD_DIM), BF16),) * 2 if fused else ()
     pool = ((nb, hkv, BS, HEAD_DIM), kv_dtype)
     scales = (((nb, hkv, BS), F32),) * 2 if kv_dtype == I8 else ()
     tail = (((slots, mbs), I32), ((slots,), I32), ((slots,), I32))
-    kernel = paged_flash_chunk_fused if fused else paged_flash_chunk
 
     def fn(q, *rest):
-        n = len(rope) + 2  # the rope rows and the two pools, then the scale planes
-        sc = dict(zip(("k_scale", "v_scale"), rest[n : n + len(scales)]))
-        return kernel(q, *rest[:n], *rest[n + len(scales) :], **sc)
+        r = len(rope)  # the rope rows, then the two pools, then the scale planes
+        opt = dict(zip(("cos", "sin"), rest[:r]))
+        opt.update(zip(("k_scale", "v_scale"), rest[r + 2 : r + 2 + len(scales)]))
+        return paged_flash_chunk(q, *rest[r : r + 2], *rest[r + 2 + len(scales) :], **opt)
 
     return fn, (q, *rope, pool, pool, *scales, *tail)
 
@@ -239,6 +239,49 @@ def test_norm_kernel_under_a_four_chip_tp_mesh(wrapped, topo):
     with partitioned_trace(mesh):  # what the engine's dispatch arms
         compiled = jax.jit(lambda *a: per_shard(kernel)(*a)).lower(x, x, w).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_loss_never_holds_the_logits(one_chip):
+    """The fused loss head's claim where it is made, on the chip's compiler,
+    at the train cell's shapes (8 x 2048 tokens, Mistral's 32 768 vocabulary):
+    the plain ``cross_entropy(x @ W)`` composition keeps the ``[N, V]`` bf16
+    logits for its backward (1 GiB of temporaries, and nothing else: the
+    chip's compiler recomputes the float32 copies); the fused forward +
+    backward never holds them, and its temporaries are its float32 dW,
+    ``[H, V]`` (512 MiB), and little else: half. At 2048 tokens the same dW
+    is FOUR times what the plain composition holds (524 MB against 131), so
+    the memory claim is one about token counts above ``2 H``, not about the
+    kernel at any size (ROADMAP S10: the float32 dW)."""
+    from paddle_tpu.kernels.fused_loss import _default_block, _pallas_path
+
+    n, v = 8 * SEQ, 32768
+    block = _default_block(HIDDEN, 2)
+
+    def fused(x, w, lab):
+        return jax.grad(
+            lambda x, w: _pallas_path(
+                x, w, lab, v=v, h=HIDDEN, ignore_index=-100, reduction="mean",
+                vocab_major=False, interpret=False, block=block,
+            ),
+            argnums=(0, 1),
+        )(x, w)
+
+    def plain(x, w, lab):
+        def loss(x, w):
+            logits = (x @ w).astype(F32)
+            picked = jnp.take_along_axis(logits, lab[:, None], axis=-1)[:, 0]
+            return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)
+
+        return jax.grad(loss, argnums=(0, 1))(x, w)
+
+    shapes = (((n, HIDDEN), BF16), ((HIDDEN, v), BF16), ((n,), I32))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    with jax.default_matmul_precision("default"):
+        held_plain = jax.jit(plain).lower(*args).compile().memory_analysis().temp_size_in_bytes
+    held_fused = _compile(fused, one_chip, *shapes).memory_analysis().temp_size_in_bytes
+    assert held_plain >= n * v * 2, held_plain  # the logits
+    assert held_fused < HIDDEN * v * 4 * 1.1, held_fused  # the float32 dW and little else
+    assert held_fused < 0.55 * held_plain, (held_fused, held_plain)
 
 
 def test_fused_loss_default_block_keeps_bench_width_on_512():
