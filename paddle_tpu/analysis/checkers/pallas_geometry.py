@@ -25,7 +25,9 @@ Codes:
          but only concrete overruns become findings
 - PG903  per-grid-step VMEM window footprint (ins + outs + scratch, every
          resolvable configuration incl. autotune candidates) exceeds the
-         per-target budget (``--vmem-budget``, default 16 MiB/core)
+         per-target budget (``--vmem-budget``, default 16 MiB/core: Mosaic's
+         default scoped limit), or the site's own
+         ``CompilerParams(vmem_limit_bytes=...)`` where it states one
 - PG904  scalar-prefetch discipline — ``PrefetchScalarGridSpec`` arg counts
          vs kernel signature positions; prefetch refs indexed only by
          grid-derived values
@@ -208,6 +210,12 @@ class PallasGeometryChecker(Checker):
     def _check_vmem(self, ctx: FileContext, site: SiteEval) -> List[Violation]:
         out: List[Violation] = []
         budget = int(self.vmem_budget)
+        limit = site.vmem_limit
+        if limit is not None:
+            # the site asks Mosaic for its own scoped limit: that is its budget
+            if not limit.known:
+                return out  # derived from runtime shapes: nothing to hold it to
+            budget = min(limit.values)
         seen: Set[str] = set()
         for cfg in site.vmem_configs:
             b = cfg.bytes_per_step

@@ -204,6 +204,10 @@ class SiteEval:
     scratch_nodes: List[Optional[ast.AST]] = field(default_factory=list)
     axis_proofs: List[AxisProof] = field(default_factory=list)
     vmem_configs: List[VmemConfig] = field(default_factory=list)
+    # the site's own ``CompilerParams(vmem_limit_bytes=...)``: None when it
+    # states none (Mosaic's default applies), else the resolved ValueSet
+    # (UNPROVEN when the site computes its limit from runtime shapes)
+    vmem_limit: Optional[ValueSet] = None
     # (lineno, detail) — prefetch refs indexed by non-grid values (PG904)
     prefetch_indexing: List[Tuple[int, str]] = field(default_factory=list)
     notes: List[str] = field(default_factory=list)
@@ -395,6 +399,7 @@ class _ModuleEval:
                 ast.FloorDiv: lambda x, y: x // y,
                 ast.Mod: lambda x, y: x % y,
                 ast.Pow: lambda x, y: x ** y if y >= 0 and y < 64 else 1 // 0,
+                ast.LShift: lambda x, y: x << y if 0 <= y < 64 else 1 // 0,  # 64 << 20
             }
             f = ops.get(type(node.op))
             return _fold2(f, a, b) if f else UNPROVEN
@@ -1104,6 +1109,7 @@ class _ModuleEval:
                 )
         site.grid_node = grid_expr
 
+        site.vmem_limit = self._stated_vmem_limit(kw.get("compiler_params"), scopes)
         self._prove_axes(site, scopes, configs)
         self._eval_vmem(site, scopes, configs)
         if site.prefetch_grid_spec and site.num_scalar_prefetch > 0:
@@ -1348,6 +1354,29 @@ class _ModuleEval:
         return ("proven", "")
 
     # -- VMEM footprint --------------------------------------------------------
+    def _stated_vmem_limit(self, expr, scopes) -> Optional[ValueSet]:
+        """``vmem_limit_bytes`` of the site's ``compiler_params``, written at
+        the site or in the local helper that builds them (whose limit then
+        comes from its arguments: stated, not resolvable)."""
+        expr = self._deref(expr, scopes) if expr is not None else None
+        if not isinstance(expr, ast.Call):
+            return None
+        limit = self._kw(expr, "vmem_limit_bytes")
+        if limit is not None:
+            if isinstance(limit, ast.Constant) and limit.value is None:
+                return None
+            value = self.resolve(limit, scopes)
+            return value if isinstance(value, ValueSet) else UNPROVEN
+        helper = self.defs.get(_last(_attr_chain(expr.func)))
+        if helper is not None and any(
+            isinstance(r, ast.Return)
+            and isinstance(r.value, ast.Call)
+            and self._kw(r.value, "vmem_limit_bytes") is not None
+            for r in ast.walk(helper)
+        ):
+            return UNPROVEN
+        return None
+
     def _eval_vmem(self, site: SiteEval, scopes, configs) -> None:
         for cfg in configs:
             total = ValueSet.of(0)

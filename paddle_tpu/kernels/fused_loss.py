@@ -17,7 +17,8 @@ attention's online softmax:
   emits ``(softmax - onehot) * dloss`` block-wise, accumulating ``dX`` (row
   blocks) and ``dW`` (vocab blocks) in two Pallas kernels — the flash-attn-2
   dq/dkv split, so each output is only ever revisited on consecutive grid
-  steps;
+  steps; both sums stay in float32 VMEM scratch and leave their kernel once,
+  in the operand dtype (no float32 ``[N, H]`` / ``[H, V]`` in HBM);
 - a ``lax.scan``-over-vocab-chunks reference with the SAME custom-VJP
   decomposition (pure jnp) runs on CPU / in tier-1 / as the fallback, so the
   numerics are pinned off-TPU. (Differentiating *through* a scan would stash
@@ -30,8 +31,12 @@ Weight layouts: ``vocab_major=False`` is ``nn.Linear`` 's ``[H, V]``
 Both fuse without a transpose — only BlockSpec index maps and dot dims
 change.
 
-Selection: ``FLAGS_use_fused_loss`` + TPU backend picks the Pallas kernels
-(vocab/row block sizes autotuned per shape, ``kernels/autotune.py``); any
+Selection: ``FLAGS_use_fused_loss`` + TPU backend picks the Pallas kernels.
+Each kernel's (row block, vocab block) comes from the call's shapes
+(``_block_geometry``: the kept block sets how often the other operand is
+re-read from HBM, so it is as large as the chip's VMEM takes, and the kernel
+asks Mosaic for that VMEM through ``vmem_limit_bytes``); ``kernels/autotune.py``
+may time the tiles the geometry admits instead. Any
 Pallas failure falls back to the scan reference through
 ``kernels.select.warn_fallback`` (counted in
 ``paddle_tpu_kernel_fallbacks_total``).
@@ -40,7 +45,8 @@ Pallas failure falls back to the scan reference through
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -325,73 +331,240 @@ def _flxent_block_d(x_ref, w_ref, lab_ref, lse_ref, gc_ref, j, *, v, blk_v, voca
     return ((p - onehot) * gc_ref[...]).astype(x.dtype)
 
 
-def _flxent_dx_kernel(x_ref, w_ref, lab_ref, lse_ref, gc_ref, dx_ref, *, v, blk_v, vocab_major):
+def _flxent_dx_kernel(
+    x_ref, w_ref, lab_ref, lse_ref, gc_ref, dx_ref, acc_ref, *, v, blk_v, vocab_major
+):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        dx_ref[...] = jnp.zeros_like(dx_ref[...])
+        acc_ref[...] = jnp.zeros_like(acc_ref[...])
 
     d = _flxent_block_d(
         x_ref, w_ref, lab_ref, lse_ref, gc_ref, j, v=v, blk_v=blk_v, vocab_major=vocab_major
     )
     w = w_ref[...]
     if vocab_major:  # d [br, bv] @ w [bv, H]
-        dx_ref[...] += jax.lax.dot_general(
+        acc_ref[...] += jax.lax.dot_general(
             d, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
     else:  # d [br, bv] @ w [H, bv]ᵀ
-        dx_ref[...] += jax.lax.dot_general(
+        acc_ref[...] += jax.lax.dot_general(
             d, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
 
+    # the float32 sum stays in VMEM; HBM sees dX once, in the operand dtype
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _store():
+        dx_ref[...] = acc_ref[...].astype(dx_ref.dtype)
 
-def _flxent_dw_kernel(x_ref, w_ref, lab_ref, lse_ref, gc_ref, dw_ref, *, v, blk_v, vocab_major):
+
+def _flxent_dw_kernel(
+    x_ref, w_ref, lab_ref, lse_ref, gc_ref, dw_ref, acc_ref, *, v, blk_v, vocab_major
+):
     j = pl.program_id(0)  # vocab block (outer, parallel)
     i = pl.program_id(1)  # row block (inner, sequential accumulation)
 
     @pl.when(i == 0)
     def _init():
-        dw_ref[...] = jnp.zeros_like(dw_ref[...])
+        acc_ref[...] = jnp.zeros_like(acc_ref[...])
 
     d = _flxent_block_d(
         x_ref, w_ref, lab_ref, lse_ref, gc_ref, j, v=v, blk_v=blk_v, vocab_major=vocab_major
     )
     x = x_ref[...]
     if vocab_major:  # dᵀ [bv, br] @ x [br, H]
-        dw_ref[...] += jax.lax.dot_general(
+        acc_ref[...] += jax.lax.dot_general(
             d, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
     else:  # xᵀ [H, br] @ d [br, bv]
-        dw_ref[...] += jax.lax.dot_general(
+        acc_ref[...] += jax.lax.dot_general(
             x, d, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _store():
+        dw_ref[...] = acc_ref[...].astype(dw_ref.dtype)
 
-@functools.lru_cache(maxsize=None)
-def _make_pallas_core(
-    n_pad, v, vp, h, blk_rows, blk_v, vocab_major, interpret, ignore_index, reduction
-):
-    nr = n_pad // blk_rows
-    nv = vp // blk_v
-    row_spec = pl.BlockSpec((blk_rows, h), lambda i, j: (i, 0))
-    col_spec = pl.BlockSpec((blk_rows, 1), lambda i, j: (i, 0))  # lab/lse/gc/m/l/tl
-    if vocab_major:
-        w_spec = pl.BlockSpec((blk_v, h), lambda i, j: (j, 0))
+
+# --------------------------------------------------------------------------
+# block geometry: each kernel's tile, and the VMEM it asks for, from the shapes
+# --------------------------------------------------------------------------
+
+
+class LossTiles(NamedTuple):
+    """(row block, vocab block) of each kernel's grid step."""
+
+    fwd: Tuple[int, int]
+    dx: Tuple[int, int]
+    dw: Tuple[int, int]
+
+
+# (rows, vocab columns) each kernel takes where VMEM allows: past these the
+# chip gains nothing (tools/loss_head_bench.py at the train cell's shapes,
+# PERF.md, PR 30). Forward and dX keep a ROW block of x in VMEM and stream W
+# past it, dW keeps a VOCAB block of W and streams x: the kept side is how
+# often the other operand is read from HBM (128 rows: W 128 times a call, and
+# the forward HBM-bound at 44 % of the MXU's peak; 512: 32 times, 90 %). The
+# forward's online softmax pays per grid step, so its vocab block is wider.
+_TILES = {"fwd": (512, 1024), "dx": (512, 512), "dw": (512, 512)}
+_SCOPED_VMEM_DEFAULT = 16 << 20  # what Mosaic gives a kernel that states no limit
+_VMEM_OFF_CHIP = 128 << 20  # v5e's, for a trace with no TPU behind it (interpret mode, a described chip)
+
+
+def _vmem_capacity() -> int:
+    """Bytes of VMEM a core of the chip has (not the 16 MiB a kernel gets by default)."""
+    try:
+        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+    except Exception:  # noqa: BLE001 - no TPU here
+        return _VMEM_OFF_CHIP
+
+
+def _vmem_need(kernel: str, br: int, bv: int, h: int, x_item: int, w_item: int) -> int:
+    """Bytes of VMEM ``kernel`` takes at a ``br`` x ``bv`` tile, as the chip's
+    compiler counts them (fitted to its refusals at 24 tiles of the train
+    cell's shapes, within -8 .. +25 %, plus an eighth; tests/test_tpu_aot_compile.py
+    holds it to that): every blocked operand double-buffered, whole-``h`` rows of
+    x and columns of W, the ``[br, 1]`` columns padded to 128 lanes, the float32
+    accumulator of dX / dW with the product that is added to it, the output
+    block in the operand dtype, and three float32 tiles of logits."""
+    x = 2 * br * h * x_item
+    w = 2 * h * bv * w_item
+    tile = 3 * br * bv * 4
+    col = 2 * br * 128 * 4
+    if kernel == "fwd":
+        need = x + w + 4 * col + tile
+    elif kernel == "fwd_quant":  # both operands are upcast to float32 for the dot
+        need = x + w + 4 * col + tile + (br * h * 4 if x_item < 4 else 0) + h * bv * 4
+    elif kernel == "dx":
+        need = x + w + 3 * col + tile + 2 * br * h * 4 + 2 * br * h * x_item
     else:
-        w_spec = pl.BlockSpec((h, blk_v), lambda i, j: (0, j))
+        need = x + w + 3 * col + tile + 2 * h * bv * 4 + 2 * h * bv * w_item
+    return need + need // 8 + (2 << 20)
+
+
+def _vmem_budget() -> int:
+    return _vmem_capacity() * 3 // 4  # the rest is the compiler's own
+
+
+def _fit(size: int, cap: int, unit: int) -> Tuple[int, int]:
+    """``(block, blocks)`` for a dimension of ``size``: the largest multiple of
+    ``unit`` between ``cap / 2`` and ``cap`` that divides it (no padding, so no
+    copy of the operand), else the fewest blocks of at most ``cap``, cut evenly:
+    32000 -> 125 x 256 under a cap of 512, 2100 rows -> 5 x 432 (not 4 x 512 + 52)."""
+    if size <= cap:
+        return _round_up(size, unit), 1
+    for c in range(cap - cap % unit, cap // 2 - 1, -unit):
+        if size % c == 0:
+            return c, size // c
+    blocks = -(-size // cap)
+    return _round_up(-(-size // blocks), unit), blocks
+
+
+def _grow(block: int, blocks: int, cap: int) -> int:
+    """The most whole ``block``s a kernel with room for ``cap`` takes at once,
+    as a divisor of their count: every kernel's tile divides the padded size."""
+    return block * max(m for m in range(1, max(cap // block, 1) + 1) if blocks % m == 0)
+
+
+def _block_geometry(
+    n: int, v: int, h: int, x_item: int, w_item: int, quantized: bool = False
+) -> LossTiles:
+    """Each kernel's (row block, vocab block) from the call's shapes and the
+    operands' item sizes: ``_TILES`` where the chip's VMEM (three quarters of
+    it) takes that, else halved, the streamed side first (down to 256: it
+    costs grid steps only), then the kept side, whose size is the flops a
+    streamed byte buys. The rows are then cut into the fewest even blocks and
+    the vocabulary into blocks that divide it where some do (``_fit``), shared
+    by the three kernels; a kernel with room for more takes whole multiples
+    (``_grow``). The kernels ask Mosaic for the VMEM their tile needs
+    (``_vmem_need`` -> ``vmem_limit_bytes``): its 16 MiB default, taken as the
+    budget, left 128 x 128 at every ``h >= 4096``."""
+    budget = _vmem_budget()
+    caps = {}
+    for kernel, tile in _TILES.items():
+        model = "fwd_quant" if quantized and kernel == "fwd" else kernel
+        tile = list(tile)
+        kept = 1 if kernel == "dw" else 0  # dW keeps vocab columns, the others rows
+        while _vmem_need(model, *tile, h, x_item, w_item) > budget and tile != [128, 128]:
+            tile[1 - kept if tile[1 - kept] > 256 or tile[kept] == 128 else kept] //= 2
+        caps[kernel] = tuple(tile)
+    rows, row_blocks = _fit(n, min(br for br, _ in caps.values()), 16)
+    cols, col_blocks = _fit(v, min(bv for _, bv in caps.values()), 128)
+    return LossTiles(
+        *((_grow(rows, row_blocks, br), _grow(cols, col_blocks, bv)) for br, bv in caps.values())
+    )
+
+
+def _admitted_tiles(h, x_item, w_item):
+    """Uniform tiles every kernel has the VMEM for: the autotuner's candidates."""
+    budget = _vmem_budget()
+    return [
+        LossTiles(*((br, bv),) * 3)
+        for br in (256, 512, 1024)
+        for bv in (256, 512, 1024)
+        if all(_vmem_need(k, br, bv, h, x_item, w_item) <= budget for k in ("fwd", "dx", "dw"))
+    ]
+
+
+def _as_tiles(block) -> LossTiles:
+    if len(block) == 2 and isinstance(block[0], int):
+        return LossTiles(*((int(block[0]), int(block[1])),) * 3)
+    return LossTiles(*((int(br), int(bv)) for br, bv in block))
+
+
+def _fit_tiles(block, n: int, v: int):
+    """``(tiles, n_pad, vp)``: a small batch is one row block in every kernel,
+    and the operands are padded once, to a size every kernel's tile divides."""
+    one = _round_up(n, 16)
+    tiles = LossTiles(*((min(br, one), bv) for br, bv in _as_tiles(block)))
+    n_pad = _round_up(n, math.lcm(*(br for br, _ in tiles)))
+    vp = _round_up(v, math.lcm(*(bv for _, bv in tiles)))
+    return tiles, n_pad, vp
+
+
+def _params(need: int):
+    """Compiler params of a kernel whose tile takes ``need`` bytes of VMEM:
+    where that is over Mosaic's default scoped limit, the kernel asks for it."""
+    return pltpu.CompilerParams(
+        # the outer grid dimension's blocks are independent (megacore-
+        # splittable); the inner one accumulates (the online softmax state, dX
+        # over vocab blocks, dW over row blocks) and MUST run sequentially
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=need if need > _SCOPED_VMEM_DEFAULT else None,
+    )
+
+
+def _w_spec(h, blk_v, vocab_major, vocab_axis):
+    """The weight's (or dW's) block, vocab block index on grid axis ``vocab_axis``."""
+    if vocab_major:
+        return pl.BlockSpec((blk_v, h), lambda *g: (g[vocab_axis], 0))
+    return pl.BlockSpec((h, blk_v), lambda *g: (0, g[vocab_axis]))
+
+
+def _pallas_engines(n_pad, v, vp, h, tiles, vocab_major, interpret):
+    """``(engine_fwd, engine_bwd)`` over padded operands (``_build_core``'s
+    contract). Forward and dX grid (row blocks, vocab blocks): a row block of x
+    stays in VMEM while the weight streams past it, so the ROW block sets how
+    often W is read from HBM. dW grids (vocab blocks, row blocks), its
+    accumulation dim innermost (an output block may only be revisited on
+    consecutive grid steps): a weight block stays and x streams past it."""
 
     def engine_fwd(x2, wp, lab):
+        br, bv = tiles.fwd
+        col = pl.BlockSpec((br, 1), lambda i, j: (i, 0))  # lab / m / l / tl
         m, l, tl = pl.pallas_call(
-            functools.partial(
-                _flxent_fwd_kernel, v=v, blk_v=blk_v, vocab_major=vocab_major
+            functools.partial(_flxent_fwd_kernel, v=v, blk_v=bv, vocab_major=vocab_major),
+            grid=(n_pad // br, vp // bv),
+            compiler_params=_params(
+                _vmem_need("fwd", br, bv, h, x2.dtype.itemsize, wp.dtype.itemsize)
             ),
-            grid=(nr, nv),
-            # row blocks are independent (megacore-splittable); the vocab dim
-            # accumulates the online softmax state and MUST run sequentially
-            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-            in_specs=[row_spec, w_spec, col_spec],
-            out_specs=[col_spec, col_spec, col_spec],
+            in_specs=[
+                pl.BlockSpec((br, h), lambda i, j: (i, 0)),
+                _w_spec(h, bv, vocab_major, 1),
+                col,
+            ],
+            out_specs=[col, col, col],
             out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.float32)] * 3,
             interpret=interpret,
             name=KERNEL_FWD,
@@ -399,69 +572,70 @@ def _make_pallas_core(
         return (m + jnp.log(l))[:, 0], tl[:, 0]
 
     def engine_bwd(x2, wp, lab, lse, gcoef):
-        lab2 = lab.reshape(n_pad, 1)
-        lse2 = lse.reshape(n_pad, 1)
-        gc2 = gcoef.reshape(n_pad, 1)
-        kw = dict(v=v, blk_v=blk_v, vocab_major=vocab_major)
+        cols = (lab.reshape(n_pad, 1), lse.reshape(n_pad, 1), gcoef.reshape(n_pad, 1))
+        sizes = (h, x2.dtype.itemsize, wp.dtype.itemsize)
+        br, bv = tiles.dx
+        col = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+        row = pl.BlockSpec((br, h), lambda i, j: (i, 0))
         dx = pl.pallas_call(
-            functools.partial(_flxent_dx_kernel, **kw),
-            grid=(nr, nv),
-            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-            in_specs=[row_spec, w_spec, col_spec, col_spec, col_spec],
-            out_specs=row_spec,
-            out_shape=jax.ShapeDtypeStruct((n_pad, h), jnp.float32),
+            functools.partial(_flxent_dx_kernel, v=v, blk_v=bv, vocab_major=vocab_major),
+            grid=(n_pad // br, vp // bv),
+            compiler_params=_params(_vmem_need("dx", br, bv, *sizes)),
+            in_specs=[row, _w_spec(h, bv, vocab_major, 1), col, col, col],
+            out_specs=row,
+            out_shape=jax.ShapeDtypeStruct((n_pad, h), x2.dtype),
+            scratch_shapes=[pltpu.VMEM((br, h), jnp.float32)],
             interpret=interpret,
             name=KERNEL_DX,
-        )(x2, wp, lab2, lse2, gc2)
-        # dW: transposed grid so its accumulation dim (rows) is innermost —
-        # an output block may only be revisited on consecutive grid steps
-        if vocab_major:
-            dw_spec = pl.BlockSpec((blk_v, h), lambda j, i: (j, 0))
-            dw_shape = jax.ShapeDtypeStruct((vp, h), jnp.float32)
-        else:
-            dw_spec = pl.BlockSpec((h, blk_v), lambda j, i: (0, j))
-            dw_shape = jax.ShapeDtypeStruct((h, vp), jnp.float32)
+        )(x2, wp, *cols)
+        br, bv = tiles.dw
+        col = pl.BlockSpec((br, 1), lambda j, i: (i, 0))
+        w_spec = _w_spec(h, bv, vocab_major, 0)
         dw = pl.pallas_call(
-            functools.partial(_flxent_dw_kernel, **kw),
-            grid=(nv, nr),
-            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-            in_specs=[
-                pl.BlockSpec((blk_rows, h), lambda j, i: (i, 0)),
-                pl.BlockSpec((blk_v, h), lambda j, i: (j, 0))
-                if vocab_major
-                else pl.BlockSpec((h, blk_v), lambda j, i: (0, j)),
-                pl.BlockSpec((blk_rows, 1), lambda j, i: (i, 0)),
-                pl.BlockSpec((blk_rows, 1), lambda j, i: (i, 0)),
-                pl.BlockSpec((blk_rows, 1), lambda j, i: (i, 0)),
-            ],
-            out_specs=dw_spec,
-            out_shape=dw_shape,
+            functools.partial(_flxent_dw_kernel, v=v, blk_v=bv, vocab_major=vocab_major),
+            grid=(vp // bv, n_pad // br),
+            compiler_params=_params(_vmem_need("dw", br, bv, *sizes)),
+            in_specs=[pl.BlockSpec((br, h), lambda j, i: (i, 0)), w_spec, col, col, col],
+            out_specs=w_spec,
+            out_shape=jax.ShapeDtypeStruct(wp.shape, wp.dtype),
+            scratch_shapes=[pltpu.VMEM((bv, h) if vocab_major else (h, bv), jnp.float32)],
             interpret=interpret,
             name=KERNEL_DW,
-        )(x2, wp, lab2, lse2, gc2)
-        return dx.astype(x2.dtype), dw.astype(wp.dtype)
+        )(x2, wp, *cols)
+        return dx, dw
 
-    return _build_core(engine_fwd, engine_bwd, ignore_index, reduction)
+    return engine_fwd, engine_bwd
+
+
+@functools.lru_cache(maxsize=None)
+def _make_pallas_core(n_pad, v, vp, h, tiles, vocab_major, interpret, ignore_index, reduction):
+    return _build_core(
+        *_pallas_engines(n_pad, v, vp, h, tiles, vocab_major, interpret),
+        ignore_index, reduction,
+    )
+
+
+def _pad_operands(x2, w, lab, n_pad, vp, ignore_index, vocab_major):
+    """Rows padded with ``ignore_index`` labels, the vocabulary with zero
+    weights (the kernels mask columns past ``v``)."""
+    n = x2.shape[0]
+    v = w.shape[0] if vocab_major else w.shape[1]
+    if n_pad > n:
+        x2 = jnp.pad(x2, ((0, n_pad - n), (0, 0)))
+        lab = jnp.pad(lab, (0, n_pad - n), constant_values=ignore_index)
+    if vp > v:
+        w = jnp.pad(w, ((0, vp - v), (0, 0)) if vocab_major else ((0, 0), (0, vp - v)))
+    return x2, w, lab
 
 
 def _pallas_path(x2, w, lab, *, v, h, ignore_index, reduction, vocab_major, interpret, block):
     n = x2.shape[0]
-    blk_rows, blk_v = block
-    blk_rows = min(blk_rows, _round_up(n, 16))  # small batches: one row block
-    n_pad = _round_up(n, blk_rows)
-    vp = _round_up(v, blk_v)
+    tiles, n_pad, vp = _fit_tiles(block, n, v)
     # padding / layout prep sits OUTSIDE the custom VJP: its transpose rules
     # slice dX and dW back to the caller's shapes automatically
-    x2p = jnp.pad(x2, ((0, n_pad - n), (0, 0))) if n_pad > n else x2
-    labp = (
-        jnp.pad(lab, (0, n_pad - n), constant_values=ignore_index) if n_pad > n else lab
-    )
-    if vp > v:
-        wp = jnp.pad(w, ((0, vp - v), (0, 0)) if vocab_major else ((0, 0), (0, vp - v)))
-    else:
-        wp = w
+    x2p, wp, labp = _pad_operands(x2, w, lab, n_pad, vp, ignore_index, vocab_major)
     core = _make_pallas_core(
-        n_pad, v, vp, h, blk_rows, blk_v, vocab_major, interpret, ignore_index, reduction
+        n_pad, v, vp, h, tiles, vocab_major, interpret, ignore_index, reduction
     )
     loss = core(x2p, wp, labp)
     if reduction == "none":
@@ -472,15 +646,7 @@ def _pallas_path(x2, w, lab, *, v, h, ignore_index, reduction, vocab_major, inte
 @functools.lru_cache(maxsize=None)
 def _make_pallas_quant_fwd(n_pad, v, vp, h, blk_rows, blk_v, vocab_major, interpret):
     """Forward-only quantized engine: the fwd kernel with a scale input."""
-    nr = n_pad // blk_rows
-    nv = vp // blk_v
-    row_spec = pl.BlockSpec((blk_rows, h), lambda i, j: (i, 0))
     col_spec = pl.BlockSpec((blk_rows, 1), lambda i, j: (i, 0))
-    if vocab_major:
-        w_spec = pl.BlockSpec((blk_v, h), lambda i, j: (j, 0))
-    else:
-        w_spec = pl.BlockSpec((h, blk_v), lambda i, j: (0, j))
-    s_spec = pl.BlockSpec((1, blk_v), lambda i, j: (0, j))
 
     def engine_fwd(x2, wp, sp, lab):
         m, l, tl = pl.pallas_call(
@@ -488,9 +654,16 @@ def _make_pallas_quant_fwd(n_pad, v, vp, h, blk_rows, blk_v, vocab_major, interp
                 _flxent_fwd_kernel, v=v, blk_v=blk_v, vocab_major=vocab_major,
                 quantized=True,
             ),
-            grid=(nr, nv),
-            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-            in_specs=[row_spec, w_spec, col_spec, s_spec],
+            grid=(n_pad // blk_rows, vp // blk_v),
+            compiler_params=_params(
+                _vmem_need("fwd_quant", blk_rows, blk_v, h, x2.dtype.itemsize, wp.dtype.itemsize)
+            ),
+            in_specs=[
+                pl.BlockSpec((blk_rows, h), lambda i, j: (i, 0)),
+                _w_spec(h, blk_v, vocab_major, 1),
+                col_spec,
+                pl.BlockSpec((1, blk_v), lambda i, j: (0, j)),
+            ],
             out_specs=[col_spec, col_spec, col_spec],
             out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.float32)] * 3,
             interpret=interpret,
@@ -505,22 +678,13 @@ def _pallas_quant_path(
     x2, w, scale, lab, *, v, h, ignore_index, reduction, vocab_major, interpret, block
 ):
     n = x2.shape[0]
-    blk_rows, blk_v = block
-    blk_rows = min(blk_rows, _round_up(n, 16))
-    n_pad = _round_up(n, blk_rows)
-    vp = _round_up(v, blk_v)
-    x2p = jnp.pad(x2, ((0, n_pad - n), (0, 0))) if n_pad > n else x2
-    labp = (
-        jnp.pad(lab, (0, n_pad - n), constant_values=ignore_index) if n_pad > n else lab
-    )
+    tiles, n_pad, vp = _fit_tiles(block, n, v)
+    x2p, wp, labp = _pad_operands(x2, w, lab, n_pad, vp, ignore_index, vocab_major)
     sp = scale.astype(jnp.float32)
     if vp > v:
-        w = jnp.pad(w, ((0, vp - v), (0, 0)) if vocab_major else ((0, 0), (0, vp - v)))
         sp = jnp.pad(sp, (0, vp - v))
-    engine = _make_pallas_quant_fwd(
-        n_pad, v, vp, h, blk_rows, blk_v, vocab_major, interpret
-    )
-    lse, tl = engine(x2p, w, sp, labp)
+    engine = _make_pallas_quant_fwd(n_pad, v, vp, h, *tiles.fwd, vocab_major, interpret)
+    lse, tl = engine(x2p, wp, sp, labp)
     loss = _quant_epilogue(lse, tl, labp, ignore_index, reduction)
     if reduction == "none":
         loss = loss[:n]
@@ -532,43 +696,16 @@ def _pallas_quant_path(
 # --------------------------------------------------------------------------
 
 
-def _default_block(h: int, itemsize: int) -> Tuple[int, int]:
-    # larger hidden sizes need smaller blocks — pick the largest tier that
-    # fits the same budget the autotune candidate filter enforces
-    for cfg in ((512, 512), (256, 256), (128, 128)):
-        if _vmem_ok(cfg[0], cfg[1], h, itemsize):
-            return cfg
-    return (128, 128)
-
-
-def _vmem_ok(blk_rows: int, blk_v: int, h: int, itemsize: int) -> bool:
-    """Whether the fattest backward kernel fits the chip's default 16 MiB
-    scoped-VMEM limit, counted as the TPU compiler counts it: the Pallas
-    pipeline double-buffers EVERY blocked operand — both inputs and the fp32
-    output accumulator (dW ``[blk_v, H]`` / dX ``[blk_rows, H]``) — and the
-    block's logits and their softmax copy live beside them."""
-    buffers = 2 * (
-        blk_rows * h * itemsize  # x block
-        + blk_v * h * itemsize  # w block
-        + max(blk_rows, blk_v) * h * 4  # fp32 dw / dx accumulator
-    )
-    temporaries = 2 * blk_rows * blk_v * 4  # logits + (softmax - onehot)
-    return buffers + temporaries <= 16 * 1024 * 1024
-
-
 def _autotune_fused_loss(n, v, h, dtype, vocab_major, interpret):
-    """Benchmark-pick (row-block, vocab-block) for this loss-head shape
-    (reference ``auto_tune_base.h:48``); defaults when tuning is off."""
+    """Benchmark-pick each kernel's (row-block, vocab-block) for this loss-head
+    shape (reference ``auto_tune_base.h:48``) among the tiles the geometry
+    admits; the geometry's own answer when tuning is off."""
     from paddle_tpu.kernels.autotune import autotune
 
     itemsize = jnp.dtype(dtype).itemsize
     key = (n, v, h, str(dtype), vocab_major)
-    candidates = [
-        (br, bv)
-        for br in (256, 512, 1024)
-        for bv in (256, 512, 1024)
-        if _vmem_ok(br, bv, h, itemsize)
-    ]
+    default = _block_geometry(n, v, h, itemsize, itemsize)
+    candidates = [default] + _admitted_tiles(h, itemsize, itemsize)
 
     def build(cfg):
         xz = jnp.zeros((n, h), dtype)
@@ -587,9 +724,7 @@ def _autotune_fused_loss(n, v, h, dtype, vocab_major, interpret):
 
         return run
 
-    return autotune(
-        "fused_linear_xent", key, candidates, build, default=_default_block(h, itemsize)
-    )
+    return autotune("fused_linear_xent", key, candidates, build, default=default)
 
 
 def fused_linear_cross_entropy(
@@ -635,7 +770,9 @@ def fused_linear_cross_entropy(
         if bool(interpret) or (
             h % 128 == 0 and pallas_enabled("use_fused_loss", bare="fused_linear_xent_quant")
         ):
-            blk = tuple(block) if block is not None else _default_block(h, 1)
+            blk = block if block is not None else _block_geometry(
+                n, v, h, x.dtype.itemsize, weight.dtype.itemsize, quantized=True
+            )
             try:
                 loss = _pallas_quant_path(
                     x2, weight, weight_scale, lab, v=v, h=h,
@@ -660,7 +797,7 @@ def fused_linear_cross_entropy(
     if bool(interpret) or (
         h % 128 == 0 and pallas_enabled("use_fused_loss", bare="fused_linear_cross_entropy")
     ):
-        blk = tuple(block) if block is not None else _autotune_fused_loss(
+        blk = block if block is not None else _autotune_fused_loss(
             n, v, h, x.dtype, vocab_major, bool(interpret)
         )
         try:

@@ -13,7 +13,8 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.core import memory as M
 from paddle_tpu.flags import GLOBAL_FLAGS, FlagRegistry
-from paddle_tpu.kernels.fused_loss import fused_linear_cross_entropy
+from paddle_tpu.kernels import fused_loss as FL
+from paddle_tpu.kernels.fused_loss import LossTiles, fused_linear_cross_entropy
 from paddle_tpu.nn.functional.loss import cross_entropy
 
 IGN = -100
@@ -35,6 +36,13 @@ def _unfused(x, w, lab, reduction="mean"):
 
 def _grads(fn, *args):
     return jax.value_and_grad(fn, argnums=(0, 1))(*args)
+
+
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_eqns(sub)
 
 
 class TestReferenceParity:
@@ -101,43 +109,87 @@ class TestReferenceParity:
         np.testing.assert_allclose(np.asarray(per).ravel(), np.asarray(ref), rtol=1e-4, atol=1e-5)
 
 
+# the block geometry's regimes (ISSUE 30): n, v, dtype, and the tiles the three
+# kernels run (None: what ``_block_geometry`` derives for the shape)
+_REGIMES = {
+    # forward, dX and dW each on its own tile; rows pad 80 -> 96, vocab 1000 -> 1024
+    "tiles_differ": dict(n=80, v=1000, block=LossTiles((32, 256), (16, 512), (48, 128))),
+    "tiles_differ_bf16": dict(
+        n=96, v=512, dtype=jnp.bfloat16, block=LossTiles((96, 128), (32, 256), (48, 512))
+    ),
+    # n and v not multiples of the tile (1000 % 128 != 0: ragged tail)
+    "ragged_rows_and_vocab": dict(n=40, v=1000, block=(16, 128)),
+    "ragged_rows_bf16": dict(n=40, v=256, dtype=jnp.bfloat16, block=(16, 128)),
+    "even_tiles": dict(n=48, v=256, block=(16, 128)),
+    # one row block, one vocab block: the geometry's answer for a small batch
+    "one_row_block": dict(n=24, v=200, block=None),
+    "one_row_block_bf16": dict(n=40, v=384, dtype=jnp.bfloat16, block=None),
+    # several row blocks accumulate dW, several vocab blocks dX, in VMEM
+    "many_blocks_each_way": dict(n=64, v=512, block=(16, 128)),
+}
+
+
 class TestPallasInterpretParity:
-    """The Pallas kernels (fwd + dX + dW), interpret mode on CPU."""
+    """The Pallas kernels (fwd + dX + dW), interpret mode on CPU, against the
+    scan reference: the same custom-VJP decomposition, the same roundings."""
 
-    @pytest.mark.parametrize("vocab_major", [False, True])
-    @pytest.mark.parametrize("v", [1000, 256])  # 1000 % 128 != 0: ragged tail
-    def test_loss_and_grads(self, vocab_major, v):
-        x, w, lab = _data(h=128, v=v)
+    @pytest.mark.parametrize("vocab_major", [False, True], ids=["hidden_major", "vocab_major"])
+    @pytest.mark.parametrize("regime", sorted(_REGIMES))
+    def test_loss_and_grads_match_the_scan_reference(self, regime, vocab_major):
+        cfg = dict(_REGIMES[regime])
+        block = cfg.pop("block")
+        x, w, lab = _data(h=128, **cfg)
         wl = w.T if vocab_major else w
-        lu, gu = _grads(_unfused, x, w, lab)
-        lp, gp = _grads(
-            lambda x, wl: fused_linear_cross_entropy(
-                x, wl, lab, vocab_major=vocab_major, interpret=True, block=(16, 128)
-            ),
-            x, wl,
-        )
-        np.testing.assert_allclose(float(lp), float(lu), rtol=1e-3, atol=1e-3)
-        np.testing.assert_allclose(np.asarray(gp[0]), np.asarray(gu[0]), rtol=1e-4, atol=1e-5)
-        dw = gp[1].T if vocab_major else gp[1]
-        np.testing.assert_allclose(np.asarray(dw), np.asarray(gu[1]), rtol=1e-4, atol=1e-5)
+        bf16 = x.dtype == jnp.bfloat16
 
-    def test_bf16_and_row_padding(self):
-        # 40 rows with a 16-row block: the kernel pads rows 40→48 with
-        # ignore_index labels; padded rows must contribute nothing
-        x, w, lab = _data(n=40, h=128, v=256, dtype=jnp.bfloat16)
-        lu, gu = _grads(_unfused, x, w, lab)
-        lp, gp = _grads(
-            lambda x, w: fused_linear_cross_entropy(
-                x, w, lab, interpret=True, block=(16, 128)
-            ),
-            x, w,
-        )
-        np.testing.assert_allclose(float(lp), float(lu), rtol=1e-3, atol=1e-3)
-        for got, ref in zip(gp, gu):
+        def run(**kw):
+            return _grads(
+                lambda x, wl: fused_linear_cross_entropy(
+                    x, wl, lab, vocab_major=vocab_major, **kw
+                ),
+                x, wl,
+            )
+
+        lr, gr = run()  # the scan reference (this backend's path)
+        lp, gp = run(interpret=True, block=block)
+        assert lp.dtype == jnp.float32
+        np.testing.assert_allclose(float(lp), float(lr), rtol=1e-5, atol=1e-5)
+        for got, ref in zip(gp, gr):
+            # dX and dW leave the kernels in the operand dtype (bf16: rounded
+            # once, from the float32 sum in VMEM, where the reference rounds)
+            assert got.dtype == ref.dtype and got.shape == ref.shape
             np.testing.assert_allclose(
                 np.asarray(got, np.float32), np.asarray(ref, np.float32),
-                rtol=1e-2, atol=1e-2,
+                rtol=2e-2 if bf16 else 1e-4, atol=2e-3 if bf16 else 1e-5,
             )
+        # and against the plain composition
+        lu, gu = _grads(_unfused, x, w, lab)
+        np.testing.assert_allclose(float(lp), float(lu), rtol=1e-3, atol=1e-3)
+        dw = gp[1].T if vocab_major else gp[1]
+        np.testing.assert_allclose(
+            np.asarray(dw, np.float32), np.asarray(gu[1], np.float32),
+            rtol=1e-2 if bf16 else 1e-4, atol=1e-2 if bf16 else 1e-5,
+        )
+
+    def test_backward_kernels_hand_back_the_operand_dtype(self):
+        """dX and dW are accumulated in float32 VMEM scratch and written once:
+        no float32 ``[N, H]`` or ``[H, V]`` leaves a kernel (ISSUE 30)."""
+        x, w, lab = _data(n=32, h=128, v=256, dtype=jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(
+            jax.grad(
+                lambda x, w: fused_linear_cross_entropy(
+                    x, w, lab, interpret=True, block=(16, 128)
+                ),
+                argnums=(0, 1),
+            )
+        )(x, w)
+        outs = {}
+        for eqn in _walk_eqns(jaxpr.jaxpr):
+            if eqn.primitive.name == "pallas_call":
+                outs[eqn.params["name"]] = [(v.aval.shape, v.aval.dtype) for v in eqn.outvars]
+        assert outs[FL.KERNEL_DX] == [((32, 128), jnp.bfloat16)]
+        assert outs[FL.KERNEL_DW] == [((128, 256), jnp.bfloat16)]
+        assert all(d == jnp.float32 for _, d in outs[FL.KERNEL_FWD])  # m, l, target logit
 
     def test_all_ignored_interpret(self):
         x, w, _ = _data(h=128, v=256)
@@ -150,6 +202,82 @@ class TestPallasInterpretParity:
         )
         assert float(lp) == 0.0
         assert float(jnp.abs(gp[0]).max()) == 0.0 and float(jnp.abs(gp[1]).max()) == 0.0
+
+
+RIDGE = 197e12 / 819e9  # a v5e's bf16 flops per HBM byte (240): under it a kernel waits for HBM
+
+
+class TestBlockGeometry:
+    """``_block_geometry``: each kernel's tile from the call's shapes (ISSUE 30).
+    Forward and dX keep a row block of x and stream W past it, dW keeps a vocab
+    block of W and streams x: the kept side sets the flops a streamed byte buys."""
+
+    @staticmethod
+    def _intensity(tiles, x_item, w_item):
+        return {
+            "fwd": 2 * tiles.fwd[0] / w_item,  # one matmul over each W block read
+            "dx": 4 * tiles.dx[0] / w_item,  # two: the logits again, then dX
+            "dw": 4 * tiles.dw[1] / x_item,  # two over each x block read
+        }
+
+    @pytest.mark.parametrize("itemsize", [1, 2, 4], ids=["int8_w", "bf16", "float32"])
+    @pytest.mark.parametrize("h", [1536, 2048, 4096, 8192])
+    def test_streams_at_the_ridge_wherever_vmem_allows(self, h, itemsize):
+        n, v = 16384, 32768
+        x_item = max(itemsize, 2)  # the int8 walk: bf16 activations, int8 weight
+        tiles = FL._block_geometry(n, v, h, x_item, itemsize)
+        budget = FL._vmem_budget()
+        for kernel, (br, bv) in tiles._asdict().items():
+            assert n % br == 0 and v % bv == 0 and bv % 128 == 0 and br % 16 == 0
+            need = FL._vmem_need(kernel, br, bv, h, x_item, itemsize)
+            assert need <= budget, (kernel, need)
+        for kernel, flops_per_byte in self._intensity(tiles, x_item, itemsize).items():
+            if flops_per_byte >= RIDGE:
+                continue
+            # under the ridge only where the next kept size up does not fit
+            br, bv = getattr(tiles, kernel)
+            grown = (br, 2 * bv) if kernel == "dw" else (2 * br, bv)
+            assert FL._vmem_need(kernel, *grown, h, x_item, itemsize) > budget, (kernel, tiles)
+
+    def test_the_train_cell_reads_its_weight_32_times_not_128(self):
+        """Mistral's width took (128, 128) from the 16 MiB guess: W read 128
+        times a forward. The geometry keeps >= 512 rows where W streams and
+        >= 512 vocab columns where x streams, and asks for the VMEM that takes."""
+        tiles = FL._block_geometry(16384, 32768, 4096, 2, 2)
+        assert tiles.fwd[0] >= 512 and tiles.dx[0] >= 512 and tiles.dw[1] >= 512
+        need = FL._vmem_need("dx", *tiles.dx, 4096, 2, 2)
+        assert FL._params(need).vmem_limit_bytes == need > 16 << 20  # over Mosaic's default: stated
+        assert FL._params(8 << 20).vmem_limit_bytes is None  # under it: the default stands
+
+    @pytest.mark.parametrize(
+        "n, v, rows, cols",
+        [
+            (24, 200, 32, 256),  # a small batch: one row block, one vocab block
+            (16384, 49152, 512, 512),  # Ouro's vocabulary
+            (2100, 32000, 432, 256),  # 5 x 432 rows (not 4 x 512 + 52); 125 x 256 divides Llama's vocabulary
+            (600, 50257, 304, 512),  # 2 x 304; a prime-ish vocabulary pads 99 x 512
+        ],
+    )
+    def test_blocks_are_cut_evenly_and_divide_the_padded_operands(self, n, v, rows, cols):
+        tiles = FL._block_geometry(n, v, 2048, 2, 2)
+        fitted, n_pad, vp = FL._fit_tiles(tiles, n, v)
+        assert fitted == tiles
+        for br, bv in tiles:
+            assert n_pad % br == 0 and vp % bv == 0
+            assert br % rows == 0 and bv % cols == 0  # whole multiples of the shared cut
+        assert n_pad - n < rows and vp - v < cols
+
+    def test_autotune_candidates_are_what_the_geometry_admits(self):
+        """At Mistral's width the old candidate list was EMPTY (every tile over
+        the 16 MiB guess), so tuning never ran."""
+        cands = FL._admitted_tiles(4096, 2, 2)
+        assert LossTiles(*(((512, 512),) * 3)) in cands and len(cands) >= 4
+        budget = FL._vmem_budget()
+        for t in cands:
+            assert all(
+                FL._vmem_need(k, *tile, 4096, 2, 2) <= budget for k, tile in t._asdict().items()
+            )
+        assert FL._admitted_tiles(1 << 17, 4, 4) == []  # nothing fits: default only
 
 
 class TestModelContract:

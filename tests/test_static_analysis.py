@@ -1826,6 +1826,65 @@ def test_pg903_fp32_same_geometry_exceeds_budget():
     assert "PG903" in codes(_pg903_dtype_site("jnp.float32"))
 
 
+def _pg903_limit_site(compiler_params: str, helper: str = "") -> str:
+    # the float32 site of _pg903_dtype_site (32 MiB a grid step, over the
+    # 16 MiB default) with the site's own compiler params
+    return (
+        _PG_PRELUDE
+        + "from jax.experimental.pallas import tpu as pltpu\n"
+        + helper
+        + "def f(need):\n"
+        "    x = jnp.zeros((8192, 8192), jnp.float32)\n"
+        "    return pl.pallas_call(\n"
+        "        k,\n"
+        "        grid=(16,),\n"
+        f"        compiler_params={compiler_params},\n"
+        "        in_specs=[pl.BlockSpec((512, 8192), lambda i: (i, 0))],\n"
+        "        out_specs=pl.BlockSpec((512, 8192), lambda i: (i, 0)),\n"
+        "        out_shape=jax.ShapeDtypeStruct((8192, 8192), jnp.float32),\n"
+        "    )(x)\n"
+    )
+
+
+_PG903_HELPER = (
+    "def _params(need):\n"
+    "    return pltpu.CompilerParams(\n"
+    "        dimension_semantics=('parallel',), vmem_limit_bytes=need)\n"
+)
+
+
+@pytest.mark.parametrize(
+    "compiler_params, helper, flagged",
+    [
+        # no limit stated: the default 16 MiB budget holds the 32 MiB window
+        ("pltpu.CompilerParams(dimension_semantics=('parallel',))", "", True),
+        ("pltpu.CompilerParams(vmem_limit_bytes=None)", "", True),
+        # the site asks for 64 MiB: that is its budget (kernels/fused_loss.py)
+        ("pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)", "", False),
+        ("pltpu.CompilerParams(vmem_limit_bytes=64 << 20)", "", False),
+        # ... and is held to it
+        ("pltpu.CompilerParams(vmem_limit_bytes=24 << 20)", "", True),
+        # a limit derived from runtime shapes, here or in the local helper
+        # that builds the params: stated, nothing to hold the window to
+        ("pltpu.CompilerParams(vmem_limit_bytes=need)", "", False),
+        ("_params(need)", _PG903_HELPER, False),
+    ],
+    ids=["none_stated", "none_literal", "own_limit_product", "own_limit_shift",
+         "over_own_limit", "runtime_limit", "helper_built"],
+)
+def test_pg903_reads_the_sites_own_vmem_limit(compiler_params, helper, flagged):
+    """A site that states ``vmem_limit_bytes`` is judged by that limit, not by
+    Mosaic's 16 MiB default."""
+    found = "PG903" in codes(_pg903_limit_site(compiler_params, helper))
+    assert found == flagged
+
+
+def test_pg903_own_limit_is_the_budget_in_the_message():
+    vs = analyze_source(_pg903_limit_site("pltpu.CompilerParams(vmem_limit_bytes=24 << 20)"))
+    (v,) = [v for v in vs if v.code == "PG903"]
+    assert f"budget {24 << 20}" in v.message
+
+
 def test_pg903_int8_width_not_assumed():
     """int8 is a KNOWN width (DTYPE_BYTES), not the assumed-1-byte fallback:
     the VMEM config must not carry the ``assumed_width`` caveat."""

@@ -148,22 +148,50 @@ def _embed_rms_norm():
     )
 
 
-def _fused_loss(vocab_major):
-    from paddle_tpu.kernels.fused_loss import _default_block, _pallas_path
+# the train cell's loss head (benchmarks/workloads/mistral7b.train_2k.json):
+# 8 x 2048 tokens, Mistral's 32 768 vocabulary, hidden 4096
+CELL_TOKENS, CELL_VOCAB = 8 * SEQ, 32768
 
-    block = _default_block(HIDDEN, 2)
+
+def _loss_grads(n, v, h, vocab_major, dtype):
+    """Forward, dX and dW at the tiles ``_block_geometry`` derives for the
+    shape, each asking for the ``vmem_limit_bytes`` its tile needs: what holds
+    ``_vmem_need`` to the chip's compiler."""
+    from paddle_tpu.kernels.fused_loss import _block_geometry, _pallas_path
+
+    item = jnp.dtype(dtype).itemsize
+    block = _block_geometry(n, v, h, item, item)
 
     def fn(x, w, lab):
         return jax.grad(
             lambda x, w: _pallas_path(
-                x, w, lab, v=VOCAB, h=HIDDEN, ignore_index=-100, reduction="mean",
+                x, w, lab, v=v, h=h, ignore_index=-100, reduction="mean",
                 vocab_major=vocab_major, interpret=False, block=block,
             ),
             argnums=(0, 1),
         )(x, w)
 
-    w = (VOCAB, HIDDEN) if vocab_major else (HIDDEN, VOCAB)
-    return fn, (((SEQ, HIDDEN), BF16), (w, BF16), ((SEQ,), I32))
+    return fn
+
+
+def _fused_loss(vocab_major=False, n=CELL_TOKENS, v=CELL_VOCAB, h=HIDDEN, dtype=BF16):
+    w = (v, h) if vocab_major else (h, v)
+    return _loss_grads(n, v, h, vocab_major, dtype), (((n, h), dtype), (w, dtype), ((n,), I32))
+
+
+def _fused_loss_quant(n=CELL_TOKENS, v=CELL_VOCAB, h=HIDDEN):
+    """The int8 forward walk: both operands are upcast in VMEM for the dot."""
+    from paddle_tpu.kernels.fused_loss import _block_geometry, _pallas_quant_path
+
+    block = _block_geometry(n, v, h, 2, 1, quantized=True)
+
+    def fn(x, w, s, lab):
+        return _pallas_quant_path(
+            x, w, s, lab, v=v, h=h, ignore_index=-100, reduction="mean",
+            vocab_major=False, interpret=False, block=block,
+        )
+
+    return fn, (((n, h), BF16), ((h, v), I8), ((v,), F32), ((n,), I32))
 
 
 def _wo_matmul():
@@ -199,8 +227,17 @@ CASES = {
     "fused_rms_norm_residual_train": lambda: _rms_norm_residual((1, SEQ, HIDDEN)),
     "fused_rms_norm_residual_step": lambda: _rms_norm_residual((SLOTS, CHUNK, HIDDEN)),
     "fused_embed_rms_norm": _embed_rms_norm,
+    # the train cell's shapes, both weight layouts
     "fused_loss_fwd_bwd_hidden_major": lambda: _fused_loss(False),
     "fused_loss_fwd_bwd_vocab_major": lambda: _fused_loss(True),
+    # Ouro's width (hidden 2048, vocabulary 49 152), twice Mistral's, float32
+    # operands, a batch that no block divides, a small one, the int8 walk
+    "fused_loss_fwd_bwd_ouro_width": lambda: _fused_loss(h=2048, v=49152),
+    "fused_loss_fwd_bwd_hidden_8192": lambda: _fused_loss(n=8192, h=8192),
+    "fused_loss_fwd_bwd_float32": lambda: _fused_loss(dtype=F32),
+    "fused_loss_fwd_bwd_ragged_llama_vocab": lambda: _fused_loss(n=2100, v=VOCAB),
+    "fused_loss_fwd_bwd_one_row_block": lambda: _fused_loss(n=SLOTS * CHUNK),
+    "fused_loss_fwd_quant_train_cell": _fused_loss_quant,
     "wo_int8_matmul_k11008": _wo_matmul,
 }
 
@@ -247,24 +284,12 @@ def test_fused_loss_never_holds_the_logits(one_chip):
     the plain ``cross_entropy(x @ W)`` composition keeps the ``[N, V]`` bf16
     logits for its backward (1 GiB of temporaries, and nothing else: the
     chip's compiler recomputes the float32 copies); the fused forward +
-    backward never holds them, and its temporaries are its float32 dW,
-    ``[H, V]`` (512 MiB), and little else: half. At 2048 tokens the same dW
-    is FOUR times what the plain composition holds (524 MB against 131), so
-    the memory claim is one about token counts above ``2 H``, not about the
-    kernel at any size (ROADMAP S10: the float32 dW)."""
-    from paddle_tpu.kernels.fused_loss import _default_block, _pallas_path
-
-    n, v = 8 * SEQ, 32768
-    block = _default_block(HIDDEN, 2)
-
-    def fused(x, w, lab):
-        return jax.grad(
-            lambda x, w: _pallas_path(
-                x, w, lab, v=v, h=HIDDEN, ignore_index=-100, reduction="mean",
-                vocab_major=False, interpret=False, block=block,
-            ),
-            argnums=(0, 1),
-        )(x, w)
+    backward never holds them, nor anything else the size of an operand: dX
+    and dW leave their kernels in bf16 (the float32 sums stay in VMEM), so the
+    512 MiB float32 dW that was the head's largest temporary until PR 30 is
+    gone, at any token count (the compiler counts 0 bytes of temporaries)."""
+    n, v = CELL_TOKENS, CELL_VOCAB
+    fused = _loss_grads(n, v, HIDDEN, False, BF16)
 
     def plain(x, w, lab):
         def loss(x, w):
@@ -280,12 +305,4 @@ def test_fused_loss_never_holds_the_logits(one_chip):
         held_plain = jax.jit(plain).lower(*args).compile().memory_analysis().temp_size_in_bytes
     held_fused = _compile(fused, one_chip, *shapes).memory_analysis().temp_size_in_bytes
     assert held_plain >= n * v * 2, held_plain  # the logits
-    assert held_fused < HIDDEN * v * 4 * 1.1, held_fused  # the float32 dW and little else
-    assert held_fused < 0.55 * held_plain, (held_fused, held_plain)
-
-
-def test_fused_loss_default_block_keeps_bench_width_on_512():
-    """The repair must not shrink the block at the width it already fit."""
-    from paddle_tpu.kernels.fused_loss import _default_block
-
-    assert _default_block(1536, 2) == (512, 512)
+    assert held_fused < n * HIDDEN * 2 / 2, held_fused  # not even half a bf16 x: no float32 dX or dW
