@@ -15,7 +15,8 @@ two ragged GEMMs.
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+import contextlib
+from typing import Any, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,159 @@ import jax.numpy as jnp
 from paddle_tpu.core.dispatch import call_op
 from paddle_tpu.core.tensor import Tensor
 
-__all__ = ["fused_moe"]
+__all__ = ["collect_expert_counts", "expert_share", "fused_moe", "route_sigmoid_topk"]
+
+# jax.named_scope names inside expert_share (the model's ``moe`` scope is around them)
+SCOPE_MOE_ROUTER = "moe_router"
+SCOPE_MOE_DISPATCH = "moe_dispatch"
+SCOPE_MOE_EXPERTS = "moe_experts"
+SCOPE_MOE_COMBINE = "moe_combine"
+
+# rows an expert may get in the programs a layer is compiled for, smallest
+# first; ``T`` (every row) always closes the list, so nothing is dropped.
+# Each pads every held expert's rows to the cap and runs ONE batched matmul
+# ``[held, cap, M] x [held, M, I]``, which reads an expert's weights once and
+# in the layout they are stored in. ``lax.ragged_dot`` was measured here first
+# (PERF.md, PR 33): XLA tiles it at min(rows, 512) x 128 x 128, so 16 experts of
+# one or two rows cost 1.2 ms a matmul in tile overhead, and it wants the
+# weights in another layout, a 160 MB copy a layer a step.
+# The first cap is the one a serving step is meant to ALWAYS take, so that a
+# step costs the same whatever the routing and the mix of prefill and decode
+# rows: a router favours some experts (at seeded weights a held expert draws
+# 10-25 % of the rows, one block in eleven over half), so a cap of 16 rows was
+# passed whenever a step held a few prefill chunks, and the inter-token tail
+# moved with how many blocks of how many steps did (PERF.md, PR 33's second
+# round). Where ONE expert overflows it (the block that sends half its rows to
+# one expert), that expert multiplies every row and the others keep the first
+# cap, at 0.1 ms more whatever its share; the caps after it are for two such
+# experts in one block, so that they do not pay for every row at once.
+EXPERT_CAPS = (64, 128, 256)
+
+_COUNTS: List[List[jax.Array]] = []
+
+
+@contextlib.contextmanager
+def collect_expert_counts() -> Iterator[List[jax.Array]]:
+    """While open, every :func:`expert_share` call appends ``int32[2]``:
+    ``(assignments that landed on held experts, held experts that got at
+    least one row)``. The serving step opens it around the model's forward
+    and hands the sum back beside its argmaxes."""
+    sink: List[jax.Array] = []
+    _COUNTS.append(sink)
+    try:
+        yield sink
+    finally:
+        _COUNTS.pop()
+
+
+def route_sigmoid_topk(
+    x: jax.Array,  # [T, M]
+    gate_w: jax.Array,  # [M, E] over ALL experts
+    select_bias: jax.Array,  # [E]
+    top_k: int,
+    scale: float,
+    norm_topk_prob: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores in float32; the ``top_k`` of ``score + select_bias`` are
+    chosen; a chosen expert's weight is its score (WITHOUT the bias) over the
+    sum of all ``top_k`` chosen scores, times ``scale``. ``(chosen [T, K]
+    int32, weights [T, K] float32)``."""
+    scores = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                                       precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    if norm_topk_prob:
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), picked * scale
+
+
+def expert_share(
+    x: jax.Array,  # [T, M]
+    gate_w: jax.Array,  # [M, E_total]: the router over ALL experts
+    select_bias: jax.Array,  # [E_total]
+    w_up: jax.Array,  # [held, M, I]: experts first_expert .. first_expert + held - 1
+    w_down: jax.Array,  # [held, I, M]
+    top_k: int,
+    scale: float,
+    first_expert: int = 0,
+    norm_topk_prob: bool = True,
+    row_mask: Optional[jax.Array] = None,  # [T] bool: rows that are real (None: all)
+    expert_caps: Sequence[int] = EXPERT_CAPS,
+) -> jax.Array:
+    """This chip's share of a sparse-expert layer (non-gated squared-ReLU
+    experts): route every row over ALL ``E_total`` experts, compute the part of
+    the result that the HELD experts give, leave out what the absent ones would
+    have added (on one chip there is no exchange; the weights are still
+    normalised over all ``top_k`` chosen). An assignment to an absent expert,
+    or of a row ``row_mask`` rules out (a padded slot, a row past ``q_lens``),
+    goes to a null group past the last and takes up no row of any expert.
+    Dropless; routing is data (``lax.switch`` picks the first of
+    ``expert_caps`` if no held expert overflows it, the same with the one
+    favoured expert on every row if only that one does, else the smallest cap
+    that holds), so one compiled program serves every mix."""
+    t, m = x.shape
+    held = w_up.shape[0]
+    with jax.named_scope(SCOPE_MOE_ROUTER):
+        chosen, weights = route_sigmoid_topk(x, gate_w, select_bias, top_k, scale, norm_topk_prob)
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        local = chosen - first_expert
+        here = (local >= 0) & (local < held)
+        if row_mask is not None:
+            here = here & row_mask[:, None]
+        group = jnp.where(here, local, held).reshape(-1)  # [T*K]; `held` is the null group
+        order = jnp.argsort(group)  # stable: held groups first, in expert order
+        token = (order // top_k).astype(jnp.int32)
+        weight = jnp.where(here, weights, 0.0).reshape(-1)[order]
+        # a compare and a sum, not a bincount: a scatter of T*K scalars is slow on the TPU
+        group_sizes = jnp.sum(group[:, None] == jnp.arange(held, dtype=group.dtype)[None, :], axis=0, dtype=jnp.int32)
+        n_here = jnp.sum(group_sizes)
+    if _COUNTS:
+        _COUNTS[-1].append(jnp.stack([n_here, jnp.sum(group_sizes > 0).astype(jnp.int32)]))
+
+    def padded(cap: int, favoured_takes_every_row: bool = False):
+        """Every held expert's rows padded to ``cap`` (no expert has more).
+        With ``favoured_takes_every_row`` the held expert with the most rows is
+        left out of the padding and multiplies EVERY row instead, weighted 0
+        where a row did not choose it (one ``[T, M] x [M, I]`` pair: a fortieth
+        of the batched matmul's weight reads more, whatever its share)."""
+        def tier(_):
+            sizes = group_sizes
+            if favoured_takes_every_row:
+                favoured = jnp.argmax(group_sizes)
+                sizes = jnp.where(jnp.arange(held) == favoured, 0, group_sizes)
+            with jax.named_scope(SCOPE_MOE_DISPATCH):
+                # expert e's c-th row is sorted assignment starts[e] + c, where it has that many
+                starts = jnp.cumsum(group_sizes) - group_sizes
+                column = jnp.arange(cap, dtype=jnp.int32)[None, :]
+                real = (column < sizes[:, None]).reshape(-1)
+                at = jnp.minimum(starts[:, None] + column, t * top_k - 1).reshape(-1)
+                rows = jnp.where(real, token[at], t)  # t: a row of zeros
+                row_weight = jnp.where(real, weight[at], 0.0)
+                gathered = jnp.concatenate([x, jnp.zeros((1, m), x.dtype)])[rows].reshape(held, cap, m)
+            with jax.named_scope(SCOPE_MOE_EXPERTS):
+                h = jnp.einsum("ecm,emi->eci", gathered, w_up.astype(x.dtype))
+                out = jnp.einsum("eci,eim->ecm", jnp.square(jax.nn.relu(h)), w_down.astype(x.dtype))
+                if favoured_takes_every_row:
+                    h = jnp.matmul(x, w_up[favoured].astype(x.dtype))
+                    every_row = jnp.matmul(jnp.square(jax.nn.relu(h)), w_down[favoured].astype(x.dtype))
+            with jax.named_scope(SCOPE_MOE_COMBINE):
+                out = out.reshape(held * cap, m).astype(jnp.float32) * row_weight[:, None]
+                out = jnp.zeros((t + 1, m), jnp.float32).at[rows].add(out)[:t]
+                if favoured_takes_every_row:
+                    its_weight = jnp.sum(jnp.where(here & (local == favoured), weights, 0.0), axis=-1)
+                    out = out + every_row.astype(jnp.float32) * its_weight[:, None]
+                return out.astype(x.dtype)
+        return tier
+
+    caps = sorted({min(int(c), t) for c in expert_caps} | {t})  # an expert gets at most every row
+    if len(caps) == 1:
+        return padded(caps[0])(None)
+    # the first cap; past it the first cap again with the ONE favoured expert on
+    # every row, if no second expert overflows it; else the smallest cap that holds
+    largest, second = jnp.max(group_sizes), (jnp.sort(group_sizes)[-2] if held > 1 else 0)
+    tiers = [padded(caps[0]), padded(caps[0], favoured_takes_every_row=True)] + [padded(c) for c in caps[1:]]
+    beyond = 1 + jnp.sum(largest > jnp.asarray(caps[:-1], jnp.int32))
+    return jax.lax.switch(jnp.where(largest <= caps[0], 0, jnp.where(second <= caps[0], 1, beyond)), tiers, None)
 
 
 def _fused_moe_impl(

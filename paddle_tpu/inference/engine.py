@@ -88,6 +88,17 @@ replicated-by-construction — the prefix cache and speculative decoding
 ride along unchanged. ``tp=1`` (the default) takes the exact single-chip
 path.
 
+**Recurrent state beside pages**: a model whose ``config.cache_sets`` names
+RECURRENT sets (state-space blocks; ``inference/paged_kv.py::RecurrentState``)
+gets them as ``[max_slots, ...]`` planes owned beside the pool, handed through
+the step after the paged sets and donated like them; the step that carries a
+request's first chunk zeroes the slot's state, and ``recover()``'s replay
+rebuilds it. What would need a snapshot of that state refuses instead of
+serving wrong tokens: prefix reuse is skipped and counted, and
+``spec_decode``, the host KV tier and ``tp > 1`` raise at construction. A model
+without ``cache_sets`` holds ``config.num_kv_sets`` paged sets and its step is
+the program it always was.
+
 Fault tolerance: because every request's prompt and generated tokens live on
 the host (``InferenceRequest``), a dispatch failure that consumed the
 donated KV buffers is recoverable — ``step()`` retries with backoff through
@@ -113,7 +124,8 @@ import numpy as np
 from paddle_tpu.core.spmd import partitioned_trace
 from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.inference.kv_tier import HostKVTier, HostNode
-from paddle_tpu.inference.paged_kv import PagedBatch, PagedKV
+from paddle_tpu.incubate.nn.functional.fused_moe import collect_expert_counts
+from paddle_tpu.inference.paged_kv import PAGED, RECURRENT, PagedBatch, PagedKV, RecurrentState
 from paddle_tpu.inference.prefix_cache import ChainNode, PrefixCache, chain_digest
 from paddle_tpu.inference.spec_decode import NGramDrafter, count_accepted
 from paddle_tpu.observability import devprof as _devprof
@@ -264,6 +276,11 @@ def _engine_metrics() -> Dict[str, Any]:
             "kv_pool_bytes_per_token",
             "Effective KV-pool bytes stored per token across all layers "
             "(int8 pools count the payload plus their fp32 scale bytes).",
+        ),
+        "state_bytes_per_slot": reg.gauge(
+            "recurrent_state_bytes_per_slot",
+            "Bytes of recurrent state (scan state and conv tail, every "
+            "state-space block) one slot holds; 0 for a model without any.",
         ),
         "kv_quant": reg.counter(
             "kv_quant_dequant_total",
@@ -462,11 +479,23 @@ class ContinuousBatchingEngine:
         )
 
         kvh = cfg.num_key_value_heads
-        hd = cfg.hidden_size // cfg.num_attention_heads
+        hd = getattr(cfg, "head_dim", None) or cfg.hidden_size // cfg.num_attention_heads
         # KV sets a token holds, and so cache planes this engine owns: the
         # model's to say (a looped stack holds passes x layers); every seam
         # that moves "a token's KV" walks this many planes
         self._num_kv_sets = int(getattr(cfg, "num_kv_sets", cfg.num_hidden_layers))
+        # a model that keeps more than paged KV says so, set by set in block
+        # order (``config.cache_sets``): RECURRENT sets are ``[max_slots,
+        # ...]`` planes owned beside the pool, handed through the step after
+        # the paged sets and zeroed by the step that admits into a slot
+        sets = list(getattr(cfg, "cache_sets", None) or ())
+        self._set_kinds: List[str] = [cs.kind for cs in sets]
+        self._state_specs = [cs for cs in sets if cs.kind == RECURRENT]
+        if sets and self._set_kinds.count(PAGED) != self._num_kv_sets:
+            raise ValueError(
+                f"config.cache_sets names {self._set_kinds.count(PAGED)} paged sets, "
+                f"config.num_kv_sets says {self._num_kv_sets}"
+            )
         # passes of the stack a step runs (the loop_passes counter): the
         # model's to say too; once unless its config says otherwise
         self._stack_passes = int(getattr(cfg, "stack_passes", 1))
@@ -518,6 +547,11 @@ class ContinuousBatchingEngine:
         self.tp = int(GLOBAL_FLAGS.get("engine_tp_degree") if tp is None else tp)
         if self.tp < 1:
             raise ValueError(f"engine tp degree must be >= 1, got {self.tp}")
+        if self.tp > 1 and self._state_specs:
+            raise ValueError(
+                "tp > 1 cannot serve a model with recurrent state sets yet: nothing shards "
+                "a state plane, the scan or an expert share over the 'tp' mesh"
+            )
         if self.tp > 1:
             from paddle_tpu.distributed.tp import (
                 build_tp_mesh,
@@ -585,6 +619,19 @@ class ContinuousBatchingEngine:
             if kv_host_tier_bytes is None
             else kv_host_tier_bytes
         )
+        # what cannot carry recurrent state REFUSES (a prefix hit, a rewind
+        # or a spilled slot would need a snapshot of the state at that token:
+        # ROADMAP M5-rest). Prefix reuse is on by default, so it is skipped and
+        # counted (``prefix_reuse_skipped_recurrent``, an admission that went
+        # without a lookup); what has to be asked for raises.
+        self._prefix_reuse_refused = bool(self._state_specs) and self._use_prefix_cache
+        if self._state_specs:
+            self._use_prefix_cache = False
+            if tier_bytes > 0:
+                raise ValueError(
+                    "kv_host_tier_bytes > 0 cannot serve a model with recurrent state sets: a "
+                    "spilled chain holds pages only, not the state at its end"
+                )
         self._host_tier: Optional[HostKVTier] = None
         if tier_bytes > 0 and self._use_prefix_cache:
             self._host_tier = HostKVTier(
@@ -647,6 +694,11 @@ class ContinuousBatchingEngine:
         )
         if self._spec_k < 1:
             self._use_spec = False
+        if self._use_spec and self._state_specs:
+            raise ValueError(
+                "spec_decode cannot serve a model with recurrent state sets: a rejected draft "
+                "rewinds pages by truncation, and the scan's state cannot be rewound"
+            )
         self._drafter = (
             NGramDrafter(int(GLOBAL_FLAGS.get("spec_decode_ngram")))
             if self._use_spec
@@ -657,6 +709,7 @@ class ContinuousBatchingEngine:
         # a looped stack) owns its [NB, KVH, BS, D] pair, all indexed by the
         # SAME block tables (the reference layout).
         self._caches = [self._new_cache_pair() for _ in range(self._num_kv_sets)]
+        self._states = self._new_states()
 
         # per-slot host state (rewritten freely between steps — it is DATA to
         # the compiled step, never part of its shape)
@@ -698,6 +751,18 @@ class ContinuousBatchingEngine:
             # and their bytes
             "loop_passes": 0, "kv_sets": self._num_kv_sets,
             "kv_bytes_per_token": self._bytes_per_token(),
+            # recurrent sets beside the pages and what one slot holds in
+            # them; experts an expert layer holds (gauges: the model's own)
+            "state_sets": len(self._state_specs),
+            "state_bytes_per_slot": self._state_bytes_per_slot(),
+            "experts_held": int(getattr(cfg, "n_routed_experts", 0)),
+            # a step's expert blocks, summed: assignments that landed on held
+            # experts, and held experts that got at least one row (from the
+            # small vector the step of a model with expert blocks hands back)
+            "moe_rows_local": 0, "moe_experts_hit": 0,
+            # admissions that went without a prefix lookup because the model
+            # keeps recurrent state
+            "prefix_reuse_skipped_recurrent": 0,
             # steps at whose planning a waiting request was held back: for
             # want of blocks with a slot free / for want of a slot
             "admit_blocked_steps.blocks": 0, "admit_blocked_steps.slots": 0,
@@ -808,6 +873,13 @@ class ContinuousBatchingEngine:
             return self._shard_zeros(), self._shard_zeros()
         return PagedKV.zeros(self._cache_shape, self._cache_dtype).planes
 
+    def _new_states(self) -> List[Tuple[Any, ...]]:
+        """The recurrent sets' planes, ``[max_slots, ...]`` each, zeroed."""
+        return [RecurrentState.zeros(self.max_slots, spec).planes for spec in self._state_specs]
+
+    def _state_bytes_per_slot(self) -> int:
+        return sum(spec.unit_bytes for spec in self._state_specs)
+
     @property
     def tp_degree(self) -> int:
         """Tensor-parallel degree (1 = single-chip engine)."""
@@ -917,6 +989,7 @@ class ContinuousBatchingEngine:
             "allocated": self.num_blocks - free,
             "kv_cache_dtype": self.kv_cache_dtype,
             "bytes_per_token": self._bytes_per_token(),
+            "state_bytes_per_slot": self._state_bytes_per_slot(),
             # blocks the prefix cache retains warm but surrenders under
             # pressure: reclaimable, so admission/overload math treats them
             # as headroom, not load
@@ -960,6 +1033,7 @@ class ContinuousBatchingEngine:
         m["blocks_alloc"].set(s["allocated"])
         m["blocks_free"].set(s["free"])
         m["kv_bytes_per_token"].set(s["bytes_per_token"])
+        m["state_bytes_per_slot"].set(s["state_bytes_per_slot"])
         m["blocks_reserved"].set(int(self._reserved.sum()))
         live = s["allocated"] - s["cached_reusable"]
         m["util"].set(live / s["total"] if s["total"] else 0.0)
@@ -983,7 +1057,7 @@ class ContinuousBatchingEngine:
     def _buffers_lost(self) -> bool:
         return any(
             getattr(a, "is_deleted", lambda: False)()
-            for entry in self._caches
+            for entry in self._caches + self._states
             for a in entry
         )
 
@@ -1211,12 +1285,17 @@ class ContinuousBatchingEngine:
         drafted slot compares rows ``0..K-1`` against its draft left-to-
         right). Rows past ``q_lens`` are garbage and never read host-side."""
         self.stats["step_traces"] += 1  # Python side: counts TRACES only
-        logits, new_caches = self._step_forward(
-            param_arrays, caches, toks, tables, lens, q_lens, active,
-            cow_src, cow_dst,
-        )
+        with collect_expert_counts() as expert_counts:
+            logits, new_caches = self._step_forward(
+                param_arrays, caches, toks, tables, lens, q_lens, active,
+                cow_src, cow_dst,
+            )
         with jax.named_scope("sample"):
             nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+        if expert_counts:
+            # a model with expert blocks: (rows that landed on held experts,
+            # held experts hit), summed over the blocks, beside the argmaxes
+            return nxt, new_caches, sum(expert_counts)
         return nxt, new_caches  # nxt [S, C]: per-row argmax
 
     def _step_forward(
@@ -1243,12 +1322,20 @@ class ContinuousBatchingEngine:
             # Scale planes ride the same CoW fork set as their payload: a
             # forked block gets its source's scales in the same step
             batch = PagedBatch(tables, lens, active, q_lens)
+            n_kv = self._num_kv_sets
             pkv = [
                 PagedKV(*planes, batch=batch).fork(cow_src, cow_dst)
-                for planes in caches
+                for planes in caches[:n_kv]
             ]
+            if self._state_specs:
+                # the model takes its sets in block order; the flat arguments
+                # keep the paged sets first, the recurrent ones after them
+                paged = iter(pkv)
+                states = (RecurrentState(*planes, batch=batch) for planes in caches[n_kv:])
+                pkv = [next(paged) if kind == PAGED else next(states) for kind in self._set_kinds]
             with paddle_tpu.no_grad():
                 logits, new_pkv = self.model(Tensor(toks), past_key_values=pkv, use_cache=True)
+            new_pkv = sorted(new_pkv, key=lambda kv: not isinstance(kv, PagedKV))  # stable: paged first
             return logits._data, [kv.planes for kv in new_pkv]
 
     def step_logits(self, prompt: Any) -> np.ndarray:
@@ -1291,6 +1378,7 @@ class ContinuousBatchingEngine:
 
         def run(param_arrays, *step_args):
             caches = [PagedKV.zeros(scratch, self._cache_dtype).planes for _ in range(self._num_kv_sets)]
+            caches += self._new_states()  # scratch state beside the scratch pool
             logits, _ = self._step_forward(param_arrays, caches, *step_args)
             return logits[0].astype(jnp.float32)
 
@@ -1388,6 +1476,8 @@ class ContinuousBatchingEngine:
         ``prefix_cache.match`` fault) degrades to a cold miss: the prompt is
         simply recomputed."""
         result = None
+        if self._prefix_reuse_refused:
+            self.stats["prefix_reuse_skipped_recurrent"] += 1
         if self._cache is not None:
             try:
                 result = self._cache.match(req.prompt)
@@ -1752,7 +1842,7 @@ class ContinuousBatchingEngine:
             try:
                 with self._shard_ctx():
                     lowered = self._step_fn.lower(
-                        self._param_arrays(), self._caches, jnp.asarray(toks),
+                        self._param_arrays(), self._caches + self._states, jnp.asarray(toks),
                         jnp.asarray(tables), jnp.asarray(self._ntok.copy()),
                         jnp.asarray(q_lens), jnp.asarray(active),
                         jnp.asarray(cow_src), jnp.asarray(cow_dst),
@@ -1812,12 +1902,13 @@ class ContinuousBatchingEngine:
             traces_before = self.stats["step_traces"]
             self._next_phase("engine.launch", "phase_s.launch")
             with self._shard_ctx():  # for the (first-call / recovery) trace
-                nxt, self._caches = self._step_fn(
-                    self._param_arrays(), self._caches, jnp.asarray(toks),
+                nxt, kept, *expert_counts = self._step_fn(
+                    self._param_arrays(), self._caches + self._states, jnp.asarray(toks),
                     jnp.asarray(tables), jnp.asarray(self._ntok.copy()),
                     jnp.asarray(q_lens), jnp.asarray(active),
                     jnp.asarray(cow_src), jnp.asarray(cow_dst),
                 )
+                self._caches, self._states = kept[: self._num_kv_sets], kept[self._num_kv_sets:]
         except BaseException:
             # roll the per-step allocations back so a transient failure
             # leaves the allocator in lockstep with _ntok (retried steps
@@ -1848,6 +1939,10 @@ class ContinuousBatchingEngine:
         self._next_phase("engine.wait", "phase_s.wait")
         nxt = np.asarray(nxt)  # device sync: the step's tokens are real here
         self._next_phase("engine.commit", "phase_s.commit")
+        if expert_counts:
+            rows_local, experts_hit = np.asarray(expert_counts[0]).tolist()
+            self.stats["moe_rows_local"] += rows_local
+            self.stats["moe_experts_hit"] += experts_hit
         if self._quant_kv and _obs.metrics_enabled():
             # host-side attribution of the step's quantized-plane traffic:
             # every new token was quantized on write, every active slot's
@@ -2339,6 +2434,7 @@ class ContinuousBatchingEngine:
         # identical shapes/dtypes/shardings (tp pools come back committed on
         # the same mesh partition) -> the compiled program is reused
         self._caches = [self._new_cache_pair() for _ in range(self._num_kv_sets)]
+        self._states = self._new_states()  # the replay below rebuilds every live slot's
         self._mgr = BlockKVCache(
             self.num_blocks, self.block_size, self._kvh, self._hd,
             self.max_blocks_per_seq, dtype=self._cache_dtype,

@@ -16,14 +16,25 @@ Two things of different lifetime:
 Both are pytrees whose leaves flatten in the order ``key, value[, key_scale,
 value_scale], block_tables, seq_lens, slot_mask, q_lens``, so a ``PagedKV``
 crosses a ``jax.jit`` boundary as it is. A model takes its paged path when
-its past IS a ``PagedKV`` (``isinstance``); a cache of another kind (window
-layers, latent rows, recurrent state) is another class with the same two
-methods, and the step does not branch on it.
+its past IS a ``PagedKV`` (``isinstance``); a cache of another kind is another
+class beside it, and the step does not branch on it.
+
+- :class:`RecurrentState`, one per RECURRENT SET (a state-space block): planes
+  ``[slots, ...]`` with no pages, under the same batch. Its lifetime differs:
+  a page is freed when its request ends, a slot's state is ZEROED by the step
+  in which the next request's first chunk arrives (``seq_lens == 0``). It owns
+  :meth:`~RecurrentState.fork` (copy a slot's state) and
+  :meth:`~RecurrentState.advance` (continue the conv and the scan over the
+  step's rows), the counterparts of ``PagedKV``'s two.
+- :class:`CacheSet` is how a model's configuration tells a cache owner what
+  sets it holds, in block order (``config.cache_sets``): kind, plane shapes
+  and dtypes. A model without it holds ``config.num_kv_sets`` paged sets.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -33,8 +44,29 @@ from paddle_tpu.incubate.nn.functional.block_attention import (
     block_cache_cow_copy,
     block_multihead_chunk_attention,
 )
+from paddle_tpu.incubate.nn.functional.mamba2 import causal_conv_chunk, split_conv_channels, ssd_chunk
 
-__all__ = ["PagedBatch", "PagedKV"]
+__all__ = ["CacheSet", "PAGED", "PagedBatch", "PagedKV", "RECURRENT", "RecurrentState"]
+
+PAGED, RECURRENT = "paged", "recurrent"
+
+# jax.named_scope names inside RecurrentState.advance
+SCOPE_SSM_CONV = "ssm_conv"
+SCOPE_SSM_SCAN = "ssm_scan"
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSet:
+    """One cache set a model holds. ``planes``: ``(shape, dtype)`` of each
+    plane for ONE unit of the set, a token of a page for ``PAGED`` (``(KVH,
+    D)`` twice) and a slot for ``RECURRENT`` (:meth:`RecurrentState.spec`)."""
+
+    kind: str
+    planes: Tuple[Tuple[Tuple[int, ...], Any], ...]
+
+    @property
+    def unit_bytes(self) -> int:
+        return sum(math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in self.planes)
 
 
 @jax.tree_util.register_dataclass
@@ -107,3 +139,68 @@ class PagedKV:
             cos=cos, sin=sin,
         )
         return out, PagedKV(*planes, batch=b)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class RecurrentState:
+    """One state-space block's per-slot state under a step's batch: the scan's
+    ``ssm [S, H, P, N]`` (float32) and the causal conv's tail ``conv [S, K-1,
+    W]``, its last ``K - 1`` inputs."""
+
+    ssm: jax.Array
+    conv: jax.Array
+    batch: Optional[PagedBatch] = None
+
+    @staticmethod
+    def spec(heads: int, head_dim: int, state: int, kernel: int, width: int, dtype: Any) -> CacheSet:
+        """What a block of these sizes keeps a slot; the conv tail in ``dtype``."""
+        return CacheSet(RECURRENT, (((heads, head_dim, state), jnp.float32), ((kernel - 1, width), dtype)))
+
+    @classmethod
+    def zeros(cls, slots: int, spec: CacheSet, batch: Optional[PagedBatch] = None) -> "RecurrentState":
+        return cls(*(jnp.zeros((slots,) + tuple(shape), dtype) for shape, dtype in spec.planes), batch=batch)
+
+    @property
+    def planes(self) -> Tuple[jax.Array, ...]:
+        """``(ssm, conv)``: what the owner keeps between steps."""
+        return self.ssm, self.conv
+
+    def fork(self, src: jax.Array, dst: jax.Array) -> "RecurrentState":
+        """Slot ``src[i]``'s state copied into slot ``dst[i]`` (``dst[i] ==
+        slots``: no fork; the scatter drops it)."""
+        return RecurrentState(
+            *(plane.at[dst].set(plane[src], mode="drop") for plane in self.planes), batch=self.batch
+        )
+
+    def advance(
+        self,
+        xbc: jax.Array,  # [S, C, W] the conv's inputs: x | B | C, before the conv
+        dt: jax.Array,  # [S, C, H] float32, after softplus
+        conv_weight: jax.Array,  # [K, W]
+        conv_bias: jax.Array,  # [W]
+        a: jax.Array,  # [H] negative
+        d_skip: jax.Array,  # [H]
+        groups: int,
+    ) -> Tuple[jax.Array, "RecurrentState"]:
+        """Continue each slot's conv and scan over the step's rows. A slot
+        whose ``seq_lens`` is 0 starts from ZERO state (a request's first
+        chunk: whatever the slot's last request left is dropped here, not at
+        release); rows past ``q_lens`` do not advance the state (``dt``
+        masked, so they neither decay nor add; the conv tail moves by
+        ``q_lens`` rows only); a slot that is masked or has no rows is left
+        untouched. Returns ``(y [S, C, H, P] float32, the set with its planes
+        updated)``; rows past ``q_lens`` of ``y`` are garbage."""
+        bt = self.batch
+        heads, p, n = self.ssm.shape[1:]
+        q = jnp.where(bt.slot_mask, bt.q_lens, 0)
+        fresh = (q > 0) & (bt.seq_lens == 0)
+        ssm = jnp.where(fresh[:, None, None, None], 0.0, self.ssm)
+        tail = jnp.where(fresh[:, None, None], 0, self.conv)
+        with jax.named_scope(SCOPE_SSM_CONV):
+            xbc, tail = causal_conv_chunk(xbc, tail, conv_weight, conv_bias, q)
+        x, b, cc = split_conv_channels(xbc, heads, p, groups, n)
+        valid = jnp.arange(xbc.shape[1], dtype=q.dtype)[None, :] < q[:, None]
+        with jax.named_scope(SCOPE_SSM_SCAN):
+            y, ssm = ssd_chunk(x, jnp.where(valid[..., None], dt, 0.0), a, b, cc, d_skip, ssm)
+        return y, RecurrentState(ssm, tail, batch=bt)
