@@ -119,6 +119,21 @@ class Optimizer:
             self._accumulators[key] = state
         return self._accumulators[key]
 
+    def _update_leaf(
+        self, param: jax.Array, grad: jax.Array, state: Dict[str, jax.Array], lr: Any, step: Any, weight_decay: float
+    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """``update`` over one leaf as ``_state_for`` laid its state out: with a
+        master weight the update runs on it in fp32 and the parameter is its
+        rounding."""
+        if "master_weight" not in state:
+            return self.update(param, grad, state, lr=lr, step=step, weight_decay=weight_decay)
+        inner = {k: v for k, v in state.items() if k != "master_weight"}
+        new_master, new_state = self.update(
+            state["master_weight"], grad.astype(jnp.float32), inner, lr=lr, step=step, weight_decay=weight_decay
+        )
+        new_state["master_weight"] = new_master
+        return new_master.astype(param.dtype), new_state
+
     # -- the step -------------------------------------------------------------
     def step(self) -> None:
         params_grads = [(p, p.grad) for p in self._parameters if not p.stop_gradient and p.grad is not None]
@@ -163,26 +178,22 @@ class Optimizer:
         g_arrays = [g.data for g in grads]
 
         if self._jit_step_fn is None:
-            update = self.update
-
             # what a device trace files the update's operations under
             @jax.named_scope(SCOPE_UPDATE)
             def fused(ps, gs, sts, lr_, step_, wd):
                 new_ps, new_sts = [], []
                 for p_, g_, st in zip(ps, gs, sts):
-                    if "master_weight" in st:
-                        mp = st["master_weight"]
-                        inner = {k: v for k, v in st.items() if k != "master_weight"}
-                        new_mp, new_inner = update(
-                            mp, g_.astype(jnp.float32), inner, lr=lr_, step=step_, weight_decay=wd
-                        )
-                        new_inner["master_weight"] = new_mp
-                        new_ps.append(new_mp.astype(p_.dtype))
-                        new_sts.append(new_inner)
-                    else:
-                        np_, nst = update(p_, g_, st, lr=lr_, step=step_, weight_decay=wd)
-                        new_ps.append(np_)
-                        new_sts.append(nst)
+                    # A gradient is a materialised array before its update
+                    # reads it. Traced into a to_static step, the update is
+                    # otherwise XLA's to fuse into the matmul that makes the
+                    # gradient, and seven update-sized buffers in VMEM leave
+                    # that matmul a tile at 35-43 % of the MXU's peak (PERF.md,
+                    # PR 34). Per leaf, so no gradient waits for the last one;
+                    # on a jit argument (the eager step) it is nothing.
+                    g_ = jax.lax.optimization_barrier(g_)
+                    np_, nst = self._update_leaf(p_, g_, st, lr_, step_, wd)
+                    new_ps.append(np_)
+                    new_sts.append(nst)
                 return new_ps, new_sts
 
             # One fused XLA program for the whole step, cached across calls
