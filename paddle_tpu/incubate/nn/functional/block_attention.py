@@ -100,6 +100,7 @@ __all__ = [
     "block_cache_prefill",
     "block_cache_append_chunk",
     "block_cache_cow_copy",
+    "latent_chunk_attention",
 ]
 
 # jax.named_scope names of the pool's writes in the engine's step body: what a
@@ -410,7 +411,6 @@ def block_cache_prefill(
     )
 
 
-@jax.named_scope(SCOPE_KV_COW)
 def block_cache_cow_copy(
     key_cache: jax.Array,  # [NB, H, BS, D]
     value_cache: jax.Array,
@@ -432,11 +432,20 @@ def block_cache_cow_copy(
     With scale planes the SAME fork copies them too (inside the one
     ``lax.cond``): a forked int8 block is bit-identical to its source, scales
     included — no requantization on CoW. Returns 4 arrays then."""
-    nb = key_cache.shape[0]
+    return _cow_copy_planes(
+        tuple(p for p in (key_cache, value_cache, key_scale, value_scale) if p is not None), src, dst
+    )
+
+
+@jax.named_scope(SCOPE_KV_COW)
+def _cow_copy_planes(planes: Tuple[jax.Array, ...], src: jax.Array, dst: jax.Array) -> Tuple[jax.Array, ...]:
+    """Blocks ``src`` of every plane ``[NB, ...]`` duplicated into ``dst``
+    (``dst == NB``: dropped), under ONE ``lax.cond``: a paged set's fork,
+    whatever planes the set has."""
+    nb = planes[0].shape[0]
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     csrc = jnp.clip(src, 0, nb - 1)
-    planes = tuple(p for p in (key_cache, value_cache, key_scale, value_scale) if p is not None)
 
     def _copy(planes):
         return tuple(p.at[dst].set(p[csrc], mode="drop") for p in planes)
@@ -466,8 +475,15 @@ def block_cache_append_chunk(
     quantizes in-flight per token row (returns 4 arrays): the scale scatter
     uses the SAME out-of-bounds routing, so dropped KV rows drop their scales
     with them."""
-    c = k.shape[1]
-    nb, bs = key_cache.shape[0], key_cache.shape[2]
+    phys, off = _chunk_write_positions(
+        k.shape[1], key_cache.shape[0], key_cache.shape[2], block_tables, seq_lens, q_lens, slot_mask
+    )
+    return _scatter_kv_rows(key_cache, value_cache, k, v, phys, off, key_scale, value_scale)
+
+
+def _chunk_write_positions(c, nb, bs, block_tables, seq_lens, q_lens, slot_mask):
+    """``(phys [B, C], off [B, C])``: the page and the row in it that chunk
+    token ``j`` of each sequence is written to (position ``seq_lens + j``)."""
     j = jnp.arange(c)[None, :]  # [1, C]
     pos = seq_lens[:, None] + j  # [B, C] absolute token index
     valid = j < q_lens[:, None]
@@ -479,8 +495,7 @@ def block_cache_append_chunk(
     # invalid rows go OUT OF BOUNDS and are dropped by the scatter — clamping
     # them onto a real block would collide with valid writes (duplicate-index
     # scatter order is undefined), exactly the block_cache_prefill rule
-    phys = jnp.where(valid, phys, nb)
-    return _scatter_kv_rows(key_cache, value_cache, k, v, phys, off, key_scale, value_scale)
+    return jnp.where(valid, phys, nb), off
 
 
 def _gather_chunk_attend(
@@ -639,6 +654,63 @@ def block_multihead_chunk_attention(
         k_scale=key_scale, v_scale=value_scale,
     )
     return (out,) + planes
+
+
+def _gather_latent_attend(
+    q: jax.Array,  # [B, C, H, W] absorbed, roped, scaled
+    pool: jax.Array,  # [NB, 1, BS, W]
+    block_tables: jax.Array,
+    seq_lens: jax.Array,
+    attend_q: jax.Array,  # [B] valid new rows (0: exact zeros)
+    value_width: int,
+) -> jax.Array:
+    """The XLA composition of the latent walk: gather each sequence's rows,
+    every head scores them as keys and weighs their first ``value_width``
+    lanes as values; float32 softmax, rows past ``attend_q`` exact zeros."""
+    b, c = q.shape[:2]
+    rows = pool[block_tables][:, :, 0].reshape(b, -1, pool.shape[-1]).astype(jnp.float32)  # [B, L, W]
+    scores = jnp.einsum("bchw,blw->bchl", q.astype(jnp.float32), rows)
+    limit = seq_lens[:, None] + jnp.arange(c)[None, :] + 1  # [B, C]
+    mask = jnp.arange(rows.shape[1])[None, None, :] < limit[:, :, None]
+    probs = jax.nn.softmax(jnp.where(mask[:, :, None, :], scores, -1e30), axis=-1)
+    out = jnp.einsum("bchl,blv->bchv", probs, rows[..., :value_width])
+    row_valid = jnp.arange(c)[None, :] < attend_q[:, None]
+    return jnp.where(row_valid[:, :, None, None], out, 0.0).astype(pool.dtype)
+
+
+def latent_chunk_attention(
+    q: jax.Array,  # [B, C, H, W] absorbed queries, roped and scaled
+    row: jax.Array,  # [B, C, W] the chunk's latent rows (normalised latent | roped key | padding)
+    pool: jax.Array,  # [NB, 1, BS, W]
+    block_tables: jax.Array,  # [B, MBS] int32
+    seq_lens: jax.Array,  # [B] tokens already cached (EXCLUDING this chunk)
+    q_lens: jax.Array,  # [B] valid new tokens this step (1 = decode row)
+    value_width: int,
+    slot_mask: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """:func:`block_multihead_chunk_attention` for a pool of LATENT rows
+    (multi-head latent attention in its absorbed form): the chunk's rows are
+    appended, then every head attends over the sequence's rows, each row key
+    and (its first ``value_width`` lanes) value at once. Returns ``(out [B, C,
+    H, value_width], pool)``; rows past ``q_lens`` and masked slots are zeros."""
+    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
+
+    with jax.named_scope(SCOPE_KV_WRITE):
+        phys, off = _chunk_write_positions(
+            row.shape[1], pool.shape[0], pool.shape[2], block_tables, seq_lens, q_lens, slot_mask
+        )
+        pool = pool.at[phys.reshape(-1), 0, off.reshape(-1)].set(
+            row.reshape(-1, row.shape[-1]).astype(pool.dtype), mode="drop"
+        )
+    attend_q = q_lens if slot_mask is None else jnp.where(slot_mask, q_lens, 0)
+    if pallas_enabled("use_pallas_paged_attention", bare="paged_latent_chunk"):
+        from paddle_tpu.kernels.paged_attention import paged_latent_chunk
+
+        try:
+            return paged_latent_chunk(q, pool, block_tables, seq_lens, attend_q, value_width=value_width), pool
+        except Exception as exc:  # noqa: BLE001 - XLA composition below
+            warn_fallback("paged_latent_chunk", exc)
+    return _gather_latent_attend(q, pool, block_tables, seq_lens, attend_q, value_width), pool
 
 
 def block_multihead_attention(

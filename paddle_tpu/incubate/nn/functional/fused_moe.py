@@ -24,7 +24,10 @@ import jax.numpy as jnp
 from paddle_tpu.core.dispatch import call_op
 from paddle_tpu.core.tensor import Tensor
 
-__all__ = ["collect_expert_counts", "expert_share", "fused_moe", "route_sigmoid_topk"]
+__all__ = [
+    "collect_expert_counts", "expert_share", "fused_moe", "route_sigmoid_topk", "route_softmax_group_limited",
+    "share_of_routed",
+]
 
 # jax.named_scope names inside expert_share (the model's ``moe`` scope is around them)
 SCOPE_MOE_ROUTER = "moe_router"
@@ -90,6 +93,30 @@ def route_sigmoid_topk(
     return chosen.astype(jnp.int32), picked * scale
 
 
+def route_softmax_group_limited(
+    x: jax.Array,  # [T, M]
+    gate_w: jax.Array,  # [M, E] over ALL experts
+    top_k: int,
+    scale: float,
+    n_group: int,
+    topk_group: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Softmax scores in float32 over all ``E`` experts, which lie in
+    ``n_group`` groups of ``E / n_group`` (a group is a device's experts); a
+    group's score is its best expert's; the ``topk_group`` best groups are
+    kept and the scores of the others zeroed; the ``top_k`` best of what is
+    left are chosen, and a chosen expert's weight is its score, NOT normalised
+    over the chosen, times ``scale`` (``group_limited_greedy``). ``(chosen [T,
+    K] int32, weights [T, K] float32)``."""
+    t, e = x.shape[0], gate_w.shape[1]
+    scores = jax.nn.softmax(jnp.matmul(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                                       precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, groups = jax.lax.top_k(jnp.max(scores.reshape(t, n_group, e // n_group), axis=-1), topk_group)
+    kept = jnp.any(groups[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)  # [T, G]
+    picked, chosen = jax.lax.top_k(jnp.where(jnp.repeat(kept, e // n_group, axis=1), scores, 0.0), top_k)
+    return chosen.astype(jnp.int32), picked * scale
+
+
 def expert_share(
     x: jax.Array,  # [T, M]
     gate_w: jax.Array,  # [M, E_total]: the router over ALL experts
@@ -103,21 +130,45 @@ def expert_share(
     row_mask: Optional[jax.Array] = None,  # [T] bool: rows that are real (None: all)
     expert_caps: Sequence[int] = EXPERT_CAPS,
 ) -> jax.Array:
-    """This chip's share of a sparse-expert layer (non-gated squared-ReLU
-    experts): route every row over ALL ``E_total`` experts, compute the part of
-    the result that the HELD experts give, leave out what the absent ones would
-    have added (on one chip there is no exchange; the weights are still
-    normalised over all ``top_k`` chosen). An assignment to an absent expert,
-    or of a row ``row_mask`` rules out (a padded slot, a row past ``q_lens``),
-    goes to a null group past the last and takes up no row of any expert.
-    Dropless; routing is data (``lax.switch`` picks the first of
-    ``expert_caps`` if no held expert overflows it, the same with the one
-    favoured expert on every row if only that one does, else the smallest cap
-    that holds), so one compiled program serves every mix."""
-    t, m = x.shape
-    held = w_up.shape[0]
+    """This chip's share of a sparse-expert layer whose router is
+    :func:`route_sigmoid_topk` and whose experts are non-gated squared-ReLU:
+    the routing, then :func:`share_of_routed`."""
     with jax.named_scope(SCOPE_MOE_ROUTER):
         chosen, weights = route_sigmoid_topk(x, gate_w, select_bias, top_k, scale, norm_topk_prob)
+    return share_of_routed(x, chosen, weights, w_up, w_down, first_expert, row_mask, expert_caps)
+
+
+def share_of_routed(
+    x: jax.Array,  # [T, M]
+    chosen: jax.Array,  # [T, K] int32: the experts each row chose, of ALL the router scores
+    weights: jax.Array,  # [T, K] float32
+    w_up: jax.Array,  # [held, M, I]: experts first_expert .. first_expert + held - 1
+    w_down: jax.Array,  # [held, I, M]
+    first_expert: int = 0,
+    row_mask: Optional[jax.Array] = None,  # [T] bool: rows that are real (None: all)
+    expert_caps: Sequence[int] = EXPERT_CAPS,
+    w_gate: Optional[jax.Array] = None,  # [held, M, I]: gated experts, silu(x W_gate) * (x W_up)
+) -> jax.Array:
+    """This chip's share of a sparse-expert layer, the routing given as data
+    (every row routed over ALL experts): compute the part of the result that
+    the HELD experts give, leave out what the absent ones would have added (on
+    one chip there is no exchange; the weights are what the router gave,
+    normalised or not over all the chosen). An expert is ``W_down
+    relu(W_up x)^2``, or with ``w_gate`` the gated ``W_down (silu(W_gate x) *
+    W_up x)``. An assignment to an absent expert, or of a row ``row_mask``
+    rules out (a padded slot, a row past ``q_lens``), goes to a null group
+    past the last and takes up no row of any expert. Dropless; routing is data
+    (``lax.switch`` picks the first of ``expert_caps`` if no held expert
+    overflows it, the same with the one favoured expert on every row if only
+    that one does, else the smallest cap that holds), so one compiled program
+    serves every mix."""
+    t, m = x.shape
+    held, top_k = w_up.shape[0], chosen.shape[1]
+
+    def activated(h, rows, gate, mm):
+        """The expert's nonlinearity on its up-projection ``h`` of ``rows``."""
+        return jnp.square(jax.nn.relu(h)) if gate is None else jax.nn.silu(mm(rows, gate.astype(x.dtype))) * h
+
     with jax.named_scope(SCOPE_MOE_DISPATCH):
         local = chosen - first_expert
         here = (local >= 0) & (local < held)
@@ -154,11 +205,13 @@ def expert_share(
                 row_weight = jnp.where(real, weight[at], 0.0)
                 gathered = jnp.concatenate([x, jnp.zeros((1, m), x.dtype)])[rows].reshape(held, cap, m)
             with jax.named_scope(SCOPE_MOE_EXPERTS):
-                h = jnp.einsum("ecm,emi->eci", gathered, w_up.astype(x.dtype))
-                out = jnp.einsum("eci,eim->ecm", jnp.square(jax.nn.relu(h)), w_down.astype(x.dtype))
+                batched = lambda a, w: jnp.einsum("ecm,emi->eci", a, w)  # noqa: E731
+                h = batched(gathered, w_up.astype(x.dtype))
+                out = jnp.einsum("eci,eim->ecm", activated(h, gathered, w_gate, batched), w_down.astype(x.dtype))
                 if favoured_takes_every_row:
                     h = jnp.matmul(x, w_up[favoured].astype(x.dtype))
-                    every_row = jnp.matmul(jnp.square(jax.nn.relu(h)), w_down[favoured].astype(x.dtype))
+                    its_gate = None if w_gate is None else w_gate[favoured]
+                    every_row = jnp.matmul(activated(h, x, its_gate, jnp.matmul), w_down[favoured].astype(x.dtype))
             with jax.named_scope(SCOPE_MOE_COMBINE):
                 out = out.reshape(held * cap, m).astype(jnp.float32) * row_weight[:, None]
                 out = jnp.zeros((t + 1, m), jnp.float32).at[rows].add(out)[:t]

@@ -99,6 +99,14 @@ serving wrong tokens: prefix reuse is skipped and counted, and
 without ``cache_sets`` holds ``config.num_kv_sets`` paged sets and its step is
 the program it always was.
 
+**Paged sets of another shape**: a PAGED set whose ``CacheSet.owner`` is a class
+beside ``PagedKV`` (``LatentKV``: one latent row a token) gets its planes from
+that class (``_new_pools``), under the same block tables, admission, prefix
+chains and copy-on-write; the pool's bytes a token, the ``step_logits`` scratch
+pool and ``recover()`` ask the set. What knows ``(key, value)`` planes only
+raises for such a set at construction: ``kv_cache_dtype="int8"``, the host KV
+tier and ``tp > 1``.
+
 Fault tolerance: because every request's prompt and generated tokens live on
 the host (``InferenceRequest``), a dispatch failure that consumed the
 donated KV buffers is recoverable — ``step()`` retries with backoff through
@@ -125,7 +133,7 @@ from paddle_tpu.core.spmd import partitioned_trace
 from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.inference.kv_tier import HostKVTier, HostNode
 from paddle_tpu.incubate.nn.functional.fused_moe import collect_expert_counts
-from paddle_tpu.inference.paged_kv import PAGED, RECURRENT, PagedBatch, PagedKV, RecurrentState
+from paddle_tpu.inference.paged_kv import PAGED, RECURRENT, CacheSet, PagedBatch, PagedKV, RecurrentState
 from paddle_tpu.inference.prefix_cache import ChainNode, PrefixCache, chain_digest
 from paddle_tpu.inference.spec_decode import NGramDrafter, count_accepted
 from paddle_tpu.observability import devprof as _devprof
@@ -504,6 +512,17 @@ class ContinuousBatchingEngine:
         # (identical shapes/dtypes/shardings -> the compiled program is reused)
         self._kvh, self._hd, self._cache_dtype = kvh, hd, dtype
         self._cache_shape = (self.num_blocks, kvh, self.block_size, hd)
+        # what each PAGED set keeps a token, in block order: the model's to say
+        # (a class beside PagedKV owns planes of another shape: LatentKV's one
+        # latent row), else keys and values of (kvh, hd). The pool's shape, a
+        # token's bytes, the scratch pool, recover() and the step's typed sets
+        # ask these; what follows for the int8 pool, the host tier and tp=
+        # knows PagedKV's planes only and refuses another owner below
+        self._paged_specs: List[CacheSet] = [cs for cs in sets if cs.kind == PAGED] or [
+            CacheSet(PAGED, (((kvh, hd), dtype),) * 2)
+        ] * self._num_kv_sets
+        self._paged_owners = [cs.owner or PagedKV for cs in self._paged_specs]
+        self._owned_sets = any(owner is not PagedKV for owner in self._paged_owners)
         # quantized KV plane (FLAGS_kv_cache_dtype="int8"): the pool stores
         # int8 blocks plus per-block-per-head-per-token fp32 scale planes
         # [NB, KVH, BS] addressed by the SAME physical block ids — every
@@ -522,6 +541,11 @@ class ContinuousBatchingEngine:
             )
         self.kv_cache_dtype = kvd
         self._quant_kv = kvd == "int8"
+        if self._quant_kv and self._owned_sets:
+            raise ValueError(
+                "kv_cache_dtype='int8' cannot serve a model whose paged sets are not PagedKV "
+                "(latent rows): the scale planes are per (token, KV head) of a key / value pair"
+            )
         if self._quant_kv:
             self._cache_dtype = jnp.int8
         self._scale_shape = (self.num_blocks, kvh, self.block_size)
@@ -551,6 +575,11 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 "tp > 1 cannot serve a model with recurrent state sets yet: nothing shards "
                 "a state plane, the scan or an expert share over the 'tp' mesh"
+            )
+        if self.tp > 1 and self._owned_sets:
+            raise ValueError(
+                "tp > 1 cannot serve a model whose paged sets are not PagedKV (latent rows) yet: "
+                "the pool is sharded over KV heads, and a latent row has none"
             )
         if self.tp > 1:
             from paddle_tpu.distributed.tp import (
@@ -632,6 +661,11 @@ class ContinuousBatchingEngine:
                     "kv_host_tier_bytes > 0 cannot serve a model with recurrent state sets: a "
                     "spilled chain holds pages only, not the state at its end"
                 )
+        if tier_bytes > 0 and self._owned_sets:
+            raise ValueError(
+                "kv_host_tier_bytes > 0 cannot serve a model whose paged sets are not PagedKV "
+                "(latent rows): a spilled block is captured and landed as (key, value) planes"
+            )
         self._host_tier: Optional[HostKVTier] = None
         if tier_bytes > 0 and self._use_prefix_cache:
             self._host_tier = HostKVTier(
@@ -708,7 +742,7 @@ class ContinuousBatchingEngine:
         # writes across layers — each KV set (a layer; a layer in one pass of
         # a looped stack) owns its [NB, KVH, BS, D] pair, all indexed by the
         # SAME block tables (the reference layout).
-        self._caches = [self._new_cache_pair() for _ in range(self._num_kv_sets)]
+        self._caches = self._new_pools()
         self._states = self._new_states()
 
         # per-slot host state (rewritten freely between steps — it is DATA to
@@ -746,6 +780,10 @@ class ContinuousBatchingEngine:
             # KV pages the paged kernel's length-bounded walk visits, a layer:
             # sum over a step's active slots of ceil((cached + new) / block)
             "paged_pages_walked": 0,
+            # (query row, key) pairs an attention call of a step has to score:
+            # sum over a step's live rows of the tokens each may see (row j of
+            # a slot: cached + j + 1); what a walk's least work is counted from
+            "attn_row_keys": 0,
             # passes of the model's stack run, summed over steps (one a step
             # unless the stack is looped); gauges: the KV sets a token holds
             # and their bytes
@@ -854,7 +892,18 @@ class ContinuousBatchingEngine:
             dtype_bytes=jnp.dtype(self._cache_dtype).itemsize,
         )
 
-    def _new_cache_pair(self) -> Tuple[Any, ...]:
+    def _new_pools(self, scratch_blocks: Optional[int] = None) -> List[Tuple[Any, ...]]:
+        """Every paged set's planes, zeroed: the engine's pool, or with
+        ``scratch_blocks`` a scratch pool of that many blocks (``step_logits``).
+        A set whose class is the model's (``CacheSet.owner``) makes its own, a
+        ``PagedKV`` set is :meth:`_new_cache_pair`."""
+        return [
+            self._new_cache_pair(scratch_blocks) if owner is PagedKV
+            else owner.zeros(scratch_blocks or self.num_blocks, self.block_size, spec).planes
+            for spec, owner in zip(self._paged_specs, self._paged_owners)
+        ]
+
+    def _new_cache_pair(self, scratch_blocks: Optional[int] = None) -> Tuple[Any, ...]:
         """One layer's (key, value) pool pair — under ``kv_cache_dtype=int8``
         a (key, value, key_scale, value_scale) QUAD, the scale planes
         ``[NB, KVH, BS]`` fp32 initialized to ONES (``quantize(zeros)`` is
@@ -864,6 +913,8 @@ class ContinuousBatchingEngine:
         block ids for its own head slice, so the host-side allocator needs
         no per-shard state. Same shapes/dtypes/shardings on every call, so
         recover()'s rebuilt pools reuse the compiled program."""
+        if scratch_blocks is not None:  # built inside step_logits' trace, plain
+            return PagedKV.zeros((scratch_blocks,) + self._cache_shape[1:], self._cache_dtype).planes
         if self._cache_sharding is not None:
             if self._quant_kv:
                 return (
@@ -934,10 +985,7 @@ class ContinuousBatchingEngine:
         reduction (1.94x at D=128)."""
         if self._quant_kv:
             return 2 * self._num_kv_sets * self._kvh * (self._hd + 4)
-        return (
-            2 * self._num_kv_sets * self._kvh * self._hd
-            * jnp.dtype(self._cache_dtype).itemsize
-        )
+        return sum(spec.unit_bytes for spec in self._paged_specs)
 
     def _new_prefix_cache(self) -> Optional[PrefixCache]:
         if not self._use_prefix_cache:
@@ -1324,8 +1372,8 @@ class ContinuousBatchingEngine:
             batch = PagedBatch(tables, lens, active, q_lens)
             n_kv = self._num_kv_sets
             pkv = [
-                PagedKV(*planes, batch=batch).fork(cow_src, cow_dst)
-                for planes in caches[:n_kv]
+                owner(*planes, batch=batch).fork(cow_src, cow_dst)
+                for owner, planes in zip(self._paged_owners, caches[:n_kv])
             ]
             if self._state_specs:
                 # the model takes its sets in block order; the flat arguments
@@ -1335,7 +1383,7 @@ class ContinuousBatchingEngine:
                 pkv = [next(paged) if kind == PAGED else next(states) for kind in self._set_kinds]
             with paddle_tpu.no_grad():
                 logits, new_pkv = self.model(Tensor(toks), past_key_values=pkv, use_cache=True)
-            new_pkv = sorted(new_pkv, key=lambda kv: not isinstance(kv, PagedKV))  # stable: paged first
+            new_pkv = sorted(new_pkv, key=lambda kv: isinstance(kv, RecurrentState))  # stable: paged first
             return logits._data, [kv.planes for kv in new_pkv]
 
     def step_logits(self, prompt: Any) -> np.ndarray:
@@ -1374,11 +1422,8 @@ class ContinuousBatchingEngine:
         ``[C, V]`` fp32 logits of ``_step_forward`` over a scratch pool."""
         mbs = self.max_blocks_per_seq
 
-        scratch = (mbs,) + self._cache_shape[1:]
-
         def run(param_arrays, *step_args):
-            caches = [PagedKV.zeros(scratch, self._cache_dtype).planes for _ in range(self._num_kv_sets)]
-            caches += self._new_states()  # scratch state beside the scratch pool
+            caches = self._new_pools(mbs) + self._new_states()  # scratch state beside the scratch pool
             logits, _ = self._step_forward(param_arrays, caches, *step_args)
             return logits[0].astype(jnp.float32)
 
@@ -2234,7 +2279,7 @@ class ContinuousBatchingEngine:
         toks = np.zeros((self.max_slots, C), np.int32)
         q_lens = np.zeros((self.max_slots,), np.int32)
         active = np.zeros((self.max_slots,), bool)
-        prefill_tokens = pages_walked = 0
+        prefill_tokens = pages_walked = row_keys = 0
         # slot -> draft packed into this attempt's chunk rows; LOCAL on
         # purpose: a failed dispatch retries through a fresh _step_attempt
         # that re-proposes, so no speculative state can ever go stale
@@ -2259,7 +2304,9 @@ class ContinuousBatchingEngine:
                         toks[i, 1 : 1 + k] = draft
                         q_lens[i] = 1 + k
                         drafts[i] = draft
-            pages_walked += -(-(cur + int(q_lens[i])) // self.block_size)
+            q = int(q_lens[i])
+            pages_walked += -(-(cur + q) // self.block_size)
+            row_keys += q * cur + q * (q + 1) // 2
         # devprof sampling decision: one cached-bool read at rate 0 (the
         # stride counter only advances while the flag is on, and the stride
         # is deterministic — no RNG draw, seeded runs stay byte-identical)
@@ -2275,6 +2322,7 @@ class ContinuousBatchingEngine:
         stats["steps"] += 1
         stats["prompt_tokens_computed"] += prefill_tokens
         stats["paged_pages_walked"] += pages_walked
+        stats["attn_row_keys"] += row_keys
         stats["loop_passes"] += self._stack_passes
         if self._admit_blocked is not None:
             stats["admit_blocked_steps." + self._admit_blocked] += 1
@@ -2433,7 +2481,7 @@ class ContinuousBatchingEngine:
         )
         # identical shapes/dtypes/shardings (tp pools come back committed on
         # the same mesh partition) -> the compiled program is reused
-        self._caches = [self._new_cache_pair() for _ in range(self._num_kv_sets)]
+        self._caches = self._new_pools()
         self._states = self._new_states()  # the replay below rebuilds every live slot's
         self._mgr = BlockKVCache(
             self.num_blocks, self.block_size, self._kvh, self._hd,

@@ -26,9 +26,15 @@ class beside it, and the step does not branch on it.
   :meth:`~RecurrentState.fork` (copy a slot's state) and
   :meth:`~RecurrentState.advance` (continue the conv and the scan over the
   step's rows), the counterparts of ``PagedKV``'s two.
+- :class:`LatentKV`, one per LATENT SET (a multi-head-latent-attention layer):
+  a PAGED set like ``PagedKV``, under the same batch, tables and copy-on-write,
+  whose unit is ONE row a token, ``[normalised latent | roped shared key]``,
+  that every query head reads as its key and (its first lanes) as its value.
+  Its :meth:`~LatentKV.attend` takes the ABSORBED queries.
 - :class:`CacheSet` is how a model's configuration tells a cache owner what
   sets it holds, in block order (``config.cache_sets``): kind, plane shapes
-  and dtypes. A model without it holds ``config.num_kv_sets`` paged sets.
+  and dtypes, and for a PAGED set the class that owns its planes. A model
+  without it holds ``config.num_kv_sets`` paged sets of ``PagedKV``.
 """
 
 from __future__ import annotations
@@ -41,12 +47,14 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.incubate.nn.functional.block_attention import (
+    _cow_copy_planes,
     block_cache_cow_copy,
     block_multihead_chunk_attention,
+    latent_chunk_attention,
 )
 from paddle_tpu.incubate.nn.functional.mamba2 import causal_conv_chunk, split_conv_channels, ssd_chunk
 
-__all__ = ["CacheSet", "PAGED", "PagedBatch", "PagedKV", "RECURRENT", "RecurrentState"]
+__all__ = ["CacheSet", "LatentKV", "PAGED", "PagedBatch", "PagedKV", "RECURRENT", "RecurrentState"]
 
 PAGED, RECURRENT = "paged", "recurrent"
 
@@ -59,10 +67,14 @@ SCOPE_SSM_SCAN = "ssm_scan"
 class CacheSet:
     """One cache set a model holds. ``planes``: ``(shape, dtype)`` of each
     plane for ONE unit of the set, a token of a page for ``PAGED`` (``(KVH,
-    D)`` twice) and a slot for ``RECURRENT`` (:meth:`RecurrentState.spec`)."""
+    D)`` twice; :meth:`LatentKV.spec`) and a slot for ``RECURRENT``
+    (:meth:`RecurrentState.spec`). ``owner``: the class that owns a PAGED
+    set's planes (``None``: :class:`PagedKV`); a unit plane ``(H, D)`` is the
+    pool plane ``[NB, H, BS, D]``."""
 
     kind: str
     planes: Tuple[Tuple[Tuple[int, ...], Any], ...]
+    owner: Any = None
 
     @property
     def unit_bytes(self) -> int:
@@ -83,6 +95,11 @@ class PagedBatch:
     def decode(cls, block_tables: jax.Array, seq_lens: jax.Array) -> "PagedBatch":
         """Every slot live with one new token (``generate_paged``'s step)."""
         return cls(block_tables, seq_lens, jnp.ones(seq_lens.shape, bool), jnp.ones_like(seq_lens))
+
+    def live_rows(self, chunk: int) -> jax.Array:
+        """``[S * chunk]`` bool: the step's rows that are real (a live slot's first ``q_lens``)."""
+        rows = jnp.arange(chunk, dtype=self.q_lens.dtype)[None, :] < self.q_lens[:, None]
+        return (rows & self.slot_mask[:, None]).reshape(-1)
 
 
 @jax.tree_util.register_dataclass
@@ -139,6 +156,56 @@ class PagedKV:
             cos=cos, sin=sin,
         )
         return out, PagedKV(*planes, batch=b)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class LatentKV:
+    """One latent set's pool plane ``[NB, 1, BS, W]`` under a step's batch: a
+    token's row is ``[c_kv (normalised) | rope(k_pe) | 0]``, ``W`` the two
+    widths padded to whole 128-lane tiles (a page leaves HBM in such tiles,
+    and the tiled layout pads the row to them anyway)."""
+
+    rows: jax.Array
+    batch: Optional[PagedBatch] = None
+
+    @staticmethod
+    def spec(latent: int, rope: int, dtype: Any) -> CacheSet:
+        """What a layer of these widths keeps a token."""
+        return CacheSet(PAGED, (((1, -(-(latent + rope) // 128) * 128), dtype),), LatentKV)
+
+    @classmethod
+    def zeros(cls, num_blocks: int, block_size: int, spec: CacheSet, batch: Optional[PagedBatch] = None) -> "LatentKV":
+        (shape, dtype), = spec.planes
+        return cls(jnp.zeros((num_blocks, shape[0], block_size, shape[1]), dtype), batch=batch)
+
+    @property
+    def planes(self) -> Tuple[jax.Array, ...]:
+        """``(rows,)``: what the pool's owner keeps between steps."""
+        return (self.rows,)
+
+    def fork(self, src: jax.Array, dst: jax.Array) -> "LatentKV":
+        """Copy-on-write, as :meth:`PagedKV.fork`: pages ``src`` duplicated into ``dst``."""
+        return LatentKV(*_cow_copy_planes(self.planes, src, dst), batch=self.batch)
+
+    def attend(
+        self,
+        q: jax.Array,  # [S, C, H, latent + rope] ABSORBED queries, roped and scaled
+        row: jax.Array,  # [S, C, latent + rope] the step's rows: normalised latent | roped key
+        value_width: int,  # the row's first lanes that are the value (the latent)
+    ) -> Tuple[jax.Array, "LatentKV"]:
+        """Append the step's rows at the batch's positions, then attend every
+        head of ``q`` over each slot's rows, a row key and value at once (the
+        one form for decode rows and prefill chunks alike). Returns ``(out [S,
+        C, H, value_width], the set with its plane updated)``."""
+        b = self.batch
+        pad = self.rows.shape[-1] - row.shape[-1]
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad),))
+        row = jnp.pad(row, ((0, 0),) * 2 + ((0, pad),))
+        out, rows = latent_chunk_attention(
+            q, row, self.rows, b.block_tables, b.seq_lens, b.q_lens, value_width, slot_mask=b.slot_mask
+        )
+        return out, LatentKV(rows, batch=b)
 
 
 @jax.tree_util.register_dataclass

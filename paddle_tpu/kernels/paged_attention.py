@@ -9,7 +9,9 @@ loop inside the kernel bounded by the sequence's length, and an online softmax
 accumulates in fp32 VMEM scratch. Grouped-query attention keeps the G query
 heads of one KV head together as the kernel's row dimension. One body and one
 ``pallas_call`` serve the engine's mixed prefill/decode step and a plain
-decode step (the chunk at ``C == 1``).
+decode step (the chunk at ``C == 1``). A second body beside it,
+``paged_latent_chunk``, walks a pool of LATENT rows (multi-head latent
+attention, absorbed): one row a token that is key and value of every head.
 
 Quantized KV (``FLAGS_kv_cache_dtype=int8``): optional ``k_scale``/``v_scale``
 planes (``[NB, HKV, BS]`` fp32 — per block, per head, per token slot, addressed
@@ -34,6 +36,7 @@ NEG_INF = -1e30
 # pallas_call name= of each kernel here: what a device trace calls it (stable, no shapes)
 KERNEL_CHUNK = "paged_attention_chunk"
 KERNEL_CHUNK_FUSED = "paged_attention_chunk_fused"  # q-RoPE folded into the walk
+KERNEL_LATENT = "paged_latent_attention_chunk"  # one latent row a token is key AND value
 
 
 def _rope_rows(q, c, s, half):
@@ -318,3 +321,159 @@ def paged_flash_chunk(
         q, cos, sin, key_cache, value_cache, block_tables, seq_lens, q_lens,
         scale, interpret, k_scale, v_scale, KERNEL_CHUNK if cos is None else KERNEL_CHUNK_FUSED,
     )
+
+
+# ---------------------------------------------------------------------------
+# The walk over LATENT pages (multi-head latent attention, absorbed form)
+# ---------------------------------------------------------------------------
+#
+# The pool holds ONE row a token, ``[c_kv | rope(k_pe) | 0]`` (``W`` lanes, a
+# whole number of 128-lane tiles), which every query head reads as its key and
+# whose first ``value_width`` lanes every head reads as its value: a page comes
+# out of HBM once and serves QK^T and PV alike. The query rows arrive absorbed
+# (``q_nope W_UK | rope(q_pe)``), roped and scaled, so the body is the plain
+# online softmax of ``_walk_kernel`` with one KV "head" and ``C x heads`` packed
+# rows. Both matmuls take their operands in the POOL's dtype with float32
+# accumulation (a bfloat16 pool runs the MXU at its bfloat16 rate; the rows are
+# 128 times as many as a grouped-query cell packs, so here the matmuls are the
+# cost). Every one of the ``C x heads`` rows is computed whatever ``q_lens``
+# is; rows past it come out as exact zeros.
+
+_LATENT_ROWS = 2048  # packed query rows a cell may hold: what keeps q, the accumulator and a score tile in VMEM
+_LATENT_VMEM_BYTES = 64 << 20  # stated to Mosaic: a 2048-row cell needs ~30 MiB, the default scope is 16
+
+
+def _latent_walk_kernel(
+    tables_ref,  # scalar prefetch: [B, MBS] int32
+    lens_ref,  # scalar prefetch: [B] int32 tokens cached BEFORE the chunk
+    qlens_ref,  # scalar prefetch: [B] int32 valid new tokens (0 = skip slot)
+    q_ref,  # [1, 1, C*HG, W] chunk-major packed rows (row = j*HG + h), roped and scaled
+    pool_ref,  # [NB, 1, BS, W] in HBM
+    o_ref,  # [1, 1, C*HG, value_width]
+    m_ref, l_ref, acc_ref,  # [C*HG, 1] x 2, [C*HG, value_width] float32
+    buf,  # [2, P*BS, W] one tile's pages, double-buffered
+    sem,
+    *,
+    block_size: int,
+    pages: int,
+    heads: int,
+    value_width: int,
+):
+    bi = pl.program_id(0)
+    lens, qlens = lens_ref[bi], qlens_ref[bi]
+    rows = q_ref.shape[2]
+    tile = pages * block_size
+    n_pages = jnp.where(qlens > 0, (lens + qlens + block_size - 1) // block_size, 0)
+    n_tiles = (n_pages + pages - 1) // pages
+
+    def copy_tile(t, slot, wait):
+        def one_page(p, carry):
+            page = tables_ref[bi, jnp.minimum(t * pages + p, n_pages - 1)]
+            cp = pltpu.make_async_copy(
+                pool_ref.at[page, 0], buf.at[slot, pl.ds(p * block_size, block_size)], sem.at[slot]
+            )
+            cp.wait() if wait else cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, pages, one_page, None, unroll=True)
+
+    @pl.when(n_pages == 0)
+    def _skip():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_pages > 0)
+    def _attend():
+        copy_tile(0, 0, wait=False)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def walk(t, carry):
+            slot = t % 2
+
+            @pl.when(t + 1 < n_tiles)
+            def _prefetch():
+                copy_tile(t + 1, 1 - slot, wait=False)
+
+            copy_tile(t, slot, wait=True)
+            pos = t * tile + jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1)
+            row_j = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 0) // heads
+            valid = (pos < lens + row_j + 1) & (row_j < qlens)
+            kv = buf[slot]  # [P*BS, W]: the tile's rows, key and value at once
+            s = jax.lax.dot_general(
+                q_ref[0, 0], kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )  # [C*HG, P*BS]
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new) * valid.astype(jnp.float32)  # as _walk_kernel: masked rows stay at 0
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p.astype(kv.dtype), kv[:, :value_width], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[...] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, walk, None)
+        live = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads < qlens
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = jnp.where(live, out, 0.0).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("value_width", "interpret"))
+def paged_latent_chunk(
+    q: jax.Array,  # [B, C, H, W] absorbed queries, roped and SCALED (row j valid iff j < q_lens)
+    pool: jax.Array,  # [NB, 1, BS, W] latent rows, the chunk's ALREADY appended
+    block_tables: jax.Array,  # [B, MBS] int32
+    seq_lens: jax.Array,  # [B] tokens cached BEFORE the chunk
+    q_lens: jax.Array,  # [B] valid new tokens (0 = inactive slot)
+    value_width: int,  # the row's first lanes that are the value
+    interpret: bool = False,
+) -> jax.Array:
+    """Attention of ``H`` query heads over ONE latent row a token, the row as
+    key and its first ``value_width`` lanes as value. Returns ``[B, C, H,
+    value_width]`` in the pool's dtype with rows past ``q_lens`` exactly 0. A
+    cell takes as many heads as keep ``C x heads`` at ``_LATENT_ROWS``: all of
+    them at a chunk of 16 and 128 heads, so that a slot's live pages leave HBM
+    once a set; a longer chunk splits the heads over cells and reads the pages
+    once a cell."""
+    b, c, h, w = q.shape
+    nb, one, bs, _ = pool.shape
+    if one != 1 or pool.shape[-1] != w:
+        raise ValueError(f"a latent pool is [NB, 1, BS, {w}], got {pool.shape}")
+    sublanes = 32 // jnp.dtype(pool.dtype).itemsize
+    if not interpret and (w % 128 or value_width % 128 or bs % sublanes):
+        raise ValueError(
+            f"the latent walk copies [{bs}, {w}] pages out of HBM: the row and its value part must be "
+            f"whole 128-lane tiles and block_size a multiple of {sublanes} sublanes"
+        )
+    hg = max(g for g in range(1, h + 1) if h % g == 0 and (g == 1 or c * g <= _LATENT_ROWS))
+    pages = max(1, 128 // bs)
+    # pack rows chunk-major per head group: [B, C, H/hg, hg, W] -> [B, H/hg, C*hg, W] (no move at one group)
+    qg = q.astype(pool.dtype).reshape(b, c, h // hg, hg, w).transpose(0, 2, 1, 3, 4).reshape(b, h // hg, c * hg, w)
+    cell = lambda bi, gj, tables, lens, qlens: (bi, gj, 0, 0)  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_latent_walk_kernel, block_size=bs, pages=pages, heads=hg, value_width=value_width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, h // hg),
+            in_specs=[pl.BlockSpec((1, 1, c * hg, w), cell), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, c * hg, value_width), cell),
+            scratch_shapes=[
+                pltpu.VMEM((c * hg, 1), jnp.float32),  # m
+                pltpu.VMEM((c * hg, 1), jnp.float32),  # l
+                pltpu.VMEM((c * hg, value_width), jnp.float32),  # acc
+                pltpu.VMEM((2, pages * bs, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h // hg, c * hg, value_width), pool.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_LATENT_VMEM_BYTES
+        ),
+        interpret=interpret,
+        name=KERNEL_LATENT,
+    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q_lens.astype(jnp.int32), qg, pool)
+    return out.reshape(b, h // hg, c, hg, value_width).transpose(0, 2, 1, 3, 4).reshape(b, c, h, value_width)

@@ -274,10 +274,7 @@ class NemotronHMoE(nn.Layer):
         """``batch``: the serving step's, whose masked slots and rows past
         ``q_lens`` are sent to no expert."""
         cfg = self.config
-        row_mask = None
-        if batch is not None:
-            rows = jnp.arange(u.shape[1], dtype=batch.q_lens.dtype)[None, :] < batch.q_lens[:, None]
-            row_mask = (rows & batch.slot_mask[:, None]).reshape(-1)
+        row_mask = None if batch is None else batch.live_rows(u.shape[1])
 
         def share(x, gate_w, bias, w_up, w_down):
             out = expert_share(

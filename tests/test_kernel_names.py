@@ -130,6 +130,13 @@ def _paged_chunk_fused():
     return fn, (((SLOTS, CHUNK, HEADS, D), BF16), cs, cs, _POOL, _POOL, _TABLES, _LENS, _LENS)
 
 
+def _latent_chunk():
+    from paddle_tpu.kernels.paged_attention import paged_latent_chunk
+
+    fn = lambda q, pool, *rest: paged_latent_chunk(q, pool, *rest, value_width=D)  # noqa: E731
+    return fn, (((SLOTS, CHUNK, HEADS, 2 * D), BF16), ((NB, 1, BS, 2 * D), BF16), _TABLES, _LENS, _LENS)
+
+
 # kernel name -> the entry whose lowering has to hold it (one pallas_call site
 # each; the rope runner is one site that two kernels share)
 SITES = {
@@ -137,6 +144,7 @@ SITES = {
     "fused_loss_fwd": _fused_loss, "fused_loss_dx": _fused_loss, "fused_loss_dw": _fused_loss,
     "fused_loss_fwd_quant": _fused_loss_quant,
     "paged_attention_chunk": _paged_chunk, "paged_attention_chunk_fused": _paged_chunk_fused,
+    "paged_latent_attention_chunk": _latent_chunk,
     "rms_norm_fwd": _rms, "rms_norm_bwd": _rms,
     "rope_fwd": _rope, "rope_adjoint": _rope,
     "rms_norm_residual_fwd": _rms_residual, "rms_norm_residual_adjoint": _rms_residual,
@@ -164,8 +172,8 @@ def test_lowered_kernel_carries_its_name(name):
 
 
 def test_every_pallas_call_site_passes_a_name_constant():
-    """The 17 sites, read from the source (the paged chunk kernel, plain and
-    rope-fused, is one): each ``pl.pallas_call(`` has a ``name=`` keyword,
+    """The 18 sites, read from the source (the paged chunk kernel, plain and
+    rope-fused, is one; the latent walk, PR 36, is the eighteenth): each ``pl.pallas_call(`` has a ``name=`` keyword,
     and every name is one of the constants above."""
     import ast
     import inspect
@@ -180,7 +188,7 @@ def test_every_pallas_call_site_passes_a_name_constant():
                 sites += 1
                 assert any(kw.arg == "name" for kw in node.keywords), f"{module.__name__}:{node.lineno}"
         constants |= {v for k, v in vars(module).items() if k.startswith("KERNEL_")}
-    assert sites == 17
+    assert sites == 18
     assert constants == set(SITES)
     assert all(re.fullmatch(r"[a-z][a-z0-9_]*", c) for c in constants)  # no shapes, trace-safe
 

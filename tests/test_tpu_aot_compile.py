@@ -205,6 +205,20 @@ def _wo_matmul():
     )
 
 
+def _latent_chunk(chunk=16, slots=16, heads=128, width=640, value=512, bs=16, mbs=528):
+    """The latent page walk at DeepSeek-V2's widths and the document cell's pool
+    (``benchmarks/workloads/deepseekv2.serve_doc.json``): 128 heads over one
+    640-lane row a token; at a chunk of 16 one cell holds all 2048 packed rows
+    (what its ``vmem_limit_bytes`` is stated for), at 64 the heads split."""
+    from paddle_tpu.kernels.paged_attention import paged_latent_chunk
+
+    return (
+        lambda q, pool, tables, lens, qlens: paged_latent_chunk(q, pool, tables, lens, qlens, value_width=value),
+        (((slots, chunk, heads, width), BF16), ((slots * mbs, 1, bs, width), BF16), ((slots, mbs), I32),
+         ((slots,), I32), ((slots,), I32)),
+    )
+
+
 CASES = {
     "flash_attention_fwd_bwd_s2048": _flash_attention,
     # the train cell (benchmarks/workloads/mistral7b.train_2k.json): batch 8, GQA 4:1, 512 x 512 blocks
@@ -222,6 +236,9 @@ CASES = {
     "paged_flash_chunk_fused_chat_scratch_pool": lambda: _paged_chunk(BF16, fused=True, **{**_CHAT, "nb": 256}),
     "paged_flash_chunk_fused_chat_tp4_shard_bf16": lambda: _paged_chunk(BF16, fused=True, **_CHAT_TP4),
     "paged_flash_chunk_fused_chat_tp4_shard_int8": lambda: _paged_chunk(I8, fused=True, **_CHAT_TP4),
+    "paged_latent_chunk_doc_cell": _latent_chunk,
+    "paged_latent_chunk_decode_only": lambda: _latent_chunk(chunk=1),
+    "paged_latent_chunk_chunk64_heads_split": lambda: _latent_chunk(chunk=64),
     "fused_rms_norm_fwd_bwd": _rms_norm,
     "fused_rope_and_adjoint": _rope,
     "fused_rms_norm_residual_train": lambda: _rms_norm_residual((1, SEQ, HIDDEN)),
@@ -306,3 +323,39 @@ def test_fused_loss_never_holds_the_logits(one_chip):
     held_fused = _compile(fused, one_chip, *shapes).memory_analysis().temp_size_in_bytes
     assert held_plain >= n * v * 2, held_plain  # the logits
     assert held_fused < n * HIDDEN * 2 / 2, held_fused  # not even half a bf16 x: no float32 dX or dW
+
+
+def test_the_latent_cells_step_compiles_at_published_widths(one_chip, monkeypatch):
+    """The engine's ONE step program for ``DeepseekV2ForCausalLM`` at the
+    published widths (the leading dense layer and one expert layer holding 8 of
+    160 experts, an eighth of the vocabulary; the document cell's slots, chunk,
+    pages and pool), lowered for the described chip with the dispatch on its
+    Pallas branch: the latent walk is in it once a layer, beside the norm
+    kernels, and no page of the plane is copied as a pool-sized temporary."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.kernels.paged_attention import KERNEL_LATENT
+    from paddle_tpu.models.deepseek_v2 import DeepseekV2Config, DeepseekV2ForCausalLM
+    from paddle_tpu.nn import initializer
+
+    # a leaf stays the zeros it is made as: drawing 0.7 B normals on the CPU buys a compile nothing
+    monkeypatch.setattr(initializer.Normal, "__call__", lambda self, param, block=None: None)
+    paddle.seed(0)
+    config = DeepseekV2Config(num_hidden_layers=2, n_routed_experts=8, n_routed_experts_total=160, vocab_size=12800)
+    model = DeepseekV2ForCausalLM(config)
+    model.eval()
+    eng = ContinuousBatchingEngine(model, max_slots=16, block_size=16, prompt_bucket=8192, max_model_len=8448,
+                                   prefill_chunk=16)
+    s, c, mbs = eng.max_slots, eng.prefill_chunk, eng.max_blocks_per_seq
+    assert eng._caches[0][0].shape == (16 * 528, 1, 16, 640) and eng.pool_stats()["bytes_per_token"] == 2 * 1280
+    args = (eng._param_arrays(), eng._caches, jnp.zeros((s, c), I32), jnp.zeros((s, mbs), I32), jnp.zeros((s,), I32),
+            jnp.ones((s,), I32), jnp.ones((s,), bool), jnp.zeros((s,), I32), jnp.full((s,), eng.num_blocks, I32))
+    shaped = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), args)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the dispatch takes its Pallas branch
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(eng._step_impl).lower(*shaped).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL_LATENT) >= 2 and text.count("tpu_custom_call") >= 2 + 4 + 4 + 1  # walks, norms, embed
+    memory = compiled.memory_analysis()
+    pool = 2 * 16 * 528 * 16 * 640 * 2
+    assert memory.temp_size_in_bytes < pool, memory  # no second copy of the pool among the temporaries
