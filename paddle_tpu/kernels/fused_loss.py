@@ -1,29 +1,45 @@
 """Fused linear + softmax-cross-entropy loss head, vocab-chunked.
 
-The training loss head is the single largest bandwidth sink in a causal-LM
-step: ``lm_head`` materializes ``[B·S, V]`` logits, the fp32 upcast copies
-them, and ``log_softmax`` allocates a third buffer — at the bench config
-(16k tokens, 32k vocab) that is ~2 GB of pure HBM traffic per copy, dwarfing
-any single matmul. This module computes ``cross_entropy(x @ Wᵀ, labels)``
-without ever materializing ``[N, V]`` in any dtype, in the style of flash
-attention's online softmax:
+The training loss head is the largest single item of a causal-LM step. The
+plain composition materializes ``[B·S, V]`` logits, upcasts them to fp32 and
+lets ``log_softmax`` allocate a third buffer, and keeps the logits for its
+backward. This module computes ``cross_entropy(x @ Wᵀ, labels)`` in the style
+of flash attention's online softmax, and holds at most ONE ``[N, V]`` array,
+in the operand dtype, for the length of its backward:
 
 - **forward** streams vocab blocks of ``x @ W_blockᵀ`` through VMEM keeping a
   per-token online max/sum (fp32) plus the target-class logit (gathered per
   block; ``ignore_index`` rows simply never match), then finishes with
   ``loss = logsumexp - target_logit`` reduced exactly like
-  ``F.cross_entropy`` (mean over non-ignored tokens, ``max(count, 1)``);
-- **backward** recomputes each block's logits from the saved logsumexp and
-  emits ``(softmax - onehot) * dloss`` block-wise, accumulating ``dX`` (row
-  blocks) and ``dW`` (vocab blocks) in two Pallas kernels — the flash-attn-2
-  dq/dkv split, so each output is only ever revisited on consecutive grid
-  steps; both sums stay in float32 VMEM scratch and leave their kernel once,
-  in the operand dtype (no float32 ``[N, H]`` / ``[H, V]`` in HBM);
+  ``F.cross_entropy`` (mean over non-ignored tokens, ``max(count, 1)``); it
+  leaves the ``[N]`` logsumexp behind and no logits;
+- **backward** runs two Pallas kernels — the flash-attn-2 dq/dkv split, so
+  each output is only ever revisited on consecutive grid steps. dX recomputes
+  each block's logits from x and W and the saved logsumexp, forms
+  ``d = (softmax - onehot) * dloss`` in the operand dtype, accumulates
+  ``d @ W`` over vocab blocks, and WRITES the ``d`` tile to an ``[N, V]``
+  array; dW reads it and is one matmul, ``xᵀ d`` accumulated over row blocks.
+  What a buffer costs against what it saves, by the chip's own numbers (v5e,
+  16 k tokens, 32 k vocabulary, ``H`` 4096, bf16): 1.07 GB written once and
+  read once is 2.6 ms at 819 GB/s; the ``[N, H] x [H, V]`` matmul that forming
+  ``d`` again costs is 4.4 TFLOP, 22.3 ms at 197 TFLOP/s. A logit is ``2·H``
+  flop to recompute and 4 bytes of traffic (~960 flop at the ridge of 240
+  flop a byte) to store: past ``H`` of a few hundred storing wins, and what
+  recomputing buys is capacity, not time. So the choice is made from the
+  capacity: where ``d`` would take more than an eighth of the device's memory
+  (``_stores_d``: 16 k rows x 128 k columns of float32 are 8.6 GB), dX stores
+  nothing and dW recomputes the block's ``d`` as dX does, a second matmul.
+  ``d`` is the same array either way (one un-tiled contraction over ``H``,
+  rounded to the operand dtype before either product reads it), so the two
+  pairs agree bit for bit. Both sums stay in float32 VMEM scratch and leave
+  their kernel once, in the operand dtype (no float32 ``[N, H]`` / ``[H, V]``
+  in HBM). Which pair a step holds is counted at trace time in
+  ``paddle_tpu_fused_loss_backward_built_total{path="stored"|"recomputed"}``;
 - a ``lax.scan``-over-vocab-chunks reference with the SAME custom-VJP
-  decomposition (pure jnp) runs on CPU / in tier-1 / as the fallback, so the
-  numerics are pinned off-TPU. (Differentiating *through* a scan would stash
-  every chunk's logits — exactly the ``[N, V]`` buffer this kernel exists to
-  avoid — hence the custom VJP on both paths.)
+  decomposition (pure jnp; ``d`` formed once a chunk) runs on CPU / in tier-1
+  / as the fallback, so the numerics are pinned off-TPU. (Differentiating
+  *through* a scan would stash every chunk's float32 logits, hence the custom
+  VJP on both paths.)
 
 Weight layouts: ``vocab_major=False`` is ``nn.Linear`` 's ``[H, V]``
 (untied lm_head); ``vocab_major=True`` is the embedding's ``[V, H]``
@@ -54,7 +70,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
+from paddle_tpu.kernels.select import count_loss_backward, pallas_enabled, warn_fallback
 
 __all__ = ["fused_linear_cross_entropy"]
 
@@ -331,9 +347,9 @@ def _flxent_block_d(x_ref, w_ref, lab_ref, lse_ref, gc_ref, j, *, v, blk_v, voca
     return ((p - onehot) * gc_ref[...]).astype(x.dtype)
 
 
-def _flxent_dx_kernel(
-    x_ref, w_ref, lab_ref, lse_ref, gc_ref, dx_ref, acc_ref, *, v, blk_v, vocab_major
-):
+def _dx_step(x_ref, w_ref, lab_ref, lse_ref, gc_ref, dx_ref, d_ref, acc_ref, v, blk_v, vocab_major):
+    """One (row block, vocab block) of dX; the block's ``d`` goes to ``d_ref``
+    where there is one (block (i, j): written once, never revisited)."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -343,6 +359,8 @@ def _flxent_dx_kernel(
     d = _flxent_block_d(
         x_ref, w_ref, lab_ref, lse_ref, gc_ref, j, v=v, blk_v=blk_v, vocab_major=vocab_major
     )
+    if d_ref is not None:
+        d_ref[...] = d
     w = w_ref[...]
     if vocab_major:  # d [br, bv] @ w [bv, H]
         acc_ref[...] += jax.lax.dot_general(
@@ -359,19 +377,26 @@ def _flxent_dx_kernel(
         dx_ref[...] = acc_ref[...].astype(dx_ref.dtype)
 
 
-def _flxent_dw_kernel(
-    x_ref, w_ref, lab_ref, lse_ref, gc_ref, dw_ref, acc_ref, *, v, blk_v, vocab_major
+def _flxent_dx_kernel(
+    x_ref, w_ref, lab_ref, lse_ref, gc_ref, dx_ref, acc_ref, *, v, blk_v, vocab_major
 ):
-    j = pl.program_id(0)  # vocab block (outer, parallel)
+    _dx_step(x_ref, w_ref, lab_ref, lse_ref, gc_ref, dx_ref, None, acc_ref, v, blk_v, vocab_major)
+
+
+def _flxent_dx_store_kernel(
+    x_ref, w_ref, lab_ref, lse_ref, gc_ref, dx_ref, d_ref, acc_ref, *, v, blk_v, vocab_major
+):
+    _dx_step(x_ref, w_ref, lab_ref, lse_ref, gc_ref, dx_ref, d_ref, acc_ref, v, blk_v, vocab_major)
+
+
+def _dw_step(x_ref, d, dw_ref, acc_ref, vocab_major):
+    """One (vocab block, row block) of dW from the block's ``d``."""
     i = pl.program_id(1)  # row block (inner, sequential accumulation)
 
     @pl.when(i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref[...])
 
-    d = _flxent_block_d(
-        x_ref, w_ref, lab_ref, lse_ref, gc_ref, j, v=v, blk_v=blk_v, vocab_major=vocab_major
-    )
     x = x_ref[...]
     if vocab_major:  # dᵀ [bv, br] @ x [br, H]
         acc_ref[...] += jax.lax.dot_general(
@@ -385,6 +410,22 @@ def _flxent_dw_kernel(
     @pl.when(i == pl.num_programs(1) - 1)
     def _store():
         dw_ref[...] = acc_ref[...].astype(dw_ref.dtype)
+
+
+def _flxent_dw_kernel(x_ref, d_ref, dw_ref, acc_ref, *, vocab_major):
+    """dW over the ``d`` that dX stored: one matmul."""
+    _dw_step(x_ref, d_ref[...], dw_ref, acc_ref, vocab_major)
+
+
+def _flxent_dw_recompute_kernel(
+    x_ref, w_ref, lab_ref, lse_ref, gc_ref, dw_ref, acc_ref, *, v, blk_v, vocab_major
+):
+    """dW where ``d`` was not stored: the block's logits again, a second matmul."""
+    j = pl.program_id(0)  # vocab block (outer, parallel)
+    d = _flxent_block_d(
+        x_ref, w_ref, lab_ref, lse_ref, gc_ref, j, v=v, blk_v=blk_v, vocab_major=vocab_major
+    )
+    _dw_step(x_ref, d, dw_ref, acc_ref, vocab_major)
 
 
 # --------------------------------------------------------------------------
@@ -402,14 +443,20 @@ class LossTiles(NamedTuple):
 
 # (rows, vocab columns) each kernel takes where VMEM allows: past these the
 # chip gains nothing (tools/loss_head_bench.py at the train cell's shapes,
-# PERF.md, PR 30). Forward and dX keep a ROW block of x in VMEM and stream W
-# past it, dW keeps a VOCAB block of W and streams x: the kept side is how
-# often the other operand is read from HBM (128 rows: W 128 times a call, and
-# the forward HBM-bound at 44 % of the MXU's peak; 512: 32 times, 90 %). The
-# forward's online softmax pays per grid step, so its vocab block is wider.
-_TILES = {"fwd": (512, 1024), "dx": (512, 512), "dw": (512, 512)}
+# PERF.md, PR 30 and PR 37). Forward and dX keep a ROW block of x in VMEM and
+# stream W past it, dW keeps a VOCAB block of its sum and streams x: the kept
+# side is how often the other operand is read from HBM (128 rows: W 128 times
+# a call, and the forward HBM-bound at 44 % of the MXU's peak; 512: 32 times,
+# 90 %). The forward's online softmax pays per grid step, so its vocab block
+# is wider. "dw" reads the d that dX stored; "dw_recompute" forms it again
+# from W and holds a weight block and the float32 logits tiles besides.
+_TILES = {
+    "fwd": (512, 1024), "fwd_quant": (512, 1024), "dx": (512, 512),
+    "dw": (512, 1024), "dw_recompute": (512, 512),
+}  # by ``_vmem_need``'s name of the kernel
 _SCOPED_VMEM_DEFAULT = 16 << 20  # what Mosaic gives a kernel that states no limit
 _VMEM_OFF_CHIP = 128 << 20  # v5e's, for a trace with no TPU behind it (interpret mode, a described chip)
+_HBM_OFF_CHIP = 16 << 30  # v5e's, likewise
 
 
 def _vmem_capacity() -> int:
@@ -420,6 +467,31 @@ def _vmem_capacity() -> int:
         return _VMEM_OFF_CHIP
 
 
+def _hbm_capacity() -> int:
+    """Bytes of device memory the chip has."""
+    try:
+        return int(pltpu.get_tpu_info().hbm_capacity_bytes)
+    except Exception:  # noqa: BLE001 - no TPU here
+        return _HBM_OFF_CHIP
+
+
+def _stores_d(n: int, v: int, item: int) -> bool:
+    """Whether the backward forms ``d`` once and keeps it, ``[n, v]`` in the
+    operand dtype, from dX to dW: where that takes at most an eighth of the
+    device's memory (by the call's own rows and vocabulary, which the tiles
+    are chosen from too; padding adds under a tile a side). ``d`` lives at the
+    start of the backward pass, when every activation of the step is still
+    held, so it gets a share and not what is free; past the share (16 k rows x
+    128 k columns of float32 are 8.6 GB) dW recomputes it, which costs a
+    matmul and no memory."""
+    return n * v * item <= _hbm_capacity() // 8
+
+
+def _dw_model(n: int, v: int, item: int) -> str:
+    """``_vmem_need``'s and ``_TILES``' name of the dW that will run."""
+    return "dw" if _stores_d(n, v, item) else "dw_recompute"
+
+
 def _vmem_need(kernel: str, br: int, bv: int, h: int, x_item: int, w_item: int) -> int:
     """Bytes of VMEM ``kernel`` takes at a ``br`` x ``bv`` tile, as the chip's
     compiler counts them (fitted to its refusals at 24 tiles of the train
@@ -427,18 +499,26 @@ def _vmem_need(kernel: str, br: int, bv: int, h: int, x_item: int, w_item: int) 
     holds it to that): every blocked operand double-buffered, whole-``h`` rows of
     x and columns of W, the ``[br, 1]`` columns padded to 128 lanes, the float32
     accumulator of dX / dW with the product that is added to it, the output
-    block in the operand dtype, and three float32 tiles of logits."""
+    block in the operand dtype (dX's ``d`` tile too), and three float32 tiles
+    of logits where the kernel forms them. The dX that stores ``d`` and the
+    one-matmul dW (PR 37) were fitted the same way, by a binary search over
+    ``vmem_limit_bytes`` at seven shapes (both layouts, float32, hidden 2048
+    to 8192): the model reads 1.31-1.49 x the compiler's count for dX and
+    1.21-1.32 x for dW, never under it."""
     x = 2 * br * h * x_item
     w = 2 * h * bv * w_item
     tile = 3 * br * bv * 4
     col = 2 * br * 128 * 4
+    d = 2 * br * bv * x_item
     if kernel == "fwd":
         need = x + w + 4 * col + tile
     elif kernel == "fwd_quant":  # both operands are upcast to float32 for the dot
         need = x + w + 4 * col + tile + (br * h * 4 if x_item < 4 else 0) + h * bv * 4
     elif kernel == "dx":
-        need = x + w + 3 * col + tile + 2 * br * h * 4 + 2 * br * h * x_item
-    else:
+        need = x + w + 3 * col + tile + 2 * br * h * 4 + 2 * br * h * x_item + d
+    elif kernel == "dw":  # a float32 copy of the streamed block, transposed for the dot
+        need = x + br * h * 4 + d + h * bv * 4 + 2 * h * bv * w_item
+    else:  # dw_recompute
         need = x + w + 3 * col + tile + 2 * h * bv * 4 + 2 * h * bv * w_item
     return need + need // 8 + (2 << 20)
 
@@ -481,10 +561,10 @@ def _block_geometry(
     (``_vmem_need`` -> ``vmem_limit_bytes``): its 16 MiB default, taken as the
     budget, left 128 x 128 at every ``h >= 4096``."""
     budget = _vmem_budget()
+    models = ("fwd_quant" if quantized else "fwd", "dx", _dw_model(n, v, x_item))
     caps = {}
-    for kernel, tile in _TILES.items():
-        model = "fwd_quant" if quantized and kernel == "fwd" else kernel
-        tile = list(tile)
+    for kernel, model in zip(LossTiles._fields, models):
+        tile = list(_TILES[model])
         kept = 1 if kernel == "dw" else 0  # dW keeps vocab columns, the others rows
         while _vmem_need(model, *tile, h, x_item, w_item) > budget and tile != [128, 128]:
             tile[1 - kept if tile[1 - kept] > 256 or tile[kept] == 128 else kept] //= 2
@@ -496,14 +576,15 @@ def _block_geometry(
     )
 
 
-def _admitted_tiles(h, x_item, w_item):
-    """Uniform tiles every kernel has the VMEM for: the autotuner's candidates."""
+def _admitted_tiles(h, x_item, w_item, dw):
+    """Uniform tiles every kernel has the VMEM for (``dw``: the dW that will
+    run, ``_vmem_need``'s name for it): the autotuner's candidates."""
     budget = _vmem_budget()
     return [
         LossTiles(*((br, bv),) * 3)
         for br in (256, 512, 1024)
         for bv in (256, 512, 1024)
-        if all(_vmem_need(k, br, bv, h, x_item, w_item) <= budget for k in ("fwd", "dx", "dw"))
+        if all(_vmem_need(k, br, bv, h, x_item, w_item) <= budget for k in ("fwd", "dx", dw))
     ]
 
 
@@ -542,13 +623,94 @@ def _w_spec(h, blk_v, vocab_major, vocab_axis):
     return pl.BlockSpec((h, blk_v), lambda *g: (0, g[vocab_axis]))
 
 
-def _pallas_engines(n_pad, v, vp, h, tiles, vocab_major, interpret):
+def _run_dx(x2, wp, cols, *, v, tile, vocab_major, interpret, store_d):
+    """``(dX, d)`` over padded operands: ``d`` is the ``[n_pad, vp]`` block
+    gradients the kernel formed on its way, or None where it stores none;
+    ``cols`` are the ``[n_pad, 1]`` labels, logsumexp and grad coefficient. Grid (row blocks, vocab blocks): a
+    row block of x and its float32 dX sum stay in VMEM while W streams past."""
+    n_pad, h = x2.shape
+    vp = wp.shape[0] if vocab_major else wp.shape[1]
+    br, bv = tile
+    params = _params(_vmem_need("dx", br, bv, h, x2.dtype.itemsize, wp.dtype.itemsize))
+    col = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+    row = pl.BlockSpec((br, h), lambda i, j: (i, 0))
+    w_spec = _w_spec(h, bv, vocab_major, 1)
+    if not store_d:
+        dx = pl.pallas_call(
+            functools.partial(_flxent_dx_kernel, v=v, blk_v=bv, vocab_major=vocab_major),
+            grid=(n_pad // br, vp // bv),
+            compiler_params=params,
+            in_specs=[row, w_spec, col, col, col],
+            out_specs=row,
+            out_shape=jax.ShapeDtypeStruct((n_pad, h), x2.dtype),
+            scratch_shapes=[pltpu.VMEM((br, h), jnp.float32)],
+            interpret=interpret,
+            name=KERNEL_DX,
+        )(x2, wp, *cols)
+        return dx, None
+    dx, d = pl.pallas_call(
+        functools.partial(_flxent_dx_store_kernel, v=v, blk_v=bv, vocab_major=vocab_major),
+        grid=(n_pad // br, vp // bv),
+        compiler_params=params,
+        in_specs=[row, w_spec, col, col, col],
+        out_specs=[row, pl.BlockSpec((br, bv), lambda i, j: (i, j))],
+        out_shape=[
+            jax.ShapeDtypeStruct((n_pad, h), x2.dtype),
+            jax.ShapeDtypeStruct((n_pad, vp), x2.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((br, h), jnp.float32)],
+        interpret=interpret,
+        name=KERNEL_DX,
+    )(x2, wp, *cols)
+    return dx, d
+
+
+def _run_dw(x2, wp, cols, d, *, v, tile, vocab_major, interpret):
+    """dW in the padded weight's shape and dtype, from the ``d`` that
+    ``_run_dx`` stored, or with ``d`` None from ``wp`` and ``cols`` again. Grid
+    (vocab blocks, row blocks), the accumulation innermost (an output block may
+    only be revisited on consecutive grid steps): a vocab block's float32 dW
+    sum stays in VMEM while x (and ``d``) stream past."""
+    n_pad, h = x2.shape
+    vp = wp.shape[0] if vocab_major else wp.shape[1]
+    br, bv = tile
+    sizes = (br, bv, h, x2.dtype.itemsize, wp.dtype.itemsize)
+    x_spec = pl.BlockSpec((br, h), lambda j, i: (i, 0))
+    w_spec = _w_spec(h, bv, vocab_major, 0)
+    acc = pltpu.VMEM((bv, h) if vocab_major else (h, bv), jnp.float32)
+    if d is not None:
+        return pl.pallas_call(
+            functools.partial(_flxent_dw_kernel, vocab_major=vocab_major),
+            grid=(vp // bv, n_pad // br),
+            compiler_params=_params(_vmem_need("dw", *sizes)),
+            in_specs=[x_spec, pl.BlockSpec((br, bv), lambda j, i: (i, j))],
+            out_specs=w_spec,
+            out_shape=jax.ShapeDtypeStruct(wp.shape, wp.dtype),
+            scratch_shapes=[acc],
+            interpret=interpret,
+            name=KERNEL_DW,
+        )(x2, d)
+    col = pl.BlockSpec((br, 1), lambda j, i: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_flxent_dw_recompute_kernel, v=v, blk_v=bv, vocab_major=vocab_major),
+        grid=(vp // bv, n_pad // br),
+        compiler_params=_params(_vmem_need("dw_recompute", *sizes)),
+        in_specs=[x_spec, w_spec, col, col, col],
+        out_specs=w_spec,
+        out_shape=jax.ShapeDtypeStruct(wp.shape, wp.dtype),
+        scratch_shapes=[acc],
+        interpret=interpret,
+        name=KERNEL_DW,
+    )(x2, wp, *cols)
+
+
+def _pallas_engines(n_pad, v, vp, h, tiles, vocab_major, interpret, store_d):
     """``(engine_fwd, engine_bwd)`` over padded operands (``_build_core``'s
     contract). Forward and dX grid (row blocks, vocab blocks): a row block of x
     stays in VMEM while the weight streams past it, so the ROW block sets how
-    often W is read from HBM. dW grids (vocab blocks, row blocks), its
-    accumulation dim innermost (an output block may only be revisited on
-    consecutive grid steps): a weight block stays and x streams past it."""
+    often W is read from HBM; dW grids the other way round (``_run_dw``). With
+    ``store_d`` each block's ``d`` is formed once, by dX, and dW is one matmul
+    over it; without, dW forms it again."""
 
     def engine_fwd(x2, wp, lab):
         br, bv = tiles.fwd
@@ -572,45 +734,21 @@ def _pallas_engines(n_pad, v, vp, h, tiles, vocab_major, interpret):
         return (m + jnp.log(l))[:, 0], tl[:, 0]
 
     def engine_bwd(x2, wp, lab, lse, gcoef):
+        count_loss_backward("stored" if store_d else "recomputed")
         cols = (lab.reshape(n_pad, 1), lse.reshape(n_pad, 1), gcoef.reshape(n_pad, 1))
-        sizes = (h, x2.dtype.itemsize, wp.dtype.itemsize)
-        br, bv = tiles.dx
-        col = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
-        row = pl.BlockSpec((br, h), lambda i, j: (i, 0))
-        dx = pl.pallas_call(
-            functools.partial(_flxent_dx_kernel, v=v, blk_v=bv, vocab_major=vocab_major),
-            grid=(n_pad // br, vp // bv),
-            compiler_params=_params(_vmem_need("dx", br, bv, *sizes)),
-            in_specs=[row, _w_spec(h, bv, vocab_major, 1), col, col, col],
-            out_specs=row,
-            out_shape=jax.ShapeDtypeStruct((n_pad, h), x2.dtype),
-            scratch_shapes=[pltpu.VMEM((br, h), jnp.float32)],
-            interpret=interpret,
-            name=KERNEL_DX,
-        )(x2, wp, *cols)
-        br, bv = tiles.dw
-        col = pl.BlockSpec((br, 1), lambda j, i: (i, 0))
-        w_spec = _w_spec(h, bv, vocab_major, 0)
-        dw = pl.pallas_call(
-            functools.partial(_flxent_dw_kernel, v=v, blk_v=bv, vocab_major=vocab_major),
-            grid=(vp // bv, n_pad // br),
-            compiler_params=_params(_vmem_need("dw", br, bv, *sizes)),
-            in_specs=[pl.BlockSpec((br, h), lambda j, i: (i, 0)), w_spec, col, col, col],
-            out_specs=w_spec,
-            out_shape=jax.ShapeDtypeStruct(wp.shape, wp.dtype),
-            scratch_shapes=[pltpu.VMEM((bv, h) if vocab_major else (h, bv), jnp.float32)],
-            interpret=interpret,
-            name=KERNEL_DW,
-        )(x2, wp, *cols)
-        return dx, dw
+        kw = dict(v=v, vocab_major=vocab_major, interpret=interpret)
+        dx, d = _run_dx(x2, wp, cols, tile=tiles.dx, store_d=store_d, **kw)
+        return dx, _run_dw(x2, wp, cols, d, tile=tiles.dw, **kw)
 
     return engine_fwd, engine_bwd
 
 
 @functools.lru_cache(maxsize=None)
-def _make_pallas_core(n_pad, v, vp, h, tiles, vocab_major, interpret, ignore_index, reduction):
+def _make_pallas_core(
+    n_pad, v, vp, h, tiles, vocab_major, interpret, store_d, ignore_index, reduction
+):
     return _build_core(
-        *_pallas_engines(n_pad, v, vp, h, tiles, vocab_major, interpret),
+        *_pallas_engines(n_pad, v, vp, h, tiles, vocab_major, interpret, store_d),
         ignore_index, reduction,
     )
 
@@ -635,7 +773,8 @@ def _pallas_path(x2, w, lab, *, v, h, ignore_index, reduction, vocab_major, inte
     # slice dX and dW back to the caller's shapes automatically
     x2p, wp, labp = _pad_operands(x2, w, lab, n_pad, vp, ignore_index, vocab_major)
     core = _make_pallas_core(
-        n_pad, v, vp, h, tiles, vocab_major, interpret, ignore_index, reduction
+        n_pad, v, vp, h, tiles, vocab_major, interpret,
+        _stores_d(n, v, x2.dtype.itemsize), ignore_index, reduction,
     )
     loss = core(x2p, wp, labp)
     if reduction == "none":
@@ -705,7 +844,7 @@ def _autotune_fused_loss(n, v, h, dtype, vocab_major, interpret):
     itemsize = jnp.dtype(dtype).itemsize
     key = (n, v, h, str(dtype), vocab_major)
     default = _block_geometry(n, v, h, itemsize, itemsize)
-    candidates = [default] + _admitted_tiles(h, itemsize, itemsize)
+    candidates = [default] + _admitted_tiles(h, itemsize, itemsize, _dw_model(n, v, itemsize))
 
     def build(cfg):
         xz = jnp.zeros((n, h), dtype)
@@ -738,12 +877,13 @@ def fused_linear_cross_entropy(
     block: Optional[Tuple[int, int]] = None,
     weight_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """``cross_entropy(x @ Wᵀ, labels)`` without materializing ``[N, V]``.
+    """``cross_entropy(x @ Wᵀ, labels)`` without materializing the ``[N, V]`` logits.
 
     ``x`` ``[..., H]``; ``weight`` ``[H, V]`` (``nn.Linear``) or ``[V, H]``
     with ``vocab_major=True`` (tied embedding); ``labels`` ``[...]`` int.
     Differentiable in ``x`` and ``weight`` (custom VJP; the backward
-    recomputes block logits from the saved logsumexp). Loss is fp32;
+    recomputes block logits from the saved logsumexp, once where the block
+    gradients fit in device memory, see the module docstring). Loss is fp32;
     reduction semantics match ``F.cross_entropy`` (mean divides by
     ``max(#non-ignored, 1)``). ``interpret=True`` forces the Pallas path in
     interpreter mode (tests); ``block`` overrides the autotuned

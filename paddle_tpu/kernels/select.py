@@ -25,6 +25,13 @@ _fallbacks_total = get_registry().counter(
     "Pallas kernel failures that degraded to the XLA fallback path, by kernel.",
     labelnames=("kernel",),
 )
+_loss_backward_total = get_registry().counter(
+    "paddle_tpu_fused_loss_backward_built_total",
+    "Fused loss head backward passes traced, by what dW does for the block "
+    "gradient d: reads the one dX stored, or recomputes it (d over its share "
+    "of device memory).",
+    labelnames=("path",),
+)
 _routed_warned: set = set()
 _routed_total = get_registry().counter(
     "paddle_tpu_kernel_partition_routed_total",
@@ -110,15 +117,26 @@ def warn_fallback(kernel: str, exc: Exception) -> None:
         _logger.warning("Pallas kernel %s failed (%s); using XLA fallback", kernel, exc)
 
 
-def _counts(family: str) -> Dict[str, float]:
+def count_loss_backward(path: str) -> None:
+    """One fused loss head backward was traced on ``path`` (``stored`` or
+    ``recomputed``): a run's scrape says which one its step holds."""
+    _loss_backward_total.labels(path=path).inc()
+
+
+def _counts(family: str, label: str = "kernel") -> Dict[str, float]:
     values = get_registry().snapshot().get(family, {}).get("values", [])
-    return {row["labels"]["kernel"]: row["value"] for row in values}
+    return {row["labels"][label]: row["value"] for row in values}
 
 
 def fallback_counts() -> Dict[str, float]:
     """``{kernel: count}`` of every ``paddle_tpu_kernel_fallbacks_total``
     series that has counted (they count only under ``FLAGS_enable_metrics``)."""
     return _counts("paddle_tpu_kernel_fallbacks_total")
+
+
+def loss_backward_counts() -> Dict[str, float]:
+    """``{path: count}`` of ``paddle_tpu_fused_loss_backward_built_total``."""
+    return _counts("paddle_tpu_fused_loss_backward_built_total", "path")
 
 
 def partition_routed_counts() -> Dict[str, float]:

@@ -129,13 +129,34 @@ _REGIMES = {
 }
 
 
+@pytest.fixture(params=["stored", "recomputed"])
+def backward(request, monkeypatch):
+    """Which backward the call builds: the one that stores ``d`` (it fits its
+    share of device memory) or, with no memory to speak of, the one whose dW
+    recomputes it. The shapes decide: nothing else is set."""
+    if request.param == "recomputed":
+        monkeypatch.setattr(FL, "_hbm_capacity", lambda: 0)
+    return request.param
+
+
+def _pallas_calls(fn, *args):
+    """``{kernel name: (in avals, out avals)}`` of the ``pallas_call``s in ``fn``'s jaxpr."""
+    calls = {}
+    for eqn in _walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            calls[eqn.params["name"]] = tuple(
+                [(v.aval.shape, v.aval.dtype) for v in vs] for vs in (eqn.invars, eqn.outvars)
+            )
+    return calls
+
+
 class TestPallasInterpretParity:
     """The Pallas kernels (fwd + dX + dW), interpret mode on CPU, against the
     scan reference: the same custom-VJP decomposition, the same roundings."""
 
     @pytest.mark.parametrize("vocab_major", [False, True], ids=["hidden_major", "vocab_major"])
     @pytest.mark.parametrize("regime", sorted(_REGIMES))
-    def test_loss_and_grads_match_the_scan_reference(self, regime, vocab_major):
+    def test_loss_and_grads_match_the_scan_reference(self, regime, vocab_major, backward):
         cfg = dict(_REGIMES[regime])
         block = cfg.pop("block")
         x, w, lab = _data(h=128, **cfg)
@@ -171,25 +192,34 @@ class TestPallasInterpretParity:
             rtol=1e-2 if bf16 else 1e-4, atol=1e-2 if bf16 else 1e-5,
         )
 
-    def test_backward_kernels_hand_back_the_operand_dtype(self):
+    def test_backward_kernels_hand_back_the_operand_dtype(self, backward):
         """dX and dW are accumulated in float32 VMEM scratch and written once:
-        no float32 ``[N, H]`` or ``[H, V]`` leaves a kernel (ISSUE 30)."""
+        no float32 ``[N, H]`` or ``[H, V]`` leaves a kernel (ISSUE 30). Where
+        ``d`` is stored it is dX's second output and dW's only operand beside
+        x, in the operand dtype; where it is not, dW takes W, the labels, the
+        logsumexp and the coefficient and forms it again (ISSUE 37)."""
         x, w, lab = _data(n=32, h=128, v=256, dtype=jnp.bfloat16)
-        jaxpr = jax.make_jaxpr(
+        calls = _pallas_calls(
             jax.grad(
                 lambda x, w: fused_linear_cross_entropy(
                     x, w, lab, interpret=True, block=(16, 128)
                 ),
                 argnums=(0, 1),
-            )
-        )(x, w)
-        outs = {}
-        for eqn in _walk_eqns(jaxpr.jaxpr):
-            if eqn.primitive.name == "pallas_call":
-                outs[eqn.params["name"]] = [(v.aval.shape, v.aval.dtype) for v in eqn.outvars]
-        assert outs[FL.KERNEL_DX] == [((32, 128), jnp.bfloat16)]
-        assert outs[FL.KERNEL_DW] == [((128, 256), jnp.bfloat16)]
-        assert all(d == jnp.float32 for _, d in outs[FL.KERNEL_FWD])  # m, l, target logit
+            ),
+            x, w,
+        )
+        bf16 = jnp.bfloat16
+        d = [((32, 256), bf16)] if backward == "stored" else []
+        assert calls[FL.KERNEL_DX][1] == [((32, 128), bf16)] + d
+        assert calls[FL.KERNEL_DW][1] == [((128, 256), bf16)]
+        dw_in = calls[FL.KERNEL_DW][0]
+        assert dw_in[0] == ((32, 128), bf16)
+        if backward == "stored":
+            assert dw_in[1:] == d
+        else:
+            cols = [((32, 1), t) for t in (jnp.int32, jnp.float32, jnp.float32)]
+            assert dw_in[1:] == [((128, 256), bf16)] + cols
+        assert all(t == jnp.float32 for _, t in calls[FL.KERNEL_FWD][1])  # m, l, target logit
 
     def test_all_ignored_interpret(self):
         x, w, _ = _data(h=128, v=256)
@@ -204,6 +234,93 @@ class TestPallasInterpretParity:
         assert float(jnp.abs(gp[0]).max()) == 0.0 and float(jnp.abs(gp[1]).max()) == 0.0
 
 
+def _padded_data(n, v, dtype, seed=1):
+    """Rows and vocabulary as the case wants them, four ``ignore_index`` rows."""
+    return _data(n=n, h=128, v=v, dtype=dtype, seed=seed, n_ignored=4)
+
+
+class TestStoredBlockGradient:
+    """The backward forms each block's ``d = (softmax - onehot) * gcoef`` once
+    where ``[n, v]`` of the operand dtype fits its share of device memory: dX
+    writes the tile, dW reads it (ISSUE 37). It is the array both kernels
+    computed before, so nothing may move, not by a bit."""
+
+    @pytest.mark.parametrize("vocab_major", [False, True], ids=["hidden_major", "vocab_major"])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "float32"])
+    @pytest.mark.parametrize(
+        "n, v",
+        [(64, 1000), (40, 512), (40, 1000), (64, 512)],
+        ids=["vocab_pads", "rows_pad", "both_pad", "neither_pads"],
+    )
+    def test_dx_and_dw_equal_the_recompute_pairs_bit_for_bit(
+        self, n, v, dtype, vocab_major, monkeypatch
+    ):
+        x, w, lab = _padded_data(n, v, dtype)
+        wl = w.T if vocab_major else w
+        # forward on its own tile; dX and dW share one, as d's logits are then
+        # the same product in both kernels off the chip too (this backend's
+        # float32 matmul sums in an order that follows the block's shape)
+        block = LossTiles((32, 256), (16, 128), (16, 128))
+
+        def grads():
+            return _grads(
+                lambda x, wl: fused_linear_cross_entropy(
+                    x, wl, lab, vocab_major=vocab_major, interpret=True, block=block
+                ),
+                x, wl,
+            )
+
+        loss_s, (dx_s, dw_s) = grads()
+        monkeypatch.setattr(FL, "_hbm_capacity", lambda: 0)
+        loss_r, (dx_r, dw_r) = grads()
+        assert float(jnp.abs(dx_s).max()) > 0 and float(jnp.abs(dw_s).max()) > 0
+        assert float(loss_s) == float(loss_r)
+        np.testing.assert_array_equal(np.asarray(dx_s, np.float32), np.asarray(dx_r, np.float32))
+        np.testing.assert_array_equal(np.asarray(dw_s, np.float32), np.asarray(dw_r, np.float32))
+
+    def test_the_shapes_choose_the_path_and_the_counter_names_it(self, monkeypatch):
+        """``d`` of 64 x 512 float32 is exactly an eighth of the (patched)
+        device memory and is stored; twice the rows are over it and recompute.
+        No flag, no argument: the same call, other shapes."""
+        from paddle_tpu.kernels import select
+
+        monkeypatch.setattr(FL, "_hbm_capacity", lambda: 8 * 64 * 512 * 4)
+        assert FL._stores_d(64, 512, 4) and not FL._stores_d(128, 512, 4)
+        assert FL._stores_d(128, 512, 2)  # the operand dtype counts: bf16 halves d
+
+        def built(n):
+            x, w, lab = _padded_data(n, 512, jnp.float32)
+            fn = jax.grad(
+                lambda x, w: fused_linear_cross_entropy(x, w, lab, interpret=True, block=(16, 128)),
+                argnums=(0, 1),
+            )
+            before = select.loss_backward_counts()
+            calls = _pallas_calls(fn, x, w)
+            after = select.loss_backward_counts()
+            counted = {k: after[k] - before.get(k, 0) for k in after if after[k] != before.get(k, 0)}
+            return len(calls[FL.KERNEL_DX][1]), len(calls[FL.KERNEL_DW][0]), counted
+
+        prior = paddle.get_flags(["FLAGS_enable_metrics"])
+        paddle.set_flags({"FLAGS_enable_metrics": True})
+        try:
+            assert built(64) == (2, 2, {"stored": 1})  # dX -> (dX, d); dW <- (x, d)
+            assert built(128) == (1, 5, {"recomputed": 1})  # dW <- (x, W, lab, lse, gcoef)
+        finally:
+            paddle.set_flags(prior)
+
+    def test_device_memory_is_read_from_the_chip_or_taken_as_a_v5es(self, monkeypatch):
+        from jax.experimental.pallas import tpu as pltpu
+
+        assert FL._hbm_capacity() == 16 << 30  # no TPU here: a v5e's
+        monkeypatch.setattr(
+            pltpu, "get_tpu_info", lambda: type("Info", (), {"hbm_capacity_bytes": 32 << 30})()
+        )
+        assert FL._hbm_capacity() == 32 << 30
+        # the train cell's d (1 GiB) fits an eighth of either; Llama-3's
+        # vocabulary at the same batch in float32 (7.8 GiB) fits neither
+        assert FL._stores_d(16384, 32768, 2) and not FL._stores_d(16384, 128256, 4)
+
+
 RIDGE = 197e12 / 819e9  # a v5e's bf16 flops per HBM byte (240): under it a kernel waits for HBM
 
 
@@ -213,11 +330,12 @@ class TestBlockGeometry:
     block of W and streams x: the kept side sets the flops a streamed byte buys."""
 
     @staticmethod
-    def _intensity(tiles, x_item, w_item):
+    def _intensity(tiles, h, x_item, w_item):
         return {
             "fwd": 2 * tiles.fwd[0] / w_item,  # one matmul over each W block read
             "dx": 4 * tiles.dx[0] / w_item,  # two: the logits again, then dX
-            "dw": 4 * tiles.dw[1] / x_item,  # two over each x block read
+            # one matmul (d is stored at this size) over each x block read and d read once
+            "dw": 2 / (x_item * (1 / tiles.dw[1] + 1 / h)),
         }
 
     @pytest.mark.parametrize("itemsize", [1, 2, 4], ids=["int8_w", "bf16", "float32"])
@@ -231,7 +349,7 @@ class TestBlockGeometry:
             assert n % br == 0 and v % bv == 0 and bv % 128 == 0 and br % 16 == 0
             need = FL._vmem_need(kernel, br, bv, h, x_item, itemsize)
             assert need <= budget, (kernel, need)
-        for kernel, flops_per_byte in self._intensity(tiles, x_item, itemsize).items():
+        for kernel, flops_per_byte in self._intensity(tiles, h, x_item, itemsize).items():
             if flops_per_byte >= RIDGE:
                 continue
             # under the ridge only where the next kept size up does not fit
@@ -248,6 +366,18 @@ class TestBlockGeometry:
         need = FL._vmem_need("dx", *tiles.dx, 4096, 2, 2)
         assert FL._params(need).vmem_limit_bytes == need > 16 << 20  # over Mosaic's default: stated
         assert FL._params(8 << 20).vmem_limit_bytes is None  # under it: the default stands
+
+    def test_dw_takes_the_tile_of_the_kernel_that_will_run(self, monkeypatch):
+        """The one-matmul dW holds no weight block and no float32 logits tiles,
+        and at 1024 vocab columns reads x half as often (22.96 ms against 23.37
+        at the train cell's shapes on the chip, PERF.md, PR 37); the dW that
+        recomputes ``d`` keeps the 512 x 512 it had (512 x 1024 is at the edge
+        of its VMEM budget and read 59 and 45 ms in two calls)."""
+        sizes = (16384, 32768, 4096, 2, 2)
+        assert FL._block_geometry(*sizes) == LossTiles((512, 1024), (512, 512), (512, 1024))
+        assert FL._vmem_need("dw", 512, 1024, 4096, 2, 2) < FL._vmem_need("dw_recompute", 512, 1024, 4096, 2, 2)
+        monkeypatch.setattr(FL, "_hbm_capacity", lambda: 0)
+        assert FL._block_geometry(*sizes) == LossTiles((512, 1024), (512, 512), (512, 512))
 
     @pytest.mark.parametrize(
         "n, v, rows, cols",
@@ -267,17 +397,19 @@ class TestBlockGeometry:
             assert br % rows == 0 and bv % cols == 0  # whole multiples of the shared cut
         assert n_pad - n < rows and vp - v < cols
 
-    def test_autotune_candidates_are_what_the_geometry_admits(self):
+    @pytest.mark.parametrize("dw", ["dw", "dw_recompute"])
+    def test_autotune_candidates_are_what_the_geometry_admits(self, dw):
         """At Mistral's width the old candidate list was EMPTY (every tile over
         the 16 MiB guess), so tuning never ran."""
-        cands = FL._admitted_tiles(4096, 2, 2)
+        cands = FL._admitted_tiles(4096, 2, 2, dw)
         assert LossTiles(*(((512, 512),) * 3)) in cands and len(cands) >= 4
         budget = FL._vmem_budget()
         for t in cands:
             assert all(
-                FL._vmem_need(k, *tile, 4096, 2, 2) <= budget for k, tile in t._asdict().items()
+                FL._vmem_need(k, *tile, 4096, 2, 2) <= budget
+                for k, tile in zip(("fwd", "dx", dw), t)
             )
-        assert FL._admitted_tiles(1 << 17, 4, 4) == []  # nothing fits: default only
+        assert FL._admitted_tiles(1 << 17, 4, 4, dw) == []  # nothing fits: default only
 
 
 class TestModelContract:

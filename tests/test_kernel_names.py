@@ -172,9 +172,11 @@ def test_lowered_kernel_carries_its_name(name):
 
 
 def test_every_pallas_call_site_passes_a_name_constant():
-    """The 18 sites, read from the source (the paged chunk kernel, plain and
-    rope-fused, is one; the latent walk, PR 36, is the eighteenth): each ``pl.pallas_call(`` has a ``name=`` keyword,
-    and every name is one of the constants above."""
+    """The 20 sites, read from the source (the paged chunk kernel, plain and
+    rope-fused, is one; the latent walk, PR 36, is the eighteenth; the loss
+    head's dX and dW are two each since PR 37, storing ``d`` or recomputing
+    it, under the same two names): each ``pl.pallas_call(`` has a ``name=``
+    keyword, and every name is one of the constants above."""
     import ast
     import inspect
 
@@ -188,7 +190,7 @@ def test_every_pallas_call_site_passes_a_name_constant():
                 sites += 1
                 assert any(kw.arg == "name" for kw in node.keywords), f"{module.__name__}:{node.lineno}"
         constants |= {v for k, v in vars(module).items() if k.startswith("KERNEL_")}
-    assert sites == 18
+    assert sites == 20
     assert constants == set(SITES)
     assert all(re.fullmatch(r"[a-z][a-z0-9_]*", c) for c in constants)  # no shapes, trace-safe
 

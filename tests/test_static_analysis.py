@@ -1911,6 +1911,36 @@ def test_pg_sweep_quant_kernel_clean():
     assert bad == [], [f"{v.code}:{v.line}" for v in bad]
 
 
+@pytest.mark.parametrize(
+    "kernel, operands",
+    [
+        # (in_specs, out_specs, out_shapes, scratch) as the site declares them
+        ("_flxent_dx_kernel", (5, 1, 1, 1)),  # x, W, lab, lse, gcoef -> dX
+        ("_flxent_dx_store_kernel", (5, 2, 2, 1)),  # ... -> dX and the d tile
+        ("_flxent_dw_kernel", (2, 1, 1, 1)),  # x and the stored d -> dW
+        ("_flxent_dw_recompute_kernel", (5, 1, 1, 1)),  # x, W, lab, lse, gcoef -> dW
+    ],
+)
+def test_pg_loss_head_backward_sites_follow_their_operand_lists(kernel, operands):
+    """The fused loss head's backward is two pairs of sites since PR 37 (dX
+    with or without the ``d`` output, dW over the stored ``d`` or recomputing
+    it): the geometry evaluator resolves each site's lists, the kernel's
+    positional refs are exactly in + out + scratch (PG901's count, proven and
+    not ``unproven``), and the file sweeps PG-clean."""
+    import ast
+
+    from paddle_tpu.analysis.kernel_geometry import evaluate_module
+
+    path = PKG / "kernels" / "fused_loss.py"
+    mod = evaluate_module(str(path), ast.parse(path.read_text()))
+    (site,) = [s for s in mod.sites if s.kernel_name == kernel]
+    assert (len(site.in_specs), len(site.out_specs), site.n_out_shapes, site.n_scratch) == operands
+    assert site.out_specs_declared and not site.has_vararg
+    assert len(site.kernel_params) == operands[0] + operands[1] + operands[3]
+    bad = [v for v in analyze_paths([str(path)], select=["PG"]) if not v.suppressed]
+    assert bad == [], [f"{v.code}:{v.line}" for v in bad]
+
+
 _PG_PREFETCH = (
     "import jax\n"
     "import jax.numpy as jnp\n"

@@ -151,12 +151,16 @@ def _embed_rms_norm():
 # the train cell's loss head (benchmarks/workloads/mistral7b.train_2k.json):
 # 8 x 2048 tokens, Mistral's 32 768 vocabulary, hidden 4096
 CELL_TOKENS, CELL_VOCAB = 8 * SEQ, 32768
+LLAMA3_VOCAB = 128256  # d [16384, 128256] bf16 is 3.9 GiB: over an eighth of a v5e's 16
 
 
 def _loss_grads(n, v, h, vocab_major, dtype):
     """Forward, dX and dW at the tiles ``_block_geometry`` derives for the
     shape, each asking for the ``vmem_limit_bytes`` its tile needs: what holds
-    ``_vmem_need`` to the chip's compiler."""
+    ``_vmem_need`` to the chip's compiler. The backward is the pair the shapes
+    choose: dX storing ``d`` and a one-matmul dW where ``[n, v]`` fits its
+    share of device memory (every case at the cells' widths), else the pair
+    whose dW recomputes it (Llama-3's vocabulary at the train cell's batch)."""
     from paddle_tpu.kernels.fused_loss import _block_geometry, _pallas_path
 
     item = jnp.dtype(dtype).itemsize
@@ -254,6 +258,7 @@ CASES = {
     "fused_loss_fwd_bwd_float32": lambda: _fused_loss(dtype=F32),
     "fused_loss_fwd_bwd_ragged_llama_vocab": lambda: _fused_loss(n=2100, v=VOCAB),
     "fused_loss_fwd_bwd_one_row_block": lambda: _fused_loss(n=SLOTS * CHUNK),
+    "fused_loss_fwd_bwd_llama3_vocab_recomputes_d": lambda: _fused_loss(v=LLAMA3_VOCAB),
     "fused_loss_fwd_quant_train_cell": _fused_loss_quant,
     "wo_int8_matmul_k11008": _wo_matmul,
 }
@@ -295,17 +300,34 @@ def test_norm_kernel_under_a_four_chip_tp_mesh(wrapped, topo):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_loss_never_holds_the_logits(one_chip):
-    """The fused loss head's claim where it is made, on the chip's compiler,
-    at the train cell's shapes (8 x 2048 tokens, Mistral's 32 768 vocabulary):
-    the plain ``cross_entropy(x @ W)`` composition keeps the ``[N, V]`` bf16
+def test_the_shapes_choose_the_loss_heads_backward():
+    from paddle_tpu.kernels.fused_loss import _stores_d
+
+    assert _stores_d(CELL_TOKENS, CELL_VOCAB, 2) and _stores_d(CELL_TOKENS, 49152, 2)
+    assert _stores_d(CELL_TOKENS, CELL_VOCAB, 4)  # float32: 2 GiB, the share itself
+    assert not _stores_d(CELL_TOKENS, LLAMA3_VOCAB, 2)
+
+
+@pytest.mark.parametrize("backward", ["stored", "recomputed"])
+def test_fused_loss_holds_one_block_gradient_and_never_the_logits(backward, one_chip, monkeypatch):
+    """What the fused loss head keeps in HBM, on the chip's compiler, at the
+    train cell's shapes (8 x 2048 tokens, Mistral's 32 768 vocabulary). The
+    plain ``cross_entropy(x @ W)`` composition keeps the ``[N, V]`` bf16
     logits for its backward (1 GiB of temporaries, and nothing else: the
-    chip's compiler recomputes the float32 copies); the fused forward +
-    backward never holds them, nor anything else the size of an operand: dX
-    and dW leave their kernels in bf16 (the float32 sums stay in VMEM), so the
-    512 MiB float32 dW that was the head's largest temporary until PR 30 is
-    gone, at any token count (the compiler counts 0 bytes of temporaries)."""
+    chip's compiler recomputes the float32 copies). The fused forward holds
+    nothing of that size; its backward holds exactly ONE ``[n_pad, vp]``
+    buffer of the operand dtype, the block gradients ``d`` that dX writes and
+    dW reads (ISSUE 37: storing them costs 2.6 ms of HBM traffic, recomputing
+    them a 22 ms matmul), and nothing else the size of an operand: dX and dW
+    leave their kernels in bf16 (the float32 sums stay in VMEM). Where ``d``
+    is over its share of device memory (here: no memory at all) the backward
+    recomputes it and the compiler counts 0 bytes of temporaries, at any
+    token count."""
+    from paddle_tpu.kernels import fused_loss
+
     n, v = CELL_TOKENS, CELL_VOCAB
+    if backward == "recomputed":
+        monkeypatch.setattr(fused_loss, "_hbm_capacity", lambda: 0)
     fused = _loss_grads(n, v, HIDDEN, False, BF16)
 
     def plain(x, w, lab):
@@ -322,7 +344,9 @@ def test_fused_loss_never_holds_the_logits(one_chip):
         held_plain = jax.jit(plain).lower(*args).compile().memory_analysis().temp_size_in_bytes
     held_fused = _compile(fused, one_chip, *shapes).memory_analysis().temp_size_in_bytes
     assert held_plain >= n * v * 2, held_plain  # the logits
-    assert held_fused < n * HIDDEN * 2 / 2, held_fused  # not even half a bf16 x: no float32 dX or dW
+    d = n * v * 2 if backward == "stored" else 0
+    # d, once, and not even half a bf16 x beside it: no float32 dX or dW
+    assert d <= held_fused < d + n * HIDDEN * 2 / 2, held_fused
 
 
 def test_the_latent_cells_step_compiles_at_published_widths(one_chip, monkeypatch):

@@ -5,17 +5,22 @@ Run by hand through the chip tool, by no benchmark cell:
 
     python tools/loss_head_bench.py                       # the train cell's shapes
     python tools/loss_head_bench.py --h 2048 --v 49152    # Ouro's width
+    python tools/loss_head_bench.py --recompute           # the recompute pair beside the stored-d pair
     python tools/loss_head_bench.py --interpret --n 64 --v 512 --h 128 --tiles 16,32x128,256
 
 For each kernel (``fused_loss_fwd``, ``fused_loss_dx``, ``fused_loss_dw``) and
 each (row block, vocab block) it prints the milliseconds a call, the share of
-the MXU's bf16 peak on the matmuls the kernel does (forward 1, dX and dW 2
-each: both recompute the block's logits), the bytes the grid streams from HBM
-and the flops per streamed byte. Then the whole head (``jax.vjp`` through
-``_pallas_path``: the pads and XLA passes around the kernels included) at the
-geometry's own tiles. ``--contraction`` adds the alternative that was timed
-and not taken (PERF.md, PR 30): a forward whose grid also tiles the
-contraction over ``h``, float32 logits in a VMEM scratch.
+the MXU's bf16 peak on the matmuls the kernel does (forward 1; dX 2: the
+block's logits again, then ``d @ W``; dW 1 over the ``d`` that dX stored), the
+bytes the grid moves to and from HBM and the flops per such byte. Then the
+whole head (``jax.vjp`` through ``_pallas_path``: the pads and XLA passes
+around the kernels included) at the geometry's own tiles. ``--recompute`` is a
+switch of this tool, not of the program: it adds the pair the program runs
+where ``d`` is over its share of device memory (``dx_rc`` stores nothing,
+``dw_rc`` recomputes ``d``: 2 matmuls) and the whole head on it, so before and
+after come from one call on one chip. ``--contraction`` adds the alternative
+that was timed and not taken (PERF.md, PR 30): a forward whose grid also tiles
+the contraction over ``h``, float32 logits in a VMEM scratch.
 
 A call's time is the host clock over ``--reps`` back-to-back calls ending in
 one ``block_until_ready``; a trace reads each call ~1 ms lower (PERF.md, PR 28).
@@ -39,10 +44,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.flags import GLOBAL_FLAGS
 from paddle_tpu.kernels import fused_loss as fl
+from paddle_tpu.kernels import select
 
 PEAK_FLOPS = {"TPU v5 lite": 197e12, "TPU v5e": 197e12}  # bf16, Google Cloud "TPU v5e"
-MATMULS = {"fwd": 1, "dx": 2, "dw": 2, "fwd_k": 1}
+MATMULS = {"fwd": 1, "dx": 2, "dw": 1, "dx_rc": 2, "dw_rc": 2, "fwd_k": 1}
+VMEM_MODEL = {"dx_rc": "dx", "dw_rc": "dw_recompute"}  # _vmem_need's name of a row's kernel
 
 
 def _time(fn, args, reps):
@@ -60,11 +68,14 @@ def _time(fn, args, reps):
 
 def _streamed_bytes(kernel, br, bv, n, v, h, item):
     """HBM bytes the grid reads: the operand that stays is read once, the one
-    that streams once per block of the other dimension."""
-    x, w = n * h * item, v * h * item
+    that streams once per block of the other dimension; ``d`` once, written by
+    dX and read by dW, where it is stored."""
+    x, w, d = n * h * item, v * h * item, n * v * item
     if kernel == "dw":
+        return d + (v // bv) * x
+    if kernel == "dw_rc":
         return w + (v // bv) * x
-    return x + (n // br) * w
+    return x + (n // br) * w + (d if kernel == "dx" else 0)
 
 
 def _fwd_contraction(n, v, h, br, bv, bh, vocab_major, interpret):
@@ -147,10 +158,13 @@ def main():
     ap.add_argument("--vocab-major", action="store_true", help="tied layout, W [V, H]")
     ap.add_argument("--tiles", default="256,512,1024", help="rows[,rows..][xcols[,cols..]]")
     ap.add_argument("--kernels", default="fwd,dx,dw")
+    ap.add_argument("--recompute", action="store_true",
+                    help="also time the pair that stores no d (dx_rc, dw_rc) and the head on it")
     ap.add_argument("--contraction", default="", help="br:bv:bh[,..] for the h-tiled forward")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--interpret", action="store_true")
     a = ap.parse_args()
+    GLOBAL_FLAGS.set("enable_metrics", True)  # the program's counters count only under it
 
     dev = jax.devices()[0]
     if not a.interpret and dev.platform != "tpu":
@@ -160,7 +174,9 @@ def main():
     item = dtype.itemsize
     n, v, h = a.n, a.v, a.h
     print(f"device {dev.device_kind} x{jax.device_count()}  n={n} v={v} h={h} {dtype.name} "
-          f"{'[V,H]' if a.vocab_major else '[H,V]'}  vmem {fl._vmem_capacity() >> 20} MiB", flush=True)
+          f"{'[V,H]' if a.vocab_major else '[H,V]'}  vmem {fl._vmem_capacity() >> 20} MiB  "
+          f"hbm {fl._hbm_capacity() / 2**30:.2f} GiB  d {n * v * item / 2**30:.2f} GiB "
+          f"({'stored' if fl._stores_d(n, v, item) else 'recomputed'})", flush=True)
 
     key = jax.random.PRNGKey(0)
     kx, kw, kl = jax.random.split(key, 3)
@@ -169,6 +185,8 @@ def main():
     lab = jax.random.randint(kl, (n,), 0, v, jnp.int32)
     lse = jnp.full((n,), 10.0, jnp.float32)
     gc = jnp.full((n,), 1.0 / n, jnp.float32)
+    # what dW reads where dX stored it: any values time the same
+    d = (jax.random.normal(kl, (n, v), jnp.float32) / n).astype(dtype)
 
     def report(kernel, tile, fn, args, streamed, vmem):
         label = f"{kernel:6s} {'x'.join(map(str, tile)):>14s}"
@@ -186,22 +204,28 @@ def main():
               f"{streamed / 1e9:6.2f} GB streamed ({flops / streamed:6.0f} flop/B, "
               f"{streamed / t / 1e9:5.0f} GB/s)  vmem {vmem}", flush=True)
 
+    kernels = a.kernels.split(",")
+    if a.recompute:
+        kernels += [k + "_rc" for k in ("dx", "dw") if k in kernels]
     for br, bv in _pairs(a.tiles):
         if n % br or v % bv:
             continue  # the kernels alone take no padding: time tiles that divide
         tiles = fl.LossTiles((br, bv), (br, bv), (br, bv))
-        fwd, bwd = fl._pallas_engines(n, v, v, h, tiles, a.vocab_major, a.interpret)
-        # an unused result's pallas_call is dropped under jit: [0] is dX alone, [1] dW alone
+        kw = dict(v=v, tile=(br, bv), vocab_major=a.vocab_major, interpret=a.interpret)
+        cols = (lab.reshape(n, 1), lse.reshape(n, 1), gc.reshape(n, 1))
         runs = {
-            "fwd": (jax.jit(fwd), (x, w, lab)),
-            "dx": (jax.jit(lambda *s: bwd(*s)[0]), (x, w, lab, lse, gc)),
-            "dw": (jax.jit(lambda *s: bwd(*s)[1]), (x, w, lab, lse, gc)),
+            "fwd": lambda: (jax.jit(fl._pallas_engines(
+                n, v, v, h, tiles, a.vocab_major, a.interpret, True)[0]), (x, w, lab)),
+            "dx": lambda: (jax.jit(lambda x, w: fl._run_dx(x, w, cols, store_d=True, **kw)), (x, w)),
+            "dx_rc": lambda: (jax.jit(lambda x, w: fl._run_dx(x, w, cols, store_d=False, **kw)), (x, w)),
+            "dw": lambda: (jax.jit(lambda x, d: fl._run_dw(x, w, cols, d, **kw)), (x, d)),
+            "dw_rc": lambda: (jax.jit(lambda x, w: fl._run_dw(x, w, cols, None, **kw)), (x, w)),
         }
-        for kernel in a.kernels.split(","):
+        for kernel in kernels:
             report(
-                kernel, (br, bv), *runs[kernel],
+                kernel, (br, bv), *runs[kernel](),
                 _streamed_bytes(kernel, br, bv, n, v, h, item),
-                f"{fl._vmem_need(kernel, br, bv, h, item, item) >> 20} MiB",
+                f"{fl._vmem_need(VMEM_MODEL.get(kernel, kernel), br, bv, h, item, item) >> 20} MiB",
             )
 
     for spec in filter(None, a.contraction.split(",")):
@@ -213,26 +237,38 @@ def main():
             "under its 64 MiB",
         )
 
-    geom = fl._block_geometry(n, v, h, item, item)
-    print(f"geometry: fwd {geom.fwd}  dx {geom.dx}  dw {geom.dw}", flush=True)
+    def head(geom):
+        def run(x, w):
+            loss, vjp = jax.vjp(
+                lambda xx, ww: fl._pallas_path(
+                    xx, ww, lab, v=v, h=h, ignore_index=-100, reduction="mean",
+                    vocab_major=a.vocab_major, interpret=a.interpret, block=geom,
+                ),
+                x, w,
+            )
+            return (loss,) + vjp(jnp.ones_like(loss))
 
-    def head(x, w):
-        loss, vjp = jax.vjp(
-            lambda xx, ww: fl._pallas_path(
-                xx, ww, lab, v=v, h=h, ignore_index=-100, reduction="mean",
-                vocab_major=a.vocab_major, interpret=a.interpret, block=geom,
-            ),
-            x, w,
-        )
-        return (loss,) + vjp(jnp.ones_like(loss))
+        return run
 
-    if a.interpret:
-        jax.block_until_ready(jax.jit(head)(x, w))
-        print("head: ran (interpreter: no time)")
-    else:
-        t = _time(jax.jit(head), (x, w), a.reps)
-        print(f"head (forward + dX + dW at the geometry's tiles, pads and converts included): "
+    def report_head(label):
+        geom = fl._block_geometry(n, v, h, item, item)
+        print(f"geometry ({label}): fwd {geom.fwd}  dx {geom.dx}  dw {geom.dw}", flush=True)
+        if a.interpret:
+            jax.block_until_ready(jax.jit(head(geom))(x, w))
+            print(f"head ({label}): ran (interpreter: no time)")
+            return
+        t = _time(jax.jit(head(geom)), (x, w), a.reps)
+        print(f"head ({label}: forward + dX + dW at the geometry's tiles, pads and converts included): "
               f"{t * 1e3:.2f} ms a step; 3 useful matmuls at peak: {3 * 2.0 * n * v * h / peak * 1e3:.2f} ms")
+
+    report_head("stored d" if fl._stores_d(n, v, item) else "d over its share: recomputed")
+    if a.recompute and fl._stores_d(n, v, item):
+        capacity, fl._hbm_capacity = fl._hbm_capacity, lambda: 0  # no d fits: the program's other pair
+        try:
+            report_head("recomputed")
+        finally:
+            fl._hbm_capacity = capacity
+    print(f"backward passes built: {select.loss_backward_counts()}")
 
 
 if __name__ == "__main__":
