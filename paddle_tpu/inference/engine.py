@@ -774,6 +774,12 @@ class ContinuousBatchingEngine:
             # (observability/tracing.py phase; deliver is the frontend's)
             "phase_s.plan": 0.0, "phase_s.launch": 0.0, "phase_s.wait": 0.0,
             "phase_s.commit": 0.0, "phase_s.deliver": 0.0,
+            # engine.launch and engine.wait tiled into sub-phases: the three
+            # launch_* sum to phase_s.launch, the two wait_* to phase_s.wait
+            # (not under "phase_s.": those five keys tile the pump alone)
+            "subphase_s.launch_put": 0.0, "subphase_s.launch_args": 0.0,
+            "subphase_s.launch_call": 0.0, "subphase_s.wait_ready": 0.0,
+            "subphase_s.wait_fetch": 0.0,
             # close_step(): steps far above the running median, and what of
             # their excess lay on the host and in the wait for the device
             "stall_steps": 0, "stall_s.host": 0.0, "stall_s.device": 0.0,
@@ -868,9 +874,12 @@ class ContinuousBatchingEngine:
         # last _STALL_WINDOW steps' (host, wait) seconds, and the limit
         self._open_step: Optional[Tuple[float, ...]] = None
         # the serving step's open phase (None outside a step: recovery
-        # replays are untimed) and the ones it has been through
+        # replays are untimed) and the ones it has been through; likewise
+        # the open phase's open sub-phase and the step's finished ones
         self._phase: Any = None
         self._phases_done: List[Any] = []
+        self._subphase: Any = None
+        self._subphases_done: List[Any] = []
         # where the last step's commit phase ended (perf_counter): a driver's
         # own phase starts there, so nothing lies between the two
         self.last_step_end_s: Optional[float] = None
@@ -1901,13 +1910,39 @@ class ContinuousBatchingEngine:
     def _next_phase(self, name: str, key: str) -> None:
         """End the serving step's open phase and open the next at the same
         instant (observability/tracing.py ``phase``); the ended one is kept
-        for the step's own accounting. No-op when none is open (recovery)."""
+        for the step's own accounting. A phase that ends with a sub-phase
+        open ends where that one does: one clock read for both. No-op when
+        none is open (recovery)."""
         cur = self._phase
         if cur is None:
             return
+        cur.end_s = self._end_subphase()
         cur.__exit__(None, None, None)
         self._phases_done.append(cur)
         self._phase = _tracing.phase(name, self.stats, key, cur.step, cur.end_s).__enter__()
+
+    def _next_subphase(self, name: str, key: str) -> None:
+        """Move the open phase's sub-phase on: end the open one, or start
+        where the phase did, and open ``name`` at that instant, nested in the
+        phase (its annotation inside the phase's, its ring span the phase's
+        child). The sub-phases of a phase tile it, as the phases do the step.
+        No-op when no phase is open (recovery)."""
+        cur = self._phase
+        if cur is None:
+            return
+        at = self._end_subphase()
+        self._subphase = _tracing.phase(
+            name, self.stats, key, cur.step, cur.start_s if at is None else at
+        ).__enter__()
+
+    def _end_subphase(self, exc_info: Tuple[Any, Any, Any] = (None, None, None)) -> Optional[float]:
+        """End the open sub-phase, if any; the instant it ended at."""
+        sub, self._subphase = self._subphase, None
+        if sub is None:
+            return None
+        sub.__exit__(*exc_info)
+        self._subphases_done.append(sub)
+        return sub.end_s
 
     def _dispatch(
         self,
@@ -1925,8 +1960,15 @@ class ContinuousBatchingEngine:
 
         Called from the serving step it moves that step's open phase on
         (``engine.plan`` -> ``.launch`` at the host-to-device puts, ->
-        ``.wait`` at the jit call's return, -> ``.commit`` after the sync);
-        called from :meth:`recover` no phase is open and nothing is timed."""
+        ``.wait`` at the jit call's return, -> ``.commit`` after the sync),
+        and tiles the two phases where the work changes hands into
+        sub-phases: ``engine.launch.put`` (the step's seven host-to-device
+        conversions) -> ``.launch.args`` (the argument lists: every weight,
+        every cache plane) -> ``.launch.call`` (the jit call: flattening,
+        cache lookup, enqueue, its outputs wrapped and taken apart), then
+        ``engine.wait.ready`` (until the result is ready on the device,
+        nothing copied) -> ``.wait.fetch`` (the tokens' copy to the host).
+        Called from :meth:`recover` no phase is open and nothing is timed."""
         appended: List[Tuple[int, int]] = []  # (slot, block) rollback list
         active_slots = [i for i in range(self.max_slots) if active[i]]
         cow_src = np.zeros((self.max_slots,), np.int32)
@@ -1946,14 +1988,21 @@ class ContinuousBatchingEngine:
             fault_point("engine.decode")
             traces_before = self.stats["step_traces"]
             self._next_phase("engine.launch", "phase_s.launch")
+            self._next_subphase("engine.launch.put", "subphase_s.launch_put")
+            small = (
+                jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(self._ntok.copy()),
+                jnp.asarray(q_lens), jnp.asarray(active),
+                jnp.asarray(cow_src), jnp.asarray(cow_dst),
+            )
+            self._next_subphase("engine.launch.args", "subphase_s.launch_args")
+            params, sets = self._param_arrays(), self._caches + self._states
+            self._next_subphase("engine.launch.call", "subphase_s.launch_call")
             with self._shard_ctx():  # for the (first-call / recovery) trace
-                nxt, kept, *expert_counts = self._step_fn(
-                    self._param_arrays(), self._caches + self._states, jnp.asarray(toks),
-                    jnp.asarray(tables), jnp.asarray(self._ntok.copy()),
-                    jnp.asarray(q_lens), jnp.asarray(active),
-                    jnp.asarray(cow_src), jnp.asarray(cow_dst),
-                )
+                nxt, kept, *expert_counts = self._step_fn(params, sets, *small)
                 self._caches, self._states = kept[: self._num_kv_sets], kept[self._num_kv_sets:]
+            # the call's arguments die where they did as temporaries of the call
+            # expression: inside engine.launch, not at this function's return
+            del params, sets, small
         except BaseException:
             # roll the per-step allocations back so a transient failure
             # leaves the allocator in lockstep with _ntok (retried steps
@@ -1982,7 +2031,14 @@ class ContinuousBatchingEngine:
             )
             self._step_recorded = True
         self._next_phase("engine.wait", "phase_s.wait")
-        nxt = np.asarray(nxt)  # device sync: the step's tokens are real here
+        self._next_subphase("engine.wait.ready", "subphase_s.wait_ready")
+        # the tokens' copy is queued behind the executable now, as np.asarray
+        # alone would queue it: asked for only once the result is ready, it
+        # would be a second round trip to the device after the first
+        nxt.copy_to_host_async()
+        nxt.block_until_ready()  # device sync: the executable is done
+        self._next_subphase("engine.wait.fetch", "subphase_s.wait_fetch")
+        nxt = np.asarray(nxt)  # the copy has landed: the step's tokens are real here
         self._next_phase("engine.commit", "phase_s.commit")
         if expert_counts:
             rows_local, experts_hit = np.asarray(expert_counts[0]).tolist()
@@ -2212,15 +2268,17 @@ class ContinuousBatchingEngine:
 
         The pass runs under four phases that tile it (``engine.plan`` ->
         ``.launch`` -> ``.wait`` -> ``.commit``, children of
-        ``engine.decode_step``; ``_dispatch`` moves from one to the next):
+        ``engine.decode_step``; ``_dispatch`` moves from one to the next,
+        and tiles ``.launch`` and ``.wait`` into their five sub-phases):
         each is on the device trace's clock while a profile is taken, and
-        always adds its seconds to ``stats["phase_s.*"]``
-        (observability/tracing.py ``phase``)."""
+        always adds its seconds to ``stats["phase_s.*"]`` /
+        ``["subphase_s.*"]`` (observability/tracing.py ``phase``)."""
         stats = self.stats
         step_no = stats["steps"] + 1
         self._step_compiled = False
         self.last_step_end_s = None
         done = self._phases_done = []
+        subs = self._subphases_done = []
         with _tracing.phase("engine.decode_step", None, None, step_no, since) as whole:
             self._phase = _tracing.phase(
                 "engine.plan", stats, "phase_s.plan", step_no, whole.start_s
@@ -2229,17 +2287,20 @@ class ContinuousBatchingEngine:
                 stepped = self._step_in_phases(whole.start_s)
             finally:
                 # whichever phase is open (plan, on an idle pass; commit, after
-                # a step; any, under an exception) ends here
+                # a step; any, with its sub-phase, under an exception) ends here
                 last, self._phase = self._phase, None
-                last.__exit__(*sys.exc_info())
+                exc_info = sys.exc_info()
+                last.end_s = self._end_subphase(exc_info)
+                last.__exit__(*exc_info)
                 done.append(last)
             if stepped is not None:
                 # engine.decode_step takes its instants from its children
                 whole.end_s = self.last_step_end_s = last.end_s
                 self._account_riders(whole, stepped)
         if stepped is not None and not self._step_compiled:
-            # what close_step() judges: the four phases' seconds
-            self._open_step = tuple(ph.end_s - ph.start_s for ph in done)
+            # what close_step() judges: the four phases' seconds, and what
+            # its record splits launch and wait into: the five sub-phases'
+            self._open_step = tuple(ph.end_s - ph.start_s for ph in done + subs)
 
     def _step_in_phases(self, now: float) -> Optional[List[Any]]:
         """The body of one pass begun at ``now``, from ``engine.plan`` (open on
@@ -2406,8 +2467,11 @@ class ContinuousBatchingEngine:
         a stall: what its host part (plan + launch + commit + deliver) and
         its wait lie above their own medians goes to ``stats["stall_s.host"]``
         / ``["stall_s.device"]``, and ONE flight-recorder event ``step_stall``
-        carries each phase's wall seconds and, over the stretch from the
-        previous step's close to this one's, the wall seconds
+        carries each phase's wall seconds, the sub-phases' (``put_s`` +
+        ``args_s`` + ``call_s`` = ``launch_s``, ``ready_s`` + ``fetch_s`` =
+        ``wait_s``: a wait that lies in ``ready_s`` is the executable's, one
+        in ``fetch_s`` the copy of a finished result) and, over the stretch
+        from the previous step's close to this one's, the wall seconds
         (``since_close_s``) beside the calling thread's CPU seconds
         (``cpu_s``) — wall far above CPU: the thread was descheduled or
         blocked (a shared host, a lock); wall about CPU: the program's own
@@ -2419,7 +2483,7 @@ class ContinuousBatchingEngine:
         if st is None:
             return
         self._open_step = None
-        plan_s, launch_s, wait_s, commit_s = st
+        plan_s, launch_s, wait_s, commit_s, put_s, args_s, call_s, ready_s, fetch_s = st
         host_s = plan_s + launch_s + commit_s + deliver_s
         wall_s = host_s + wait_s
         walls = self._step_walls
@@ -2440,6 +2504,8 @@ class ContinuousBatchingEngine:
                 plan_s=round(plan_s, 6), launch_s=round(launch_s, 6),
                 wait_s=round(wait_s, 6), commit_s=round(commit_s, 6),
                 deliver_s=round(deliver_s, 6),
+                put_s=round(put_s, 6), args_s=round(args_s, 6), call_s=round(call_s, 6),
+                ready_s=round(ready_s, 6), fetch_s=round(fetch_s, 6),
                 stall_host_s=round(host_x, 6), stall_device_s=round(wait_x, 6),
                 since_close_s=None if mark is None else round(now[0] - mark[0], 6),
                 cpu_s=None if mark is None else round(now[1] - mark[1], 6),

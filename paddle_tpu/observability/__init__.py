@@ -36,7 +36,14 @@ step) and the four children of ``engine.decode_step``: ``engine.plan``
 (admission up to the jit call), ``engine.launch`` (host-to-device puts and
 the call up to its return), ``engine.wait`` (the host blocked on the
 device), ``engine.commit`` (bookkeeping and token emission after the sync).
-Recovery replays run under ``engine.recover``. Each phase is
+The two phases where the work changes hands are tiled again, into
+sub-phases (the same primitive, nested in their parent):
+``engine.launch.put`` (the step's seven host-to-device conversions) ->
+``engine.launch.args`` (the argument lists: every weight, every cache plane)
+-> ``engine.launch.call`` (the jit call up to its return), and
+``engine.wait.ready`` (until the result is ready on the device, nothing
+copied) -> ``engine.wait.fetch`` (the tokens' copy to the host).
+Recovery replays run under ``engine.recover`` and are not timed. Each phase is
 
 - a ``jax.profiler.TraceAnnotation("paddle_tpu.<phase>")``: start
   ``profiler.Profiler`` or ``jax.profiler.start_trace`` and the phases lie
@@ -49,20 +56,35 @@ Recovery replays run under ``engine.recover``. Each phase is
   forward's);
 - seconds added to ``engine.stats``: ``phase_s.plan``, ``phase_s.launch``,
   ``phase_s.wait``, ``phase_s.commit``, ``phase_s.deliver`` (cumulative;
-  divide a delta by the delta of ``steps``);
+  divide a delta by the delta of ``steps``; these five tile the pump), and
+  for the sub-phases ``subphase_s.launch_put`` + ``subphase_s.launch_args`` +
+  ``subphase_s.launch_call`` (they sum to ``phase_s.launch``) and
+  ``subphase_s.wait_ready`` + ``subphase_s.wait_fetch`` (to ``phase_s.wait``);
 - at ``FLAGS_trace_sample_rate >= 1`` a span in the ring, child of the
-  enclosing phase, with the step number.
+  enclosing phase, with the step number: thirteen spans a pump, so the
+  default ring of 4096 records (``FLAGS_trace_buffer_size``) holds about
+  140 steps of a busy engine's requests and phases (230 before the
+  sub-phases).
 
 **Stalls.** A step whose wall time passes 5 x the median of the last 64
 steps adds what lay above the median to ``engine.stats["stall_s.host"]``
 (plan + launch + commit + deliver) or ``["stall_s.device"]`` (wait), bumps
 ``["stall_steps"]`` and records ONE flight-recorder event ``step_stall``:
-each phase's wall seconds, ``median_wall_s``, and over the stretch from the
+each phase's wall seconds (``plan_s``, ``launch_s``, ``wait_s``,
+``commit_s``, ``deliver_s``), the sub-phases' (``put_s`` + ``args_s`` +
+``call_s`` = ``launch_s``; ``ready_s`` + ``fetch_s`` = ``wait_s``),
+``median_wall_s``, and over the stretch from the
 previous step's close to this one's the wall seconds (``since_close_s``)
 beside the pump thread's CPU seconds (``cpu_s``, ``time.thread_time()``, one
 read a step). ``cpu_s`` far below ``since_close_s``: the thread was
 descheduled or blocked (a shared host, a lock);
-about equal: the program's own Python ran that long. Steps that compile or
+about equal: the program's own Python ran that long. A stall that lies in
+``ready_s``: the executable had not finished (the device computed, or the
+runtime reported its completion late; a device trace of the step tells the
+two apart). One that lies in ``fetch_s``: the result was ready and its copy
+to the host took that long (the transfer, or the host). One in ``call_s``:
+the jit call itself (a cache miss, a blocked enqueue); in ``put_s``: the
+host-to-device copies of the step's small arguments. Steps that compile or
 recover are not judged. Read it from a dump
 (``obs.GLOBAL_FLIGHT_RECORDER.dump("why")`` then ``python -m
 paddle_tpu.observability.dump <file>``) or from ``snapshot()``.

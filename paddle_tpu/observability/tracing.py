@@ -38,7 +38,12 @@ is being taken (``profiler.Profiler``, ``jax.profiler.start_trace``) it lies
 on the device trace's clock, in the same ``.xplane.pb`` as the "XLA Ops"
 line — and always adds its wall seconds to a cumulative counter handed to it
 (``engine.stats["phase_s.<name>"]``); only at ``FLAGS_trace_sample_rate >= 1``
-does it also add a span to the ring, parented to the enclosing phase.
+does it also add a span to the ring, parented to the enclosing phase. Phases
+nest: one serving pump is thirteen of them (``frontend.pump``, two
+``frontend.deliver``, ``engine.decode_step``, its four children, and the five
+sub-phases that tile ``engine.launch`` and ``engine.wait``), so at that rate
+the default ring of 4096 records holds about 140 steps (230 before the
+sub-phases; the ring was not grown).
 ``profiler.RecordEvent`` makes its annotation through the same
 :func:`annotate`, so every host span the program puts into a device trace
 comes from here.

@@ -830,3 +830,200 @@ class TestStallAccounting:
         # the replay dispatched several times; only the retried step launched under a phase
         assert names.count("engine.launch") == 1
         assert eng.stats["phase_s.launch"] > launch_before
+
+
+# -- PR 38: engine.launch and engine.wait tiled into sub-phases ----------------
+SUBPHASES = {
+    "launch": ("launch_put", "launch_args", "launch_call"),
+    "wait": ("wait_ready", "wait_fetch"),
+}
+SUBPHASE_SPANS = {
+    "engine.launch": ("engine.launch.put", "engine.launch.args", "engine.launch.call"),
+    "engine.wait": ("engine.wait.ready", "engine.wait.fetch"),
+}
+# the stall record's name for each sub-phase, and for its parent
+STALL_FIELDS = {
+    "launch_s": ("put_s", "args_s", "call_s"),
+    "wait_s": ("ready_s", "fetch_s"),
+}
+
+
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` while "a profile is
+    taken": says it is enabled, and logs every enter and exit by name."""
+
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+class TestSubPhases:
+    @pytest.mark.parametrize("parent", sorted(SUBPHASES))
+    def test_subphase_counters_tile_their_parent(self, parent):
+        fe, eng, _handles = _phase_frontend(seed=41)
+        keys = [f"subphase_s.{p}" for p in SUBPHASES[parent]]
+        before = dict(eng.stats)
+        for _ in range(40):
+            fe.pump()
+        assert eng.stats["steps"] - before["steps"] == 40
+        grown = {k: eng.stats[k] - before[k] for k in keys}
+        assert all(v > 0 for v in grown.values()), grown
+        # children take their instants from each other and from the parent
+        assert sum(grown.values()) == pytest.approx(
+            eng.stats[f"phase_s.{parent}"] - before[f"phase_s.{parent}"], rel=1e-9
+        )
+        # and no child is a phase of the pump: the five phase_s keys are all there are
+        assert sorted(k for k in eng.stats if k.startswith("phase_s.")) == sorted(PHASE_KEYS)
+
+    def test_the_five_phase_keys_still_tile_the_pump(self):
+        import time
+
+        fe, eng, _handles = _phase_frontend(seed=42)
+        before = dict(eng.stats)
+        walls = []
+        for _ in range(30):
+            t0 = time.perf_counter()
+            fe.pump()
+            walls.append(time.perf_counter() - t0)
+        phases = sum(eng.stats[k] - before[k] for k in PHASE_KEYS)
+        subs = sum(eng.stats[k] - before[k] for k in eng.stats if k.startswith("subphase_s."))
+        assert phases == pytest.approx(sum(walls), rel=0.02) and phases <= sum(walls)
+        # the sub-phases cover launch and wait once more: counted beside the
+        # phases they would tile the pump twice over
+        assert subs == pytest.approx(
+            sum(eng.stats[f"phase_s.{p}"] - before[f"phase_s.{p}"] for p in SUBPHASES), rel=1e-9
+        )
+
+    def test_under_a_profile_the_annotations_nest_in_their_parents_in_order(self, monkeypatch):
+        fe, eng, _handles = _phase_frontend(seed=43)
+        log = _RecordingAnnotation.log = []
+        monkeypatch.setattr(tracing, "_TraceAnnotation", _RecordingAnnotation)
+        for _ in range(3):
+            fe.pump()
+        monkeypatch.undo()
+        prefix = tracing.PHASE_PREFIX
+        want = []
+        for parent, kids in SUBPHASE_SPANS.items():
+            want.append(("enter", prefix + parent))
+            for kid in kids:
+                want += [("enter", prefix + kid), ("exit", prefix + kid)]
+            want.append(("exit", prefix + parent))
+        mine = [e for e in log if e[1].startswith((prefix + "engine.launch", prefix + "engine.wait"))]
+        assert mine == want * 3
+        # and the whole log is well nested: every exit closes the innermost open annotation
+        stack = []
+        for what, name in log:
+            if what == "enter":
+                stack.append(name)
+            else:
+                assert stack.pop() == name
+        assert not stack
+
+    def test_subphase_spans_are_children_of_their_parents_span(self, tracing_on):
+        fe, eng, _handles = _phase_frontend(seed=44)
+        tracing_on.clear()
+        for _ in range(10):
+            fe.pump()
+        spans = tracing_on.spans()
+        counts = {}
+        for s in spans:
+            counts[s["name"]] = counts.get(s["name"], 0) + 1
+        assert counts["frontend.pump"] == 10
+        assert len(spans) == 13 * 10  # a pump is thirteen records of the ring now
+        for parent, names in SUBPHASE_SPANS.items():
+            parents = [s for s in spans if s["name"] == parent]
+            assert len(parents) == 10
+            for p in parents:
+                kids = sorted((k for k in spans if k["parent_id"] == p["span_id"]), key=lambda k: k["ts_us"])
+                assert [k["name"] for k in kids] == list(names)
+                assert all(k["trace_id"] == p["trace_id"] and k["attrs"]["step"] == p["attrs"]["step"] for k in kids)
+                assert kids[0]["ts_us"] == p["ts_us"]
+                assert kids[-1]["ts_us"] + kids[-1]["dur_us"] == pytest.approx(p["ts_us"] + p["dur_us"], abs=1e-3)
+                for a, b in zip(kids, kids[1:]):  # consecutive sub-phases share an instant
+                    assert a["ts_us"] + a["dur_us"] == pytest.approx(b["ts_us"], abs=1e-3)
+
+    # which call ends the sub-phase that the patched clock stretches, and
+    # whose stall that is
+    ENDS = {
+        "put_s": ("engine.launch.args", "host"), "args_s": ("engine.launch.call", "host"),
+        "call_s": ("engine.wait", "host"), "ready_s": ("engine.wait.fetch", "device"),
+        "fetch_s": ("engine.commit", "device"),
+    }
+
+    @pytest.mark.parametrize("field", sorted(ENDS))
+    def test_a_slow_subphase_is_one_stall_whose_parts_sum_to_launch_and_wait(self, field, monkeypatch, tmp_path, capsys):
+        import time
+
+        stall_s, stall_at = 0.25, 40
+        ended_by, side = self.ENDS[field]
+        fe, eng, _handles = _phase_frontend(seed=45)
+        flight = flightrec.FlightRecorder(capacity=512)
+        eng._flight = flight
+        base = TestStallAccounting()
+        base.BASE_S = 0.010  # a running median (so a stall limit, 5 x) far above the suite's noise
+        base._slowed(eng, stall_at=-1)
+        # the process's clock (the phases', the frontend's), with a jump of
+        # stall_s inside ONE sub-phase of step stall_at
+        ahead, real_clock = [0.0], time.perf_counter
+        monkeypatch.setattr(time, "perf_counter", lambda: real_clock() + ahead[0])
+        calls = [0]
+        for mover in ("_next_phase", "_next_subphase"):
+            real = getattr(eng, mover)
+
+            def moved_on(name, key, real=real):
+                if name == ended_by:
+                    calls[0] += 1
+                    if calls[0] == stall_at:
+                        ahead[0] += stall_s
+                real(name, key)
+
+            setattr(eng, mover, moved_on)
+        for _ in range(60):
+            fe.pump()
+        (ev,) = [e for e in flight.snapshot() if e["kind"] == "step_stall"]
+        assert eng.stats["stall_steps"] == 1
+        assert stall_s <= ev[field] < stall_s + 0.1
+        for parent, parts in STALL_FIELDS.items():  # each rounded to a microsecond
+            assert sum(ev[p] for p in parts) == pytest.approx(ev[parent], abs=3e-6)
+        # the division into host and device is the parents', as before
+        mine, other = (("host", "device") if side == "host" else ("device", "host"))
+        assert 0.8 * stall_s <= ev[f"stall_{mine}_s"] < stall_s + 0.1
+        assert ev[f"stall_{other}_s"] < 0.1 * stall_s
+        assert eng.stats[f"stall_s.{mine}"] == pytest.approx(ev[f"stall_{mine}_s"], abs=1e-5)
+        # the dump CLI prints the new fields as it prints any other
+        path = flight.dump("stalls", path=str(tmp_path / "stalls.json"))
+        assert dump_cli.main([path]) == 0
+        printed = capsys.readouterr().out
+        assert "step_stall" in printed and all(f'"{f}"' in printed for f in self.ENDS)
+
+    def test_recover_times_no_subphase(self, tracing_on):
+        fe, eng, _handles = _phase_frontend(seed=46, max_recoveries=2, recovery_backoff=0.0)
+        for _ in range(3):
+            fe.pump()
+        tracing_on.clear()
+        before = dict(eng.stats)
+        with faults.inject(faults.FaultPlan([faults.FaultTrigger("engine.decode", 0)])):
+            fe.pump()  # the dispatch dies in plan, recover() replays every live slot, the retry steps
+        assert eng.stats["recoveries"] == before["recoveries"] + 1
+        names = [s["name"] for s in tracing_on.spans()]
+        assert names.count("engine.recover") == 1
+        # the replay dispatched several times under no phase: one step's sub-phases, no more
+        for kids in SUBPHASE_SPANS.values():
+            for kid in kids:
+                assert names.count(kid) == 1, kid
+        for parent, parts in SUBPHASES.items():
+            grown = sum(eng.stats[f"subphase_s.{p}"] - before[f"subphase_s.{p}"] for p in parts)
+            assert grown == pytest.approx(eng.stats[f"phase_s.{parent}"] - before[f"phase_s.{parent}"], rel=1e-9)
+        assert eng._phase is None and eng._subphase is None

@@ -4,13 +4,18 @@
 Two timings on the machine this runs on, one JSON line:
 
 - ``sequence_us``: the instrumentation of one pump alone, in a loop with no
-  work inside: the eight ``observability.tracing.phase`` blocks
-  (``frontend.pump``, two ``frontend.deliver``, ``engine.decode_step`` and its
-  four children, with the instants they share) and ``close_step`` with its one
-  ``thread_time`` read; beside ``stub_us``, the same loop with a phase that keeps
-  the two clock reads the step needs anyway (its histogram, devprof) and
+  work inside: the thirteen ``observability.tracing.phase`` blocks
+  (``frontend.pump``, two ``frontend.deliver``, ``engine.decode_step``, its
+  four children and the five sub-phases of ``engine.launch`` and
+  ``engine.wait``, with the instants they share) and ``close_step`` with its
+  one ``thread_time`` read; beside ``stub_us``, the same loop with a phase that
+  keeps the two clock reads the step needs anyway (its histogram, devprof) and
   nothing else. The difference is what the primitive adds.
-  ``sequence_profiling_us`` is the first loop again while a profile is taken.
+  ``sequence_no_subphases_us`` is the loop with the eight blocks PR 23 had:
+  ``subphases_added_us_per_step`` is what PR 38's five cost.
+  ``sequence_profiling_us`` is the first loop again while a profile is taken,
+  ``sequence_full_rate_us`` at ``FLAGS_trace_sample_rate=1`` (every phase a
+  span in the ring).
 - ``pump_us`` / ``pump_stub_us``: the median wall of ``ServingFrontend.pump()``
   on a small engine, with the primitive and with the stub in its place, in
   alternating blocks.
@@ -65,8 +70,23 @@ class _Tracing:
         return getattr(tracing, name)
 
 
-def sequence(phase, engine, n=20000):
+def sequence(phase, engine, n=20000, subphases=True):
     stats = engine.stats
+
+    def tiled(names, parent):
+        """Sub-phases as ``engine._next_subphase`` lays them: each starts where
+        the one before ended, the parent ends where the last did."""
+        at = parent.start_s
+        for name, key in names:
+            with phase(name, stats, key, 1, at) as sub:
+                pass
+            at = sub.end_s
+        parent.end_s = at
+
+    launch_subs = (("engine.launch.put", "subphase_s.launch_put"), ("engine.launch.args", "subphase_s.launch_args"),
+                   ("engine.launch.call", "subphase_s.launch_call")) if subphases else ()
+    wait_subs = (("engine.wait.ready", "subphase_s.wait_ready"),
+                 ("engine.wait.fetch", "subphase_s.wait_fetch")) if subphases else ()
     t0 = time.perf_counter()
     for _ in range(n):
         with phase("frontend.pump") as pump:
@@ -76,14 +96,16 @@ def sequence(phase, engine, n=20000):
                 with phase("engine.plan", stats, "phase_s.plan", 1, whole.start_s) as plan:
                     pass
                 with phase("engine.launch", stats, "phase_s.launch", 1, plan.end_s) as launch:
-                    pass
+                    if launch_subs:
+                        tiled(launch_subs, launch)
                 with phase("engine.wait", stats, "phase_s.wait", 1, launch.end_s) as wait:
-                    pass
+                    if wait_subs:
+                        tiled(wait_subs, wait)
                 with phase("engine.commit", stats, "phase_s.commit", 1, wait.end_s) as commit:
                     pass
                 whole.end_s = commit.end_s
             if phase is not StubPhase:
-                engine._open_step = (1e-3, 1e-3, 1e-3, 1e-3)
+                engine._open_step = (1e-3,) * 9
             with phase("frontend.deliver", stats, "phase_s.deliver", None, commit.end_s) as after:
                 if phase is not StubPhase:
                     engine.close_step(1e-4)
@@ -119,10 +141,21 @@ def main():
             fe.pump()
             walls[kind].append(time.perf_counter() - t0)
     engine_module._tracing = frontend_module._tracing = tracing
-    real, stub = sequence(tracing.phase, engine), sequence(StubPhase, engine)
+    # the two real loops in alternating blocks, so that the host's drift lands on both
+    blocks = [(sequence(tracing.phase, engine, 4000), sequence(tracing.phase, engine, 4000, subphases=False))
+              for _ in range(5)]
+    real, bare = (statistics.median(b[i] for b in blocks) for i in (0, 1))
+    stub = sequence(StubPhase, engine)
     traced = _sequence_while_profiling(engine)
+    paddle.set_flags({"FLAGS_trace_sample_rate": 1.0})
+    try:
+        full = sequence(tracing.phase, engine, 4000)
+    finally:
+        paddle.set_flags({"FLAGS_trace_sample_rate": 0.0})
+        tracing.GLOBAL_TRACER.clear()
     print(json.dumps({
         "sequence_us": real, "stub_us": stub, "added_us_per_step": real - stub, "sequence_profiling_us": traced,
+        "sequence_no_subphases_us": bare, "subphases_added_us_per_step": real - bare, "sequence_full_rate_us": full,
         "pump_us": 1e6 * statistics.median(walls["real"]), "pump_stub_us": 1e6 * statistics.median(walls["stub"]),
         "pumps_each": len(walls["real"]), "thread_time_us": 1e6 * _cost(time.thread_time),
         "perf_counter_us": 1e6 * _cost(time.perf_counter),
