@@ -24,7 +24,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["causal_conv_chunk", "gated_group_rms_norm", "split_conv_channels", "ssd_chunk", "ssd_sequence"]
+__all__ = [
+    "causal_conv_chunk", "gated_group_rms_norm", "split_conv_channels", "ssd_chunk", "ssd_chunk_slots", "ssd_sequence",
+]
 
 _STATE_PRECISION = jax.lax.Precision.HIGHEST
 
@@ -57,6 +59,45 @@ def split_conv_channels(xbc: jax.Array, heads: int, head_dim: int, groups: int, 
             xbc[..., inner + gn:].reshape(*lead, groups, state))
 
 
+def _within_chunk(x, dt, a, b, c):
+    """What a chunk computes without the carried state, from float32 ``x``,
+    ``b``, ``c``: ``(y [S, C, H, P]`` of the chunk's own rows, ``cum [S, C, H]``
+    the log of the decay since the chunk began, ``xs [S, C, H, P]`` each row's
+    ``x`` scaled by ``dt`` and its decay to the chunk's end``)``."""
+    n_rows, r = x.shape[1], x.shape[2] // b.shape[2]
+    cum = jnp.cumsum(dt * a.astype(jnp.float32), axis=1)  # [S, C, H], <= 0
+    causal = jnp.tril(jnp.ones((n_rows, n_rows), bool))[None, :, :, None]
+    # exp only of what is kept: above the diagonal cum_t - cum_s is positive and may overflow
+    decay = jnp.exp(jnp.where(causal, cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf))  # [S, t, s, H]
+    cb = jnp.einsum("stgn,sugn->stug", c, b, precision=_STATE_PRECISION)
+    scores = jnp.repeat(cb, r, axis=-1) * decay * dt[:, None, :, :]
+    y = jnp.einsum("stuh,suhp->sthp", scores, x, precision=_STATE_PRECISION)
+    to_end = dt * jnp.exp(cum[:, -1:, :] - cum)  # [S, C, H]
+    return y, cum, x * to_end[..., None]
+
+
+def _carry_xla(c, b, xs, decay, state):
+    """The carried state's part of a chunk as XLA runs it: ``(carried [S, C,
+    H, P]``, what the chunk's rows read of ``state``; the state after the
+    chunk``)`` from ``decay = exp(cum_C) [S, H]``."""
+    s, n_rows, g, n = c.shape
+    h, p = state.shape[1], state.shape[2]
+    r = h // g
+    carried = jnp.einsum("stgn,sgrpn->stgrp", c, state.reshape(s, g, r, p, n),
+                         precision=_STATE_PRECISION).reshape(s, n_rows, h, p)
+    added = jnp.einsum("scgrp,scgn->sgrpn", xs.reshape(s, n_rows, g, r, p), b,
+                       precision=_STATE_PRECISION).reshape(s, h, p, n)
+    return carried, decay[:, :, None, None] * state + added
+
+
+def _chunk(x, dt, a, b, c, d_skip, carry):
+    """One chunk around ``carry(c, b, xs, decay) -> (carried, state after)``."""
+    x, b, c = (t.astype(jnp.float32) for t in (x, b, c))
+    y, cum, xs = _within_chunk(x, dt, a, b, c)
+    carried, state = carry(c, b, xs, jnp.exp(cum[:, -1]))
+    return y + carried * jnp.exp(cum)[..., None] + d_skip.astype(jnp.float32)[:, None] * x, state
+
+
 def ssd_chunk(
     x: jax.Array,  # [S, C, H, P]
     dt: jax.Array,  # [S, C, H] float32, after softplus; 0 on rows that must not advance the state
@@ -68,24 +109,36 @@ def ssd_chunk(
 ) -> Tuple[jax.Array, jax.Array]:
     """One chunk of the recurrence from ``state``: ``(y [S, C, H, P] float32,
     the state after the chunk)``. Head ``h`` reads group ``h // (H / G)``."""
-    s, n_rows, h, p = x.shape
-    g, n = b.shape[2], b.shape[3]
-    r = h // g
-    x, b, c = (t.astype(jnp.float32) for t in (x, b, c))
-    cum = jnp.cumsum(dt * a.astype(jnp.float32), axis=1)  # [S, C, H], <= 0
-    causal = jnp.tril(jnp.ones((n_rows, n_rows), bool))[None, :, :, None]
-    # exp only of what is kept: above the diagonal cum_t - cum_s is positive and may overflow
-    decay = jnp.exp(jnp.where(causal, cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf))  # [S, t, s, H]
-    cb = jnp.einsum("stgn,sugn->stug", c, b, precision=_STATE_PRECISION)
-    scores = jnp.repeat(cb, r, axis=-1) * decay * dt[:, None, :, :]
-    y = jnp.einsum("stuh,suhp->sthp", scores, x, precision=_STATE_PRECISION)
-    grouped = state.reshape(s, g, r, p, n)
-    carried = jnp.einsum("stgn,sgrpn->stgrp", c, grouped, precision=_STATE_PRECISION).reshape(s, n_rows, h, p)
-    y = y + carried * jnp.exp(cum)[..., None] + d_skip.astype(jnp.float32)[:, None] * x
-    to_end = dt * jnp.exp(cum[:, -1:, :] - cum)  # [S, C, H]
-    added = jnp.einsum("scgrp,scgn->sgrpn", (x * to_end[..., None]).reshape(s, n_rows, g, r, p), b,
-                       precision=_STATE_PRECISION).reshape(s, h, p, n)
-    return y, jnp.exp(cum[:, -1])[:, :, None, None] * state + added
+    return _chunk(x, dt, a, b, c, d_skip, lambda *rows: _carry_xla(*rows, state))
+
+
+def ssd_chunk_slots(
+    x: jax.Array,  # [S, C, H, P]
+    dt: jax.Array,  # [S, C, H] float32; 0 on rows that must not advance the state
+    a: jax.Array,
+    b: jax.Array,  # [S, C, G, N]
+    c: jax.Array,
+    d_skip: jax.Array,
+    plane: jax.Array,  # [S, H, P, N] float32: every slot's state, the caller's to give away
+    live: jax.Array,  # [S] bool: the slot has rows whose dt is not 0
+    fresh: jax.Array,  # [S] bool: the slot starts from ZERO state, whatever the plane holds
+) -> Tuple[jax.Array, jax.Array]:
+    """:func:`ssd_chunk` over a PLANE of per-slot states that outlives the
+    call (the serving step's, donated): on a TPU one Pallas kernel
+    (``kernels/ssm_scan.py``) reads each slot's tile once, contracts it with
+    ``c``, decays it, adds the chunk's rows and writes it back in place, and
+    moves nothing for a slot that is not ``live``; elsewhere, and as the
+    kernel's reference, the XLA composition."""
+    from paddle_tpu.kernels.select import pallas_enabled, warn_fallback
+
+    if pallas_enabled("use_pallas_fused", bare="ssm_state_scan"):
+        from paddle_tpu.kernels.ssm_scan import ssm_state_scan
+
+        try:
+            return _chunk(x, dt, a, b, c, d_skip, lambda *rows: ssm_state_scan(*rows, plane, live, fresh))
+        except Exception as exc:  # noqa: BLE001 - XLA composition below
+            warn_fallback("ssm_state_scan", exc)
+    return ssd_chunk(x, dt, a, b, c, d_skip, jnp.where(fresh[:, None, None, None], 0.0, plane))
 
 
 def ssd_sequence(

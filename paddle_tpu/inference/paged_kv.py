@@ -52,7 +52,7 @@ from paddle_tpu.incubate.nn.functional.block_attention import (
     block_multihead_chunk_attention,
     latent_chunk_attention,
 )
-from paddle_tpu.incubate.nn.functional.mamba2 import causal_conv_chunk, split_conv_channels, ssd_chunk
+from paddle_tpu.incubate.nn.functional.mamba2 import causal_conv_chunk, split_conv_channels, ssd_chunk_slots
 
 __all__ = ["CacheSet", "LatentKV", "PAGED", "PagedBatch", "PagedKV", "RECURRENT", "RecurrentState"]
 
@@ -262,12 +262,11 @@ class RecurrentState:
         heads, p, n = self.ssm.shape[1:]
         q = jnp.where(bt.slot_mask, bt.q_lens, 0)
         fresh = (q > 0) & (bt.seq_lens == 0)
-        ssm = jnp.where(fresh[:, None, None, None], 0.0, self.ssm)
         tail = jnp.where(fresh[:, None, None], 0, self.conv)
         with jax.named_scope(SCOPE_SSM_CONV):
             xbc, tail = causal_conv_chunk(xbc, tail, conv_weight, conv_bias, q)
         x, b, cc = split_conv_channels(xbc, heads, p, groups, n)
         valid = jnp.arange(xbc.shape[1], dtype=q.dtype)[None, :] < q[:, None]
         with jax.named_scope(SCOPE_SSM_SCAN):
-            y, ssm = ssd_chunk(x, jnp.where(valid[..., None], dt, 0.0), a, b, cc, d_skip, ssm)
+            y, ssm = ssd_chunk_slots(x, jnp.where(valid[..., None], dt, 0.0), a, b, cc, d_skip, self.ssm, q > 0, fresh)
         return y, RecurrentState(ssm, tail, batch=bt)

@@ -45,6 +45,31 @@ def _seed():
     _mesh._global_mesh = None
 
 
+@pytest.fixture
+def scan_kernel_route(monkeypatch):
+    """``RecurrentState.advance`` on the route it takes on a TPU: the dispatch
+    of ``mamba2.ssd_chunk_slots`` is told yes for the state-space scan's
+    kernel (and for no other kernel), and ``kernels/ssm_scan.py`` runs in
+    Pallas' interpreter. Yields the list of state-plane shapes the kernel was
+    traced with; the route has to have been taken, and without a fallback."""
+    import functools
+
+    from paddle_tpu.kernels import select, ssm_scan
+
+    traced, scan = [], ssm_scan.ssm_state_scan
+
+    def interpreted(*args, **kwargs):
+        traced.append(tuple(args[4].shape))
+        return scan(*args, interpret=True, **kwargs)
+
+    monkeypatch.setattr(ssm_scan, "ssm_state_scan", functools.wraps(scan)(interpreted))
+    monkeypatch.setattr(select, "pallas_enabled", lambda flag, bare=None, row_wise=False: bare == ssm_scan.KERNEL_SCAN)
+    before = dict(select.fallback_counts())
+    yield traced
+    assert traced, "the kernel route was not taken"
+    assert dict(select.fallback_counts()) == before  # and it did not degrade to the XLA composition
+
+
 def assert_engine_pool_exact(eng):
     """The engine pool-accounting churn invariant, shared by every engine
     suite (engine / spec-decode / prefix-cache / tp): refcount truth —

@@ -137,6 +137,15 @@ def _latent_chunk():
     return fn, (((SLOTS, CHUNK, HEADS, 2 * D), BF16), ((NB, 1, BS, 2 * D), BF16), _TABLES, _LENS, _LENS)
 
 
+def _ssm_scan():
+    from paddle_tpu.kernels.ssm_scan import ssm_state_scan
+
+    s, h, p, n, g = SLOTS, 4, 64, 128, 2
+    rows = ((s, CHUNK, g, n), F32)
+    return ssm_state_scan, (rows, rows, ((s, CHUNK, h, p), F32), ((s, h), F32), ((s, h, p, n), F32),
+                            ((s,), jnp.bool_), ((s,), jnp.bool_))
+
+
 # kernel name -> the entry whose lowering has to hold it (one pallas_call site
 # each; the rope runner is one site that two kernels share)
 SITES = {
@@ -145,6 +154,7 @@ SITES = {
     "fused_loss_fwd_quant": _fused_loss_quant,
     "paged_attention_chunk": _paged_chunk, "paged_attention_chunk_fused": _paged_chunk_fused,
     "paged_latent_attention_chunk": _latent_chunk,
+    "ssm_state_scan": _ssm_scan,
     "rms_norm_fwd": _rms, "rms_norm_bwd": _rms,
     "rope_fwd": _rope, "rope_adjoint": _rope,
     "rms_norm_residual_fwd": _rms_residual, "rms_norm_residual_adjoint": _rms_residual,
@@ -172,25 +182,26 @@ def test_lowered_kernel_carries_its_name(name):
 
 
 def test_every_pallas_call_site_passes_a_name_constant():
-    """The 20 sites, read from the source (the paged chunk kernel, plain and
+    """The 21 sites, read from the source (the paged chunk kernel, plain and
     rope-fused, is one; the latent walk, PR 36, is the eighteenth; the loss
     head's dX and dW are two each since PR 37, storing ``d`` or recomputing
-    it, under the same two names): each ``pl.pallas_call(`` has a ``name=``
+    it, under the same two names; the state-space scan's carried-state
+    kernel, PR 39, is the twenty-first): each ``pl.pallas_call(`` has a ``name=``
     keyword, and every name is one of the constants above."""
     import ast
     import inspect
 
-    from paddle_tpu.kernels import flash_attention, fused, fused_loss, paged_attention, quant
+    from paddle_tpu.kernels import flash_attention, fused, fused_loss, paged_attention, quant, ssm_scan
 
     sites, constants = 0, set()
-    for module in (flash_attention, fused, fused_loss, paged_attention, quant):
+    for module in (flash_attention, fused, fused_loss, paged_attention, quant, ssm_scan):
         tree = ast.parse(inspect.getsource(module))
         for node in ast.walk(tree):
             if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute) and node.func.attr == "pallas_call":
                 sites += 1
                 assert any(kw.arg == "name" for kw in node.keywords), f"{module.__name__}:{node.lineno}"
         constants |= {v for k, v in vars(module).items() if k.startswith("KERNEL_")}
-    assert sites == 20
+    assert sites == 21
     assert constants == set(SITES)
     assert all(re.fullmatch(r"[a-z][a-z0-9_]*", c) for c in constants)  # no shapes, trace-safe
 

@@ -311,6 +311,23 @@ def test_prefill_in_chunks_then_decode_is_the_references_full_forward(model):
     assert close(eng.step_logits(prompts[0]), ref_logits(model, prompts[0][:16]))
 
 
+def test_the_scan_kernels_route_serves_the_references_tokens(model, scan_kernel_route):
+    """The same five requests over three slots with ``RecurrentState.advance``
+    on the route it takes on a TPU (``kernels/ssm_scan.py``, here in the
+    interpreter; every other dispatch stays where the CPU puts it): slots are
+    admitted at different steps, reused after a request ends (a first chunk
+    over whatever the last request left) and idle in between, and every served
+    token is still the reference's own argmax at its position."""
+    eng = engine(model)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(1, VOCAB, n).astype(np.int32) for n in (37, 5, 21, 50, 18)]
+    ids = [eng.add_request(p, max_new_tokens=g) for p, g in zip(prompts, (6, 9, 4, 5, 7))]
+    out = eng.run()
+    assert len(scan_kernel_route) == 3 and eng.stats["step_traces"] == 1  # one kernel an M block of the ONE traced step
+    for rid, prompt in zip(ids, prompts):
+        assert served_gap(model, prompt, out[rid].generated).max() == 0.0
+
+
 def test_a_share_of_the_experts_serves_the_references_share():
     """16 experts scored, experts 4..7 held: program and reference leave out the same part."""
     model = build(pattern="MEM*", n_routed_experts=4, n_routed_experts_total=16, first_expert=4)

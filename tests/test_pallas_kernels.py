@@ -402,3 +402,52 @@ class TestFusedKernels:
         np.testing.assert_allclose(
             np.asarray(gp[2]).reshape(s, d), np.asarray(gr[2]), rtol=1e-4, atol=1e-4
         )
+
+
+class TestStateScanKernelStructure:
+    """What keeps ``benchmarks/metrics/ssm_pct.serve.py`` counting the scan's
+    carried-state kernel: the metric is the device time of operations whose
+    scope path holds ``ssm_mixer``, so a ``pallas_call`` that left the scope
+    (or changed its event name) would make the metric fall for no reason."""
+
+    def _kernel_scopes(self, monkeypatch):
+        """``{kernel name: [scope path of each of its pallas_calls]}`` of a small hybrid engine's step."""
+        from paddle_tpu.inference import ContinuousBatchingEngine
+        from paddle_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
+
+        config = NemotronHConfig(  # a state-space block of shapes the kernel takes: two heads of 64 a group, state 128
+            vocab_size=128, hidden_size=64, num_hidden_layers=2, hybrid_override_pattern="ME", mamba_num_heads=4,
+            mamba_head_dim=64, n_groups=2, ssm_state_size=128, n_routed_experts=4, num_experts_per_tok=2,
+            moe_intermediate_size=32, moe_shared_expert_intermediate_size=32, max_position_embeddings=64,
+            dtype="float32",
+        )
+        model = NemotronHForCausalLM(config)
+        model.eval()
+        eng = ContinuousBatchingEngine(model, max_slots=2, block_size=16, prompt_bucket=32, max_model_len=64)
+        s, c = eng.max_slots, eng.prefill_chunk
+        zeros = jnp.zeros((s,), jnp.int32)
+        args = (eng._param_arrays(), eng._caches + eng._states, jnp.zeros((s, c), jnp.int32),
+                jnp.zeros((s, eng.max_blocks_per_seq), jnp.int32), zeros, zeros, jnp.zeros((s,), bool), zeros, zeros)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the dispatch takes its Pallas branch
+        found = {}
+
+        def walk(jaxpr, prefix):
+            for eqn in jaxpr.eqns:
+                path = "/".join(filter(None, (prefix, str(eqn.source_info.name_stack))))
+                if eqn.primitive.name == "pallas_call":
+                    found.setdefault(eqn.params["name"], []).append(path)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, path)  # a nested jit's scopes are relative to its call
+
+        walk(jax.make_jaxpr(eng._step_impl)(*args).jaxpr, "")
+        return found
+
+    def test_the_kernel_sits_under_ssm_scan_inside_ssm_mixer(self, monkeypatch):
+        from paddle_tpu.inference.paged_kv import SCOPE_SSM_SCAN
+        from paddle_tpu.kernels.ssm_scan import KERNEL_SCAN
+        from paddle_tpu.models.nemotron_h import SCOPE_SSM
+
+        assert (KERNEL_SCAN, SCOPE_SSM, SCOPE_SSM_SCAN) == ("ssm_state_scan", "ssm_mixer", "ssm_scan")
+        scopes = self._kernel_scopes(monkeypatch)
+        assert len(scopes[KERNEL_SCAN]) == 1  # one M block, one kernel
+        assert scopes[KERNEL_SCAN][0].split("/")[:2] == [SCOPE_SSM, SCOPE_SSM_SCAN], scopes

@@ -15,6 +15,8 @@ The topology is described inside a fixture (never at import, in a
 TPU library, and under xdist every worker imports every test file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -383,3 +385,71 @@ def test_the_latent_cells_step_compiles_at_published_widths(one_chip, monkeypatc
     memory = compiled.memory_analysis()
     pool = 2 * 16 * 528 * 16 * 640 * 2
     assert memory.temp_size_in_bytes < pool, memory  # no second copy of the pool among the temporaries
+
+
+# --- the state-space scan's carried-state kernel (kernels/ssm_scan.py) -----------------------------------------
+
+SSM_SLOTS, SSM_HEADS, SSM_HEAD_DIM, SSM_STATE, SSM_GROUPS = 32, 64, 64, 128, 8  # nemotron3nano.serve_chat
+
+
+@pytest.mark.parametrize("rows", [16, 1])
+def test_ssm_state_scan_compiles_at_the_hybrid_cells_widths(rows, one_chip):
+    """The kernel at the hybrid cell's shapes (32 slots of 64 heads x [64, 128]
+    float32), a chunk of 16 rows and a single decode row (``generate_paged``),
+    with the plane donated: the compiled program aliases the whole plane into
+    its result and holds no temporary of that size."""
+    from paddle_tpu.kernels.ssm_scan import KERNEL_SCAN, ssm_state_scan
+
+    s, h, p, n, g = SSM_SLOTS, SSM_HEADS, SSM_HEAD_DIM, SSM_STATE, SSM_GROUPS
+    shapes = (((s, rows, g, n), F32), ((s, rows, g, n), F32), ((s, rows, h, p), F32), ((s, h), F32),
+              ((s, h, p, n), F32), ((s,), jnp.bool_), ((s,), jnp.bool_))
+    args = [jax.ShapeDtypeStruct(shape, d, sharding=one_chip) for shape, d in shapes]
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(ssm_state_scan, donate_argnums=(4,)).lower(*args).compile()
+    assert compiled.as_text().count(KERNEL_SCAN) and "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    plane = s * h * p * n * 4
+    assert memory.alias_size_in_bytes == plane and memory.temp_size_in_bytes < plane // 8, memory
+
+
+def test_the_hybrid_step_updates_every_state_plane_in_place(one_chip, monkeypatch):
+    """The engine's ONE step program for a small ``NemotronHForCausalLM`` whose
+    state-space blocks have shapes the kernel takes (two heads of 64 a group,
+    state 128), lowered for the described chip with the dispatch on its Pallas
+    branch and the caches donated as the engine donates them on a TPU: the scan
+    kernel is in it once an ``M`` block, every plane the engine keeps (KV pages,
+    state, conv tail) is aliased into the result. (At this size the compiler
+    stages a 1 MB plane through fast memory around the kernel; that a plane of
+    the cell's size is handed over as it is, with no temporary, is the test above.)"""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.kernels.ssm_scan import KERNEL_SCAN
+    from paddle_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
+
+    paddle.seed(0)
+    config = NemotronHConfig(
+        vocab_size=512, hidden_size=256, num_hidden_layers=4, hybrid_override_pattern="M*EM",
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128, mamba_num_heads=4, mamba_head_dim=64,
+        n_groups=2, ssm_state_size=128, n_routed_experts=8, num_experts_per_tok=2, moe_intermediate_size=128,
+        moe_shared_expert_intermediate_size=128, max_position_embeddings=256,
+    )
+    model = NemotronHForCausalLM(config)
+    model.eval()
+    eng = ContinuousBatchingEngine(model, max_slots=8, block_size=16, prompt_bucket=128, max_model_len=256,
+                                   prefill_chunk=16)
+    s, c, mbs = eng.max_slots, eng.prefill_chunk, eng.max_blocks_per_seq
+    caches = eng._caches + eng._states  # the step's flat argument: the paged sets, then the recurrent ones
+    assert [tuple(planes[0].shape) for planes in eng._states] == [(8, 4, 64, 128)] * 2
+    args = (eng._param_arrays(), caches, jnp.zeros((s, c), I32), jnp.zeros((s, mbs), I32), jnp.zeros((s,), I32),
+            jnp.ones((s,), I32), jnp.ones((s,), bool), jnp.zeros((s,), I32), jnp.full((s,), eng.num_blocks, I32))
+    shaped = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), args)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the dispatch takes its Pallas branch
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(eng._step_impl, donate_argnums=(1,)).lower(*shaped).compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(rf"%({KERNEL_SCAN}[.\d]*) = ", text))) == 2, "one kernel an M block"
+    # the name a device trace gives the kernel's events: what ``ssm_pct.serve`` finds ``ssm_mixer`` in
+    assert f'op_name="jit(_step_impl)/ssm_mixer/ssm_scan/jit({KERNEL_SCAN})/{KERNEL_SCAN}/pallas_call"' in text
+    kept = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == kept, (memory, kept)
