@@ -453,6 +453,21 @@ def _cow_copy_planes(planes: Tuple[jax.Array, ...], src: jax.Array, dst: jax.Arr
     return jax.lax.cond(jnp.any(dst < nb), _copy, lambda planes: planes, planes)
 
 
+@jax.named_scope(SCOPE_KV_COW)
+def _fork_pages(planes: Tuple[jax.Array, ...], src: jax.Array, dst: jax.Array) -> Tuple[jax.Array, ...]:
+    """:func:`_cow_copy_planes` with no ``lax.cond``: the gather and the
+    scatter run every step, and a step in which no slot forks (``dst == NB``
+    everywhere) drops every row. For a set whose planes the step's caller
+    donates and whose append keeps the plane's layout: a conditional takes a
+    copy of the whole donated plane as each branch's operand before it reads
+    its predicate (two a set a step, whatever the slots do), this updates the
+    plane where it lies."""
+    nb = planes[0].shape[0]
+    dst = jnp.asarray(dst, jnp.int32)
+    csrc = jnp.clip(jnp.asarray(src, jnp.int32), 0, nb - 1)
+    return tuple(p.at[dst].set(p[csrc], mode="drop") for p in planes)
+
+
 @jax.named_scope(SCOPE_KV_WRITE)
 def block_cache_append_chunk(
     key_cache: jax.Array,  # [NB, H, BS, D]

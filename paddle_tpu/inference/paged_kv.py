@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.incubate.nn.functional.block_attention import (
-    _cow_copy_planes,
+    _fork_pages,
     block_cache_cow_copy,
     block_multihead_chunk_attention,
     latent_chunk_attention,
@@ -185,8 +185,10 @@ class LatentKV:
         return (self.rows,)
 
     def fork(self, src: jax.Array, dst: jax.Array) -> "LatentKV":
-        """Copy-on-write, as :meth:`PagedKV.fork`: pages ``src`` duplicated into ``dst``."""
-        return LatentKV(*_cow_copy_planes(self.planes, src, dst), batch=self.batch)
+        """Copy-on-write, as :meth:`PagedKV.fork`: pages ``src`` duplicated into
+        ``dst``, with no conditional around the copy (the append after it keeps
+        the plane's layout, so the donated plane is updated where it lies)."""
+        return LatentKV(*_fork_pages(self.planes, src, dst), batch=self.batch)
 
     def attend(
         self,

@@ -24,7 +24,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.core.tensor import Tensor
-from paddle_tpu.incubate.nn.functional.block_attention import _gather_latent_attend
+from paddle_tpu.incubate.nn.functional.block_attention import _cow_copy_planes, _gather_latent_attend
 from paddle_tpu.incubate.nn.functional.fused_moe import (
     collect_expert_counts,
     route_softmax_group_limited,
@@ -341,6 +341,29 @@ def test_fork_copies_pages_and_drops_no_fork():
     assert bool(jnp.array_equal(forked.rows[jnp.asarray(untouched)], kv.rows[jnp.asarray(untouched)]))
     same = kv.fork(jnp.zeros((3,), jnp.int32), jnp.full((3,), NB))
     assert bool(jnp.array_equal(same.rows, kv.rows))
+
+
+def test_fork_lowers_with_no_conditional_and_is_the_conditional_forks_copy():
+    """The latent fork is ONE unconditional gather and scatter (a ``lax.cond`` over a donated plane costs
+    a copy of the plane a branch, every step); a call in which some slots fork and the others carry
+    ``dst == NB`` gives, page for page, what ``PagedKV``'s conditional fork gives for the same arguments."""
+    kv = latent_set()
+    fork = jax.jit(lambda kv, src, dst: kv.fork(src, dst))
+    src, dst = jnp.asarray([3, 0, 7], jnp.int32), jnp.asarray([5, NB, 11], jnp.int32)
+    lowered = fork.lower(kv, src, dst)
+    text = lowered.as_text()
+    assert "stablehlo.scatter" in text and "kv_cow" in lowered.as_text(debug_info=True)
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    conditional = jax.jit(_cow_copy_planes)
+    assert "stablehlo.case" in conditional.lower(kv.planes, src, dst).as_text()  # the form it is held against
+    for d in (dst, jnp.full((3,), NB, jnp.int32)):
+        forked = fork(kv, src, d)
+        assert type(forked) is LatentKV and forked.batch is not None
+        assert bool(jnp.array_equal(forked.rows, conditional(kv.planes, src, d)[0]))
+    forked = fork(kv, src, dst)
+    assert bool(jnp.array_equal(forked.rows[jnp.asarray([5, 11])], kv.rows[jnp.asarray([3, 7])]))
+    untouched = jnp.asarray([b for b in range(NB) if b not in (5, 11)])
+    assert bool(jnp.array_equal(forked.rows[untouched], kv.rows[untouched]))
 
 
 def test_attend_appends_the_rows_and_is_dense_attention_over_them():

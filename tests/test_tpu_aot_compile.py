@@ -356,8 +356,11 @@ def test_the_latent_cells_step_compiles_at_published_widths(one_chip, monkeypatc
     published widths (the leading dense layer and one expert layer holding 8 of
     160 experts, an eighth of the vocabulary; the document cell's slots, chunk,
     pages and pool), lowered for the described chip with the dispatch on its
-    Pallas branch: the latent walk is in it once a layer, beside the norm
-    kernels, and no page of the plane is copied as a pool-sized temporary."""
+    Pallas branch and the caches donated as the engine donates them on a TPU:
+    the latent walk is in it once a layer, beside the norm kernels, every plane
+    is aliased into the result, and NO operation copies a plane (the fork, the
+    append and the walk all work on the donated plane where it lies; a
+    ``lax.cond`` around the fork cost two such copies a layer a step, PR 42)."""
     import paddle_tpu as paddle
     from paddle_tpu.inference import ContinuousBatchingEngine
     from paddle_tpu.kernels.paged_attention import KERNEL_LATENT
@@ -379,12 +382,14 @@ def test_the_latent_cells_step_compiles_at_published_widths(one_chip, monkeypatc
     shaped = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), args)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the dispatch takes its Pallas branch
     with jax.default_matmul_precision("default"):
-        compiled = jax.jit(eng._step_impl).lower(*shaped).compile()
+        compiled = jax.jit(eng._step_impl, donate_argnums=(1,)).lower(*shaped).compile()
     text = compiled.as_text()
     assert text.count(KERNEL_LATENT) >= 2 and text.count("tpu_custom_call") >= 2 + 4 + 4 + 1  # walks, norms, embed
+    plane_copies = re.findall(r"= bf16\[8448,(?:1,)?16,640\]\S* copy\(", text)
+    assert not plane_copies, plane_copies
     memory = compiled.memory_analysis()
-    pool = 2 * 16 * 528 * 16 * 640 * 2
-    assert memory.temp_size_in_bytes < pool, memory  # no second copy of the pool among the temporaries
+    plane = 16 * 528 * 16 * 640 * 2
+    assert memory.alias_size_in_bytes == 2 * plane and memory.temp_size_in_bytes < plane, memory
 
 
 # --- the state-space scan's carried-state kernel (kernels/ssm_scan.py) -----------------------------------------
